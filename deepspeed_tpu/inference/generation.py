@@ -861,7 +861,8 @@ def generate(params, config, prompt_ids, max_new_tokens, temperature=0.0,
     probe result) and ``kernel_interpret`` forces interpret mode (None =
     auto: interpret everywhere but real TPU); both resolve through
     `kernels.get_registry()` and become jit statics — a failed probe
-    degrades to the XLA fallback, never a crash."""
+    raises ``KernelProbeError`` on a TPU backend and degrades to the
+    XLA twin elsewhere."""
     if temperature < 0.0:
         raise ValueError(f"temperature must be >= 0, got {temperature}")
     if temperature != 0.0 and rng is None:

@@ -81,15 +81,16 @@ class ServingConfig:
     # bucket ladder.
     attention_impl: object = None
     # Kernel-tier implementation for the "pallas_decode"/"pallas_sparse"
-    # attention backends: None (the registry's execution-probe result —
-    # Pallas where it runs, the composed-XLA fallback otherwise),
-    # "pallas" (prefer the fused kernels; still degrades with a
-    # telemetry instant if the probe failed), or "xla" (force the
-    # fallback — the parity-oracle side of every kernel test).
+    # attention backends: None or "pallas" (the fused kernels; a failed
+    # execution probe raises KernelProbeError on a TPU backend and
+    # degrades to the XLA twin with a telemetry instant elsewhere), or
+    # "xla" (run the composed-XLA twin on purpose — the parity-oracle
+    # side of every kernel test).
     attention_kernel: str = None
     # Pallas interpret mode: None = auto (interpret everywhere but a
     # real TPU backend, so CPU CI executes the same kernel bodies
-    # eagerly), True/False to force. Static in every jitted program.
+    # eagerly), True/False to force; True is refused on a TPU backend.
+    # Static in every jitted program.
     kernel_interpret: object = None
     # Tokens per KV page. None = 128 (clamped/adjusted to divide
     # max_seq_len — see resolve_page_tokens). Smaller pages = finer

@@ -93,9 +93,10 @@ Kernel-tier backends (``pallas_decode`` / ``pallas_sparse``): the same
 dispatch seam routed through ``deepspeed_tpu/kernels`` — hand-fused
 Pallas attention resolved ONCE at engine construction through the
 op_builder-style ``KernelRegistry`` (``serving.attention_kernel`` can
-force "pallas"/"xla"; None takes the probe result, degrading to the
-composed-XLA fallback with an edge-triggered ``jax/kernel_fallback``
-instant instead of crashing). ``pallas_decode`` lanes decode through
+force "pallas"/"xla"; None takes the probe result: on a TPU a failed
+probe raises ``KernelProbeError``, off-TPU it degrades to the
+composed-XLA twin with an edge-triggered ``jax/kernel_fallback``
+instant). ``pallas_decode`` lanes decode through
 ``_decode_step_kernel_jit``: the fused paged kernel consumes the pool's
 STORAGE-dtype pages directly through the lane page tables (int8 scales
 fused into the matmul — no dequantized gather copy), so the paged
@@ -1041,9 +1042,9 @@ class ServingEngine:
                 f"{self.max_seq_len} < {(SPARSE_BAND + 1) * page_tokens} "
                 f"(kv_page_tokens={page_tokens})")
         # kernel-tier backends: resolve the (impl, interpret) statics ONCE
-        # here, through the registry's availability probe — a failed probe
-        # degrades the whole engine to the XLA fallback math (same oracle)
-        # instead of crashing construction or, worse, the serving loop.
+        # here, through the registry's availability probe — on a TPU a
+        # failed probe fails construction (KernelProbeError); off-TPU it
+        # degrades the whole engine to the XLA twin (same oracle).
         kernel_backends = sorted(impls & set(kernels.KERNEL_BACKENDS))
         if cfg.attention_kernel is not None and not kernel_backends:
             raise ValueError(
@@ -1088,6 +1089,19 @@ class ServingEngine:
                     f"serving.mesh_shape model axis {mp} must divide "
                     f"num_attention_heads={self.n_heads} (the KV pool "
                     f"shards heads)")
+            native = [be for be in kernel_backends
+                      if self._kernel_impl[be] == "pallas"
+                      and not self._kernel_interpret[be]]
+            if mp > 1 and native:
+                # GSPMD cannot partition a Mosaic call, and these programs
+                # do not shard_map their kernels over the model axis yet:
+                # say so here, not from inside the first prefill
+                raise NotImplementedError(
+                    f"serving.attention_impl {native} compiles Pallas "
+                    f"kernels natively, which a tensor-parallel mesh "
+                    f"(model axis {mp}) cannot partition yet; use a "
+                    f"dense/flash/sparse_xla backend or "
+                    f"attention_kernel='xla' on this mesh")
             self.params = self.registry.shard(self.mesh, params)
             self._replicated_sharding = serving_sharding(
                 self.mesh, "serving/lane_state", registry=self.registry)

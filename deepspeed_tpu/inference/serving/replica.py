@@ -616,7 +616,9 @@ def replica_main(argv=None):
     line to stdout once listening (the launcher/bench reads it), then
     serves until SIGTERM -> drain -> ``EXIT_PREEMPTED``."""
     from deepspeed_tpu.launcher.supervisor import EXIT_CLEAN, EXIT_PREEMPTED
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="serving-fleet replica")
     parser.add_argument("--config",
                         default=os.environ.get(REPLICA_CONFIG_ENV))

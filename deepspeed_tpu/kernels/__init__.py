@@ -7,14 +7,17 @@ Pallas implementation AND the repo's existing composed-XLA
 implementation as its fallback/parity oracle, and a ``KernelRegistry``
 probes availability by *executing* a tiny instance at first use:
 
-- TPU backend        -> native Pallas (real custom calls)
+- TPU backend        -> native Pallas (real custom calls), never the
+                        interpreter. A failed probe RAISES
+                        ``KernelProbeError`` with the compiler's
+                        message unless ``attention_kernel="xla"``
+                        asked for the twin on purpose.
 - CPU / CI           -> Pallas interpret mode (same kernel body,
                         executed eagerly — what the parity suite pins
                         bitwise against the XLA fallback)
-- probe failure      -> the XLA fallback, plus ONE edge-triggered
-                        ``jax/kernel_fallback`` telemetry instant and a
-                        ``Kernels/fallbacks_total`` counter — never a
-                        crash.
+- probe failure off  -> the XLA fallback, plus ONE edge-triggered
+  the TPU               ``jax/kernel_fallback`` telemetry instant and a
+                        ``Kernels/fallbacks_total`` counter.
 
 Kernels registered here:
 
@@ -64,9 +67,10 @@ def resolve(attn_impl, requested=None, interpret=None):
     """Resolve the (kernel_impl, kernel_interpret) static pair for a
     kernel-tier backend name: ``requested`` forces "pallas"/"xla"
     (None = the probe result), ``interpret`` forces interpret mode
-    (None = auto: interpret everywhere but on a real TPU backend).
-    A forced-but-unavailable "pallas" degrades to "xla" with the
-    edge-triggered fallback instant — never a crash."""
+    (None = auto: interpret everywhere but on a real TPU backend, where
+    it is refused). An unavailable "pallas" raises ``KernelProbeError``
+    on a TPU backend and degrades to "xla" with the edge-triggered
+    fallback instant elsewhere."""
     name = kernel_for_backend(attn_impl)
     if name is None:
         return None, False

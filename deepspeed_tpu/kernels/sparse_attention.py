@@ -19,23 +19,19 @@ independence makes results bitwise invariant to batching/chunking
 
 import functools
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
+
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.inference.generation import (
     _window_base,
     _window_slice_one,
 )
-from deepspeed_tpu.kernels.registry import KernelProbeError
-
-try:  # pallas ships with jax here, but the tier must import without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_IMPORT_ERROR = None
-except Exception as _e:  # pragma: no cover - environment-dependent
-    pl = None
-    pltpu = None
-    _PALLAS_IMPORT_ERROR = _e
+from deepspeed_tpu.kernels.registry import assert_probe_parity
 
 
 def _band_math(q, k_win, v_win, k_sink, v_sink, win_valid, sink_valid,
@@ -45,22 +41,36 @@ def _band_math(q, k_win, v_win, k_sink, v_sink, win_valid, sink_valid,
     from 2D iota, the fallback from arange; the VALUES are identical so
     the shared body keeps the two bitwise-equal).
 
-    q [nh, hd]; k_win/v_win [nh, W, hd]; k_sink/v_sink [nh, pt, hd];
+    The query carries a unit row axis so both contractions are batched
+    matmuls with the head batch dimension LEADING on both operands — the
+    only batched form Mosaic lowers (a rank-2 ``nd,nwd->nw`` has none).
+
+    q [nh, 1, hd]; k_win/v_win [nh, W, hd]; k_sink/v_sink [nh, pt, hd];
     win_valid [1, W] bool (window key pos <= query pos); sink_valid
     [1, pt] bool (sink key pos < window base). Masked -1e30 scores
-    underflow to exact-zero probability under the fp32 softmax."""
-    hd = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype))
-    s_win = jnp.einsum("nd,nwd->nw", q, k_win) * scale           # [nh, W]
-    s_win = jnp.where(win_valid, s_win, jnp.asarray(-1e30, s_win.dtype))
-    s_sink = jnp.einsum("nd,nsd->ns", q, k_sink) * scale         # [nh, pt]
-    s_sink = jnp.where(sink_valid, s_sink, jnp.asarray(-1e30, s_sink.dtype))
+    underflow to exact-zero probability under the fp32 softmax.
+    Returns [nh, 1, hd]."""
+    # host constant: Mosaic cannot legalize a bf16 sqrt in the kernel body
+    dt = np.dtype(dtype)
+    scale = np.ones((), dt) / np.sqrt(np.asarray(q.shape[-1], dt))
+    # f32 MXU accumulator (Mosaic rejects a bf16 one), rounded once to the
+    # compute dtype `_attend_window_one` scores in
+    f32 = dict(preferred_element_type=jnp.float32)
+    s_win = jnp.einsum("nqd,nwd->nqw", q, k_win,
+                       **f32).astype(dtype) * scale              # [nh,1,W]
+    s_win = jnp.where(win_valid[None], s_win,
+                      jnp.asarray(-1e30, s_win.dtype))
+    s_sink = jnp.einsum("nqd,nsd->nqs", q, k_sink,
+                        **f32).astype(dtype) * scale             # [nh,1,pt]
+    s_sink = jnp.where(sink_valid[None], s_sink,
+                       jnp.asarray(-1e30, s_sink.dtype))
     s = jnp.concatenate([s_sink, s_win], axis=-1).astype(jnp.float32)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(dtype)
     v_all = jnp.concatenate([v_sink, v_win], axis=-2)            # [nh,pt+W,hd]
-    return jnp.einsum("ns,nsd->nd", probs, v_all)                # [nh, hd]
+    return jnp.einsum("nqs,nsd->nqd", probs, v_all,
+                      **f32).astype(dtype)                       # [nh,1,hd]
 
 
 # -- Pallas implementation ----------------------------------------------------
@@ -74,43 +84,41 @@ def _make_kernel(W, pt, dtype):
         # TPU needs >=2D iota; [1, W]/[1, pt] broadcast over heads
         kpos_w = base + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
         kpos_s = jax.lax.broadcasted_iota(jnp.int32, (1, pt), 1)
-        out_ref[...] = _band_math(
-            q_ref[...][0], kw_ref[...][0], vw_ref[...][0],
-            ks_ref[...][0], vs_ref[...][0],
-            kpos_w <= pos, kpos_s < base, dtype)[None]
+        out_ref[0] = _band_math(
+            q_ref[0], kw_ref[0], vw_ref[0], ks_ref[0], vs_ref[0],
+            kpos_w <= pos, kpos_s < base, dtype)
 
     return body
 
 
 def _band_attend_pallas(q, k_win, v_win, k_sink, v_sink, pos, base, dtype,
                         interpret):
-    if pl is None:  # pragma: no cover - environment-dependent
-        raise KernelProbeError(
-            f"pallas unavailable: {_PALLAS_IMPORT_ERROR}")
-    N, nh, hd = q.shape
+    """q [N, nh, 1, hd] -> [N, nh, 1, hd]."""
+    N, nh, _, hd = q.shape
     W = k_win.shape[2]
     pt = k_sink.shape[2]
 
     def row(i, pos_, base_):
-        return (i, 0, 0)
+        return (i, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((1, nh, hd), row),
-            pl.BlockSpec((1, nh, W, hd), lambda i, p, b: (i, 0, 0, 0)),
-            pl.BlockSpec((1, nh, W, hd), lambda i, p, b: (i, 0, 0, 0)),
-            pl.BlockSpec((1, nh, pt, hd), lambda i, p, b: (i, 0, 0, 0)),
-            pl.BlockSpec((1, nh, pt, hd), lambda i, p, b: (i, 0, 0, 0)),
+            pl.BlockSpec((1, nh, 1, hd), row),
+            pl.BlockSpec((1, nh, W, hd), row),
+            pl.BlockSpec((1, nh, W, hd), row),
+            pl.BlockSpec((1, nh, pt, hd), row),
+            pl.BlockSpec((1, nh, pt, hd), row),
         ],
-        out_specs=pl.BlockSpec((1, nh, hd), row),
+        out_specs=pl.BlockSpec((1, nh, 1, hd), row),
     )
     return pl.pallas_call(
         _make_kernel(W, pt, dtype),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, nh, hd), dtype),
+        out_shape=jax.ShapeDtypeStruct((N, nh, 1, hd), dtype),
         interpret=interpret,
+        name="band_sparse_attention",
     )(pos, base, q, k_win, v_win, k_sink, v_sink)
 
 
@@ -144,11 +152,14 @@ def band_attend(q, k_win, v_win, k_sink, v_sink, pos, base, *, dtype,
     be static at every jit call site. Returns [N, nh, hd]."""
     pos = pos.astype(jnp.int32)
     base = base.astype(jnp.int32)
+    qr = q[:, :, None, :]                # unit row axis, see `_band_math`
     if impl == "pallas":
-        return _band_attend_pallas(q, k_win, v_win, k_sink, v_sink, pos,
-                                   base, dtype, bool(interpret))
-    return _band_attend_xla(q, k_win, v_win, k_sink, v_sink, pos, base,
-                            dtype)
+        out = _band_attend_pallas(qr, k_win, v_win, k_sink, v_sink, pos,
+                                  base, dtype, bool(interpret))
+    else:
+        out = _band_attend_xla(qr, k_win, v_win, k_sink, v_sink, pos, base,
+                               dtype)
+    return out[:, :, 0]
 
 
 def _band_block(qb, pb, cache_k, cache_v, pt, dtype, impl, interpret):
@@ -220,13 +231,12 @@ def _probe_case():
 
 def probe(interpret):
     """Execution probe: a tiny band instance through the Pallas path
-    must run AND match the XLA fallback."""
-    import numpy as np
+    must run AND match the XLA twin (at full f32 matmul precision, see
+    ``decode_attention.probe``)."""
     q, kw, vw, ks, vs, pos, base = _probe_case()
-    got = band_attend(q, kw, vw, ks, vs, pos, base, dtype=jnp.float32,
-                      impl="pallas", interpret=interpret)
-    want = band_attend(q, kw, vw, ks, vs, pos, base, dtype=jnp.float32,
-                       impl="xla")
-    if not np.allclose(np.asarray(got), np.asarray(want),
-                       rtol=1e-5, atol=1e-5):
-        raise KernelProbeError("sparse_attention probe mismatch vs fallback")
+    with jax.default_matmul_precision("highest"):
+        got = band_attend(q, kw, vw, ks, vs, pos, base, dtype=jnp.float32,
+                          impl="pallas", interpret=interpret)
+        want = band_attend(q, kw, vw, ks, vs, pos, base, dtype=jnp.float32,
+                           impl="xla")
+    assert_probe_parity("sparse_attention", got, want, interpret)

@@ -3,21 +3,20 @@
 Capability parity with the reference's ``deepspeed/ops/adam/cpu_adam.py`` +
 ``csrc/adam/cpu_adam.cpp`` (SIMD/OpenMP Adam over the fp32 master shard,
 5-7x over a naive host Adam). The kernel lives in ``csrc/cpu_adam.cpp``,
-compiled to ``deepspeed_tpu/ops/lib/libdstpu_cpu.so`` and loaded via ctypes
-(the op_builder JIT-compiles it on first use if missing); a pure-numpy fallback
-keeps the feature available without a toolchain.
+which the op_builder compiles on this machine at first use and loads via
+ctypes; a pure-numpy fallback (announced once, at warning level) keeps the
+feature available without a toolchain.
 
 It also implements the device-path optimizer interface (init/update) by
 delegating to FusedAdam so the same config runs with or without offload.
 """
 
 import ctypes
-import os
 
 import numpy as np
 
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
-from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.ops.op_builder import load_host_library
 
 _LIB = None
 _LIB_TRIED = False
@@ -28,35 +27,14 @@ def _load_lib():
     if _LIB_TRIED:
         return _LIB
     _LIB_TRIED = True
-    path = os.path.join(os.path.dirname(__file__), "..", "lib", "libdstpu_cpu.so")
-    path = os.path.abspath(path)
-    if not os.path.exists(path):
-        try:
-            from deepspeed_tpu.ops.op_builder import CPUAdamBuilder
-
-            path = CPUAdamBuilder().load_path()
-        except Exception as e:
-            logger.warning(f"cpu_adam native kernel unavailable ({e}); using numpy fallback")
-            return None
-    try:
-        lib = ctypes.CDLL(path)
-        lib.ds_adam_step.argtypes = [
-            ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
-            ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
-            ctypes.c_int64, ctypes.c_float, ctypes.c_float, ctypes.c_float,
-            ctypes.c_float, ctypes.c_float, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-        ]
-        if hasattr(lib, "ds_adam_step_out"):  # absent in pre-streaming .so builds
-            lib.ds_adam_step_out.argtypes = [
-                ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
-                ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
-                ctypes.POINTER(ctypes.c_float),
-                ctypes.c_int64, ctypes.c_float, ctypes.c_float, ctypes.c_float,
-                ctypes.c_float, ctypes.c_float, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-            ]
-        _LIB = lib
-    except OSError as e:
-        logger.warning(f"failed to load cpu_adam native kernel: {e}; using numpy fallback")
+    lib = load_host_library()
+    if lib is None:
+        return None
+    fp = ctypes.POINTER(ctypes.c_float)
+    scalars = [ctypes.c_int64] + [ctypes.c_float] * 5 + [ctypes.c_int] * 3
+    lib.ds_adam_step.argtypes = [fp] * 4 + scalars
+    lib.ds_adam_step_out.argtypes = [fp] * 5 + scalars
+    _LIB = lib
     return _LIB
 
 
@@ -137,15 +115,9 @@ class DeepSpeedCPUAdam(FusedAdam):
             if out is None:
                 lib.ds_adam_step(m.ctypes.data_as(fp), gp,
                                  ea.ctypes.data_as(fp), es.ctypes.data_as(fp), *common)
-            elif hasattr(lib, "ds_adam_step_out"):
+            else:
                 lib.ds_adam_step_out(m.ctypes.data_as(fp), out.ctypes.data_as(fp), gp,
                                      ea.ctypes.data_as(fp), es.ctypes.data_as(fp), *common)
-            else:
-                # stale .so without the out-of-place symbol: copy-then-step
-                # keeps the exact in-place arithmetic (bitwise identical)
-                np.copyto(out, m)
-                lib.ds_adam_step(out.ctypes.data_as(fp), gp,
-                                 ea.ctypes.data_as(fp), es.ctypes.data_as(fp), *common)
         else:
             if self.weight_decay and not self.adam_w_mode:
                 g = g + self.weight_decay * m

@@ -1,40 +1,38 @@
 """ctypes bindings for the native host ops (csrc/host_ops.cpp): parallel
 flatten/unflatten, block-sparse layout->LUT segmentation, host LAMB.
 
-Each op has a numpy fallback so the library is optional (reference op_builder
-semantics: prefer the compiled op, degrade gracefully — builder.py:170-180).
+The library is built from ``csrc/`` on first use (``op_builder``). Each op
+keeps a numpy twin for machines without a compiler; taking it is logged once
+at warning level by ``load_host_library``, never silent.
 """
 
 import ctypes
-import os
 
 import numpy as np
 
-_LIB = None
+from deepspeed_tpu.ops.op_builder import load_host_library
+
+_LIB = None  # None = not tried yet; False = numpy fallback
 
 
 def _load():
     global _LIB
     if _LIB is not None:
         return _LIB
-    path = os.path.join(os.path.dirname(__file__), "lib", "libdstpu_cpu.so")
-    if not os.path.exists(path):
+    lib = load_host_library()
+    if lib is None:
         _LIB = False
         return False
-    try:
-        lib = ctypes.CDLL(path)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        fp = ctypes.POINTER(ctypes.c_float)
-        fpp = ctypes.POINTER(fp)
-        lib.ds_flatten.argtypes = [fpp, i64p, ctypes.c_int64, fp]
-        lib.ds_unflatten.argtypes = [fp, i64p, ctypes.c_int64, fpp]
-        lib.ds_layout_to_lut.argtypes = [i64p, ctypes.c_int64, ctypes.c_int64,
-                                         ctypes.c_int64, ctypes.c_int64, i32p, i32p]
-        lib.ds_lamb_step.argtypes = [fp, fp, fp, fp, ctypes.c_int64] + [ctypes.c_float] * 7 + [ctypes.c_int]
-        _LIB = lib
-    except OSError:
-        _LIB = False
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    fp = ctypes.POINTER(ctypes.c_float)
+    fpp = ctypes.POINTER(fp)
+    lib.ds_flatten.argtypes = [fpp, i64p, ctypes.c_int64, fp]
+    lib.ds_unflatten.argtypes = [fp, i64p, ctypes.c_int64, fpp]
+    lib.ds_layout_to_lut.argtypes = [i64p, ctypes.c_int64, ctypes.c_int64,
+                                     ctypes.c_int64, ctypes.c_int64, i32p, i32p]
+    lib.ds_lamb_step.argtypes = [fp, fp, fp, fp, ctypes.c_int64] + [ctypes.c_float] * 7 + [ctypes.c_int]
+    _LIB = lib
     return _LIB
 
 

@@ -1,11 +1,15 @@
 """Native-op build system.
 
 Capability parity with the reference's ``op_builder/`` (``OpBuilder.load()``:
-import a pre-built library or ninja-JIT-compile it on first use,
-builder.py:170-220). Here ops are plain C shared libraries compiled with g++
-and loaded via ctypes; AOT builds go through ``csrc/Makefile`` or setup.py.
+ninja-JIT-compile a library on first use, builder.py:170-220). Here ops are
+plain C shared libraries compiled with g++ and loaded via ctypes. They are
+built with ``-march=native``, so no binary is committed: the library is built
+from ``csrc/*.cpp`` on the machine that runs it, at first use (or ahead of
+time with ``make ops``), into the git-ignored ``deepspeed_tpu/ops/lib/``.
 """
 
+import ctypes
+import functools
 import os
 import shutil
 import subprocess
@@ -42,9 +46,17 @@ class OpBuilder:
         if not self.is_compatible():
             raise RuntimeError(f"no C++ compiler available to build op {self.NAME}")
         os.makedirs(LIBDIR, exist_ok=True)
-        cmd = self.command(out)
+        # build beside the target and rename: a concurrent process (test
+        # workers, replicas) never loads a half-written library
+        tmp = f"{out}.{os.getpid()}.tmp"
+        cmd = self.command(tmp)
         logger.info(f"JIT-building op {self.NAME}: {' '.join(cmd)}")
-        subprocess.check_call(cmd)
+        try:
+            subprocess.check_call(cmd)
+            os.replace(tmp, out)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
         return out
 
 
@@ -53,6 +65,21 @@ class CPUAdamBuilder(OpBuilder):
 
     NAME = "cpu"
     SOURCES = ["cpu_adam.cpp", "host_ops.cpp"]
+
+
+@functools.lru_cache(maxsize=None)
+def load_host_library():
+    """ctypes handle on the host library (offload Adam/LAMB, flatten,
+    LUT segmenter), built on this machine at first use. Returns None —
+    after ONE warning — when there is no compiler or the build or the load
+    fails; callers then run their numpy twin."""
+    try:
+        return ctypes.CDLL(CPUAdamBuilder().load_path())
+    except (RuntimeError, OSError, subprocess.CalledProcessError) as e:
+        logger.warning(
+            f"native host library unavailable ({type(e).__name__}: {e}); "
+            "host ops run their numpy fallback")
+        return None
 
 
 class PallasOp:

@@ -23,6 +23,16 @@ kernels (``_attn_bwd_dq_kernel`` / ``_attn_bwd_dkv_kernel``): dq streams the
 row LUT, dk/dv/dbias stream the transposed (column) LUT, recomputing p from
 the saved log-sum-exp residual so memory stays O(S*D). On non-TPU backends
 the dense jnp reference path runs fwd and bwd (same numerics, dense-masked).
+On a TPU nothing switches implementation behind the caller's back: a dense
+sequence that is not a block multiple is padded up to one (pad keys masked,
+pad queries sliced off) and still runs the kernels; only an explicit
+``force_reference=True`` selects the jnp path there. Which path was traced is
+counted in the shared metrics registry (``Kernels/flash_attention/*_traces``).
+
+Several devices: GSPMD cannot partition a Mosaic kernel, so under a mesh the
+kernels ``shard_map`` themselves over the mesh in context at trace time (the
+engine traces the model under its own): batch over ``data``, heads over
+``model`` — see ``_shard_over_context_mesh``.
 """
 
 import functools
@@ -33,8 +43,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType, PartitionSpec
+
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 DEFAULT_BLOCK = 128
+
+# Trace-time tallies of the implementation each flash_attention call lowered
+# to: what bench.py and chip_smoke.py read to report the attention that RAN.
+PALLAS_TRACES = "Kernels/flash_attention/pallas_traces"
+REFERENCE_TRACES = "Kernels/flash_attention/reference_traces"
+
+
+def _count_trace(tag):
+    telemetry.get_registry().counter(
+        tag, help="flash_attention traces lowered to this implementation").inc()
+
+
+def trace_counts():
+    """(Pallas, reference) flash_attention traces in this process so far."""
+    counters = telemetry.get_registry()
+    return (counters.counter(PALLAS_TRACES).value,
+            counters.counter(REFERENCE_TRACES).value)
+
+
+def traced_implementation(since=(0, 0)):
+    """Which implementation flash_attention lowered to since the
+    ``trace_counts()`` snapshot ``since``: "pallas" (the TPU kernels),
+    "reference" (the dense jnp path), "mixed" when both were, "none" when it
+    was never traced — so a report never attributes one implementation's
+    numbers to another."""
+    pallas, reference = (now - then
+                         for now, then in zip(trace_counts(), since))
+    if pallas and reference:
+        return "mixed"
+    return "pallas" if pallas else ("reference" if reference else "none")
 
 
 # ---------------------------------------------------------------------------
@@ -65,6 +109,11 @@ def layout_to_lut(layout):
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+# Odd, so multiplying by it permutes i32 space: the stride between the
+# dropout seeds of consecutive (batch, head) rows.
+_SEED_ROW_STRIDE = -1640531527
+
+
 def _fold_dropout_seed(seed, bh, qi, kj):
     """Fold the 4-word dropout-PRNG identity into the TWO seed words Mosaic's
     tpu.prng_set_seed_32 accepts (real-TPU compile rejects more). Injective
@@ -74,7 +123,7 @@ def _fold_dropout_seed(seed, bh, qi, kj):
     and traced i32 alike (unit-tested for injectivity; the kernel path is
     only compilable on real TPU hardware)."""
     return (
-        seed + bh * jnp.int32(-1640531527),
+        seed + bh * jnp.int32(_SEED_ROW_STRIDE),
         qi * jnp.int32(65536) + kj,
     )
 
@@ -161,6 +210,7 @@ def _attention_pallas(q, k, v, bias, lut, counts, *, block_q, block_k, causal,
                       interpret=False, dropout_rate=0.0, seed=None):
     """q,k,v: [B, H, S, D]; bias additive [B, S] (key bias, e.g. padding).
     ``seed``: [1] int32 array feeding the in-kernel dropout PRNG."""
+    _count_trace(PALLAS_TRACES)
     B, H, S, D = q.shape
     BH = B * H
     qr = q.reshape(BH, S, D)
@@ -197,6 +247,7 @@ def _attention_pallas(q, k, v, bias, lut, counts, *, block_q, block_k, causal,
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(seed_arr, jnp.asarray(counts), jnp.asarray(lut), qr, kr, vr, bias_r)
     return out.reshape(B, H, S, D), lse.reshape(BH, S)
 
@@ -339,6 +390,7 @@ def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(seed_arr, jnp.asarray(counts), jnp.asarray(lut), qr, kr, vr, bias_r, dor, lse_r, delta_r)
 
     # dk/dv/dbias: grid over k block columns with the TRANSPOSED LUT
@@ -371,6 +423,7 @@ def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(seed_arr, jnp.asarray(qcounts), jnp.asarray(qlut), qr, kr, vr, bias_r, dor, lse_r, delta_r)
 
     unrs = lambda t: t.reshape(B, H, S, D)
@@ -384,6 +437,7 @@ def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts
 
 def _attention_reference(q, k, v, bias, layout_mask, *, causal,
                          dropout_rate=0.0, seed=None):
+    _count_trace(REFERENCE_TRACES)
     B, H, S, D = q.shape
     scale = 1.0 / np.sqrt(D)
     s = jnp.einsum("bhsd,bhtd->bhst", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
@@ -535,6 +589,52 @@ def _register_layout(layout):
     return key
 
 
+def _shard_over_context_mesh(attend, q_shape, shard_heads, has_seed):
+    """Make ``attend(q, k, v, bias[, seed])`` run per device under a mesh.
+
+    XLA's partitioner refuses a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), and attention is embarrassingly parallel
+    over batch and heads, so the kernels are shard_mapped explicitly: batch
+    over the ``data`` axis, heads over the ``model`` axis. The mesh is the
+    one in context at trace time. Mosaic wants EVERY mesh axis manual, so
+    the map takes all the axes that are still automatic (any other one just
+    sees replicated operands); axes that are already manual (the caller
+    sits in its own shard_map: ring/Ulysses attention, the 1-bit Adam step)
+    are the caller's. With one device under automatic axes ``attend`` is
+    returned as it is. ``shard_heads=False`` keeps the heads together (a
+    block-sparse LUT is indexed by GLOBAL head)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = [name for name, kind in zip(mesh.axis_names, mesh.axis_types)
+            if kind == AxisType.Auto]
+    if all(mesh.shape[name] == 1 for name in auto):
+        return attend
+    b_ax = DATA_AXIS if DATA_AXIS in auto else None
+    h_ax = MODEL_AXIS if MODEL_AXIS in auto and shard_heads else None
+    axes = tuple(ax for ax in (b_ax, h_ax) if ax is not None)
+    local_bh = q_shape[0] * q_shape[1]
+    for ax in axes:
+        local_bh //= mesh.shape[ax]
+
+    def local(q, k, v, bias, *seed):
+        if has_seed:
+            # every shard numbers its (batch, head) rows from 0: shift the
+            # seed by the shard's first global row so no two shards draw
+            # the same dropout masks
+            shard = 0
+            for ax in axes:
+                shard = shard * mesh.shape[ax] + jax.lax.axis_index(ax)
+            seed = (seed[0] + (shard * local_bh).astype(jnp.int32)
+                    * jnp.int32(_SEED_ROW_STRIDE),)
+        return attend(q, k, v, bias, *seed)
+
+    qkv = PartitionSpec(b_ax, h_ax, None, None)
+    in_specs = (qkv, qkv, qkv, PartitionSpec(b_ax, None))
+    if has_seed:
+        in_specs += (PartitionSpec(),)
+    return jax.shard_map(local, in_specs=in_specs, out_specs=qkv,
+                         axis_names=frozenset(auto), check_vma=False)
+
+
 def flash_attention(q, k, v, mask=None, layout=None, block=DEFAULT_BLOCK,
                     causal=False, force_reference=False,
                     dropout_rate=0.0, dropout_rng=None):
@@ -561,9 +661,6 @@ def flash_attention(q, k, v, mask=None, layout=None, block=DEFAULT_BLOCK,
     else:
         seed = None
         dropout_rate = 0.0
-    if S % block != 0:
-        # Unaligned sequence: fall back to the dense reference path.
-        force_reference = True
     if mask is None:
         bias = jnp.zeros((B, S), q.dtype)
     elif mask.ndim == 4:
@@ -581,5 +678,28 @@ def flash_attention(q, k, v, mask=None, layout=None, block=DEFAULT_BLOCK,
     else:
         bias = mask
     key = _register_layout(layout)
-    return _attention(q, k, v, bias, seed, key, block, causal, force_reference,
-                      float(dropout_rate))
+    if force_reference or not _on_tpu():
+        return _attention(q, k, v, bias, seed, key, block, causal, True,
+                          float(dropout_rate))
+
+    def attend(q, k, v, bias, seed=None):
+        return _attention(q, k, v, bias, seed, key, block, causal, False,
+                          float(dropout_rate))
+
+    pad = -S % block
+    if pad:
+        # The kernels tile S in whole blocks. Pad rather than change
+        # implementation: pad keys get a -1e30 bias (exact-zero probability,
+        # like every other masked key) and pad queries are sliced off; the
+        # pad/slice pair sits outside the custom VJP, so autodiff handles it.
+        if layout is not None:
+            raise ValueError(
+                f"block-sparse flash_attention needs S % block == 0 "
+                f"(the layout is in whole blocks), got S={S}, block={block}")
+        widen = lambda t: jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        q, k, v = widen(q), widen(k), widen(v)
+        bias = jnp.pad(bias, ((0, 0), (0, pad)), constant_values=-1e30)
+    attend = _shard_over_context_mesh(attend, q.shape, layout is None,
+                                      seed is not None)
+    out = attend(q, k, v, bias, *(() if seed is None else (seed,)))
+    return out[:, :, :S] if pad else out

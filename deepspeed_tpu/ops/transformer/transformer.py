@@ -25,8 +25,6 @@ memory knobs, ``stochastic_mode`` — built the TPU way:
 
 from dataclasses import dataclass, field
 
-import os
-
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
@@ -102,11 +100,6 @@ def _attention_core(q, k, v, mask, dropout_ratio, deterministic, dropout_rng,
     Shapes: q,k,v = [B, H, S, D]; mask = [B, 1, 1, S] additive key bias;
     ``causal`` applies autoregressive masking (in-kernel on the fused path).
     """
-    # DSTPU_ATTN=xla forces the jnp einsum chain (XLA-fused attention) even on
-    # TPU — the A/B switch for benchmarking the Pallas kernel against XLA's
-    # own fusion at a given shape without code changes.
-    if os.environ.get("DSTPU_ATTN", "").strip().lower() == "xla":
-        use_pallas = False
     if use_pallas:
         from deepspeed_tpu.ops.transformer.attention import flash_attention
 

@@ -31,11 +31,20 @@ def flatten_dense_tensors(tree, dtype=jnp.float32):
 
 
 def unflatten_dense_tensors(flat, treedef, shapes, dtypes):
-    """Inverse of flatten: split + reshape back into the pytree (jit-safe)."""
+    """Inverse of flatten: split + reshape back into the pytree (jit-safe).
+
+    Each piece passes an optimization barrier between its 1-D slice and its
+    reshape. Without it XLA:TPU commutes the two, and a leaf with a tiny
+    minor dimension (BERT's [1024, 2] next-sentence head) re-views the WHOLE
+    flat vector as [n/2, 2], whose tiled layout pads the 2 to 128 lanes: a
+    64x copy of the vector (43 GB for BERT-large) that fails buffer
+    assignment."""
     sizes = [int(np.prod(s)) if len(s) else 1 for s in shapes]
     offsets = np.cumsum([0] + sizes)
     leaves = [
-        jax.lax.dynamic_slice(flat, (int(offsets[i]),), (sizes[i],)).reshape(shapes[i]).astype(dtypes[i])
+        jax.lax.optimization_barrier(
+            jax.lax.slice(flat, (int(offsets[i]),), (int(offsets[i + 1]),))
+        ).reshape(shapes[i]).astype(dtypes[i])
         for i in range(len(shapes))
     ]
     return jax.tree_util.tree_unflatten(treedef, leaves)

@@ -65,10 +65,9 @@ def ring_attention_local(q, k, v, bias, axis_name, causal=False):
     m = jnp.full(q.shape[:3] + (1,), -1e30, jnp.float32)
     l = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
     acc = jnp.zeros(q.shape, jnp.float32)
-    if hasattr(jax.lax, "pcast"):
-        # carry entries must be device-varying over the ring axis from the
-        # start (shard_map vma typing): constants start unvarying.
-        m, l, acc = (jax.lax.pcast(t, (axis_name,), to="varying") for t in (m, l, acc))
+    # carry entries must be device-varying over the ring axis from the
+    # start (shard_map vma typing): constants start unvarying.
+    m, l, acc = (jax.lax.pcast(t, (axis_name,), to="varying") for t in (m, l, acc))
 
     def body(step, carry):
         m, l, acc, k_cur, v_cur, b_cur = carry

@@ -70,14 +70,18 @@ class UnknownAxisError(ShardingRegistryError):
 # attn_out/ff2 (input dim over `model`), everything else replicated.
 # The non-param `serving/*` paths are the engine's device buffers:
 # the paged KV pool and its quant scales shard the heads dim, lane
-# state uploads replicate.
+# state uploads replicate. The buffer specs carry no trailing ``None``s:
+# that is the form jit hands back on its outputs, and the jit fast-path
+# cache compares specs literally — a pool created as (None, None, model,
+# None, None) and returned as (None, None, model) would cost every decode
+# program a second cache entry.
 SERVING_PARTITION_RULES = {
     r"(qkv|ff1)/(kernel|kernel_q)$": PartitionSpec(None, None, MODEL_AXIS),
     r"(qkv|ff1)/(bias|scale)$": PartitionSpec(None, MODEL_AXIS),
     r"(attn_out|ff2)/(kernel|kernel_q)$": PartitionSpec(None, MODEL_AXIS, None),
-    r"^serving/kv_pool$": PartitionSpec(None, None, MODEL_AXIS, None, None),
-    r"^serving/kv_scale$": PartitionSpec(None, None, MODEL_AXIS, None, None),
-    r"^serving/prefill_kv$": PartitionSpec(None, None, MODEL_AXIS, None, None),
+    r"^serving/kv_pool$": PartitionSpec(None, None, MODEL_AXIS),
+    r"^serving/kv_scale$": PartitionSpec(None, None, MODEL_AXIS),
+    r"^serving/prefill_kv$": PartitionSpec(None, None, MODEL_AXIS),
     r"^serving/lane_state$": PartitionSpec(),
     r".*": PartitionSpec(),
 }

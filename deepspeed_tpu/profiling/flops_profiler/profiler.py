@@ -26,29 +26,36 @@ import jax
 
 from deepspeed_tpu.utils.logging import logger
 
-# Published bf16 peak TFLOPs per chip by device-kind substring (the table
-# bench.py uses for its MFU column — kept here so the profiler's exported
-# Train/Samples/mfu gauge and the bench agree on the denominator).
+# Published dense bf16 peak TFLOP/s per chip, by a substring of the device
+# kind JAX reports (Google Cloud TPU documentation, per-generation system
+# architecture pages). THE one table: bench.py imports it, so the profiler's
+# exported Train/Samples/mfu gauge and the bench agree on the denominator.
+# There is no catch-all row: a TPU this table does not know is an error, not
+# some other generation's peak.
 _PEAK_TFLOPS = [
     ("v6", 918.0),        # Trillium
     ("v5p", 459.0),
     ("v5 lite", 197.0),   # v5e reports "TPU v5 lite"
     ("v5e", 197.0),
-    ("v5", 459.0),
     ("v4", 275.0),
     ("v3", 123.0),
     ("v2", 45.0),
 ]
 
 
-def device_peak_tflops(device_kind):
-    """Peak bf16 TFLOPs for a jax ``device_kind`` string, None if unknown
-    (CPU / unrecognized accelerator — MFU is then unreportable)."""
-    kind = (device_kind or "").lower()
+def device_peak_tflops(device):
+    """Peak bf16 TFLOP/s of a jax device. None for a non-TPU platform (MFU
+    is unreportable there); a TPU whose ``device_kind`` is not in the table
+    raises rather than borrow another chip's peak."""
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
     for sub, peak in _PEAK_TFLOPS:
         if sub in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no published peak for TPU device_kind {device.device_kind!r}: add "
+        f"its row to _PEAK_TFLOPS (with the source) before reporting MFU")
 
 
 def _count_params(params):
@@ -222,15 +229,13 @@ class FlopsProfiler:
             return None
         return self.flops / self.duration / 1e12
 
-    def mfu(self, device_kind=None):
-        """Model FLOPs utilization vs the device's peak, or None when the
-        peak is unknown (CPU, unrecognized accelerator)."""
+    def mfu(self):
+        """Model FLOPs utilization vs the device's published peak, or None
+        before a profile completes and on a non-TPU platform."""
         achieved = self.achieved_tflops()
         if achieved is None:
             return None
-        if device_kind is None:
-            device_kind = jax.devices()[0].device_kind
-        peak = device_peak_tflops(device_kind)
+        peak = device_peak_tflops(jax.devices()[0])
         return achieved / peak if peak else None
 
     def _inclusive_tree(self):

@@ -547,11 +547,24 @@ class DeepSpeedEngine:
             raise ValueError("deepspeed_tpu.initialize requires a model")
 
         if hasattr(model, "apply") and callable(model.apply):
-            self.apply_fn = model.apply
+            apply = model.apply
         elif callable(model):
-            self.apply_fn = model
+            apply = model
         else:
             raise TypeError("model must be a flax-style module with .apply or a callable(params, *batch)")
+        abstract_mesh = self.mesh.abstract_mesh
+
+        def apply_fn(*args, **kwargs):
+            # Trace the model with the engine's mesh in context: ops GSPMD
+            # cannot partition by itself (the Pallas attention kernels)
+            # shard_map themselves over it. A context that is already there
+            # (the 1-bit step's shard_map) is the caller's and stays.
+            if not jax.sharding.get_abstract_mesh().empty:
+                return apply(*args, **kwargs)
+            with jax.sharding.use_abstract_mesh(abstract_mesh):
+                return apply(*args, **kwargs)
+
+        self.apply_fn = apply_fn
 
         if model_parameters is None:
             model_parameters = getattr(model, "params", None)

@@ -1,30 +1,20 @@
-"""One shard_map import for every jax version in the wild.
+"""The repo's one ``shard_map`` entry point.
 
-jax >= 0.8 moved shard_map to the top level and renamed ``check_rep`` to
-``check_vma`` (adding ``axis_names`` for partial-manual meshes); the
-experimental module still imports but warns. This is the single place that
-knows — everything in the repo (and the tests) imports ``shard_map`` from
-here with the OLD keyword surface (``check_rep``, optional ``axis_names``).
+A thin adapter over ``jax.shard_map`` that keeps the keyword surface the
+call sites were written against: ``check_rep`` (upstream's ``check_vma``) and
+an optional ``axis_names`` given as any iterable.
 """
 
-try:  # jax >= 0.8
-    from jax import shard_map as _new_shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=True, axis_names=None):
-        # check_rep defaults True like BOTH upstream APIs — callers that need
-        # it off (pallas_call bodies whose ShapeDtypeStructs carry no vma
-        # annotations, custom-vjp pipelines) must say so explicitly.
-        # axis_names = the MANUAL axes; any other mesh axis (e.g. a TP
-        # ``model`` axis) stays automatic and GSPMD handles its collectives.
-        return _new_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep, axis_names=frozenset(axis_names or ()),
-        )
-except ImportError:  # pragma: no cover — jax < 0.8
-    from jax.experimental.shard_map import shard_map as _old_shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=True, axis_names=None):
-        auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-                if axis_names else frozenset())
-        return _old_shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=check_rep, auto=auto)
+def shard_map(f, mesh, in_specs, out_specs, check_rep=True, axis_names=None):
+    # check_rep defaults True like upstream — callers that need it off
+    # (pallas_call bodies whose ShapeDtypeStructs carry no vma annotations,
+    # custom-vjp pipelines) must say so explicitly.
+    # axis_names = the MANUAL axes; any other mesh axis (e.g. a TP ``model``
+    # axis) stays automatic and GSPMD handles its collectives.
+    return _shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_rep, axis_names=frozenset(axis_names or ()),
+    )

@@ -208,6 +208,46 @@ def test_grad_binds_flash_backward_kernels(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
 
 
+def test_flash_kernels_shard_map_over_the_context_mesh(monkeypatch):
+    """Under a mesh in context the TPU path shard_maps its kernels (GSPMD
+    cannot partition a Mosaic call): batch over ``data``, heads over
+    ``model``. Kernels in interpret mode on a 1x2x2 CPU mesh; outputs and
+    grads must equal the unsharded reference, and come back sharded."""
+    import functools
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops.transformer import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        A, "_attention_pallas", functools.partial(A._attention_pallas, interpret=True))
+    monkeypatch.setattr(
+        A, "_attention_pallas_bwd",
+        functools.partial(A._attention_pallas_bwd, interpret=True))
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 2, 2),
+                ("pipe", "data", "model"))
+    q, k, v = rand_qkv(B=4, H=2, S=100, D=16, seed=3)      # S pads to 128
+    placed = [jax.device_put(t, NamedSharding(mesh, P("data", "model")))
+              for t in (q, k, v)]
+
+    def loss(q, k, v, **kw):
+        return jnp.sum(A.flash_attention(q, k, v, causal=True, **kw) ** 2)
+
+    def meshed(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return loss(q, k, v)
+
+    val, g = jax.jit(jax.value_and_grad(meshed, argnums=(0, 1, 2)))(*placed)
+    val_ref, g_ref = jax.value_and_grad(
+        functools.partial(loss, force_reference=True), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(val_ref), rtol=1e-5)
+    for a, b in zip(g, g_ref):
+        assert a.sharding.spec == P("data", "model")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+
 def test_dropout_reference_path_statistics_and_determinism():
     """dropout_rate>0 on the (CPU) reference path: deterministic per rng,
     different across rngs, keep-rate ~ (1-p), unbiased in expectation."""

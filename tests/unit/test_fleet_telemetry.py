@@ -494,13 +494,27 @@ def test_decode_stays_transfer_free_with_collector_and_slo_armed():
 # -- bench gate -------------------------------------------------------------
 
 SERVING_BASE = os.path.join(REPO_ROOT, "SERVING_BENCH_CPU.json")
-TRAIN_BASE = os.path.join(REPO_ROOT, "BENCH_r05.json")
 
 
 def _write(tmp_path, name, doc):
     p = tmp_path / name
     p.write_text(json.dumps(doc))
     return str(p)
+
+
+@pytest.fixture()
+def train_base(tmp_path):
+    """A driver-wrapped ``bench.py`` train line (the shape a driver stores:
+    command, exit code and the parsed JSON line). No chip record is
+    committed for the gate to read, so the test brings its own."""
+    return _write(tmp_path, "train_base.json", {
+        "n": 1, "cmd": "python bench.py", "rc": 0,
+        "parsed": {
+            "metric": "bert-large pretrain samples/sec/chip @ seq128 (tpu)",
+            "value": 240.05, "unit": "samples/sec", "vs_baseline": 0.883,
+            "tflops_per_chip": 63.15, "mfu": 0.3206,
+            "device_kind": "TPU v5 lite", "n_devices": 1, "global_batch": 64,
+            "step_ms": 266.61, "params": 336232258, "micro_batch": 64}})
 
 
 def test_bench_gate_schema_accepts_committed_baselines():
@@ -519,9 +533,9 @@ def test_bench_gate_schema_rejects_partial_or_broken(tmp_path):
         ["--check-schema", _write(tmp_path, "broken.json", doc)]) == 1
 
 
-def test_bench_gate_self_compare_passes():
+def test_bench_gate_self_compare_passes(train_base):
     assert bench_gate.main(["compare", SERVING_BASE, SERVING_BASE]) == 0
-    assert bench_gate.main(["compare", TRAIN_BASE, TRAIN_BASE]) == 0
+    assert bench_gate.main(["compare", train_base, train_base]) == 0
 
 
 def test_bench_gate_fails_on_regression(tmp_path, capsys):
@@ -562,11 +576,11 @@ def test_bench_gate_skips_mismatched_context(tmp_path):
                             "--require-comparable"]) == 2
 
 
-def test_bench_gate_unwraps_train_driver_artifact(tmp_path):
-    with open(TRAIN_BASE) as f:
+def test_bench_gate_unwraps_train_driver_artifact(tmp_path, train_base):
+    with open(train_base) as f:
         wrapper = json.load(f)
-    kind, doc = bench_gate.load_artifact(TRAIN_BASE)
+    kind, doc = bench_gate.load_artifact(train_base)
     assert kind == "train" and doc == wrapper["parsed"]
     wrapper["parsed"]["step_ms"] = wrapper["parsed"].get("step_ms", 100.0) * 10
     fresh = _write(tmp_path, "slow_train.json", wrapper)
-    assert bench_gate.main(["compare", fresh, TRAIN_BASE]) == 1
+    assert bench_gate.main(["compare", fresh, train_base]) == 1

@@ -2,6 +2,7 @@
 within tolerance of the analytic count)."""
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -202,3 +203,20 @@ def test_scan_trip_count_multiplication():
 def test_formatting():
     assert flops_to_string(2e12) == "2.00 TFLOPS"
     assert params_to_string(336e6).endswith("M")
+
+
+def test_peaks_table_knows_or_raises():
+    """One published-peaks table: a known TPU kind gives its peak, a
+    non-TPU platform gives None, and a TPU kind the table does not list
+    raises instead of borrowing another generation's number."""
+    from types import SimpleNamespace as Dev
+
+    from deepspeed_tpu.profiling.flops_profiler.profiler import device_peak_tflops
+
+    assert device_peak_tflops(Dev(platform="tpu", device_kind="TPU v5 lite")) == 197.0
+    assert device_peak_tflops(Dev(platform="tpu", device_kind="TPU v5p")) == 459.0
+    assert device_peak_tflops(Dev(platform="cpu", device_kind="cpu")) is None
+    with pytest.raises(ValueError, match="no published peak"):
+        device_peak_tflops(Dev(platform="tpu", device_kind="TPU v5"))
+    with pytest.raises(ValueError, match="no published peak"):
+        device_peak_tflops(Dev(platform="tpu", device_kind="TPU v9x"))

@@ -13,9 +13,11 @@ Three layers of coverage, mirroring the tier's contract
    the null-sink (base == 0) band case, and int8 pages with the
    quantization thresholds test_quantization.py established.
 2. **Registry semantics** — probe caching, config-forced selection,
-   ValueError on bad requests, probe-failure degrade to the XLA
-   fallback with ONE edge-triggered ``jax/kernel_fallback`` instant,
-   call counters in the snapshot.
+   ValueError on bad requests, call counters in the snapshot, and what
+   a failed probe means: off-TPU a degrade to the XLA fallback with ONE
+   edge-triggered ``jax/kernel_fallback`` instant; on a (patched) TPU
+   backend a ``KernelProbeError`` carrying the compiler's message, with
+   interpret mode never selected there.
 3. **Integration** — ``generate()`` per kernel backend bitwise vs the
    dense greedy oracle, the serving continuous-vs-``generate()`` oracle
    per backend (mixed classes, speculation, int8 pool), CompileSentinel
@@ -34,7 +36,7 @@ from deepspeed_tpu.inference.generation import generate
 from deepspeed_tpu.inference.serving import engine as serving_engine_mod
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.engine import ServingEngine
-from deepspeed_tpu.kernels.registry import KernelRegistry
+from deepspeed_tpu.kernels.registry import KernelProbeError, KernelRegistry
 from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2
 from deepspeed_tpu.profiling import CompileSentinel, transfer_free
 from deepspeed_tpu.runtime.config import get_serving_config
@@ -338,6 +340,50 @@ def test_registry_probe_failure_degrades_with_one_instant():
     snap = reg.snapshot()["broken"]
     assert snap["available"] is False and snap["selected"] == "xla"
     assert "no pallas lowering" in snap["probe_error"]
+
+
+@pytest.fixture()
+def tpu_backend(monkeypatch):
+    monkeypatch.setattr("deepspeed_tpu.kernels.registry._on_tpu", lambda: True)
+
+
+def _broken_probe(interpret):
+    raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+
+def test_failed_probe_raises_on_tpu(tpu_backend):
+    """On the chip a kernel that does not compile must never be reported
+    as running: asking for Pallas raises with the compiler's message,
+    and "xla" stays the explicit way to get the twin."""
+    reg = KernelRegistry().register("broken", _broken_probe)
+    for requested in (None, "pallas"):
+        with pytest.raises(KernelProbeError, match="Mosaic failed to compile"):
+            reg.resolve("broken", requested=requested)
+    assert reg.resolve("broken", requested="xla") == ("xla", False)
+    with pytest.raises(KernelProbeError, match="unknown kernel"):
+        reg.resolve("nope")
+
+
+def test_no_interpret_mode_on_tpu(tpu_backend):
+    reg = KernelRegistry().register("fine", lambda interpret: None)
+    assert reg.resolve("fine") == ("pallas", False)
+    with pytest.raises(ValueError, match="kernel_interpret=True on a TPU"):
+        reg.resolve("fine", interpret=True)
+    with pytest.raises(ValueError, match="kernel_interpret=True on a TPU"):
+        kernels.resolve("pallas_decode", interpret=True)
+
+
+def test_serving_probe_failure_raises_on_tpu(model, clean_registry,
+                                             tpu_backend):
+    """The engine resolves its kernels at construction, so a broken
+    kernel fails the build instead of serving from the twin."""
+    cfg, params = model
+    clean_registry.force_probe_result("decode_attention", False,
+                                      error="simulated lowering failure")
+    with pytest.raises(KernelProbeError, match="simulated lowering failure"):
+        ServingEngine(params, cfg, ServingConfig(
+            max_slots=2, max_seq_len=32, prompt_buckets=(4, 8),
+            kv_page_tokens=4, attention_impl="pallas_decode"))
 
 
 def test_registry_snapshot_counts_calls(clean_registry):

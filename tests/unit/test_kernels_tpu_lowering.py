@@ -1,0 +1,232 @@
+"""Every Pallas kernel the repo ships must LOWER for a TPU.
+
+The CPU suite runs the kernels with ``interpret=True``, which never
+touches the Pallas-to-Mosaic lowering — so a kernel Mosaic cannot take
+(a vector load from SMEM, an illegal block tiling, a batched contraction
+without a leading batch dimension) stays green here and only fails on
+the chip. ``jit(f).trace(...).lower(lowering_platforms=("tpu",))`` runs
+that lowering on the CPU host: no chip, no libtpu call, about a second a
+kernel. Shapes are the ones ``chip_smoke.py`` runs: BERT-large training
+attention and the GPT-2-large serving tier (20 heads of 64, 128-token
+pages).
+
+The ``slow`` tests go one step further and run the real Mosaic/XLA:TPU
+compiler from the installed libtpu against a v5e topology description,
+which also catches what only the TPU compiler decides: an accumulator dtype
+Mosaic rejects, VMEM overflow, a layout that blows a buffer up past HBM.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu import kernels
+from deepspeed_tpu.ops.transformer import attention as attn_mod
+from deepspeed_tpu.ops.transformer.attention import flash_attention
+
+SDS = jax.ShapeDtypeStruct
+NH, HD, PT = 20, 64, 128          # GPT-2 large heads, default KV page
+
+
+def _lower_tpu(fn, *args):
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def _assert_mosaic(lowered, n_kernels):
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") >= n_kernels, (
+        f"expected >= {n_kernels} Mosaic custom call(s) in the lowered "
+        f"module, found {text.count('tpu_custom_call')}")
+
+
+@pytest.fixture()
+def pallas_path(monkeypatch):
+    """flash_attention picks its implementation from the default backend,
+    which is the CPU here: force the TPU branch so the trace holds the
+    kernels being lowered."""
+    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+
+
+def _banded_layout(heads, nb):
+    layout = np.zeros((heads, nb, nb), np.int64)
+    for i in range(nb):
+        layout[:, i, max(0, i - 1):i + 1] = 1
+    return layout
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1])
+def test_training_flash_fwd_bwd_lowers(pallas_path, dropout):
+    """BERT-large seq128 / micro-batch 64: forward kernel plus both
+    backward kernels, with and without in-kernel dropout."""
+    qkv = SDS((64, 16, 128, 64), jnp.bfloat16)
+    rng = jax.random.PRNGKey(0)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, dropout_rate=dropout,
+                              dropout_rng=rng if dropout else None)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _assert_mosaic(_lower_tpu(jax.grad(loss, argnums=(0, 1, 2)),
+                              qkv, qkv, qkv), 3)
+
+
+def test_training_flash_unaligned_seq_pads_into_kernel(pallas_path):
+    """S=100 on a TPU is padded to a block multiple and still runs the
+    kernel — it must not switch to the jnp reference."""
+    qkv = SDS((2, 4, 100, 64), jnp.bfloat16)
+    lowered = _lower_tpu(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                         qkv, qkv, qkv)
+    _assert_mosaic(lowered, 1)
+    assert lowered.out_info.shape == (2, 4, 100, 64)
+    with pytest.raises(ValueError, match="block-sparse"):
+        flash_attention(*(jnp.zeros((1, 4, 100, 64), jnp.bfloat16),) * 3,
+                        layout=_banded_layout(4, 1))
+
+
+def test_training_block_sparse_lowers(pallas_path):
+    """One block-sparse layout (banded causal, the scalar-prefetch LUT
+    path) at seq512, forward and backward."""
+    qkv = SDS((16, 16, 512, 64), jnp.bfloat16)
+    layout = _banded_layout(16, 4)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, layout=layout, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _assert_mosaic(_lower_tpu(jax.grad(loss, argnums=(0, 1, 2)),
+                              qkv, qkv, qkv), 3)
+
+
+def test_training_flash_lowers_under_a_mesh(pallas_path):
+    """Batch-sharded q/k/v under jit on several devices: the kernels must
+    shard_map themselves over the mesh in context, because Mosaic refuses
+    automatic partitioning (this failed the first four-chip run, and the
+    CPU suite could not see it: it runs the jnp reference)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 4, 1),
+                ("pipe", "data", "model"))
+    qkv = SDS((64, 16, 128, 64), jnp.bfloat16,
+              sharding=NamedSharding(mesh, P("data")))
+    rng = jax.random.PRNGKey(0)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, dropout_rate=0.1, dropout_rng=rng)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def meshed(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return loss(q, k, v)
+
+    _assert_mosaic(_lower_tpu(jax.grad(meshed, argnums=(0, 1, 2)),
+                              qkv, qkv, qkv), 3)
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+def _decode_args(chunk, page_dtype, lanes=8, pages_per_lane=8):
+    n_pages = lanes * pages_per_lane + 1
+    pages = SDS((n_pages, NH, PT, HD), page_dtype)
+    args = [SDS((lanes, chunk, NH, HD), jnp.float32), pages, pages,
+            SDS((lanes, pages_per_lane), jnp.int32),
+            SDS((lanes, chunk), jnp.int32)]
+    if page_dtype == jnp.int8:
+        args += [SDS((n_pages, NH), jnp.float32)] * 2
+    return args
+
+
+def _decode_fn(q, pk, pv, tables, qpos, k_scale=None, v_scale=None):
+    return kernels.decode_attend(
+        q, pk, pv, tables, qpos, page_tokens=PT, dtype=jnp.float32,
+        impl="pallas", interpret=False, k_scale=k_scale, v_scale=v_scale)
+
+
+@pytest.mark.parametrize("page_dtype", [jnp.float32, jnp.int8])
+@pytest.mark.parametrize("chunk", [1, 256])
+def test_decode_attention_lowers(page_dtype, chunk):
+    """Paged decode at GPT-2-large width: the one-token decode step and
+    a prefill-bucket-wide chunk (tiled over the query-block grid axis),
+    fp32 and int8 pages."""
+    _assert_mosaic(_lower_tpu(_decode_fn, *_decode_args(chunk, page_dtype)), 1)
+
+
+def _band_args(dtype, n=8):
+    win = SDS((n, NH, 2 * PT, HD), dtype)
+    sink = SDS((n, NH, PT, HD), dtype)
+    return [SDS((n, NH, HD), dtype), win, win, sink, sink,
+            SDS((n,), jnp.int32), SDS((n,), jnp.int32)]
+
+
+def _band_fn(dtype):
+    return lambda *a: kernels.band_attend(*a, dtype=dtype, impl="pallas",
+                                          interpret=False)
+
+
+def test_sparse_attention_lowers():
+    _assert_mosaic(_lower_tpu(_band_fn(jnp.float32),
+                              *_band_args(jnp.float32)), 1)
+
+
+@pytest.mark.slow
+def test_serving_kernels_compile_for_v5e():
+    """The real compiler: Mosaic + XLA:TPU from the installed libtpu,
+    against a v5e topology description, for both serving kernels in
+    every storage dtype. Catches what lowering cannot (an accumulator
+    dtype Mosaic rejects, an op it cannot legalize, VMEM overflow)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    dev = SingleDeviceSharding(topo.devices[0])
+    place = lambda args: [SDS(a.shape, a.dtype, sharding=dev) for a in args]
+    for page_dtype in (jnp.float32, jnp.bfloat16, jnp.int8):
+        for chunk in (1, 256):
+            _lower_tpu(_decode_fn,
+                       *place(_decode_args(chunk, page_dtype))).compile()
+    for dtype in (jnp.float32, jnp.bfloat16):
+        _lower_tpu(_band_fn(dtype), *place(_band_args(dtype))).compile()
+
+
+@pytest.mark.slow
+def test_zero2_update_compiles_for_v5e_2x2():
+    """ZeRO-2's flat-master update for a four-chip host. Rebuilding a
+    [1024, 2] leaf (BERT's next-sentence head) out of the gathered flat
+    vector once made XLA:TPU re-view the whole vector as [n/2, 2] and pad
+    the 2 to 128 lanes: 64x the vector, 43 GB for BERT-large, refused at
+    buffer assignment on the first four-chip run. Same structure here,
+    sized so that the 64x copy cannot fit 16 GB."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
+    from deepspeed_tpu.ops.utils_op import tree_spec
+    from deepspeed_tpu.runtime.zero.sharded_optimizer import (
+        ZeroShardedOptimizer,
+        ZeroState,
+    )
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1),
+                ("pipe", "data", "model"))
+    rep, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    tree = lambda dtype: {
+        "encoder": SDS((140, 1000, 1000), dtype, sharding=rep),
+        "nsp_head": SDS((1024, 2), dtype, sharding=rep)}
+    opt = ZeroShardedOptimizer(FusedAdam(lr=1e-4), stage=2, mesh=mesh)
+    opt._spec = tree_spec(tree(jnp.bfloat16))
+    opt._numel = sum(opt._spec[3])
+    flat = SDS((-(-opt._numel // 4) * 4,), jnp.float32, sharding=shard)
+    inner = jax.tree_util.tree_map(
+        lambda x: SDS(x.shape, x.dtype,
+                      sharding=shard if x.shape == flat.shape else rep),
+        jax.eval_shape(opt.inner.init, flat))
+    compiled = jax.jit(
+        lambda g, st, p, lr: opt.update(g, st, p, lr=lr)).trace(
+        tree(jnp.float32), ZeroState(flat_master=flat, inner_state=inner),
+        tree(jnp.bfloat16), SDS((), jnp.float32, sharding=rep)).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30

@@ -190,7 +190,7 @@ def test_create_serving_mesh_shapes_and_device_floor():
 def test_serving_sharding_resolves_engine_buffer_paths():
     mesh = _mesh()
     kv = serving_sharding(mesh, "serving/kv_pool")
-    assert kv.spec == PartitionSpec(None, None, MODEL_AXIS, None, None)
+    assert kv.spec == PartitionSpec(None, None, MODEL_AXIS)
     lane = serving_sharding(mesh, "serving/lane_state")
     assert lane.spec == PartitionSpec()
 

@@ -6,7 +6,7 @@ Two modes:
 ``--check-schema [files...]``
     Validate that bench artifacts are structurally sound (required keys,
     numeric types, ``complete: true``). Defaults to the committed
-    baselines (``SERVING_BENCH_CPU.json`` + ``BENCH_r05.json`` +
+    baselines (``SERVING_BENCH_CPU.json`` +
     ``LONGDOC_BENCH_CPU.json`` + ``FLEET_BENCH_CPU.json`` +
     ``KERNEL_BENCH_CPU.json`` + ``CHAOS_BENCH_CPU.json`` +
     ``ROLLOUT_BENCH_CPU.json`` + ``DISAGG_BENCH_CPU.json`` +
@@ -21,7 +21,7 @@ Two modes:
     the committed artifact is never clobbered).
 
 Artifact kinds are auto-detected: a dict with a ``parsed`` key is a
-driver wrapper (``BENCH_r05.json``) and is unwrapped;
+driver wrapper around ``bench.py``'s one JSON line and is unwrapped;
 ``speedup_sparse_vs_dense_16k`` marks a long-document serving artifact
 (``LONGDOC_BENCH_CPU.json``); ``fleet_scaling_2x`` marks a fleet
 scale-out artifact (``FLEET_BENCH_CPU.json``); ``disagg_ttft_p95_s``
@@ -64,7 +64,7 @@ import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-DEFAULT_ARTIFACTS = ("SERVING_BENCH_CPU.json", "BENCH_r05.json",
+DEFAULT_ARTIFACTS = ("SERVING_BENCH_CPU.json",
                      "LONGDOC_BENCH_CPU.json", "FLEET_BENCH_CPU.json",
                      "KERNEL_BENCH_CPU.json", "CHAOS_BENCH_CPU.json",
                      "ROLLOUT_BENCH_CPU.json", "DISAGG_BENCH_CPU.json",
@@ -490,7 +490,7 @@ def load_artifact(path):
     if not isinstance(doc, dict):
         raise ValueError(f"{path}: artifact must be a JSON object")
     if "parsed" in doc and isinstance(doc["parsed"], dict):
-        doc = doc["parsed"]       # driver wrapper (BENCH_r05.json shape)
+        doc = doc["parsed"]       # driver wrapper around bench.py's line
     # longdoc first: it carries per-backend tokens/sec but no bare
     # "tokens_per_sec", and its "metric"-shaped stdout line never lands
     # in the artifact — still, keep the most specific marker in front.
@@ -1018,8 +1018,8 @@ def main(argv=None):
     parser.add_argument("--check-schema", nargs="*", default=None,
                         metavar="FILE",
                         help="validate artifact schema(s); defaults to the "
-                             "committed SERVING_BENCH_CPU.json + BENCH_r05."
-                             "json + LONGDOC_BENCH_CPU.json + "
+                             "committed SERVING_BENCH_CPU.json + "
+                             "LONGDOC_BENCH_CPU.json + "
                              "FLEET_BENCH_CPU.json + KERNEL_BENCH_CPU.json "
                              "+ CHAOS_BENCH_CPU.json + ROLLOUT_BENCH_CPU."
                              "json + DISAGG_BENCH_CPU.json + "
