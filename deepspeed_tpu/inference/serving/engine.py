@@ -391,6 +391,16 @@ def _prefill_batch_kernel_window_jit(params, init_k, init_v, padded_ids,
     return k, v, _prefill_tail(params, h, starts, true_lens)
 
 
+def _sample(logits, tokens, positions, active):
+    """Shared tail of every decode program: the greedy token of each
+    active lane and its advanced position (inactive lanes keep theirs)."""
+    with jax.named_scope("sample"):
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tokens = jnp.where(active, nxt, tokens)
+        positions = jnp.where(active, positions + 1, positions)
+    return tokens, positions
+
+
 @partial(jax.jit, static_argnames=("n_heads",), donate_argnums=(1, 2, 4, 5))
 def _decode_step_jit(params, pool_k, pool_v, page_tables, tokens, positions,
                      active, *, n_heads):
@@ -407,25 +417,27 @@ def _decode_step_jit(params, pool_k, pool_v, page_tables, tokens, positions,
     are NOT (they live on device across steps), so steady-state decode
     still needs no per-step host->device upload at all."""
     pt = pool_k.shape[3]
-    lanes_k = _gather_lanes(pool_k, page_tables)
-    lanes_v = _gather_lanes(pool_v, page_tables)
+    with jax.named_scope("kv_gather"):
+        lanes_k = _gather_lanes(pool_k, page_tables)
+        lanes_v = _gather_lanes(pool_v, page_tables)
 
     def lane(ck, cv, tok, pos):
         logits, (ck2, cv2) = _step(params, n_heads, (ck[:, None], cv[:, None]),
                                    tok[None], pos)
         return logits[0], ck2[:, 0], cv2[:, 0]
 
-    logits, lanes_k, lanes_v = jax.vmap(
-        lane, in_axes=(1, 1, 0, 0), out_axes=(0, 1, 1))(
-        lanes_k, lanes_v, tokens, positions)
-    pool_k = _scatter_rows(pool_k, page_tables, _lane_rows(lanes_k, positions),
-                           positions, active, pt)
-    pool_v = _scatter_rows(pool_v, page_tables, _lane_rows(lanes_v, positions),
-                           positions, active, pt)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tokens = jnp.where(active, nxt, tokens)
-    positions = jnp.where(active, positions + 1, positions)
-    return tokens, positions, pool_k, pool_v
+    with jax.named_scope("attend"):
+        logits, lanes_k, lanes_v = jax.vmap(
+            lane, in_axes=(1, 1, 0, 0), out_axes=(0, 1, 1))(
+            lanes_k, lanes_v, tokens, positions)
+    with jax.named_scope("kv_scatter"):
+        pool_k = _scatter_rows(pool_k, page_tables,
+                               _lane_rows(lanes_k, positions),
+                               positions, active, pt)
+        pool_v = _scatter_rows(pool_v, page_tables,
+                               _lane_rows(lanes_v, positions),
+                               positions, active, pt)
+    return _sample(logits, tokens, positions, active) + (pool_k, pool_v)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "qmode"),
@@ -444,8 +456,9 @@ def _decode_step_quant_jit(params, pool_k, pool_v, k_scale, v_scale,
     "bf16" the scale operands are None)."""
     dtype = _cache_dtype(params)
     pt = pool_k.shape[3]
-    lanes_k = _gather_lanes(pool_k, page_tables)
-    lanes_v = _gather_lanes(pool_v, page_tables)
+    with jax.named_scope("kv_gather"):
+        lanes_k = _gather_lanes(pool_k, page_tables)
+        lanes_v = _gather_lanes(pool_v, page_tables)
 
     if qmode == "int8":
         def lane(ck, cv, sk, sv, tok, pos):
@@ -457,9 +470,10 @@ def _decode_step_quant_jit(params, pool_k, pool_v, k_scale, v_scale,
             return (logits[0], requantize_kv(ck2[:, 0], sk),
                     requantize_kv(cv2[:, 0], sv))
 
-        logits, lanes_k, lanes_v = jax.vmap(
-            lane, in_axes=(1, 1, 1, 1, 0, 0), out_axes=(0, 1, 1))(
-            lanes_k, lanes_v, k_scale, v_scale, tokens, positions)
+        with jax.named_scope("attend"):
+            logits, lanes_k, lanes_v = jax.vmap(
+                lane, in_axes=(1, 1, 1, 1, 0, 0), out_axes=(0, 1, 1))(
+                lanes_k, lanes_v, k_scale, v_scale, tokens, positions)
     else:
         def lane(ck, cv, tok, pos):
             logits, (ck2, cv2) = _step(
@@ -469,17 +483,18 @@ def _decode_step_quant_jit(params, pool_k, pool_v, k_scale, v_scale,
             return (logits[0], ck2[:, 0].astype(jnp.bfloat16),
                     cv2[:, 0].astype(jnp.bfloat16))
 
-        logits, lanes_k, lanes_v = jax.vmap(
-            lane, in_axes=(1, 1, 0, 0), out_axes=(0, 1, 1))(
-            lanes_k, lanes_v, tokens, positions)
-    pool_k = _scatter_rows(pool_k, page_tables, _lane_rows(lanes_k, positions),
-                           positions, active, pt)
-    pool_v = _scatter_rows(pool_v, page_tables, _lane_rows(lanes_v, positions),
-                           positions, active, pt)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tokens = jnp.where(active, nxt, tokens)
-    positions = jnp.where(active, positions + 1, positions)
-    return tokens, positions, pool_k, pool_v
+        with jax.named_scope("attend"):
+            logits, lanes_k, lanes_v = jax.vmap(
+                lane, in_axes=(1, 1, 0, 0), out_axes=(0, 1, 1))(
+                lanes_k, lanes_v, tokens, positions)
+    with jax.named_scope("kv_scatter"):
+        pool_k = _scatter_rows(pool_k, page_tables,
+                               _lane_rows(lanes_k, positions),
+                               positions, active, pt)
+        pool_v = _scatter_rows(pool_v, page_tables,
+                               _lane_rows(lanes_v, positions),
+                               positions, active, pt)
+    return _sample(logits, tokens, positions, active) + (pool_k, pool_v)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "page_tokens", "qmode",
@@ -534,8 +549,9 @@ def _decode_step_window_jit(params, pool_k, pool_v, k_scale, v_scale,
             krow, vrow = kk.astype(jnp.bfloat16), vv.astype(jnp.bfloat16)
         else:
             krow, vrow = kk, vv
-        pk_l = pk_l.at[dp, :, off].set(krow)
-        pv_l = pv_l.at[dp, :, off].set(vrow)
+        with jax.named_scope("kv_scatter"):
+            pk_l = pk_l.at[dp, :, off].set(krow)
+            pv_l = pv_l.at[dp, :, off].set(vrow)
 
         def stripe(buf, scale):
             def dq(x):
@@ -548,16 +564,19 @@ def _decode_step_window_jit(params, pool_k, pool_v, k_scale, v_scale,
             win = win.reshape(B, n_heads, (SPARSE_BAND + 1) * pt, -1)
             return dq(win), dq(buf[sink_phys])
 
-        k_win, k_sink = stripe(pk_l, sk_l)
-        v_win, v_sink = stripe(pv_l, sv_l)
-        if kernel_impl is not None:
-            ctx = kernels.band_attend(
-                q, k_win, v_win, k_sink, v_sink, positions, base,
-                dtype=dtype, impl=kernel_impl, interpret=kernel_interpret)
-        else:
-            ctx = jax.vmap(_attend_window_one,
-                           in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
-                q, k_win, v_win, k_sink, v_sink, positions, base, dtype)
+        with jax.named_scope("kv_gather"):
+            k_win, k_sink = stripe(pk_l, sk_l)
+            v_win, v_sink = stripe(pv_l, sv_l)
+        with jax.named_scope("attend"):
+            if kernel_impl is not None:
+                ctx = kernels.band_attend(
+                    q, k_win, v_win, k_sink, v_sink, positions, base,
+                    dtype=dtype, impl=kernel_impl,
+                    interpret=kernel_interpret)
+            else:
+                ctx = jax.vmap(_attend_window_one,
+                               in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
+                    q, k_win, v_win, k_sink, v_sink, positions, base, dtype)
         h = _window_finish(lp, h, ctx)
         return h, (pk_l, pv_l)
 
@@ -565,10 +584,7 @@ def _decode_step_window_jit(params, pool_k, pool_v, k_scale, v_scale,
         layer_body, h, (layer_p, pool_k, pool_v, k_scale, v_scale))
     h = _ln(h, tr["ln_f"])
     logits = h @ logits_table(tr["wte"], h.dtype).T
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tokens = jnp.where(active, nxt, tokens)
-    positions = jnp.where(active, positions + 1, positions)
-    return tokens, positions, pool_k, pool_v
+    return _sample(logits, tokens, positions, active) + (pool_k, pool_v)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "page_tokens", "qmode",
@@ -623,12 +639,15 @@ def _decode_step_kernel_jit(params, pool_k, pool_v, k_scale, v_scale,
         else:
             krow, vrow = kk, vv
             ksp = vsp = None
-        pk_l = pk_l.at[dp, :, off].set(krow)
-        pv_l = pv_l.at[dp, :, off].set(vrow)
-        ctx = kernels.decode_attend(
-            q[:, None], pk_l, pv_l, page_tables, qpos, page_tokens=pt,
-            dtype=dtype, impl=kernel_impl, interpret=kernel_interpret,
-            k_scale=ksp, v_scale=vsp)[:, 0]
+        with jax.named_scope("kv_scatter"):
+            pk_l = pk_l.at[dp, :, off].set(krow)
+            pv_l = pv_l.at[dp, :, off].set(vrow)
+        # the paged gather happens inside the kernel's DMA schedule
+        with jax.named_scope("attend"):
+            ctx = kernels.decode_attend(
+                q[:, None], pk_l, pv_l, page_tables, qpos, page_tokens=pt,
+                dtype=dtype, impl=kernel_impl, interpret=kernel_interpret,
+                k_scale=ksp, v_scale=vsp)[:, 0]
         h = _window_finish(lp, h, ctx)
         return h, (pk_l, pv_l)
 
@@ -636,10 +655,7 @@ def _decode_step_kernel_jit(params, pool_k, pool_v, k_scale, v_scale,
         layer_body, h, (layer_p, pool_k, pool_v, k_scale, v_scale))
     h = _ln(h, tr["ln_f"])
     logits = h @ logits_table(tr["wte"], h.dtype).T
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tokens = jnp.where(active, nxt, tokens)
-    positions = jnp.where(active, positions + 1, positions)
-    return tokens, positions, pool_k, pool_v
+    return _sample(logits, tokens, positions, active) + (pool_k, pool_v)
 
 
 def _attend_window_chunk(q, cache_k, cache_v, qpos, pt, dtype):
@@ -909,7 +925,8 @@ class _ChunkedPrefill:
     (carried across engine steps between chunk calls), how far it has
     prefilled, and the pool slot reserved for it at start."""
 
-    __slots__ = ("req", "k", "v", "pos", "reuse", "slot", "prefill_s")
+    __slots__ = ("req", "k", "v", "pos", "reuse", "slot", "prefill_s",
+                 "positions_run")
 
     def __init__(self, req, k, v, pos, reuse, slot):
         self.req = req
@@ -919,6 +936,7 @@ class _ChunkedPrefill:
         self.reuse = reuse
         self.slot = slot
         self.prefill_s = 0.0
+        self.positions_run = 0
 
 
 class _EngineLadderShim:
@@ -1279,6 +1297,10 @@ class ServingEngine:
         self._chunking = None               # at most one chunked prefill
         self._step_count = 0
         self._busy_steps = 0                # steps that had active lanes
+        # prefills (and chunks) run so far: a request remembers the count
+        # at each token it emits, so the next gap knows whether a prefill
+        # ran inside it (stalled by cause, not by a threshold)
+        self._prefill_seq = 0
         self._loop_thread = None
         self._stop = threading.Event()
         self._draining = False              # planned restart: admit nothing
@@ -1296,6 +1318,11 @@ class ServingEngine:
         telemetry.configure_from_config(telemetry_config, rank=rank,
                                         role="serve")
         self._tracer = telemetry.get_tracer()
+        # an armed span is also a TraceMe event: under a jax.profiler
+        # session the host spans land in the same .xplane.pb, on the same
+        # clock, as the device's programs (telemetry itself never imports
+        # jax, so the engine hands it the annotation class)
+        self._tracer.set_annotation_factory(jax.profiler.TraceAnnotation)
         self._trace_file = None
         self.telemetry_server = None
         self.slo = None
@@ -1653,6 +1680,7 @@ class ServingEngine:
             self._activate(req, slot, int(first_token), emit=False)
             req.future._append(int(first_token))
             req.emitted = 1
+            self._stamp_token(req, now)
             self.metrics.record_handoff("resume")
             # defensively retire right away if the first token already
             # ended the request (the router short-circuits these, but a
@@ -1667,9 +1695,11 @@ class ServingEngine:
         """One scheduler iteration: expire, advance any chunked prefill,
         admit (batched per bucket), one batched decode step, retire.
         Returns an activity dict (all zeros = idle)."""
-        now = time.monotonic()
+        top = now = time.monotonic()
         stats = {"admitted": 0, "decoded": 0, "retired": 0,
                  "prefill_chunks": 0}
+        read_back = None        # instant the decode step's tokens landed
+        espan = telemetry.NULL_SPAN
 
         self._drain_loop_ops()
 
@@ -1680,9 +1710,10 @@ class ServingEngine:
         # one chunk per step: a long prompt makes progress without ever
         # stalling the in-flight lanes' inter-token latency
         if self._chunking is not None:
-            self._advance_chunk(stats)
+            now = self._advance_chunk(stats)
 
-        self._admit_from_queue(stats)
+        # admission is timed from the last stamp the iteration holds
+        self._admit_from_queue(stats, now)
 
         if self.injector is not None:
             self.injector.maybe_evict_prefix(self._step_count,
@@ -1716,7 +1747,11 @@ class ServingEngine:
             dspan.__enter__()
             t0 = time.monotonic()
             if self._lane_dirty:
-                self._upload_lane_state()
+                with (self._tracer.span("serving/upload_lanes",
+                                        cat="serving",
+                                        args={"active": len(self._active)})
+                      if self._tracer.enabled else telemetry.NULL_SPAN):
+                    self._upload_lane_state()
             guard = transfer_free() if self._transfer_guard else nullcontext()
             # host-side np masks: np.bool_ drives the dispatch branches
             # directly (a bool() cast here reads as a device sync to JL002)
@@ -1771,12 +1806,18 @@ class ServingEngine:
                         oracle[mask] = o[mask]
                         accepted[mask] = a[mask]
                 step_s = time.monotonic() - t0
+                read_back = t0 + step_s
                 oracle = oracle.tolist()        # host numpy -> python ints
                 accepted = accepted.tolist()
                 acc_total = sum(accepted[s] for s in self._active)
                 if span_args is not None:
                     span_args["accepted"] = acc_total
                 dspan.__exit__(None, None, None)
+                espan = (self._tracer.span(
+                             "serving/emit", cat="serving",
+                             args={"active": len(self._active)})
+                         if self._tracer.enabled else telemetry.NULL_SPAN)
+                espan.__enter__()
                 now = time.monotonic()
                 n_active = len(self._active)
                 decoded_before = stats["decoded"]
@@ -1792,6 +1833,7 @@ class ServingEngine:
                         self.pool.advance(slot)
                         if base + 1 + j < self.max_seq_len:
                             self._lane_history[slot, base + 1 + j] = tok
+                        self._stamp_token(req, now)
                         self._emit(req, tok)
                         stats["decoded"] += 1
                         if self._maybe_retire(req, tok, now):
@@ -1875,7 +1917,13 @@ class ServingEngine:
                 # tokens
                 host_tokens = jax.device_get(self._dev_tokens)  # jaxlint: disable=JL002(one explicit host read per step)
                 step_s = time.monotonic() - t0
+                read_back = t0 + step_s
                 dspan.__exit__(None, None, None)
+                espan = (self._tracer.span(
+                             "serving/emit", cat="serving",
+                             args={"active": len(self._active)})
+                         if self._tracer.enabled else telemetry.NULL_SPAN)
+                espan.__enter__()
                 self._lane_tokens = host_tokens.copy()
                 toks = host_tokens.tolist()
                 now = time.monotonic()
@@ -1892,6 +1940,7 @@ class ServingEngine:
                         # drafter context (stale history would only cost
                         # accept rate, but fresh is free here)
                         self._lane_history[slot, base + 1] = toks[slot]
+                    self._stamp_token(req, now)
                     self._emit(req, toks[slot])
                     stats["decoded"] += 1
                     stats["retired"] += self._maybe_retire(req, toks[slot],
@@ -1915,6 +1964,14 @@ class ServingEngine:
             # host-only snapshot + pushed gauges; under policy="fail" a
             # firing rule raises SloViolationError out of step()
             self.slo.evaluate(self._slo_values())
+        if (stats["decoded"] or stats["admitted"] or stats["retired"]
+                or stats["prefill_chunks"]):
+            # the iteration's one new clock read: it closes the busy time
+            # and the host phase behind the token read-back
+            end = time.monotonic()
+            self.metrics.record_iteration(
+                end - top, end - read_back if read_back is not None else 0.0)
+        espan.__exit__(None, None, None)
         return stats
 
     def _slo_values(self):
@@ -2161,11 +2218,14 @@ class ServingEngine:
             self._tracer.write(self._trace_file)
 
     # -- admission ------------------------------------------------------
-    def _admit_from_queue(self, stats):
+    def _admit_from_queue(self, stats, now):
         """Join-at-free-slot admission, batched per bucket: pop the FIFO
         head, gather every queued request sharing its (prefix-adjusted)
         bucket up to the free-slot count, and prefill them as ONE call.
-        Long prompts divert to the chunked path (one at a time)."""
+        Long prompts divert to the chunked path (one at a time).
+        ``now`` is the latest stamp the iteration holds: an admission
+        that admitted anything is timed from it to its end."""
+        admitted = stats["admitted"]
         if self._tracer.enabled and self.scheduler.queue_depth() > 0:
             with self._tracer.span(
                     "serving/admission", cat="serving",
@@ -2173,6 +2233,8 @@ class ServingEngine:
                 self._admit_from_queue_now(stats)
         else:
             self._admit_from_queue_now(stats)
+        if stats["admitted"] > admitted:
+            self.metrics.admit_time_s += time.monotonic() - now
 
     def _impl_for_len(self, prompt_len):
         """Attention backend for a request, selected by its FULL prompt
@@ -2252,7 +2314,7 @@ class ServingEngine:
         pspan = (self._tracer.span(
                      "serving/prefill_batch", cat="serving",
                      args={"request_ids": [r.id for r in group],
-                           "bucket": bucket})
+                           "bucket": bucket, "group": len(group)})
                  if self._tracer.enabled else telemetry.NULL_SPAN)
         pspan.__enter__()
         B, total = self._prefill_batch, self.max_seq_len
@@ -2318,11 +2380,19 @@ class ServingEngine:
                                         self._put_host(lens))
         first_host = np.asarray(first)             # sync: TTFT endpoint
         prefill_s = time.monotonic() - t0
+        self._prefill_seq += 1
+        # every row of the bucket runs, whatever the group's size
         self.metrics.record_prefill(
             tokens=sum(len(r.prompt) - re for r, re, _, _ in plan),
             reused_tokens=sum(re for _, re, _, _ in plan),
-            requests=len(plan), prefill_s=prefill_s)
+            requests=len(plan), prefill_s=prefill_s, positions_run=B * Sb)
+        self.metrics.record_queue_wait(
+            sum(t0 - r.submit_time for r, _, _, _ in plan), len(plan))
 
+        ispan = (self._tracer.span("serving/install", cat="serving",
+                                   args={"group": len(plan)})
+                 if self._tracer.enabled else telemetry.NULL_SPAN)
+        ispan.__enter__()
         now = time.monotonic()
         retired = 0
         for i, (req, reuse, entry, slot) in enumerate(plan):
@@ -2332,12 +2402,14 @@ class ServingEngine:
             req.prefix_entry = entry
             req.first_token_time = now
             self.metrics.record_first_token(now - req.submit_time)
+            self._stamp_token(req, now)
             self._activate(req, slot, int(first_host[i]))
             retired += self._maybe_retire(req, int(first_host[i]), now)
         # settle the queued lane installs here so they are accounted to
         # admission, not silently absorbed into the next decode step's
         # measured latency
         self.pool.k.block_until_ready()
+        ispan.__exit__(None, None, None)
         pspan.__exit__(None, None, None)
         return len(plan), retired
 
@@ -2433,16 +2505,17 @@ class ServingEngine:
         """Run the next chunk of the in-flight chunked prefill (same
         compiled program as batched prefill, at B=1/Sb=chunk); install
         and activate on the final chunk. Mid chunks never block the host
-        — only the final chunk syncs, for its first token."""
+        — only the final chunk syncs, for its first token. Returns the
+        last clock stamp it took; a chunk's own time is admission time."""
         st = self._chunking
         req = st.req
-        now = time.monotonic()
+        top = now = time.monotonic()
         if req.deadline_exceeded(now):
             req.slot = st.slot             # hand the reserved slot back
             self._finish_timeout(req, phase="prefill")
             self._chunking = None
             stats["retired"] += 1
-            return
+            return now
         impl = getattr(req, "attn_impl", "dense")
         chunk_len = self.config.prefill_chunk_tokens
         # sparse chunks pad to a page multiple (blocked attention width
@@ -2460,29 +2533,39 @@ class ServingEngine:
                                          "chunk": len(chunk)})
                  if self._tracer.enabled else telemetry.NULL_SPAN)
         t0 = time.monotonic()
+        if st.pos == st.reuse:                     # the first chunk
+            self.metrics.record_queue_wait(t0 - req.submit_time)
         with cspan:
             st.k, st.v, first = self._run_prefill(
                 impl, st.k, st.v, self._put_host(ids),
                 self._put_host(np.asarray([st.pos], np.int32)),
                 self._put_host(np.asarray([len(req.prompt)], np.int32)))
         st.pos += len(chunk)
+        st.positions_run += cw
         stats["prefill_chunks"] += 1
+        self._prefill_seq += 1
         if st.pos < len(req.prompt):
-            st.prefill_s += time.monotonic() - t0
-            return
+            now = time.monotonic()
+            st.prefill_s += now - t0
+            self.metrics.admit_time_s += now - top
+            return now
         first_tok = int(np.asarray(first)[0])      # sync: TTFT endpoint
         st.prefill_s += time.monotonic() - t0
         now = time.monotonic()
+        self.metrics.admit_time_s += now - top
         self.metrics.record_prefill(
             tokens=len(req.prompt) - st.reuse, reused_tokens=st.reuse,
-            requests=1, prefill_s=st.prefill_s)
+            requests=1, prefill_s=st.prefill_s,
+            positions_run=st.positions_run)
         self._maybe_insert_prefix(req, st.reuse, st.k, st.v, lane=0)
         self.pool.install(st.k, st.v, st.slot, position=len(req.prompt))
         req.first_token_time = now
         self.metrics.record_first_token(now - req.submit_time)
+        self._stamp_token(req, now)
         self._activate(req, st.slot, first_tok)
         stats["retired"] += self._maybe_retire(req, first_tok, now)
         self._chunking = None
+        return now
 
     # -- prefix cache ---------------------------------------------------
     def _suffix_len(self, req):
@@ -2578,6 +2661,18 @@ class ServingEngine:
         self._lane_dirty = True
         if emit:
             self._emit(req, first_tok)
+
+    def _stamp_token(self, req, now):
+        """Beside each token handed out: ``now`` is the stamp the iteration
+        already holds (taken after the read-back that produced the token).
+        The gap to the request's previous token is counted from it, and
+        counted stalled when a prefill ran since that token."""
+        if req.last_emit_time is not None:
+            self.metrics.record_token_gap(
+                now - req.last_emit_time,
+                req.last_emit_prefill_seq != self._prefill_seq)
+        req.last_emit_time = now
+        req.last_emit_prefill_seq = self._prefill_seq
 
     def _emit(self, req, token):
         req.emitted += 1

@@ -86,8 +86,11 @@ def _install_pages(pool_k, pool_v, new_k, new_v, dest_pages, page_tokens):
         pages = lane.reshape(L, nh, mp, page_tokens, hd)
         return jnp.moveaxis(pages, 2, 1)                 # [L, mp, nh, pt, hd]
 
-    pool_k = pool_k.at[:, dest_pages].set(paged(new_k).astype(pool_k.dtype))
-    pool_v = pool_v.at[:, dest_pages].set(paged(new_v).astype(pool_v.dtype))
+    with jax.named_scope("install_pages"):
+        pool_k = pool_k.at[:, dest_pages].set(
+            paged(new_k).astype(pool_k.dtype))
+        pool_v = pool_v.at[:, dest_pages].set(
+            paged(new_v).astype(pool_v.dtype))
     return pool_k, pool_v
 
 
@@ -106,12 +109,15 @@ def _install_pages_int8(pool_k, pool_v, k_scale, v_scale, new_k, new_v,
         pages = q.reshape(L, nh, mp, page_tokens, hd)
         return jnp.moveaxis(pages, 2, 1), s
 
-    qk, sk = quant_paged(new_k)
-    qv, sv = quant_paged(new_v)
-    pool_k = pool_k.at[:, dest_pages].set(qk)
-    pool_v = pool_v.at[:, dest_pages].set(qv)
-    k_scale = jax.lax.dynamic_update_index_in_dim(k_scale, sk, slot, axis=1)
-    v_scale = jax.lax.dynamic_update_index_in_dim(v_scale, sv, slot, axis=1)
+    with jax.named_scope("install_pages"):
+        qk, sk = quant_paged(new_k)
+        qv, sv = quant_paged(new_v)
+        pool_k = pool_k.at[:, dest_pages].set(qk)
+        pool_v = pool_v.at[:, dest_pages].set(qv)
+        k_scale = jax.lax.dynamic_update_index_in_dim(k_scale, sk, slot,
+                                                      axis=1)
+        v_scale = jax.lax.dynamic_update_index_in_dim(v_scale, sv, slot,
+                                                      axis=1)
     return pool_k, pool_v, k_scale, v_scale
 
 

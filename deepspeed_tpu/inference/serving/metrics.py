@@ -11,7 +11,6 @@ configured the recorder is still useful as a cheap in-process stats
 object (``snapshot()``).
 """
 
-import time
 from collections import deque
 
 # TTFT percentile window: newest samples win once full (a long-running
@@ -46,6 +45,27 @@ class ServingMetrics:
         self.prefill_tokens = 0
         self.prefill_reused_tokens = 0
         self.prefill_time_s = 0.0
+        # positions the prefill programs RAN (rows x bucket, dummy rows
+        # and padding included), beside the positions the prompts needed
+        self.prefill_positions_run = 0
+        # the loop's own account of its time: every one a monotone sum
+        # over clock stamps the loop takes anyway (no container, nothing
+        # per token but float adds). loop_busy_s: wall time of iterations
+        # that did anything; decode_host_s: token read-back to the end of
+        # the iteration; admit_time_s: whole admissions (prefill_time_s is
+        # the part inside them spent from prefill dispatch to read-back)
+        self.loop_busy_s = 0.0
+        self.decode_host_s = 0.0
+        self.admit_time_s = 0.0
+        # submit() to prefill dispatch, per admitted request
+        self.queue_wait_s = 0.0
+        self.queue_waits = 0
+        # gaps between consecutive tokens of one request; a gap is
+        # stalled when a prefill (or a chunk) ran between its two tokens
+        self.token_gaps = 0
+        self.token_gap_s = 0.0
+        self.stalled_gaps = 0
+        self.stalled_gap_s = 0.0
         # prefix cache lookups (mirrors the cache's own counters so a
         # snapshot works without reaching into the engine)
         self.prefix_hits = 0
@@ -84,7 +104,6 @@ class ServingMetrics:
         # deque(maxlen=...) evicts the oldest sample in O(1); the old list
         # did an O(n) pop(0) memmove per TTFT once full
         self._ttft_window = deque(maxlen=_TTFT_WINDOW)
-        self._started = time.monotonic()
 
     # -- recording hooks (engine calls these) ---------------------------
     def record_first_token(self, ttft_s):
@@ -94,18 +113,43 @@ class ServingMetrics:
         self._ttft_window.append(ttft_s)
         self._record("Serving/ttft_s", ttft_s, self._ttft_count)
 
-    def record_prefill(self, tokens, reused_tokens, requests, prefill_s):
+    def record_prefill(self, tokens, reused_tokens, requests, prefill_s,
+                       positions_run=0):
         """One prefill call: ``tokens`` computed this call (suffix only
         on a prefix hit), ``reused_tokens`` seeded from the prefix cache,
-        over ``requests`` prompts in ``prefill_s`` seconds."""
+        over ``requests`` prompts in ``prefill_s`` seconds;
+        ``positions_run`` is what the program computed for them (rows x
+        bucket, summed over the chunks of a chunked prefill)."""
         self.prefill_calls += 1
         self.prefill_tokens += tokens
+        self.prefill_positions_run += positions_run
         self.prefill_reused_tokens += reused_tokens
         self.prefill_time_s += prefill_s
         if prefill_s > 0:
             self._record("Serving/prefill_tokens_per_sec",
                          tokens / prefill_s, self.prefill_calls)
         self._record("Serving/prefill_batch", requests, self.prefill_calls)
+
+    def record_queue_wait(self, wait_s, requests=1):
+        """``requests`` admitted prompts waited ``wait_s`` seconds in all
+        between submit() and the dispatch of their prefill."""
+        self.queue_wait_s += wait_s
+        self.queue_waits += requests
+
+    def record_token_gap(self, gap_s, stalled):
+        """One gap between consecutive tokens of one request."""
+        self.token_gaps += 1
+        self.token_gap_s += gap_s
+        if stalled:
+            self.stalled_gaps += 1
+            self.stalled_gap_s += gap_s
+
+    def record_iteration(self, busy_s, decode_host_s):
+        """One loop iteration that did anything: its wall time, and the
+        part of it after the decode step's token read-back (0.0 when no
+        decode step ran)."""
+        self.loop_busy_s += busy_s
+        self.decode_host_s += decode_host_s
 
     def record_prefix_lookup(self, hit):
         if hit:
@@ -280,6 +324,20 @@ class ServingMetrics:
             "decode_tokens": self.tokens_emitted,
             "prefill_calls": self.prefill_calls,
             "prefill_tokens_per_sec": self.prefill_tokens_per_sec(),
+            "prefill_positions_run": self.prefill_positions_run,
+            # where the loop's time went (monotone sums: a reader takes
+            # after-minus-before over its window)
+            "decode_time_s": self.decode_time_s,
+            "prefill_time_s": self.prefill_time_s,
+            "loop_busy_s": self.loop_busy_s,
+            "decode_host_s": self.decode_host_s,
+            "admit_time_s": self.admit_time_s,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_waits": self.queue_waits,
+            "token_gaps": self.token_gaps,
+            "token_gap_s": self.token_gap_s,
+            "stalled_gaps": self.stalled_gaps,
+            "stalled_gap_s": self.stalled_gap_s,
             "prefix_reused_tokens": self.prefill_reused_tokens,
             "prefix_hit_rate": self.prefix_hit_rate(),
             # speculative decoding + pool storage
@@ -300,7 +358,6 @@ class ServingMetrics:
             # host RSS are read at snapshot time, not last-recorded)
             "spill_hit_rate": self.spill_hit_rate(),
             "spill_corrupt_total": self.spill_corrupt_total,
-            "uptime_s": time.monotonic() - self._started,
         }
         if self._spill_stats_fn is not None:
             try:

@@ -133,6 +133,10 @@ class Request:
         self.first_token_time = None            # TTFT endpoint
         self.slot = None
         self.emitted = 0
+        # the engine's stamp at this request's latest token, and how many
+        # prefills the loop had run by then (token gaps, stalled or not)
+        self.last_emit_time = None
+        self.last_emit_prefill_seq = 0
         self.prefix_entry = None                # held prefix-cache ref
         self.attn_impl = "dense"                # set by engine at admission
 

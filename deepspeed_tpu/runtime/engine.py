@@ -334,6 +334,10 @@ class DeepSpeedEngine:
         telemetry.configure_from_config(self._config.telemetry_config,
                                         rank=self.global_rank, role="train")
         self._tracer = telemetry.get_tracer()
+        # armed spans also open a profiler annotation, so that they land in
+        # a jax.profiler trace on the device's clock (telemetry imports no
+        # jax; the engine hands it the class)
+        self._tracer.set_annotation_factory(jax.profiler.TraceAnnotation)
         from deepspeed_tpu.monitor import monitor_from_config
 
         self.monitor = monitor_from_config(self._config, self.global_rank)
@@ -818,6 +822,7 @@ class DeepSpeedEngine:
         tap = self._grad_overlap_tap()
 
         def fwd_bwd(params, scale, rng, theta, *batch):
+            @jax.named_scope("loss")
             def loss_fn(p):
                 if tap is not None:
                     # overlap_comm: identity on the forward; each bucket's
@@ -1136,18 +1141,20 @@ class DeepSpeedEngine:
                 def body(acc, mb):
                     i, batch = mb
                     loss, grads = fwd_bwd(params, scale, jax.random.fold_in(rng, i), theta, *batch)
-                    acc = jax.tree_util.tree_map(
-                        lambda a, g: a + g.astype(jnp.float32) * factor, acc, grads
-                    )
+                    with jax.named_scope("grad_accumulate"):
+                        acc = jax.tree_util.tree_map(
+                            lambda a, g: a + g.astype(jnp.float32) * factor, acc, grads
+                        )
                     return acc, loss
 
                 zeros = jax.tree_util.tree_map(
                     lambda p: jnp.zeros(p.shape, jnp.float32), params
                 )
                 acc, losses = jax.lax.scan(body, zeros, (jnp.arange(gas), stacked))
-                new_params, new_opt_state, new_scaler, overflow, gnorm = update(
-                    params, opt_state, acc, scaler_state, lr
-                )
+                with jax.named_scope("optimizer_update"):
+                    new_params, new_opt_state, new_scaler, overflow, gnorm = update(
+                        params, opt_state, acc, scaler_state, lr
+                    )
                 return new_params, new_opt_state, new_scaler, jnp.mean(losses), overflow, gnorm
 
             # params/opt_state/scaler donate always (in-place update in HBM).
@@ -1608,19 +1615,6 @@ class DeepSpeedEngine:
                 self.params, self.opt_state, self.scaler_state, self._next_rng(), theta,
                 lr, *stacked,
             )
-            if self._tracer.enabled:
-                # overlap_comm: one child span per reduce bucket. The dispatch
-                # is async and the collectives live inside ONE XLA program, so
-                # these are schedule markers (bucket id + numel), not wall
-                # timings — the timeline shows WHICH buckets the backward
-                # reduces and in what order.
-                for b, n in enumerate(
-                        getattr(self.optimizer, "bucket_numels", None) or ()):
-                    with self._tracer.span(
-                            "train/grad_reduce", cat="train",
-                            args={"step": self.global_steps, "bucket": b,
-                                  "numel": n}):
-                        pass
         self._last_loss = loss
         self._loss_sum = loss * gas
         self.micro_steps += gas

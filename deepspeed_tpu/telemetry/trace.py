@@ -18,6 +18,15 @@ Design constraints (they are the whole point):
   loops additionally guard on ``tracer.enabled`` (one attribute read) so
   even argument construction is skipped.
 
+- **One span system, two sinks.** A process that imports JAX installs an
+  annotation factory (:meth:`Tracer.set_annotation_factory`, given
+  ``jax.profiler.TraceAnnotation`` by the engines): an armed span then also
+  opens one such annotation, so under a profiler session the host span
+  lands in the profiler's own trace, on its clock, beside the device's
+  programs. Scalar span arguments go along as the annotation's keyword
+  arguments; lists (request ids) stay with the ring buffer. Without a
+  factory, or with no session open, nothing changes.
+
 The emitted JSON is the Chrome trace event format (load in Perfetto or
 ``chrome://tracing``): complete events ``ph="X"`` with ``ts``/``dur`` in
 microseconds, instant events ``ph="i"``, one ``pid`` per process and the
@@ -39,6 +48,9 @@ PH_METADATA = "M"
 
 _DEFAULT_MAX_EVENTS = 65536
 
+# span arguments cheap enough to ride on a profiler annotation
+_SCALARS = (int, float, str, bool)
+
 
 class _NullSpan:
     """Shared no-op context manager returned by disabled tracers."""
@@ -59,7 +71,7 @@ class _Span:
     """Live span: ``__enter__`` stamps t0, ``__exit__`` stamps t1 and
     appends one tuple. Everything else happens at render time."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -67,13 +79,22 @@ class _Span:
         self._cat = cat
         self._args = args
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self):
+        factory = self._tracer._annotation_factory
+        if factory is not None:
+            scalars = {k: v for k, v in self._args.items()
+                       if isinstance(v, _SCALARS)} if self._args else {}
+            self._annotation = factory(self._name, **scalars)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._append(
             (PH_COMPLETE, self._name, self._cat, self._t0, t1 - self._t0,
              threading.get_ident(), self._args))
@@ -94,6 +115,7 @@ class Tracer:
         self._epoch = time.perf_counter()
         self.epoch_unix = time.time()
         self._process_info = None
+        self._annotation_factory = None
         self._lock = threading.Lock()   # drain/render only; appends rely on GIL
 
     # -- configuration --------------------------------------------------
@@ -110,6 +132,14 @@ class Tracer:
     @property
     def max_events(self):
         return self._events.maxlen
+
+    def set_annotation_factory(self, factory):
+        """``factory(name, **scalar_args)`` returns a context manager that
+        every armed span opens beside its ring-buffer record (``None``
+        takes it out again). The engines pass
+        ``jax.profiler.TraceAnnotation``; this module never imports it."""
+        self._annotation_factory = factory
+        return self
 
     def set_process_info(self, rank=None, role=None, label=None,
                          sort_index=None):
