@@ -35,8 +35,11 @@ def chunked_cross_entropy(hidden, kernel, bias, labels, ignore_index=-1,
         h = jnp.concatenate([h, jnp.zeros((pad, H), h.dtype)])
         y = jnp.concatenate([y, jnp.full((pad,), ignore_index, y.dtype)])
     n_chunks = h.shape[0] // rows
-    h = h.reshape(n_chunks, rows, H)
-    y = y.reshape(n_chunks, rows)
+    # Chunk c holds flat rows c, c + n_chunks, ...: the axis the scan walks
+    # is then never the one a batch sharding splits (every chunk takes an
+    # equal slice of each device's rows), so no device gathers the others'.
+    h = jnp.moveaxis(h.reshape(rows, n_chunks, H), 1, 0)
+    y = y.reshape(rows, n_chunks).T
 
     @jax.checkpoint
     def chunk_nll(hc, yc):

@@ -69,3 +69,55 @@ def test_chunked_ce_no_logits_in_backward_residuals():
         h_, w, None, labels, rows_per_chunk=64)))
     hlo = fn.lower(h).compile().as_text()
     assert f"f32[{B * S},{V}]" not in hlo, "full logits materialized"
+
+
+# name -> (B, S, H, V, rows_per_chunk, whether the compiled text is read)
+_SHARDED_CASES = {
+    # the dp4 cell's ratio: 256 x 128 rows in 64 chunks of 512
+    "dp4_cell": (256, 128, 16, 128, 512, True),
+    # GPT-2's call: h[:, :-1] against labels[:, 1:], n a multiple of rows
+    "gpt2_call": (8, 65, 16, 128, 64, True),
+    # n = 4 * 9 is no multiple of 7: the padding path, numbers only
+    "padded_tail": (4, 9, 16, 131, 7, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SHARDED_CASES))
+def test_chunked_ce_gathers_nothing_over_a_sharded_batch(case):
+    """Chunk c holds flat rows c, c + n_chunks, ...: the scan walks an axis
+    the batch sharding does not split, so no device gathers the others' rows
+    (the contiguous order all-gathered the whole [n_chunks, rows, H] array,
+    forward and backward). ``hidden`` and ``labels`` are split over the batch
+    on 4 of the tests' 8 devices, the kernel whole on each."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    B, S, H, V, rows, read_text = _SHARDED_CASES[case]
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    by_batch, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    rng = np.random.RandomState(2)
+    h = jax.device_put(rng.randn(B, S, H).astype(np.float32), by_batch)
+    labels = jax.device_put(
+        np.where(rng.rand(B, S) < 0.3, -1, rng.randint(0, V, (B, S))).astype(np.int32),
+        by_batch)
+    w = jax.device_put(rng.randn(H, V).astype(np.float32) * 0.1, whole)
+    if case == "gpt2_call":     # the call site's own slicing, inside the jit
+        view = lambda h_, y_: (h_[:, :-1], y_[:, 1:])
+    else:
+        view = lambda h_, y_: (h_, y_)
+
+    def chunked(h_, w_):
+        hv, yv = view(h_, labels)
+        return chunked_cross_entropy(hv, w_, None, yv, rows_per_chunk=rows)
+
+    def dense(h_, w_):
+        hv, yv = view(h_, labels)
+        return _dense_ce(hv, w_, None, yv)
+
+    fn = jax.jit(jax.value_and_grad(chunked, argnums=(0, 1)))
+    if read_text:
+        assert "all-gather" not in fn.lower(h, w).compile().as_text()
+    got, g_c = fn(h, w)
+    want, g_d = jax.value_and_grad(dense, argnums=(0, 1))(h, w)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for a, d in zip(g_c, g_d):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(d), rtol=1e-5, atol=1e-6)

@@ -130,16 +130,21 @@ class TestOverlapParity:
 # donation pins
 # ---------------------------------------------------------------------------
 
-def _compiled_head(engine):
-    """First line of the compiled fused-step HLO (module attrs incl. aliasing)."""
+def _compiled_text(engine, *stacked):
+    """The compiled fused-step HLO for ``stacked`` ([gas, batch, ...] each)."""
     engine._ensure_opt_state()
-    fused = engine._get_train_step(engine._module_needs_rng(), 2)
+    fused = engine._get_train_step(engine._module_needs_rng(), len(stacked))
     inner = getattr(fused, "_fn", fused)
-    x = jnp.zeros((1, 16, HIDDEN), jnp.float32)
     lowered = inner.lower(engine.params, engine.opt_state, engine.scaler_state,
                           jax.random.PRNGKey(0), jnp.float32(1.0),
-                          jnp.float32(1e-3), x, x)
-    return lowered.compile().as_text().split("\n", 1)[0]
+                          jnp.float32(1e-3), *stacked)
+    return lowered.compile().as_text()
+
+
+def _compiled_head(engine):
+    """First line of the compiled fused-step HLO (module attrs incl. aliasing)."""
+    x = jnp.zeros((1, 16, HIDDEN), jnp.float32)
+    return _compiled_text(engine, x, x).split("\n", 1)[0]
 
 
 class TestDonationPins:
@@ -171,6 +176,44 @@ class TestDonationPins:
         assert all(x.is_deleted() for x in p_old)
         with pytest.raises(RuntimeError):
             np.asarray(p_old[0])
+
+
+# ---------------------------------------------------------------------------
+# the loss under a sharded batch
+# ---------------------------------------------------------------------------
+
+def test_fused_zero2_step_gathers_no_hidden_states_for_the_loss():
+    """A tiny BertForPreTraining under ZeRO-2 over 4 devices: the compiled
+    fused step all-gathers nothing shaped like the chunked loss's
+    [n_chunks, rows, H] array (the loss's scan once walked the axis the batch
+    sharding splits, and every device gathered every row, forward and
+    backward)."""
+    import re
+
+    from deepspeed_tpu.models.bert import BertConfig, init_bert
+
+    B, S, H = 32, 64, 48        # 2048 rows: 4 chunks of the loss's 512
+    model, params = init_bert(BertConfig(
+        vocab_size=256, hidden_size=H, num_hidden_layers=1,
+        num_attention_heads=4, intermediate_size=96,
+        max_position_embeddings=S, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0), batch_size=2, seq_len=S)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=params, config_params={
+            "train_batch_size": B, "train_micro_batch_size_per_gpu": B // 4,
+            "gradient_accumulation_steps": 1,
+            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 2},
+            "mesh": {"data_parallel_size": 4}})
+    ids = np.zeros((B, S), np.int32)
+    text = _compiled_text(engine, *(
+        engine._shard_stacked(jnp.asarray(x)[None])
+        for x in (ids, ids, ids + 1, ids, np.zeros((B,), np.int32))))
+    chunks = f"[{B * S // 512},512,{H}]"
+    gathers = [l.strip() for l in text.splitlines()
+               if re.search(r"= \S+ all-gather(-start)?\(", l)]
+    assert gathers, "ZeRO-2 over 4 devices gathers at least its parameters"
+    assert not [l for l in gathers if chunks in l.split(" all-gather")[0]]
 
 
 # ---------------------------------------------------------------------------
