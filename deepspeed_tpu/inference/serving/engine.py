@@ -158,6 +158,7 @@ from deepspeed_tpu.inference.quantization import (
     vocab_size,
 )
 from deepspeed_tpu.inference.serving.config import ServingConfig
+from deepspeed_tpu.inference.serving.family import family_for
 from deepspeed_tpu.inference.serving.fault_injection import ServingFaultInjector
 from deepspeed_tpu.inference.serving.kv_pool import (
     KV_CACHE_DTYPES,
@@ -972,6 +973,11 @@ class ServingEngine:
         self.params = params
         self.model_config = model_config
         self.config = cfg
+        # the one seam between the shared loop and a model family: what a
+        # lane's state is, which programs fill and advance it, and which
+        # options it cannot honour (those raise here, by name)
+        self.family = family_for(model_config)
+        self.family.check_options(cfg, params)
         self.n_layers = model_config.num_hidden_layers
         self.n_heads = model_config.num_attention_heads
         self.head_dim = model_config.hidden_size // self.n_heads
@@ -1126,23 +1132,9 @@ class ServingEngine:
             self._prefill_kv_sharding = serving_sharding(
                 self.mesh, "serving/prefill_kv", registry=self.registry)
 
-        dtype = _cache_dtype(params)
-        self.pool = KVCachePool(self.n_layers, cfg.max_slots, self.n_heads,
-                                self.max_seq_len, self.head_dim, dtype=dtype,
-                                kv_cache_dtype=cfg.kv_cache_dtype,
-                                page_tokens=cfg.kv_page_tokens,
-                                pool_tokens=cfg.kv_pool_tokens,
-                                mesh=self.mesh, registry=self.registry)
-        # _qmode: storage<->compute conversion the decode programs need.
-        # "fp32" stores the compute dtype directly, and "bf16" on a bf16
-        # checkpoint is ALSO storage==compute — both take the plain
-        # (bitwise) programs; only a real narrowing pays the quant path.
-        if cfg.kv_cache_dtype == "int8":
-            self._qmode = "int8"
-        elif jnp.dtype(self.pool.k.dtype) != jnp.dtype(dtype):
-            self._qmode = "bf16"
-        else:
-            self._qmode = None
+        self._qmode = None
+        self.metrics = ServingMetrics(monitor)
+        self.pool = self.family.build_pool(self, cfg)
         self._spec_k = int(cfg.speculative_k)
         # degraded-mode ladder: armed by configure_degrade() (from_config
         # wires the fleet.degrade block) or lazily by set_degrade_rung()
@@ -1154,7 +1146,6 @@ class ServingEngine:
             max_queue=cfg.max_queue, buckets=buckets,
             default_max_new_tokens=cfg.default_max_new_tokens,
             request_timeout_s=cfg.request_timeout_s)
-        self.metrics = ServingMetrics(monitor)
         self.metrics.record_kv_pool_bytes(self.pool.nbytes())
         if injector is None and cfg.fault_injection:
             injector = ServingFaultInjector(cfg.fault_injection)
@@ -1230,16 +1221,11 @@ class ServingEngine:
         self._noise_armed = False
         if sentinel_config is not None and sentinel_config.enabled:
             budget = sentinel_config.compile_budget
-            if self._spec_k > 0:
-                decode_prog = (_spec_step_quant_jit if self._qmode
-                               else _spec_step_jit)
-            else:
-                decode_prog = (_decode_step_quant_jit if self._qmode
-                               else _decode_step_jit)
+            decode_prog, prefill_prog = self.family.sentinel_programs(self)
             self.decode_sentinel = CompileSentinel(
                 decode_prog, budget, name="serving decode step")
             self.prefill_sentinel = CompileSentinel(
-                _prefill_batch_jit, budget, name="serving batched prefill")
+                prefill_prog, budget, name="serving batched prefill")
             # backend programs get their own pins only when armed — an
             # all-dense config keeps the exact legacy sentinel set
             self.decode_window_sentinel = (
@@ -1354,6 +1340,34 @@ class ServingEngine:
                 registry=telemetry.get_registry())
             if self.slo is not None and self.telemetry_server is not None:
                 self.slo.attach(self.telemetry_server)
+
+    # -- the gpt2 family's side of the seam (family.py: GPT2Family) --------
+    def _build_kv_pool(self, cfg):
+        dtype = _cache_dtype(self.params)
+        pool = KVCachePool(self.n_layers, cfg.max_slots, self.n_heads,
+                           self.max_seq_len, self.head_dim, dtype=dtype,
+                           kv_cache_dtype=cfg.kv_cache_dtype,
+                           page_tokens=cfg.kv_page_tokens,
+                           pool_tokens=cfg.kv_pool_tokens,
+                           mesh=self.mesh, registry=self.registry)
+        # _qmode: storage<->compute conversion the decode programs need.
+        # "fp32" stores the compute dtype directly, and "bf16" on a bf16
+        # checkpoint is ALSO storage==compute — both take the plain
+        # (bitwise) programs; only a real narrowing pays the quant path.
+        if cfg.kv_cache_dtype == "int8":
+            self._qmode = "int8"
+        elif jnp.dtype(pool.k.dtype) != jnp.dtype(dtype):
+            self._qmode = "bf16"
+        return pool
+
+    def _gpt2_sentinel_programs(self):
+        if self._spec_k > 0:
+            decode_prog = (_spec_step_quant_jit if self._qmode
+                           else _spec_step_jit)
+        else:
+            decode_prog = (_decode_step_quant_jit if self._qmode
+                           else _decode_step_jit)
+        return decode_prog, _prefill_batch_jit
 
     def _build_telemetry_server(self, port):
         srv = telemetry.TelemetryServer(
@@ -1592,6 +1606,7 @@ class ServingEngine:
         allocated span) is bit-identical to what a mixed-mode admission
         would have produced. Returns the Request (the caller reads
         ``export_payload`` after ``future.result()``)."""
+        self.family.refuse_handoff()
         if self._draining:
             raise EngineDrainingError(
                 "engine is draining for a planned restart; "
@@ -1639,12 +1654,14 @@ class ServingEngine:
         ``_alloc_tokens``: an armed injector forces full-lane claims, so
         the claim always holds at least as many pages as the (also
         full-lane) prefill-side export ships."""
+        self.family.refuse_handoff()
         n = None if self.injector is not None else int(n_tokens)
         return self._run_on_loop(lambda: self.pool.allocate(n))
 
     def handoff_install(self, slot, meta, frames, handoff_key=None):
         """Decode-side phase 2: install transferred pages into the
         claimed slot. Returns False on an idempotent duplicate."""
+        self.family.refuse_handoff()
         def _do():
             fresh = self.pool.install_raw(slot, meta, frames,
                                           handoff_key=handoff_key)
@@ -1664,6 +1681,7 @@ class ServingEngine:
         generated token was already delivered by the prefill worker, so
         it is recorded (``emitted=1``, appended to the future) but NOT
         re-streamed through ``stream_cb``. Returns the Request."""
+        self.family.refuse_handoff()
         prompt = [int(t) for t in np.asarray(prompt_ids).reshape(-1)]
         submitted_at = (time.monotonic() - float(age_s)
                         if age_s and age_s > 0 else None)
@@ -1709,8 +1727,7 @@ class ServingEngine:
 
         # one chunk per step: a long prompt makes progress without ever
         # stalling the in-flight lanes' inter-token latency
-        if self._chunking is not None:
-            now = self._advance_chunk(stats)
+        now = self.family.advance_prefill(self, stats, now)
 
         # admission is timed from the last stamp the iteration holds
         self._admit_from_queue(stats, now)
@@ -1751,7 +1768,7 @@ class ServingEngine:
                                         cat="serving",
                                         args={"active": len(self._active)})
                       if self._tracer.enabled else telemetry.NULL_SPAN):
-                    self._upload_lane_state()
+                    self.family.upload_lanes(self)
             guard = transfer_free() if self._transfer_guard else nullcontext()
             # host-side np masks: np.bool_ drives the dispatch branches
             # directly (a bool() cast here reads as a device sync to JL002)
@@ -1852,70 +1869,11 @@ class ServingEngine:
                     pages_in_use=occ["pages_in_use"],
                     page_fragmentation=occ["page_fragmentation"])
             else:
-                with guard:
-                    if full_any:
-                        if self._qmode is not None:
-                            (self._dev_tokens, self._dev_positions,
-                             self.pool.k, self.pool.v) = \
-                                _decode_step_quant_jit(
-                                    self.params, self.pool.k, self.pool.v,
-                                    self.pool.k_scale, self.pool.v_scale,
-                                    self._dev_page_tables, self._dev_tokens,
-                                    self._dev_positions, self._dev_active,
-                                    n_heads=self.n_heads, qmode=self._qmode)
-                        else:
-                            (self._dev_tokens, self._dev_positions,
-                             self.pool.k, self.pool.v) = _decode_step_jit(
-                                self.params, self.pool.k, self.pool.v,
-                                self._dev_page_tables, self._dev_tokens,
-                                self._dev_positions, self._dev_active,
-                                n_heads=self.n_heads)
-                    if win_any:
-                        (self._dev_tokens, self._dev_positions, self.pool.k,
-                         self.pool.v) = _decode_step_window_jit(
-                            self.params, self.pool.k, self.pool.v,
-                            self.pool.k_scale, self.pool.v_scale,
-                            self._dev_page_tables, self._dev_tokens,
-                            self._dev_positions, self._dev_active_win,
-                            n_heads=self.n_heads,
-                            page_tokens=self.pool.page_tokens,
-                            qmode=self._qmode)
-                    if kfull_any:
-                        kernels.record_call(
-                            "decode_attention",
-                            self._kernel_impl["pallas_decode"])
-                        (self._dev_tokens, self._dev_positions, self.pool.k,
-                         self.pool.v) = _decode_step_kernel_jit(
-                            self.params, self.pool.k, self.pool.v,
-                            self.pool.k_scale, self.pool.v_scale,
-                            self._dev_page_tables, self._dev_tokens,
-                            self._dev_positions, self._dev_active_kfull,
-                            n_heads=self.n_heads,
-                            page_tokens=self.pool.page_tokens,
-                            qmode=self._qmode,
-                            kernel_impl=self._kernel_impl["pallas_decode"],
-                            kernel_interpret=self._kernel_interpret[
-                                "pallas_decode"])
-                    if kwin_any:
-                        kernels.record_call(
-                            "sparse_attention",
-                            self._kernel_impl["pallas_sparse"])
-                        (self._dev_tokens, self._dev_positions, self.pool.k,
-                         self.pool.v) = _decode_step_window_jit(
-                            self.params, self.pool.k, self.pool.v,
-                            self.pool.k_scale, self.pool.v_scale,
-                            self._dev_page_tables, self._dev_tokens,
-                            self._dev_positions, self._dev_active_kwin,
-                            n_heads=self.n_heads,
-                            page_tokens=self.pool.page_tokens,
-                            qmode=self._qmode,
-                            kernel_impl=self._kernel_impl["pallas_sparse"],
-                            kernel_interpret=self._kernel_interpret[
-                                "pallas_sparse"])
-                self._check_decode_sentinels()
-                # the step's single deliberate sync: EOS checks need the
-                # tokens
-                host_tokens = jax.device_get(self._dev_tokens)  # jaxlint: disable=JL002(one explicit host read per step)
+                # ``lanes``: the slots whose token this is (every active
+                # lane, but for a family that keeps one step in flight and
+                # hands back the step BEFORE the one it just dispatched)
+                host_tokens, lanes = self.family.decode_step(
+                    self, guard, (full_any, win_any, kfull_any, kwin_any))
                 step_s = time.monotonic() - t0
                 read_back = t0 + step_s
                 dspan.__exit__(None, None, None)
@@ -1927,8 +1885,8 @@ class ServingEngine:
                 self._lane_tokens = host_tokens.copy()
                 toks = host_tokens.tolist()
                 now = time.monotonic()
-                n_active = len(self._active)
-                for slot in list(self._active):
+                n_active = len(lanes)
+                for slot in lanes:
                     req = self._active[slot]
                     base = self.pool.positions[slot]
                     self.pool.advance(slot)
@@ -1973,6 +1931,75 @@ class ServingEngine:
                 end - top, end - read_back if read_back is not None else 0.0)
         espan.__exit__(None, None, None)
         return stats
+
+    def _gpt2_decode_programs(self, guard, classes):
+        """One decode step of the gpt2 family: (at most) one jitted call
+        per armed lane class, then the step's one host read."""
+        full_any, win_any, kfull_any, kwin_any = classes
+        with guard:
+            if full_any:
+                if self._qmode is not None:
+                    (self._dev_tokens, self._dev_positions,
+                     self.pool.k, self.pool.v) = \
+                        _decode_step_quant_jit(
+                            self.params, self.pool.k, self.pool.v,
+                            self.pool.k_scale, self.pool.v_scale,
+                            self._dev_page_tables, self._dev_tokens,
+                            self._dev_positions, self._dev_active,
+                            n_heads=self.n_heads, qmode=self._qmode)
+                else:
+                    (self._dev_tokens, self._dev_positions,
+                     self.pool.k, self.pool.v) = _decode_step_jit(
+                        self.params, self.pool.k, self.pool.v,
+                        self._dev_page_tables, self._dev_tokens,
+                        self._dev_positions, self._dev_active,
+                        n_heads=self.n_heads)
+            if win_any:
+                (self._dev_tokens, self._dev_positions, self.pool.k,
+                 self.pool.v) = _decode_step_window_jit(
+                    self.params, self.pool.k, self.pool.v,
+                    self.pool.k_scale, self.pool.v_scale,
+                    self._dev_page_tables, self._dev_tokens,
+                    self._dev_positions, self._dev_active_win,
+                    n_heads=self.n_heads,
+                    page_tokens=self.pool.page_tokens,
+                    qmode=self._qmode)
+            if kfull_any:
+                kernels.record_call(
+                    "decode_attention",
+                    self._kernel_impl["pallas_decode"])
+                (self._dev_tokens, self._dev_positions, self.pool.k,
+                 self.pool.v) = _decode_step_kernel_jit(
+                    self.params, self.pool.k, self.pool.v,
+                    self.pool.k_scale, self.pool.v_scale,
+                    self._dev_page_tables, self._dev_tokens,
+                    self._dev_positions, self._dev_active_kfull,
+                    n_heads=self.n_heads,
+                    page_tokens=self.pool.page_tokens,
+                    qmode=self._qmode,
+                    kernel_impl=self._kernel_impl["pallas_decode"],
+                    kernel_interpret=self._kernel_interpret[
+                        "pallas_decode"])
+            if kwin_any:
+                kernels.record_call(
+                    "sparse_attention",
+                    self._kernel_impl["pallas_sparse"])
+                (self._dev_tokens, self._dev_positions, self.pool.k,
+                 self.pool.v) = _decode_step_window_jit(
+                    self.params, self.pool.k, self.pool.v,
+                    self.pool.k_scale, self.pool.v_scale,
+                    self._dev_page_tables, self._dev_tokens,
+                    self._dev_positions, self._dev_active_kwin,
+                    n_heads=self.n_heads,
+                    page_tokens=self.pool.page_tokens,
+                    qmode=self._qmode,
+                    kernel_impl=self._kernel_impl["pallas_sparse"],
+                    kernel_interpret=self._kernel_interpret[
+                        "pallas_sparse"])
+        self._check_decode_sentinels()
+        # the step's single deliberate sync: EOS checks need the
+        # tokens
+        return jax.device_get(self._dev_tokens)  # jaxlint: disable=JL002(one explicit host read per step)
 
     def _slo_values(self):
         """SLO inputs: the live serving snapshot under ``Serving/*`` plus
@@ -2117,7 +2144,7 @@ class ServingEngine:
 
     def pending(self):
         """Requests still owed work: queued + chunking + in flight."""
-        return (len(self._active) + (1 if self._chunking is not None else 0)
+        return (len(self._active) + self.family.prefilling(self)
                 + self.scheduler.queue_depth())
 
     def _put_prefill_kv(self, arr):
@@ -2230,9 +2257,9 @@ class ServingEngine:
             with self._tracer.span(
                     "serving/admission", cat="serving",
                     args={"queue_depth": self.scheduler.queue_depth()}):
-                self._admit_from_queue_now(stats)
+                self.family.admit(self, stats)
         else:
-            self._admit_from_queue_now(stats)
+            self.family.admit(self, stats)
         if stats["admitted"] > admitted:
             self.metrics.admit_time_s += time.monotonic() - now
 
@@ -2400,11 +2427,7 @@ class ServingEngine:
             self.pool.install_lane(k, v, lane=i, slot=slot,
                                    position=len(req.prompt))
             req.prefix_entry = entry
-            req.first_token_time = now
-            self.metrics.record_first_token(now - req.submit_time)
-            self._stamp_token(req, now)
-            self._activate(req, slot, int(first_host[i]))
-            retired += self._maybe_retire(req, int(first_host[i]), now)
+            retired += self._first_token(req, slot, int(first_host[i]), now)
         # settle the queued lane installs here so they are accounted to
         # admission, not silently absorbed into the next decode step's
         # measured latency
@@ -2559,11 +2582,7 @@ class ServingEngine:
             positions_run=st.positions_run)
         self._maybe_insert_prefix(req, st.reuse, st.k, st.v, lane=0)
         self.pool.install(st.k, st.v, st.slot, position=len(req.prompt))
-        req.first_token_time = now
-        self.metrics.record_first_token(now - req.submit_time)
-        self._stamp_token(req, now)
-        self._activate(req, st.slot, first_tok)
-        stats["retired"] += self._maybe_retire(req, first_tok, now)
+        stats["retired"] += self._first_token(req, st.slot, first_tok, now)
         self._chunking = None
         return now
 
@@ -2661,6 +2680,16 @@ class ServingEngine:
         self._lane_dirty = True
         if emit:
             self._emit(req, first_tok)
+
+    def _first_token(self, req, slot, first_tok, now):
+        """A prompt's prefill is done and its state is in ``slot``: stamp
+        and hand out the first token and join the decode lanes. Returns 1
+        if that token already ended the request, else 0."""
+        req.first_token_time = now
+        self.metrics.record_first_token(now - req.submit_time)
+        self._stamp_token(req, now)
+        self._activate(req, slot, first_tok)
+        return self._maybe_retire(req, first_tok, now)
 
     def _stamp_token(self, req, now):
         """Beside each token handed out: ``now`` is the stamp the iteration

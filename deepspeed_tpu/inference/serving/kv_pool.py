@@ -180,30 +180,22 @@ def import_entry_frames(meta, frames):
     return k, v, k_scale, v_scale
 
 
-class KVCachePool:
-    """Fixed-capacity paged KV storage plus its host-side allocator."""
+class PagedSlots:
+    """The host-side allocator every serving pool shares: ``max_slots``
+    admission lanes, and pages of ``page_tokens`` positions under one
+    ``pool_tokens`` budget, mapped per lane by ``page_tables`` (physical
+    page 0 is the null page). No device memory: a subclass holds what the
+    pages and the slots index (keys and values, latent rows, recurrent
+    state)."""
 
-    def __init__(self, n_layers, max_slots, n_heads, max_seq_len, head_dim,
-                 dtype=jnp.float32, kv_cache_dtype="fp32",
-                 page_tokens=None, pool_tokens=None, mesh=None,
-                 registry=None):
+    def __init__(self, max_slots, max_seq_len, page_tokens=None,
+                 pool_tokens=None):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
         if max_seq_len < 2:
             raise ValueError(f"max_seq_len must be >= 2, got {max_seq_len}")
-        if kv_cache_dtype not in KV_CACHE_DTYPES:
-            raise ValueError(
-                f"kv_cache_dtype must be one of {KV_CACHE_DTYPES}, "
-                f"got {kv_cache_dtype!r}")
-        self.n_layers = int(n_layers)
         self.max_slots = int(max_slots)
-        self.n_heads = int(n_heads)
         self.max_seq_len = int(max_seq_len)
-        self.head_dim = int(head_dim)
-        # ``dtype`` is the model's COMPUTE dtype ("fp32" mode stores it
-        # directly); quantized modes store narrower and dequant at use.
-        self.compute_dtype = dtype
-        self.kv_cache_dtype = kv_cache_dtype
         self.page_tokens = resolve_page_tokens(
             page_tokens or DEFAULT_PAGE_TOKENS, self.max_seq_len)
         self.pages_per_lane = self.max_seq_len // self.page_tokens
@@ -220,83 +212,23 @@ class KVCachePool:
         self.pool_tokens = max(int(pool_tokens),
                                self.pages_per_lane * self.page_tokens)
         self.n_data_pages = self.pool_tokens // self.page_tokens
-        n_pages = self.n_data_pages + 1                  # + null page 0
-        shape = (self.n_layers, n_pages, self.n_heads,
-                 self.page_tokens, self.head_dim)
-        storage = {"fp32": dtype, "bf16": jnp.bfloat16,
-                   "int8": jnp.int8}[kv_cache_dtype]
-        # Tensor-parallel pool: the heads dim splits over the mesh's
-        # `model` axis (specs resolved through the sharding registry —
-        # the single source both engines consume). mesh=None keeps the
-        # single-device layout byte-identical.
-        self.mesh = mesh
-        self.kv_sharding = None
-        self.replicated_sharding = None
-        if mesh is not None:
-            mp = mp_world_size(mesh)
-            if self.n_heads % mp != 0:
-                raise ValueError(
-                    f"n_heads={self.n_heads} not divisible by the mesh's "
-                    f"model axis size {mp}; the KV pool shards heads")
-            self.kv_sharding = serving_sharding(mesh, "serving/kv_pool",
-                                                registry=registry)
-            self.replicated_sharding = serving_sharding(
-                mesh, "serving/lane_state", registry=registry)
-            self.k = jnp.zeros(shape, storage, device=self.kv_sharding)
-            self.v = jnp.zeros(shape, storage, device=self.kv_sharding)
-        else:
-            self.k = jnp.zeros(shape, storage)
-            self.v = jnp.zeros(shape, storage)
-        if kv_cache_dtype == "int8":
-            # one symmetric scale per (layer, slot, head) — per LANE, not
-            # per page: pages are never shared across lanes, and keeping
-            # the old shape keeps dequantize_kv broadcasting unchanged
-            sshape = (self.n_layers, self.max_slots, self.n_heads, 1, 1)
-            if mesh is not None:
-                scale_sh = serving_sharding(mesh, "serving/kv_scale",
-                                            registry=registry)
-                self.k_scale = jnp.ones(sshape, jnp.float32,
-                                        device=scale_sh)
-                self.v_scale = jnp.ones(sshape, jnp.float32,
-                                        device=scale_sh)
-            else:
-                self.k_scale = jnp.ones(sshape, jnp.float32)
-                self.v_scale = jnp.ones(sshape, jnp.float32)
-        else:
-            self.k_scale = None
-            self.v_scale = None
+        self.n_pages = self.n_data_pages + 1             # + null page 0
         # lowest-index-first allocation keeps slot/page assignment
         # deterministic for a given arrival order (oracle tests replay
         # schedules)
         self._free = sorted(range(self.max_slots), reverse=True)
-        self._free_pages = sorted(range(1, n_pages), reverse=True)
+        self._free_pages = sorted(range(1, self.n_pages), reverse=True)
         # logical->physical page map per lane; 0 (the null page) means
         # unmapped. The engine mirrors this to the device only on churn.
         self.page_tables = np.zeros((self.max_slots, self.pages_per_lane),
                                     np.int32)
         self._lane_pages = [[] for _ in range(self.max_slots)]
-        # handoff idempotency: key -> slot for lanes installed via
-        # install_raw(); a re-sent handoff under a live key is a no-op
-        self._handoff_keys = {}
-        self._slot_handoff_key = {}
         # per-slot NEXT write/read position (== tokens cached so far)
         self.positions = np.zeros(self.max_slots, np.int32)
         self.allocations = 0
         self.frees = 0
         self.peak_in_use = 0
         self.peak_pages_in_use = 0
-
-    def host_put(self, x, dtype=None, sharded=False):
-        """Sharding-aware host->device placement: on a mesh, commit to
-        the registry-resolved sharding (replicated lane state, or the
-        pool's heads-sharded layout when ``sharded``) instead of the
-        default device — a default-device put on a >1-device mesh would
-        force a reshard inside the next jitted step."""
-        arr = np.asarray(x, dtype) if dtype is not None else np.asarray(x)
-        if self.mesh is None:
-            return jnp.asarray(arr)
-        target = self.kv_sharding if sharded else self.replicated_sharding
-        return jax.device_put(arr, target)
 
     # -- slot lifecycle -------------------------------------------------
     @property
@@ -358,9 +290,7 @@ class KVCachePool:
         if slot in self._free:
             raise PageStateError(
                 f"slot {slot} is already free (double free)")
-        key = self._slot_handoff_key.pop(slot, None)
-        if key is not None:
-            self._handoff_keys.pop(key, None)
+        self._on_free(slot)
         self.frees += 1
         self.positions[slot] = 0
         # zero the table row BEFORE returning pages: the freed lane's
@@ -376,6 +306,128 @@ class KVCachePool:
     def lane_tokens(self, slot):
         """Token capacity actually backed by this lane's pages."""
         return len(self._lane_pages[slot]) * self.page_tokens
+
+    def _on_free(self, slot):
+        """Hook: what a subclass forgets about a slot being freed."""
+
+    def advance(self, slot):
+        """Bump a slot's position after a decode step wrote its token.
+        Clamped at the last cache index: a (injected-fault) runaway
+        request keeps overwriting the final position instead of relying
+        on silent OOB-scatter behavior."""
+        self.positions[slot] = min(self.positions[slot] + 1,
+                                   self.max_seq_len - 1)
+
+    def occupancy(self):
+        """The allocator's occupancy snapshot for metrics/debugging."""
+        in_use = self.slots_in_use
+        covered = self.pages_in_use * self.page_tokens
+        return {
+            "max_slots": self.max_slots,
+            "in_use": in_use,
+            "free": self.free_slots,
+            "utilization": in_use / self.max_slots,
+            "allocations": self.allocations,
+            "frees": self.frees,
+            "peak_in_use": self.peak_in_use,
+            "cached_tokens": int(self.positions.sum()),
+            "page_tokens": self.page_tokens,
+            "pages_total": self.n_data_pages,
+            "pages_in_use": self.pages_in_use,
+            "pages_free": self.free_pages,
+            "peak_pages_in_use": self.peak_pages_in_use,
+            # tokens reserved by claimed pages but not (yet) cached —
+            # internal fragmentation of the page granularity
+            "page_fragmentation": ((covered - int(self.positions.sum()))
+                                   / max(covered, 1)),
+        }
+
+
+class KVCachePool(PagedSlots):
+    """Fixed-capacity paged KV storage over the shared allocator."""
+
+    def __init__(self, n_layers, max_slots, n_heads, max_seq_len, head_dim,
+                 dtype=jnp.float32, kv_cache_dtype="fp32",
+                 page_tokens=None, pool_tokens=None, mesh=None,
+                 registry=None):
+        if kv_cache_dtype not in KV_CACHE_DTYPES:
+            raise ValueError(
+                f"kv_cache_dtype must be one of {KV_CACHE_DTYPES}, "
+                f"got {kv_cache_dtype!r}")
+        super().__init__(max_slots, max_seq_len, page_tokens, pool_tokens)
+        self.n_layers = int(n_layers)
+        self.n_heads = int(n_heads)
+        self.head_dim = int(head_dim)
+        # ``dtype`` is the model's COMPUTE dtype ("fp32" mode stores it
+        # directly); quantized modes store narrower and dequant at use.
+        self.compute_dtype = dtype
+        self.kv_cache_dtype = kv_cache_dtype
+        n_pages = self.n_pages
+        shape = (self.n_layers, n_pages, self.n_heads,
+                 self.page_tokens, self.head_dim)
+        storage = {"fp32": dtype, "bf16": jnp.bfloat16,
+                   "int8": jnp.int8}[kv_cache_dtype]
+        # Tensor-parallel pool: the heads dim splits over the mesh's
+        # `model` axis (specs resolved through the sharding registry —
+        # the single source both engines consume). mesh=None keeps the
+        # single-device layout byte-identical.
+        self.mesh = mesh
+        self.kv_sharding = None
+        self.replicated_sharding = None
+        if mesh is not None:
+            mp = mp_world_size(mesh)
+            if self.n_heads % mp != 0:
+                raise ValueError(
+                    f"n_heads={self.n_heads} not divisible by the mesh's "
+                    f"model axis size {mp}; the KV pool shards heads")
+            self.kv_sharding = serving_sharding(mesh, "serving/kv_pool",
+                                                registry=registry)
+            self.replicated_sharding = serving_sharding(
+                mesh, "serving/lane_state", registry=registry)
+            self.k = jnp.zeros(shape, storage, device=self.kv_sharding)
+            self.v = jnp.zeros(shape, storage, device=self.kv_sharding)
+        else:
+            self.k = jnp.zeros(shape, storage)
+            self.v = jnp.zeros(shape, storage)
+        if kv_cache_dtype == "int8":
+            # one symmetric scale per (layer, slot, head) — per LANE, not
+            # per page: pages are never shared across lanes, and keeping
+            # the old shape keeps dequantize_kv broadcasting unchanged
+            sshape = (self.n_layers, self.max_slots, self.n_heads, 1, 1)
+            if mesh is not None:
+                scale_sh = serving_sharding(mesh, "serving/kv_scale",
+                                            registry=registry)
+                self.k_scale = jnp.ones(sshape, jnp.float32,
+                                        device=scale_sh)
+                self.v_scale = jnp.ones(sshape, jnp.float32,
+                                        device=scale_sh)
+            else:
+                self.k_scale = jnp.ones(sshape, jnp.float32)
+                self.v_scale = jnp.ones(sshape, jnp.float32)
+        else:
+            self.k_scale = None
+            self.v_scale = None
+        # handoff idempotency: key -> slot for lanes installed via
+        # install_raw(); a re-sent handoff under a live key is a no-op
+        self._handoff_keys = {}
+        self._slot_handoff_key = {}
+
+    def host_put(self, x, dtype=None, sharded=False):
+        """Sharding-aware host->device placement: on a mesh, commit to
+        the registry-resolved sharding (replicated lane state, or the
+        pool's heads-sharded layout when ``sharded``) instead of the
+        default device — a default-device put on a >1-device mesh would
+        force a reshard inside the next jitted step."""
+        arr = np.asarray(x, dtype) if dtype is not None else np.asarray(x)
+        if self.mesh is None:
+            return jnp.asarray(arr)
+        target = self.kv_sharding if sharded else self.replicated_sharding
+        return jax.device_put(arr, target)
+
+    def _on_free(self, slot):
+        key = self._slot_handoff_key.pop(slot, None)
+        if key is not None:
+            self._handoff_keys.pop(key, None)
 
     def install(self, new_k, new_v, slot, position):
         """Install a prefilled request cache ([L, 1, nh, S, hd] with
@@ -507,14 +559,6 @@ class KVCachePool:
         """Slot currently holding ``handoff_key``, or None."""
         return self._handoff_keys.get(handoff_key)
 
-    def advance(self, slot):
-        """Bump a slot's position after a decode step wrote its token.
-        Clamped at the last cache index: a (injected-fault) runaway
-        request keeps overwriting the final position instead of relying
-        on silent OOB-scatter behavior."""
-        self.positions[slot] = min(self.positions[slot] + 1,
-                                   self.max_seq_len - 1)
-
     # -- stats ----------------------------------------------------------
     def nbytes(self):
         """Device bytes held by the pool's KV storage (+ scales in int8
@@ -541,26 +585,87 @@ class KVCachePool:
 
     def occupancy(self):
         """Occupancy snapshot for metrics/debugging."""
-        in_use = self.slots_in_use
-        covered = self.pages_in_use * self.page_tokens
-        return {
-            "max_slots": self.max_slots,
-            "in_use": in_use,
-            "free": self.free_slots,
-            "utilization": in_use / self.max_slots,
-            "allocations": self.allocations,
-            "frees": self.frees,
-            "peak_in_use": self.peak_in_use,
-            "cached_tokens": int(self.positions.sum()),
-            "kv_cache_dtype": self.kv_cache_dtype,
-            "pool_bytes": self.nbytes(),
-            "page_tokens": self.page_tokens,
-            "pages_total": self.n_data_pages,
-            "pages_in_use": self.pages_in_use,
-            "pages_free": self.free_pages,
-            "peak_pages_in_use": self.peak_pages_in_use,
-            # tokens reserved by claimed pages but not (yet) cached —
-            # internal fragmentation of the page granularity
-            "page_fragmentation": ((covered - int(self.positions.sum()))
-                                   / max(covered, 1)),
-        }
+        return dict(super().occupancy(),
+                    kv_cache_dtype=self.kv_cache_dtype,
+                    pool_bytes=self.nbytes())
+
+
+def _zero_slot(arrays, slot):
+    return {name: a.at[:, slot].set(0) for name, a in arrays.items()}
+
+
+_zero_slot_jit = jax.jit(_zero_slot, donate_argnums=(0,))
+
+
+class HybridStatePool(PagedSlots):
+    """State of two kinds behind the one allocator, for models whose layers
+    do not all cache keys and values:
+
+    - *paged* arrays ``[layers, n_pages, width, page_tokens]`` (no head
+      axis): ``width`` values a token, a page's tokens along the last
+      axis (they fill the chip's 128-wide tiles exactly, where a width
+      such as 576 would be padded), reached through the lane's page table
+      like the KV pool's pages (a latent-attention cache);
+    - *slot* arrays ``[layers, max_slots, ...]``: a fixed-size state a
+      lane (recurrent state, convolution tails).
+
+    Admission needs a free slot AND pages (``allocate``, inherited), and
+    then ``reset_slot``: a recurrent layer has no position mask that could
+    hide the previous occupant, so the slot's rows are zeroed when a lane
+    is reused. ``state`` is the ``{name: array}`` dict the programs take
+    and give back whole (donated)."""
+
+    def __init__(self, max_slots, max_seq_len, paged, slotted,
+                 page_tokens=None, pool_tokens=None):
+        """``paged``: {name: (layers, width, dtype)}; ``slotted``:
+        {name: (layers, per-slot shape, dtype)}."""
+        super().__init__(max_slots, max_seq_len, page_tokens, pool_tokens)
+        self.paged_names = tuple(paged)
+        self.slot_names = tuple(slotted)
+        self.state = {}
+        for name, (layers, width, dtype) in paged.items():
+            self.state[name] = jnp.zeros(
+                (layers, self.n_pages, width, self.page_tokens), dtype)
+        for name, (layers, shape, dtype) in slotted.items():
+            self.state[name] = jnp.zeros(
+                (layers, self.max_slots) + tuple(shape), dtype)
+        self.slot_resets = 0
+        # fixed at construction (the arrays are donated and replaced, never
+        # resized), so the loop's gauges do not sum them every step
+        self._paged_bytes = int(sum(self.state[n].nbytes
+                                    for n in self.paged_names))
+        self._slot_bytes = int(sum(self.state[n].nbytes
+                                   for n in self.slot_names))
+
+    def reset_slot(self, slot):
+        """Zero ``slot``'s rows of every slot array (in place: the arrays
+        are donated)."""
+        if slot in self._free:
+            raise PageStateError(
+                f"reset of slot {slot} which is not allocated")
+        zeroed = _zero_slot_jit(
+            {n: self.state[n] for n in self.slot_names}, jnp.int32(slot))
+        self.state.update(zeroed)
+        self.slot_resets += 1
+
+    def paged_bytes(self):
+        return self._paged_bytes
+
+    def slot_bytes(self):
+        return self._slot_bytes
+
+    def nbytes(self):
+        return self.paged_bytes() + self.slot_bytes()
+
+    def occupancy(self):
+        return dict(super().occupancy(), pool_bytes=self.nbytes(),
+                    paged_bytes=self.paged_bytes(),
+                    slot_bytes=self.slot_bytes(),
+                    slot_resets=self.slot_resets)
+
+    def delete(self):
+        """Free the device arrays (the benchmark's reference runs after
+        the program, in the memory it leaves)."""
+        for a in self.state.values():
+            a.delete()
+        self.state = {}

@@ -97,6 +97,22 @@ class ServingMetrics:
         self.handoff_dup_installs = 0
         self.handoff_resumes = 0
         self.handoff_reaped = 0
+        # routed experts and state of a second kind (families that have
+        # them; zero otherwise). Monotone sums over what the decode
+        # program hands back beside its tokens: expert layers run (layers x
+        # decode steps), token-expert picks that fell on held experts, held
+        # experts with at least one token, the busiest held expert's tokens
+        # (the last two summed over layers and steps); chunks of chunked
+        # prefill; and last-value gauges of the hybrid state pool
+        self.moe_layer_steps = 0
+        self.moe_picks_here = 0
+        self.moe_experts_touched = 0
+        self.moe_expert_load_max = 0
+        self.prefill_chunks = 0
+        self.state_slots_in_use = 0
+        self.latent_pages_in_use = 0
+        self.state_pool_bytes = 0
+        self.latent_pool_bytes = 0
         # TTFT: time from submit() to the request's first token
         self._ttft_sum = 0.0
         self._ttft_count = 0
@@ -129,6 +145,27 @@ class ServingMetrics:
             self._record("Serving/prefill_tokens_per_sec",
                          tokens / prefill_s, self.prefill_calls)
         self._record("Serving/prefill_batch", requests, self.prefill_calls)
+
+    def record_prefill_chunk(self):
+        """One call of a chunked prefill program (whatever its rows)."""
+        self.prefill_chunks += 1
+
+    def record_moe(self, layer_steps, picks_here, experts_touched,
+                   expert_load_max):
+        """One decode step's expert layers, from the integers the decode
+        program returns in the same transfer as its tokens."""
+        self.moe_layer_steps += layer_steps
+        self.moe_picks_here += picks_here
+        self.moe_experts_touched += experts_touched
+        self.moe_expert_load_max += expert_load_max
+
+    def record_state_pool(self, slots_in_use, pages_in_use, slot_bytes,
+                          paged_bytes):
+        """Gauges of a hybrid state pool (slot state beside paged rows)."""
+        self.state_slots_in_use = int(slots_in_use)
+        self.latent_pages_in_use = int(pages_in_use)
+        self.state_pool_bytes = int(slot_bytes)
+        self.latent_pool_bytes = int(paged_bytes)
 
     def record_queue_wait(self, wait_s, requests=1):
         """``requests`` admitted prompts waited ``wait_s`` seconds in all
@@ -348,6 +385,16 @@ class ServingMetrics:
             "kv_pool_bytes": self.kv_pool_bytes,
             "pages_in_use": self.pages_in_use,
             "page_fragmentation": self.page_fragmentation,
+            # routed experts, chunked prefill, hybrid state pool
+            "moe_layer_steps": self.moe_layer_steps,
+            "moe_picks_here": self.moe_picks_here,
+            "moe_experts_touched": self.moe_experts_touched,
+            "moe_expert_load_max": self.moe_expert_load_max,
+            "prefill_chunks": self.prefill_chunks,
+            "state_slots_in_use": self.state_slots_in_use,
+            "latent_pages_in_use": self.latent_pages_in_use,
+            "state_pool_bytes": self.state_pool_bytes,
+            "latent_pool_bytes": self.latent_pool_bytes,
             # disaggregated prefill/decode handoff lifecycle
             "handoff_exports": self.handoff_exports,
             "handoff_installs": self.handoff_installs,

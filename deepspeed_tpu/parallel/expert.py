@@ -102,6 +102,131 @@ def moe_ffn(params, x, capacity):
 
 
 # ---------------------------------------------------------------------------
+# Sigmoid top-k routing without capacity; a chip's share of the experts
+# ---------------------------------------------------------------------------
+
+def sigmoid_topk_routing(x, router, bias, k, scaling=1.0, renormalize=True):
+    """DeepSeek-V3-style router over ALL experts: ``s = sigmoid(x W_r)`` in
+    float32 (``highest`` matmul precision: a TPU would otherwise round the
+    operands), the ``k`` largest of ``s + bias`` are picked, and a pick
+    weighs ``s / sum of the picked s * scaling`` (``bias`` chooses, it does
+    not weigh). x [T, d]; returns (idx [T, k] int32, weights [T, k] f32)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
+    """The part of a routed SwiGLU expert layer that the experts held here
+    give: ``sum over picks p of token t with idx[t, p] held of weights[t, p]
+    * SwiGLU_e(x_t)``. No capacity, no dropped token: the picks are grouped
+    by expert, each group padded up to a multiple of ``tile`` rows, and a
+    loop runs over the tiles in use (its trip count is data, the shapes are
+    not), reading one expert's weights a tile. An expert no token picked is
+    never read; a pick of an expert held elsewhere costs nothing here.
+
+    x [T, d]; experts {gate_proj, up_proj [E_h, d, f], down_proj [E_h, f,
+    d]}; idx, weights [T, k]; held (first, count) among the router's
+    experts; live [T] bool marks real tokens (None = all). Returns
+    (y [T, d] float32, counts [3] int32: picks that fell on held experts,
+    held experts with at least one token, the busiest one's tokens)."""
+    T, d = x.shape
+    k = idx.shape[1]
+    first, n_held = held
+    wg, wu, wd = experts["gate_proj"], experts["up_proj"], experts["down_proj"]
+    assert wg.shape[0] == n_held, (wg.shape, held)
+    M = T * k
+    e = idx.reshape(M) - first
+    here = (e >= 0) & (e < n_held)
+    if live is not None:
+        here = here & jnp.repeat(live, k)
+    e = jnp.where(here, e, n_held)                   # n_held = "not here"
+    counts = jnp.zeros(n_held + 1, jnp.int32).at[e].add(1)[:n_held]
+    padded = -(-counts // tile) * tile
+    group_end = jnp.cumsum(padded)
+    group_start = group_end - padded
+    # a pick's row: its group's start plus its rank among the group's picks
+    order = jnp.argsort(e, stable=True)
+    sorted_e = e[order]
+    first_of = jnp.cumsum(counts) - counts
+    rank = jnp.arange(M) - first_of[jnp.minimum(sorted_e, n_held - 1)]
+    m_pad = -(-M // tile) * tile + n_held * tile     # static bound
+    dest_sorted = jnp.where(
+        sorted_e < n_held,
+        group_start[jnp.minimum(sorted_e, n_held - 1)] + rank, m_pad)
+    dest = jnp.zeros(M, jnp.int32).at[order].set(dest_sorted.astype(jnp.int32))
+    token_of_row = jnp.full(m_pad, T, jnp.int32).at[dest].set(
+        jnp.arange(M, dtype=jnp.int32) // k, mode="drop")
+    rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[token_of_row]
+    n_tiles = group_end[-1] // tile
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        group_end, jnp.arange(m_pad // tile) * tile, side="right"),
+        n_held - 1)
+
+    def one_tile(i, out):
+        ex = tile_expert[i]
+        r = jax.lax.dynamic_slice_in_dim(rows, i * tile, tile, axis=0)
+        a = jnp.matmul(r, jax.lax.dynamic_index_in_dim(wg, ex, 0, False),
+                       preferred_element_type=jnp.float32)
+        b = jnp.matmul(r, jax.lax.dynamic_index_in_dim(wu, ex, 0, False),
+                       preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(a) * b).astype(x.dtype)
+        y = jnp.matmul(h, jax.lax.dynamic_index_in_dim(wd, ex, 0, False),
+                       preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_slice_in_dim(out, y, i * tile, axis=0)
+
+    out = jax.lax.fori_loop(0, n_tiles, one_tile,
+                            jnp.zeros((m_pad, d), jnp.float32))
+    picked = out.at[dest].get(mode="fill", fill_value=0.0)       # [M, d]
+    y = jnp.einsum("tkd,tk->td", picked.reshape(T, k, d),
+                   weights.astype(jnp.float32))
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                       jnp.max(counts)]).astype(jnp.int32)
+    return y, stats
+
+
+def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
+                    tile):
+    """One chip's share of a sigmoid-routed expert layer with a shared
+    expert: the router scores every expert of the published count and picks
+    ``k`` a token; this chip adds up what the experts it holds
+    (``held = (first, count)``) give, plus the shared expert, which every
+    chip of the deployment computes alike. What the other chips' experts
+    would add is their part of the sum: on one chip the layer runs without
+    its exchange, and nothing stands in for it.
+
+    params: {gate: {kernel [d, E], e_score_correction_bias [E]},
+    experts: {gate_proj, up_proj, down_proj}, shared_experts (optional):
+    {gate_proj/kernel, up_proj/kernel, down_proj/kernel}}. x [T, d].
+    Returns (y [T, d] in x's type, counts [3] int32 as ``held_experts_ffn``
+    gives them)."""
+    with jax.named_scope("moe_route"):
+        idx, w = sigmoid_topk_routing(
+            x, params["gate"]["kernel"],
+            params["gate"]["e_score_correction_bias"], k, scaling,
+            renormalize)
+    with jax.named_scope("moe_experts"):
+        y, stats = held_experts_ffn(x, params["experts"], idx, w, held, tile,
+                                    live)
+    if "shared_experts" in params:
+        with jax.named_scope("moe_shared"):
+            sp = params["shared_experts"]
+            a = jnp.matmul(x, sp["gate_proj"]["kernel"],
+                           preferred_element_type=jnp.float32)
+            b = jnp.matmul(x, sp["up_proj"]["kernel"],
+                           preferred_element_type=jnp.float32)
+            y = y + jnp.matmul((jax.nn.silu(a) * b).astype(x.dtype),
+                               sp["down_proj"]["kernel"],
+                               preferred_element_type=jnp.float32)
+    return y.astype(x.dtype), stats
+
+
+# ---------------------------------------------------------------------------
 # Expert parallelism (runs inside shard_map)
 # ---------------------------------------------------------------------------
 
