@@ -820,6 +820,7 @@ class DeepSpeedEngine:
         remat = getattr(self, "_remat_apply_fn", False)
         gather = self._gather_params_fn()
         tap = self._grad_overlap_tap()
+        layouts = self._param_layouts()
 
         def fwd_bwd(params, scale, rng, theta, *batch):
             @jax.named_scope("loss")
@@ -854,6 +855,13 @@ class DeepSpeedEngine:
                 return loss.astype(jnp.float32) * scale
 
             scaled_loss, grads = jax.value_and_grad(loss_fn)(params)
+            # Gradients leave the backward pass in their parameter's layout.
+            # Left open, GSPMD carries the optimizer's layout (ZeRO's flat
+            # P('data') shard) backwards through the accumulator into the
+            # model's backward loops, and a carry split that way is reduced
+            # once an iteration instead of once a step.
+            grads = jax.tree_util.tree_map(
+                jax.lax.with_sharding_constraint, grads, layouts)
             return scaled_loss / scale, grads
 
         return fwd_bwd
@@ -1004,6 +1012,18 @@ class DeepSpeedEngine:
 
         self._jit_cache["onebit_step"] = jax.jit(step_fn, donate_argnums=(0, 1, 2))
         return self._jit_cache["onebit_step"]
+
+    def _param_layouts(self):
+        """The sharding of every parameter leaf as the engine stores it:
+        replicated under plain data parallelism and ZeRO-1/2, the ``model``
+        axis under tensor parallelism, the ``data``-split storage layout
+        under ZeRO-3. Read off the leaves themselves, on the engine's mesh."""
+        def layout(p):
+            sh = getattr(p, "sharding", None)
+            spec = sh.spec if isinstance(sh, NamedSharding) else PartitionSpec()
+            return NamedSharding(self.mesh, spec)
+
+        return jax.tree_util.tree_map(layout, self.params)
 
     def _gather_params_fn(self):
         """Identity, except under ZeRO-3: constrain every leaf to replicated

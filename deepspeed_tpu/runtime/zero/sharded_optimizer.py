@@ -356,6 +356,14 @@ class ZeroShardedOptimizer:
         cannot always elide. Stage>=2's scatter still happens: ``update()``
         constrains the flat grads to ``P('data')``, which against an
         already-reduced replicated buffer is a free local slice.
+
+        With or without the tap, the engine pins the whole gradient tree to
+        its parameters' layout where the backward returns it
+        (``engine._fwd_bwd_core``): replicated for the stages this tap
+        serves, the same layout a bucket's pin asks for. The tap moves WHEN a
+        bucket's reduction runs (inside the backward, a bucket at a time);
+        the engine's pin fixes the layout in which every gradient arrives at
+        ``update()``, so the two never disagree.
         """
         if not self.overlap_comm:
             return None
@@ -468,9 +476,16 @@ class ZeroShardedOptimizer:
 
     # -- device path (jit-traceable) --------------------------------------
     def update(self, grads, opt_state, params, lr=None):
-        """One sharded step. grads: pytree (full, replicated under jit); the
-        sharding constraint below makes XLA materialize only the local slice
-        post-collective (reduce-scatter for stage >= 2)."""
+        """One sharded step. ``grads`` arrive as the engine hands them over:
+        each leaf already reduced and pinned to its parameter's layout
+        (``engine._fwd_bwd_core``; replicated for stages 1/2, the storage
+        split for stage 3), so the ``zero/flat_shard`` constraint below is a
+        local slice of an already reduced vector (stage >= 2: only the
+        owner's shard persists). The pin upstream is what keeps this
+        ``P('data')`` from travelling backwards through the concatenate and
+        the accumulator into the carries of the model's backward loops,
+        where it once made the loss reduce-scatter its kernel gradient once
+        a chunk."""
         treedef, shapes, dtypes, _ = self._spec
 
         flat_grads = flatten_dense_tensors(grads, jnp.float32)

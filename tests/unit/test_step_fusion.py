@@ -1,7 +1,7 @@
 """Compiler-driven train-step fusion: overlapped per-bucket backward/reduce,
 donated buffers, and the interleaved-1F1B pipeline schedule.
 
-Four proof layers, mirroring the bench leg (TRAIN_BENCH_CPU.json):
+Five proof layers, mirroring the bench leg (TRAIN_BENCH_CPU.json):
 
 - ``compute_bucket_ranges`` round-trips every leaf exactly once under any
   bucket size (the overlap tap's bucket plan).
@@ -13,16 +13,24 @@ Four proof layers, mirroring the bench leg (TRAIN_BENCH_CPU.json):
   only under overlap_comm, a CompileSentinel sees exactly one compile
   across repeated steps, and donated param buffers are really gone
   (no post-donation reads).
+- Gradients leave the backward pass in their parameter's layout: the
+  compiled ZeRO-2 step of a tiny BERT holds no collective in the chunked
+  loss's loops (for four described v5e chips; over CPU devices the carry
+  is whole), and ZeRO 1/2/3 and ZeRO-2 x tensor parallel match a
+  one-device engine, fused and with two microbatches.
 - The interleaved schedule's instruction streams match hand-computed
   Megatron-style traces at (S=2, V=2) and (S=4, V=2), and the dataflow
   simulator reproduces the analytic bubble ideals exactly.
 """
+
+import re
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 import deepspeed_tpu
 from deepspeed_tpu.runtime.config import DeepSpeedConfig, DeepSpeedConfigError
@@ -130,14 +138,24 @@ class TestOverlapParity:
 # donation pins
 # ---------------------------------------------------------------------------
 
-def _compiled_text(engine, *stacked):
-    """The compiled fused-step HLO for ``stacked`` ([gas, batch, ...] each)."""
+def _compiled_text(engine, *stacked, mesh=None):
+    """The compiled fused-step HLO for ``stacked`` ([gas, batch, ...] each);
+    ``mesh`` names devices that are described and not attached (a TPU
+    topology laid out as the engine's mesh) to compile for instead."""
     engine._ensure_opt_state()
+    args = (engine.params, engine.opt_state, engine.scaler_state,
+            jax.random.PRNGKey(0), jnp.float32(1.0), jnp.float32(1e-3),
+            *stacked)
+    if mesh is not None:
+        # the engine's programs read the mesh when they are traced
+        engine.mesh = engine.optimizer.mesh = mesh
+        args = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(
+                mesh, getattr(x.sharding, "spec", PartitionSpec()))), args)
     fused = engine._get_train_step(engine._module_needs_rng(), len(stacked))
-    inner = getattr(fused, "_fn", fused)
-    lowered = inner.lower(engine.params, engine.opt_state, engine.scaler_state,
-                          jax.random.PRNGKey(0), jnp.float32(1.0),
-                          jnp.float32(1e-3), *stacked)
+    traced = getattr(fused, "_fn", fused).trace(*args)
+    lowered = (traced.lower() if mesh is None
+               else traced.lower(lowering_platforms=("tpu",)))
     return lowered.compile().as_text()
 
 
@@ -182,20 +200,15 @@ class TestDonationPins:
 # the loss under a sharded batch
 # ---------------------------------------------------------------------------
 
-def test_fused_zero2_step_gathers_no_hidden_states_for_the_loss():
-    """A tiny BertForPreTraining under ZeRO-2 over 4 devices: the compiled
-    fused step all-gathers nothing shaped like the chunked loss's
-    [n_chunks, rows, H] array (the loss's scan once walked the axis the batch
-    sharding splits, and every device gathered every row, forward and
-    backward)."""
-    import re
-
+def _tiny_bert_zero2(vocab_size, hidden, layers, **config):
+    """(engine, stacked batch) of a tiny BertForPreTraining under ZeRO-2
+    over 4 devices: 32 x 64 = 2048 rows, 4 chunks of the loss's 512."""
     from deepspeed_tpu.models.bert import BertConfig, init_bert
 
-    B, S, H = 32, 64, 48        # 2048 rows: 4 chunks of the loss's 512
+    B, S = 32, 64
     model, params = init_bert(BertConfig(
-        vocab_size=256, hidden_size=H, num_hidden_layers=1,
-        num_attention_heads=4, intermediate_size=96,
+        vocab_size=vocab_size, hidden_size=hidden, num_hidden_layers=layers,
+        num_attention_heads=4, intermediate_size=2 * hidden,
         max_position_embeddings=S, hidden_dropout_prob=0.0,
         attention_probs_dropout_prob=0.0), batch_size=2, seq_len=S)
     engine, _, _, _ = deepspeed_tpu.initialize(
@@ -204,16 +217,235 @@ def test_fused_zero2_step_gathers_no_hidden_states_for_the_loss():
             "gradient_accumulation_steps": 1,
             "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
             "zero_optimization": {"stage": 2},
-            "mesh": {"data_parallel_size": 4}})
+            "mesh": {"data_parallel_size": 4}, **config})
     ids = np.zeros((B, S), np.int32)
-    text = _compiled_text(engine, *(
-        engine._shard_stacked(jnp.asarray(x)[None])
-        for x in (ids, ids, ids + 1, ids, np.zeros((B,), np.int32))))
-    chunks = f"[{B * S // 512},512,{H}]"
+    return engine, [engine._shard_stacked(jnp.asarray(x)[None]) for x in
+                    (ids, ids, ids + 1, ids, np.zeros((B,), np.int32))]
+
+
+def test_fused_zero2_step_gathers_no_hidden_states_for_the_loss():
+    """A tiny BertForPreTraining under ZeRO-2 over 4 devices: the compiled
+    fused step all-gathers nothing shaped like the chunked loss's
+    [n_chunks, rows, H] array (the loss's scan once walked the axis the batch
+    sharding splits, and every device gathered every row, forward and
+    backward)."""
+    H = 48
+    engine, stacked = _tiny_bert_zero2(256, H, 1)
+    text = _compiled_text(engine, *stacked)
+    chunks = f"[4,512,{H}]"
     gathers = [l.strip() for l in text.splitlines()
                if re.search(r"= \S+ all-gather(-start)?\(", l)]
     assert gathers, "ZeRO-2 over 4 devices gathers at least its parameters"
     assert not [l for l in gathers if chunks in l.split(" all-gather")[0]]
+
+
+# ---------------------------------------------------------------------------
+# gradients leave the backward pass in their parameter's layout
+# ---------------------------------------------------------------------------
+
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_COLLECTIVE = re.compile(
+    r"\s(all-reduce|reduce-scatter|all-reduce-scatter|collective-permute"
+    r"|all-gather|all-to-all)(-start)?\(")
+
+
+def _computations(text):
+    """Compiled HLO text as {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _loop_bodies(text):
+    """For each while of a compiled HLO text, every line its body reaches:
+    the body's own and those of the fusions, reducers and nested loops it
+    calls."""
+    comps = _computations(text)
+    out = []
+    for lines in comps.values():
+        for line in lines:
+            body = re.search(r"\swhile\(.*body=%?([\w.\-]+)", line)
+            if not body:
+                continue
+            seen, todo = [], [body.group(1)]
+            while todo:
+                name = todo.pop()
+                if name in seen or name not in comps:
+                    continue
+                seen.append(name)
+                for inner in comps[name]:
+                    for m in _CALLED.finditer(inner):
+                        todo.extend(n.strip().lstrip("%") for n in
+                                    (m.group(1) or m.group(2)).split(","))
+            out.append([l for name in seen for l in comps[name]])
+    return out
+
+
+def _collectives(lines):
+    """The collectives among ``lines``, by opcode; the TPU compiler's
+    all-reduce-scatter is a fusion that calls a computation of that name."""
+    heads = [l.split(", metadata=")[0] for l in lines]
+    return [h for h in heads if _COLLECTIVE.search(h)
+            or (" fusion(" in h and "all-reduce-scatter" in h)]
+
+
+def _loss_loops(text):
+    """Bodies of the whiles that belong to the chunked loss, forward and
+    backward: their operations are named ``.../BertForPreTraining/while/
+    body/...``, the encoder's ``.../bert/encoder/while/body/...``."""
+    return [lines for lines in _loop_bodies(text)
+            if any("BertForPreTraining/while/body" in l for l in lines)]
+
+
+def _tiny_bert_zero2_step(mesh_of=None):
+    """The compiled fused ZeRO-2 step of a tiny bf16 BertForPreTraining whose
+    vocabulary (250) no 4 chips split evenly; ``mesh_of(cpu_mesh)`` names
+    described devices to compile for."""
+    engine, stacked = _tiny_bert_zero2(250, 64, 2, bf16={"enabled": True})
+    return _compiled_text(
+        engine, *stacked, mesh=mesh_of and mesh_of(engine.mesh))
+
+
+def test_zero2_flat_shard_stays_out_of_the_loss_loops_on_described_v5e():
+    """Compiled for four described v5e chips, neither loop of the chunked
+    loss holds a collective: each chunk's partial kernel gradient is summed
+    locally and reduced once after the loop. Before the gradients were
+    pinned to their parameters' layout, ZeRO-2's flat ``P('data')`` shard
+    reached back into the backward loop's carry, split the word table's
+    gradient over the vocabulary, and the loop reduce-scattered it (with a
+    halo exchange, 250 / 4 being uneven) once a chunk."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without the chip: keep it out of one
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = _tiny_bert_zero2_step(lambda cpu: Mesh(
+            np.array(topo.devices).reshape(cpu.devices.shape),
+            cpu.axis_names))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    loops = _loss_loops(text)
+    assert len(loops) == 2, "the loss's forward and backward scans"
+    assert [_collectives(lines) for lines in loops] == [[], []]
+    # the instrument sees a collective in a loop when there is one: the
+    # encoder's backward loop reduces its layer's gradients
+    assert any(_collectives(lines) for lines in _loop_bodies(text))
+
+
+def test_zero2_flat_shard_does_not_split_the_loss_loops_carry_on_cpu():
+    """The CPU compiler does not move an all-reduce out of a loop, so over 4
+    CPU devices the backward loop of the loss still reduces each chunk's
+    partial kernel gradient; what the pinned layout changes there is the
+    carry: whole and summed over all four devices, where ZeRO-2's flat shard
+    had split it over the vocabulary and reduced it in groups of two."""
+    loops = _loss_loops(_tiny_bert_zero2_step())
+    assert len(loops) == 2
+    found = [c for lines in loops for c in _collectives(lines)]
+    assert found and all(" all-reduce(" in c for c in found), found
+    assert all("replica_groups=[1,4]<=[4]" in c for c in found), found
+    assert any("[250,64]" in c.split(" all-reduce(")[0] for c in found), found
+
+
+def _layout_case(case, gas=1, batch=16):
+    """(engine, x, y) of test_zero_tp's two-layer MLP under ``case``: a ZeRO
+    stage over 4 devices, ZeRO-2 over a 2 x 2 (data, model) mesh, or the
+    one-device stage-0 engine the others are compared with."""
+    from tests.unit.test_zero_tp import make_model_and_batch
+
+    stage, dp, tp = {"one_device": (0, 1, 1), "zero1": (1, 4, 1),
+                     "zero2": (2, 4, 1), "zero3": (3, 4, 1),
+                     "zero2_tp2": (2, 2, 2)}[case]
+    model, params, x, y = make_model_and_batch()
+    config = {
+        "train_batch_size": batch * gas,
+        "train_micro_batch_size_per_gpu": batch // dp,
+        "gradient_accumulation_steps": gas,
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
+        "mesh": {"data_parallel_size": dp}}
+    if stage:
+        config["zero_optimization"] = {"stage": stage}
+    if tp > 1:
+        config["tensor_parallel"] = {"size": tp}
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=params, config_params=config)
+    return engine, np.asarray(x), np.asarray(y)
+
+
+def _fwd_bwd(engine, x, y):
+    return engine._get_fwd_bwd(False)(
+        engine.params, jnp.float32(1.0), jax.random.PRNGKey(0),
+        jnp.float32(1.0), x, y)
+
+
+LAYOUT_CASES = ["zero1", "zero2", "zero3", "zero2_tp2"]
+
+
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_gradients_leave_in_their_parameters_layout(case):
+    """Each gradient leaf comes out of the backward pass laid out as its
+    parameter is stored (replicated under ZeRO-1/2, split over ``data`` as
+    stored under ZeRO-3, over ``model`` under tensor parallelism) and equal
+    to the one-device gradient."""
+    engine, x, y = _layout_case(case)
+    base, _, _ = _layout_case("one_device")
+    _, want = _fwd_bwd(base, x, y)
+    _, grads = _fwd_bwd(engine, x, y)
+    flat = jax.tree_util.tree_leaves_with_path
+    for (path, g), (_, p), (_, w) in zip(
+            flat(grads), flat(engine.params), flat(want)):
+        assert g.sharding.is_equivalent_to(p.sharding, g.ndim), (path, g.sharding)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-7)
+    ff1 = tuple(grads["params"]["ff1"]["kernel"].sharding.spec)
+    assert ff1 + (None,) * (2 - len(ff1)) == {
+        "zero3": ("data", None), "zero2_tp2": (None, "model")
+    }.get(case, (None, None))
+
+
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_fused_step_matches_the_one_device_step(case):
+    engine, x, y = _layout_case(case)
+    base, _, _ = _layout_case("one_device")
+    losses = [float(e.train_step([(x, y)])) for e in (base, engine)]
+    np.testing.assert_allclose(losses[1], losses[0], rtol=1e-4)
+    for a, b in zip(_leaves(base.params), _leaves(engine.params)):
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_two_microbatches_give_the_doubled_batchs_update(case):
+    rng = np.random.RandomState(1)
+    _, x, y = _layout_case("one_device")
+    x2, y2 = (rng.randn(*a.shape).astype(np.float32) for a in (x, y))
+    two, _, _ = _layout_case(case, gas=2)
+    one, _, _ = _layout_case(case, batch=32)
+    loss_two = float(two.train_step([(x, y), (x2, y2)]))
+    loss_one = float(one.train_step(
+        [(np.concatenate([x, x2]), np.concatenate([y, y2]))]))
+    np.testing.assert_allclose(loss_two, loss_one, rtol=1e-5)
+    for a, b in zip(_leaves(one.params), _leaves(two.params)):
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
