@@ -453,7 +453,7 @@ def _serve_phase(cfg, *, max_seq_len, prompt_buckets, max_slots, requests,
 
     from deepspeed_tpu.inference import generate
     from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
-    from deepspeed_tpu.inference.serving import engine as engine_mod
+    from deepspeed_tpu.inference.serving.families import gpt2 as gpt2_mod
     from deepspeed_tpu.models.gpt2 import init_gpt2
     from deepspeed_tpu.profiling.sentinels import CompileSentinel
 
@@ -465,7 +465,7 @@ def _serve_phase(cfg, *, max_seq_len, prompt_buckets, max_slots, requests,
     rng = np.random.RandomState(seed)
     prompts = [rng.randint(0, cfg.vocab_size, n).tolist() for n, _ in requests]
 
-    decode_sentinel = CompileSentinel(engine_mod._decode_step_jit, 1,
+    decode_sentinel = CompileSentinel(gpt2_mod._decode_step_jit, 1,
                                       name="serving decode step")
     engine = ServingEngine(params, cfg, ServingConfig(
         max_slots=max_slots, max_queue=max(len(requests), 1),

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import kernels, telemetry
 from deepspeed_tpu.inference.generation import generate
-from deepspeed_tpu.inference.serving import engine as serving_engine_mod
+from deepspeed_tpu.inference.serving.families import gpt2 as serving_engine_mod
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.engine import ServingEngine
 from deepspeed_tpu.kernels.registry import KernelProbeError, KernelRegistry
@@ -552,7 +552,7 @@ def test_serving_probe_failure_degrades_to_xla(model, clean_registry):
     clean_registry.force_probe_result("decode_attention", False,
                                       error="simulated lowering failure")
     eng = _engine(cfg, params, attention_impl="pallas_decode")
-    assert eng._kernel_impl["pallas_decode"] == "xla"
+    assert eng.family._kernel_impl["pallas_decode"] == "xla"
     prompts = [[5, 9, 3], [7, 1]]
     got = _serve(eng, prompts)
     for p, g in zip(prompts, got):
@@ -616,7 +616,7 @@ def test_steady_state_transfer_free_kernel(model, backend):
     futs = [eng.submit(p, max_new_tokens=8) for p in prompts]
     eng.step()
     eng.step()
-    assert eng._lane_dirty is False and len(eng._active) == 2
+    assert eng.lanes.dirty is False and len(eng.lanes.requests) == 2
     with transfer_free():
         for _ in range(4):
             stats = eng.step()

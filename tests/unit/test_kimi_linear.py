@@ -19,8 +19,10 @@ import jax.numpy as jnp
 from benchmarks.refs import kimi_linear_ref as ref
 from benchmarks.refs import weights as weights_mod
 from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
-from deepspeed_tpu.inference.serving.family import (
+from deepspeed_tpu.inference.serving.families.kimi_linear import (
     KimiLinearFamily,
+)
+from deepspeed_tpu.inference.serving.family import (
     UnsupportedOptionError,
     family_for,
 )
@@ -122,10 +124,10 @@ def test_engine_logits_match_the_reference_forward_pass(slow_decay):
     eng.family.keep_logits = True
     real = eng.family.decode_step
 
-    def spy(engine, guard, classes):
-        lanes = {s: r.id for s, r in engine._active.items()}
-        out = real(engine, guard, classes)
-        logits = np.asarray(engine.family.last_logits)
+    def spy(guard):
+        lanes = {s: r.id for s, r in eng.lanes.requests.items()}
+        out = real(guard)
+        logits = np.asarray(eng.family.last_logits)
         for slot, rid in lanes.items():
             occupants.setdefault(slot, set()).add(rid)
             seen.setdefault(rid, []).append(logits[slot])

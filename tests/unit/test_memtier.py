@@ -21,7 +21,7 @@ from deepspeed_tpu.inference.serving import (
     ServingEngine,
     ServingFaultInjector,
 )
-from deepspeed_tpu.inference.serving import engine as serving_engine_mod
+from deepspeed_tpu.inference.serving.families import gpt2 as serving_engine_mod
 from deepspeed_tpu.inference.serving.chaos import (
     MEMTIER_FAULT_KINDS,
     MemtierChaosHarness,

@@ -100,8 +100,18 @@ def test_no_cross_generation_replay_poisons_instead(stubs):
            ReplicaEndpoint("g2", "127.0.0.1", b.port, generation="2")]
     r = Router(eps, FleetConfig(enabled=True, **FAST_CFG))
     got = []
-    fut = r.submit([1, 2, 3], max_new_tokens=6,
-                   stream_cb=lambda k, t: got.append(t))
+
+    def on_token(_key, tok):
+        got.append(tok)
+        if len(got) == 2:
+            # generation 1's only replica dies with its stream, listener and
+            # all. Left listening, whether a health probe finds it alive
+            # again inside the retry budget is a race with health_ttl_s
+            # (20 ms): lost under load, the router replays there, which is
+            # legitimate within a generation, and delivers two tokens more
+            a.close()
+
+    fut = r.submit([1, 2, 3], max_new_tokens=6, stream_cb=on_token)
     with pytest.raises(RequestPoisonedError):
         fut.result(timeout=10)
     # the two delivered tokens came from generation 1, exactly once

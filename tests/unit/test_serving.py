@@ -31,7 +31,7 @@ from deepspeed_tpu.inference.serving import (
     bucket_for,
     default_buckets,
 )
-from deepspeed_tpu.inference.serving import engine as serving_engine_mod
+from deepspeed_tpu.inference.serving.families import gpt2 as serving_engine_mod
 from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2
 from deepspeed_tpu.profiling import CompileSentinel, transfer_free
 from deepspeed_tpu.profiling.config import DeepSpeedSentinelConfig
@@ -206,7 +206,7 @@ def test_deadline_mid_decode(model):
     assert not doomed.done()
     # shrink the in-flight deadline so the NEXT step reaps it mid-decode
     # (a submit-time micro-deadline would expire while still queued)
-    next(r for r in eng._active.values()
+    next(r for r in eng.lanes.requests.values()
          if r.future is doomed).timeout_s = 1e-6
     eng.drain(max_steps=100)
 
@@ -284,7 +284,7 @@ def test_steady_state_decode_is_transfer_free(model):
     futs = [eng.submit(p, max_new_tokens=8) for p in prompts]
     eng.step()             # admission: prefill + lane-churn upload queued
     eng.step()             # flushes the churn upload (explicit device_put)
-    assert eng._lane_dirty is False and len(eng._active) == 2
+    assert eng.lanes.dirty is False and len(eng.lanes.requests) == 2
     with transfer_free():
         for _ in range(4):  # steady state: no admission, no retirement
             stats = eng.step()
@@ -579,8 +579,8 @@ def test_chunked_prefill_deadline_aborts_with_prefill_phase(model):
     doomed = eng.submit(_prompts(1, lengths=(8,))[0], max_new_tokens=4,
                         timeout_s=60.0)
     eng.step()                                   # chunked prefill started
-    assert eng._chunking is not None
-    eng._chunking.req.timeout_s = 1e-6           # expire it mid-prefill
+    assert eng.family._chunking is not None
+    eng.family._chunking.req.timeout_s = 1e-6           # expire it mid-prefill
     eng.drain(max_steps=100)
     with pytest.raises(RequestTimeoutError) as ei:
         doomed.result(timeout=1)
@@ -915,8 +915,8 @@ def test_armed_window_sentinels_via_config(model):
         "enabled": True, "compile_budget": 8, "transfer_guard": True}})
     eng = _engine(cfg, params, attention_impl="sparse_xla",
                   kv_page_tokens=8, sentinel_config=sent_cfg)
-    assert eng.decode_window_sentinel is not None
-    assert eng.prefill_window_sentinel is not None
+    assert eng.family.decode_window_sentinel is not None
+    assert eng.family.prefill_window_sentinel is not None
     prompts = _prompts(3)
     wants = [_backend_oneshot(cfg, params, p, 4, "sparse_xla")
              for p in prompts]
@@ -924,7 +924,7 @@ def test_armed_window_sentinels_via_config(model):
     eng.drain(max_steps=200)
     for f, want in zip(futs, wants):
         assert f.result(timeout=1) == want
-    assert eng.decode_window_sentinel.check() <= 8
+    assert eng.family.decode_window_sentinel.check() <= 8
 
 
 def test_steady_state_transfer_free_sparse(model):
@@ -940,7 +940,7 @@ def test_steady_state_transfer_free_sparse(model):
     futs = [eng.submit(p, max_new_tokens=8) for p in prompts]
     eng.step()
     eng.step()
-    assert eng._lane_dirty is False and len(eng._active) == 2
+    assert eng.lanes.dirty is False and len(eng.lanes.requests) == 2
     with transfer_free():
         for _ in range(4):
             stats = eng.step()

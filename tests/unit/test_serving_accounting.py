@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
 from deepspeed_tpu.inference.serving import engine as engine_mod
+from deepspeed_tpu.inference.serving.families import gpt2 as gpt2_mod
 from deepspeed_tpu.inference.serving import scheduler as scheduler_mod
 from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2
 from deepspeed_tpu.telemetry import trace as trace_mod
@@ -76,8 +77,9 @@ def clock(monkeypatch):
     ``stream_cb`` per token."""
     c = Clock()
     monkeypatch.setattr(engine_mod, "time", c)
+    monkeypatch.setattr(gpt2_mod, "time", c)
     monkeypatch.setattr(scheduler_mod, "time", c)
-    prefill, decode = engine_mod._prefill_batch_jit, engine_mod._decode_step_jit
+    prefill, decode = gpt2_mod._prefill_batch_jit, gpt2_mod._decode_step_jit
 
     def slow_prefill(*a, **k):
         c.advance(PREFILL_S)
@@ -87,8 +89,8 @@ def clock(monkeypatch):
         c.advance(DECODE_S)
         return decode(*a, **k)
 
-    monkeypatch.setattr(engine_mod, "_prefill_batch_jit", slow_prefill)
-    monkeypatch.setattr(engine_mod, "_decode_step_jit", slow_decode)
+    monkeypatch.setattr(gpt2_mod, "_prefill_batch_jit", slow_prefill)
+    monkeypatch.setattr(gpt2_mod, "_decode_step_jit", slow_decode)
     return c
 
 
@@ -323,15 +325,17 @@ def test_decode_and_install_programs_carry_their_scopes(model):
     eng.submit([1, 2, 3], max_new_tokens=2)
     eng.step()
     text = _lowered_text(
-        engine_mod._decode_step_jit, eng.params, eng.pool.k, eng.pool.v,
-        eng._dev_page_tables, eng._dev_tokens, eng._dev_positions,
-        eng._dev_active, n_heads=eng.n_heads)
+        gpt2_mod._decode_step_jit, eng.params, eng.pool.k, eng.pool.v,
+        eng.lanes.dev_page_tables, eng.lanes.dev_tokens,
+        eng.lanes.dev_positions, eng.lanes.dev_active,
+        n_heads=eng.family.n_heads)
     for scope in ("kv_gather", "attend", "kv_scatter", "sample"):
         assert f"/{scope}/" in text, scope
 
     from deepspeed_tpu.inference.serving import kv_pool
 
-    shape = (eng.n_layers, 1, eng.n_heads, eng.max_seq_len, eng.head_dim)
+    fam = eng.family
+    shape = (fam.n_layers, 1, fam.n_heads, eng.max_seq_len, fam.head_dim)
     new = jnp.zeros(shape, eng.pool.compute_dtype)
     dest = jnp.zeros((eng.pool.page_tables.shape[1],), jnp.int32)
     text = _lowered_text(kv_pool._install_pages_jit, eng.pool.k, eng.pool.v,
@@ -351,9 +355,10 @@ def test_sibling_decode_programs_carry_the_same_scopes(model, program, kwargs):
     if "page_tokens" in kwargs:
         kwargs = dict(kwargs, page_tokens=eng.pool.page_tokens)
     text = _lowered_text(
-        getattr(engine_mod, program), eng.params, eng.pool.k, eng.pool.v,
-        None, None, eng._dev_page_tables, eng._dev_tokens,
-        eng._dev_positions, eng._dev_active, n_heads=eng.n_heads, **kwargs)
+        getattr(gpt2_mod, program), eng.params, eng.pool.k, eng.pool.v,
+        None, None, eng.lanes.dev_page_tables, eng.lanes.dev_tokens,
+        eng.lanes.dev_positions, eng.lanes.dev_active,
+        n_heads=eng.family.n_heads, **kwargs)
     for scope in ("kv_gather", "attend", "kv_scatter", "sample"):
         assert f"{scope}/" in text, scope
     eng.drain(max_steps=10)

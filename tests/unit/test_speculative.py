@@ -41,7 +41,7 @@ from deepspeed_tpu.inference.serving import (
     ServingEngine,
     ServingFaultInjector,
 )
-from deepspeed_tpu.inference.serving import engine as serving_engine_mod
+from deepspeed_tpu.inference.serving.families import gpt2 as serving_engine_mod
 from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2
 from deepspeed_tpu.profiling import CompileSentinel, transfer_free
 from deepspeed_tpu.runtime.config import get_serving_config
@@ -207,7 +207,7 @@ def test_spec_steady_state_transfer_free(model):
     futs = [eng.submit(p, max_new_tokens=16) for p in prompts]
     eng.step()             # admission: prefill + lane-churn upload queued
     eng.step()             # flushes the churn upload (explicit device_put)
-    assert eng._lane_dirty is False and len(eng._active) == 2
+    assert eng.lanes.dirty is False and len(eng.lanes.requests) == 2
     with transfer_free():
         for _ in range(3):  # steady state: no admission, no retirement
             stats = eng.step()

@@ -476,7 +476,7 @@ def test_steady_state_decode_transfer_free_with_tracing_armed():
                 for _ in range(2)]
         eng.step()             # admission
         eng.step()             # flush lane churn upload
-        assert eng._tracer.enabled
+        assert eng.tracer.enabled
         with transfer_free():
             for _ in range(4):
                 stats = eng.step()

@@ -215,31 +215,12 @@ ALL_CODES = tuple(sorted(RULES))
 
 # -- JL002 hot-loop registry -------------------------------------------------
 # Fully-qualified (posix path suffix, function qualname) pairs the repo
-# considers steady-state hot loops: the serving decode step and both
-# training engines' per-step core. A function is also treated as hot when
-# its `def` line (or the line above) carries a `# jaxlint: hot` marker,
-# so new hot loops opt in without editing this table.
+# considers steady-state hot loops: both training engines' per-step core.
+# A function is also treated as hot when its `def` line (or the line
+# above) carries a `# jaxlint: hot` marker, so new hot loops opt in
+# without editing this table: the serving loop's `step`, every jitted
+# serving program and both families' `decode_step` carry it.
 HOT_LOOPS = (
-    ("deepspeed_tpu/inference/serving/engine.py", "ServingEngine.step"),
-    # paged prefill/decode programs: the jitted bodies every scheduler
-    # step re-enters — a host sync traced into any of them stalls all
-    # MaxSlots lanes at once
-    ("deepspeed_tpu/inference/serving/engine.py", "_prefill_batch_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_prefill_batch_flash_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_prefill_batch_window_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_decode_step_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_decode_step_quant_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_decode_step_window_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_spec_step_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_spec_step_quant_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_spec_step_window_jit"),
-    # kernel-tier programs: the same per-step contract, plus the fused
-    # int8 path (JL010 taint through the pool pages the kernel consumes)
-    ("deepspeed_tpu/inference/serving/engine.py", "_prefill_batch_kernel_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py",
-     "_prefill_batch_kernel_window_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_decode_step_kernel_jit"),
-    ("deepspeed_tpu/inference/serving/engine.py", "_spec_step_kernel_jit"),
     ("deepspeed_tpu/runtime/engine.py", "DeepSpeedEngine._train_batch_now"),
     ("deepspeed_tpu/runtime/pipe/engine.py", "PipelineEngine._train_batch_now"),
     # train-step fusion tier: the overlap tap's custom-vjp backward is
