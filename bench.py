@@ -225,6 +225,7 @@ def child_main():
         "remat_policy": cfg.checkpoint_policy,
         "scan_unroll": cfg.scan_unroll,
         "attn_impl": _attn_impl_label(),
+        "attn_rows_per_step": _attn_rows_per_step(),
         "final_loss": round(final_loss, 3),
     }))
     return 0
@@ -287,6 +288,7 @@ def gpt2_child_main():
         "remat_policy": cfg.checkpoint_policy,
         "scan_unroll": cfg.scan_unroll,
         "attn_impl": _attn_impl_label(),
+        "attn_rows_per_step": _attn_rows_per_step(),
         "final_loss": round(final_loss, 3),
     }))
     return 0
@@ -2554,6 +2556,14 @@ def _attn_impl_label():
     from deepspeed_tpu.ops.transformer.attention import traced_implementation
 
     return traced_implementation()
+
+
+def _attn_rows_per_step():
+    """(batch, head) rows a grid step of the flash kernels that step was
+    traced with took; 0 where the jnp reference ran."""
+    from deepspeed_tpu.ops.transformer.attention import traced_rows_per_step
+
+    return traced_rows_per_step()
 
 
 # ---------------------------------------------------------------------------

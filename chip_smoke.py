@@ -117,6 +117,7 @@ def _flash_legs(report, shapes, block, dropout_rate, native, seed):
         _check(f"{tag}_fwd", _rel_err(got, want), 3e-2, report)
         _check(f"{tag}_bwd", max(_rel_err(a, b)
                                  for a, b in zip(got_g, want_g)), 5e-2, report)
+        report[f"{tag}_rows_per_step"] = attn.traced_rows_per_step()
 
         # dropout: same key, same mask; a different output than without it;
         # and the backward runs (its mask is regenerated, not stored)
@@ -176,6 +177,11 @@ def _flash_legs(report, shapes, block, dropout_rate, native, seed):
     _check("flash_sparse_bwd", max(_rel_err(a, b)
                                    for a, b in zip(got_g, want_g)),
            5e-2, report)
+    report["flash_sparse_rows_per_step"] = attn.traced_rows_per_step()
+    if native and report["flash_sparse_rows_per_step"] != 1:
+        raise AssertionError(
+            "a block-sparse layout was given several rows a grid step: "
+            "its LUT differs by head")
 
     if native and attn.trace_counts()[0] == traced_before[0]:
         raise AssertionError(
@@ -360,6 +366,7 @@ def train_phase(cfg, *, seq_len, micro_batch, warmup, steps, native, seed=0):
     report = {
         "params": n_params, "global_batch": global_batch, "seq_len": seq_len,
         "devices": n_dev, "attention": attention,
+        "attention_rows_per_step": attn.traced_rows_per_step(),
         "losses": [round(x, 4) for x in losses],
         "init_and_first_step_s": round(compile_s, 1),
         "step_ms": [round(t * 1e3, 1) for t in times],

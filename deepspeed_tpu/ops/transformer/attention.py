@@ -10,8 +10,13 @@ Capability parity with TWO reference native-kernel subsystems at once:
   ``csrc/sparse_attention/utils.cpp``'s layout->LUT preprocessing).
 
 TPU-first design: ONE Pallas kernel computes QK^T -> masked online-softmax ->
-PV per (batch*head, query-block-row) grid cell, streaming key/value blocks
-named by a per-row lookup table (LUT). A dense layout makes it flash
+PV per (group of G batch*head rows, query-block-row) grid cell, streaming
+key/value blocks named by a per-row lookup table (LUT). G follows the shape
+(``rows_per_step``: as many rows as a fixed VMEM budget holds, so 16 at
+BERT's seq 128 and 1 at 8k; 1 for a block-sparse layout): at a short
+sequence a row is a few dozen MXU cycles behind a grid step's fixed cost,
+and G rows in one step share that cost and overlap their softmax chains. A
+row's arithmetic does not depend on G. A dense layout makes it flash
 attention; a sparse layout (Fixed/BigBird/Longformer, see
 ``sparsity_config.py``) skips absent blocks entirely, which is exactly the
 load-balanced-LUT design of the reference's Triton kernels re-tiled for the
@@ -54,6 +59,9 @@ DEFAULT_BLOCK = 128
 # to: what bench.py and chip_smoke.py read to report the attention that RAN.
 PALLAS_TRACES = "Kernels/flash_attention/pallas_traces"
 REFERENCE_TRACES = "Kernels/flash_attention/reference_traces"
+# Trace-time gauge: the (batch, head) rows a grid step of the last traced
+# kernels handles (``rows_per_step``).
+ROWS_PER_STEP = "Kernels/flash_attention/rows_per_step"
 
 
 def _count_trace(tag):
@@ -79,6 +87,12 @@ def traced_implementation(since=(0, 0)):
     if pallas and reference:
         return "mixed"
     return "pallas" if pallas else ("reference" if reference else "none")
+
+
+def traced_rows_per_step():
+    """(batch, head) rows a grid step of the flash kernels traced last took
+    (``rows_per_step``); 0 while the kernels were never traced."""
+    return int(telemetry.get_registry().gauge(ROWS_PER_STEP).value)
 
 
 # ---------------------------------------------------------------------------
@@ -128,36 +142,63 @@ def _fold_dropout_seed(seed, bh, qi, kj):
     )
 
 
-def _dropout_keep(seed_ref, bh, qi, kj, block_q, block_k, rate):
-    """[BQ, BK] keep/(1-rate) scale mask from the TPU PRNG, deterministically
-    re-derivable from (seed, bh, qi, kj) — the forward and BOTH backward
-    kernels regenerate the identical mask instead of storing O(S^2) bits
-    (the flash-dropout trick; reference stores the mask from its fused
-    dropout kernels, csrc/transformer/dropout_kernels.cu)."""
-    pltpu.prng_seed(*_fold_dropout_seed(seed_ref[0], bh, qi, kj))
-    bits = pltpu.prng_random_bits((block_q, block_k)).astype(jnp.uint32)
+def _dropout_keep(seed_ref, bh0, rows, qi, kj, block_q, block_k, rate):
+    """[G, BQ, BK] keep/(1-rate) scale masks from the TPU PRNG, one per row
+    of the group, each deterministically re-derivable from (seed, bh, qi,
+    kj) with ``bh = bh0 + g`` the row's number in the call — the forward and
+    BOTH backward kernels regenerate the identical mask instead of storing
+    O(S^2) bits (the flash-dropout trick; reference stores the mask from its
+    fused dropout kernels, csrc/transformer/dropout_kernels.cu). A row draws
+    what it draws alone in a group of one: the group size is no part of the
+    identity."""
     threshold = jnp.uint32(min(int(rate * 2**32), 2**32 - 1))
-    return jnp.where(bits >= threshold, 1.0 / (1.0 - rate), 0.0)
+    bits = []
+    for g in range(rows):
+        pltpu.prng_seed(*_fold_dropout_seed(seed_ref[0], bh0 + g, qi, kj))
+        bits.append(pltpu.prng_random_bits((block_q, block_k)).astype(jnp.uint32))
+    return jnp.where(jnp.stack(bits) >= threshold, 1.0 / (1.0 - rate), 0.0)
+
+
+def _rows_dot(a, b, a_axis, b_axis):
+    """Per-row contraction of ``[G, ., .]`` operands over ``a_axis`` of ``a``
+    and ``b_axis`` of ``b``, fp32 result: the G (batch, head) rows of a grid
+    step ride as the leading batch dimension of ONE dot, so their MXU passes
+    and the softmax chains between them are scheduled side by side."""
+    return jax.lax.dot_general(
+        a, b, (((a_axis,), (b_axis,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
+def _stat_columns(x):
+    """[G, 1, N] row statistics (lse, delta: stored along lanes) as the
+    [G, N, 1] columns the [G, N, K] scores broadcast. Written as a reshape,
+    which Mosaic lowers row by row and fuses with the broadcast that follows:
+    the form to use inside a loop (measured on a v5e, PERF.md PR 30)."""
+    return x[:, 0, :][:, :, None]
 
 
 def _attn_kernel(seed_ref, counts_ref, lut_ref, q_ref, k_ref, v_ref, bias_ref,
                  o_ref, lse_ref,
                  *, num_heads, block_q, block_k, maxn, scale, causal, dropout_rate):
-    """One (batch*head, q-block-row) cell: stream LUT-named k/v blocks with
-    online softmax. carry = (m, l, acc) runs in registers/VMEM values.
+    """One (group of G batch*head rows, q-block-row) cell: stream LUT-named
+    k/v blocks with online softmax, all G rows in every operation. carry =
+    (m, l, acc) runs in registers/VMEM values. G is the blocks' leading
+    extent (``rows_per_step``); the rows of a group share the LUT row of
+    the group's first head, which is why only dense layouts group.
 
     Dropout (rate > 0) applies to the softmax PROBS: the normalizer l
     accumulates the UNDROPPED p while acc accumulates (mask * p / keep) @ v,
     so out = dropout(softmax(s)) @ v exactly."""
-    bh = pl.program_id(0)
+    rows = q_ref.shape[0]
+    bh0 = pl.program_id(0) * rows
     qi = pl.program_id(1)
-    h = jax.lax.rem(bh, num_heads)
+    h = jax.lax.rem(bh0, num_heads)
 
     # MXU dtype discipline: matmul OPERANDS stay in the input dtype (bf16
     # inputs hit the native bf16 MXU path — fp32 matmuls are several times
     # slower on TPU) while every accumulation/softmax runs in fp32 via
     # preferred_element_type. Scale applies to the fp32 scores, not to q.
-    q = q_ref[0]                                      # [BQ, D], input dtype
+    q = q_ref[...]                                    # [G, BQ, D], input dtype
     in_dtype = q.dtype
     D = q.shape[-1]
     count = counts_ref[h, qi]
@@ -167,12 +208,11 @@ def _attn_kernel(seed_ref, counts_ref, lut_ref, q_ref, k_ref, v_ref, bias_ref,
     def body(n, carry):
         m, l, acc = carry
         kj = lut_ref[h, qi, n]
-        k_blk = k_ref[0, pl.ds(kj * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kj * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                      # [BQ, BK] fp32
-        s = s + bias_ref[0, 0, pl.ds(kj * block_k, block_k)].astype(jnp.float32)[None, :]
+        cols = pl.ds(kj * block_k, block_k)
+        k_blk = k_ref[:, cols, :]
+        v_blk = v_ref[:, cols, :]
+        s = _rows_dot(q, k_blk, 2, 2) * scale         # [G, BQ, BK] fp32
+        s = s + bias_ref[:, :, cols].astype(jnp.float32)
         if causal:
             k_pos = kj * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
@@ -184,33 +224,86 @@ def _attn_kernel(seed_ref, counts_ref, lut_ref, q_ref, k_ref, v_ref, bias_ref,
         l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
         p_acc = p
         if dropout_rate > 0.0:
-            p_acc = p * _dropout_keep(seed_ref, bh, qi, kj, block_q, block_k, dropout_rate)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p_acc.astype(in_dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            p_acc = p * _dropout_keep(seed_ref, bh0, rows, qi, kj,
+                                      block_q, block_k, dropout_rate)
+        acc_new = acc * corr + _rows_dot(p_acc.astype(in_dtype), v_blk, 2, 1)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((block_q, 1), -1e30, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, D), jnp.float32)
+    m0 = jnp.full((rows, block_q, 1), -1e30, jnp.float32)
+    l0 = jnp.zeros((rows, block_q, 1), jnp.float32)
+    acc0 = jnp.zeros((rows, block_q, D), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, count, body, (m0, l0, acc0))
 
     out = jnp.where(l > 0.0, acc / jnp.where(l > 0.0, l, 1.0), 0.0)
-    o_ref[0] = out.astype(o_ref.dtype)
+    o_ref[...] = out.astype(o_ref.dtype)
     # log-sum-exp residual for the flash backward; +inf-like for empty rows so
-    # exp(s - lse) == 0 there. Stored [1,1,BQ]: Mosaic requires the last two
+    # exp(s - lse) == 0 there. Stored [G,1,BQ]: Mosaic requires the last two
     # block dims be (8,128)-aligned or equal to the array dims, which a 2D
-    # (1, BQ) block on a (BH, S) array violates whenever BH > 1.
-    lse = jnp.where(l[:, 0] > 0.0, m[:, 0] + jnp.log(jnp.where(l[:, 0] > 0, l[:, 0], 1.0)), 1e30)
-    lse_ref[0, 0] = lse
+    # (G, BQ) block on a (BH, S) array violates whenever G is not 8-aligned.
+    lse = jnp.where(l > 0.0, m + jnp.log(jnp.where(l > 0.0, l, 1.0)), 1e30)
+    if rows == 1:
+        lse_ref[0, 0] = lse[0, :, 0]
+    else:
+        # one transpose for the whole group beats G relayouts (PERF.md PR 30)
+        lse_ref[...] = jnp.swapaxes(lse, 1, 2)
+
+
+# VMEM one grid step's blocks and temporaries may take, by ``rows_per_step``'s
+# own count, of the 16 MiB a Mosaic kernel is given by default. The count is
+# an upper estimate: at every sequence length the groups this budget admits
+# compile for a v5e (head sizes 32-256, bf16 and fp32, with dropout), and the
+# next power of two, 17 MiB or more by the same count, is refused.
+_VMEM_BUDGET = 12 * 2**20
+
+
+def rows_per_step(bh, S, D, dtype, dense, block=DEFAULT_BLOCK):
+    """How many (batch, head) rows one grid step of the three kernels takes.
+
+    A row's own work at a short sequence is a few dozen MXU cycles behind a
+    fixed cost per grid step and one dependent chain (QK^T, max, exp, sum,
+    PV), so rows are grouped until the step's VMEM is spent: the largest
+    power of two that divides ``bh`` (the rows of THIS call: per device
+    under ``shard_map``) and whose blocks fit ``_VMEM_BUDGET``, sized by the
+    hungriest kernel (dk/dv: q and dO whole, k, v, dk, dv one block each,
+    all double-buffered, with [., block, D] tiles padded to 128 lanes; four
+    fp32 [block, block] temporaries and two fp32 accumulators). It falls as
+    ``S`` grows, when a row brings enough work of its own. A block-sparse
+    layout keeps 1: the rows of a group share one LUT row, and a sparse
+    LUT differs by head."""
+    if not dense:
+        return 1
+    lanes = -(-D // 128) * 128
+    tile = block * lanes * jnp.dtype(dtype).itemsize
+    per_row = (2 * (2 * (S // block) + 4) * tile
+               + 4 * block * block * 4 + 2 * block * lanes * 4)
+    rows = 1
+    while bh % (2 * rows) == 0 and 2 * rows * per_row <= _VMEM_BUDGET:
+        rows *= 2
+    return rows
+
+
+def _group_specs(rows, S, D, block):
+    """BlockSpecs of the three kernels' operands for ``rows`` (batch, head)
+    rows a grid step, grid ``(BH // rows, S // block)``: (a [BH, S, D]
+    array's block of the step's sequence block, the same array's whole
+    sequence, a [BH, 1, S] row statistic's block, its whole sequence)."""
+    return (
+        pl.BlockSpec((rows, block, D), lambda g, i, *_: (g, i, 0)),
+        pl.BlockSpec((rows, S, D), lambda g, i, *_: (g, 0, 0)),
+        pl.BlockSpec((rows, 1, block), lambda g, i, *_: (g, 0, i)),
+        pl.BlockSpec((rows, 1, S), lambda g, i, *_: (g, 0, 0)),
+    )
 
 
 def _attention_pallas(q, k, v, bias, lut, counts, *, block_q, block_k, causal,
-                      interpret=False, dropout_rate=0.0, seed=None):
+                      interpret=False, dropout_rate=0.0, seed=None, rows=1):
     """q,k,v: [B, H, S, D]; bias additive [B, S] (key bias, e.g. padding).
-    ``seed``: [1] int32 array feeding the in-kernel dropout PRNG."""
+    ``seed``: [1] int32 array feeding the in-kernel dropout PRNG. ``rows``:
+    (batch, head) rows a grid step, from ``rows_per_step``."""
     _count_trace(PALLAS_TRACES)
+    telemetry.get_registry().gauge(
+        ROWS_PER_STEP, help="(batch, head) rows a grid step of the last "
+        "traced flash_attention kernels handles").set(rows)
     B, H, S, D = q.shape
     BH = B * H
     qr = q.reshape(BH, S, D)
@@ -219,19 +312,12 @@ def _attention_pallas(q, k, v, bias, lut, counts, *, block_q, block_k, causal,
     maxn = lut.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
 
+    blk, seq, stat_blk, stat_seq = _group_specs(rows, S, D, block_q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(BH, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, *_: (bh, qi, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda bh, qi, *_: (bh, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, *_: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, *_: (bh, 0, qi)),
-        ),
+        grid=(BH // rows, S // block_q),
+        in_specs=[blk, seq, seq, stat_seq],
+        out_specs=(blk, stat_blk),
     )
     kernel = functools.partial(
         _attn_kernel, num_heads=H, block_q=block_q, block_k=block_k,
@@ -255,61 +341,69 @@ def _attention_pallas(q, k, v, bias, lut, counts, *, block_q, block_k, causal,
 def _attn_bwd_dq_kernel(seed_ref, counts_ref, lut_ref, q_ref, k_ref, v_ref, bias_ref,
                         do_ref, lse_ref, delta_ref, dq_ref,
                         *, num_heads, block_q, block_k, scale, causal, dropout_rate):
-    """dq for one (bh, q-block-row): dq = scale * sum_j ds_j @ k_j with
-    ds = p * (mask * dO @ v^T - delta) and p = exp(s - lse). The dropout mask
-    regenerates from (seed, bh, qi, kj) — identical to the forward's."""
-    bh = pl.program_id(0)
+    """dq for one (group of rows, q-block-row): dq = scale * sum_j ds_j @ k_j
+    with ds = p * (mask * dO @ v^T - delta) and p = exp(s - lse). The dropout
+    mask regenerates from (seed, bh, qi, kj) — identical to the forward's."""
+    rows = q_ref.shape[0]
+    bh0 = pl.program_id(0) * rows
     qi = pl.program_id(1)
-    h = jax.lax.rem(bh, num_heads)
+    h = jax.lax.rem(bh0, num_heads)
 
-    q = q_ref[0]                      # input dtype; scale applied to scores
-    do = do_ref[0]
+    q = q_ref[...]                    # input dtype; scale applied to scores
+    do = do_ref[...]
     in_dtype = q.dtype
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[...]                # [G, 1, BQ], along lanes as stored
+    delta = delta_ref[...]
+    columns = _stat_columns
+    if rows > 1:
+        # A group's statistics turn into columns ONCE, by a transpose ahead
+        # of the loop: G relayouts a key block would cost more than they do
+        # for one row, where they stay in the loop (PERF.md PR 30).
+        lse, delta = jnp.swapaxes(lse, 1, 2), jnp.swapaxes(delta, 1, 2)
+        columns = lambda x: x
     D = q.shape[-1]
     count = counts_ref[h, qi]
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
     def body(n, dq):
         kj = lut_ref[h, qi, n]
-        k_blk = k_ref[0, pl.ds(kj * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kj * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + bias_ref[0, 0, pl.ds(kj * block_k, block_k)].astype(jnp.float32)[None, :]
+        cols = pl.ds(kj * block_k, block_k)
+        k_blk = k_ref[:, cols, :]
+        v_blk = v_ref[:, cols, :]
+        s = _rows_dot(q, k_blk, 2, 2) * scale
+        s = s + bias_ref[:, :, cols].astype(jnp.float32)
         if causal:
             k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        p = jnp.exp(s - columns(lse))
+        dp = _rows_dot(do, v_blk, 2, 2)
         if dropout_rate > 0.0:
-            dp = dp * _dropout_keep(seed_ref, bh, qi, kj, block_q, block_k, dropout_rate)
-        ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(ds.astype(in_dtype), k_blk, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+            dp = dp * _dropout_keep(seed_ref, bh0, rows, qi, kj,
+                                    block_q, block_k, dropout_rate)
+        ds = p * (dp - columns(delta))
+        return dq + _rows_dot(ds.astype(in_dtype), k_blk, 2, 1)
 
-    dq = jax.lax.fori_loop(0, count, body, jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    dq = jax.lax.fori_loop(0, count, body, jnp.zeros((rows, block_q, D), jnp.float32))
+    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _attn_bwd_dkv_kernel(seed_ref, qcounts_ref, qlut_ref, q_ref, k_ref, v_ref, bias_ref,
                          do_ref, lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
                          *, num_heads, block_q, block_k, scale, causal, dropout_rate):
-    """dk/dv/dbias for one (bh, k-block-column), looping the transposed LUT's
-    q blocks: dv = sum (mask*p)^T dO; dk = sum ds^T (scale*q); dbias =
-    sum_rows ds. The dropout mask regenerates with the same (seed, bh, qi,
-    kj) ordering as the forward, regardless of this kernel's transposed
-    iteration order."""
-    bh = pl.program_id(0)
+    """dk/dv/dbias for one (group of rows, k-block-column), looping the
+    transposed LUT's q blocks: dv = sum (mask*p)^T dO; dk = sum ds^T
+    (scale*q); dbias = sum_rows ds. The dropout mask regenerates with the
+    same (seed, bh, qi, kj) ordering as the forward, regardless of this
+    kernel's transposed iteration order."""
+    rows = k_ref.shape[0]
+    bh0 = pl.program_id(0) * rows
     kj = pl.program_id(1)
-    h = jax.lax.rem(bh, num_heads)
+    h = jax.lax.rem(bh0, num_heads)
 
-    k_blk = k_ref[0]                  # input dtype; scale folded at write-out
-    v_blk = v_ref[0]
+    k_blk = k_ref[...]                # input dtype; scale folded at write-out
+    v_blk = v_ref[...]
     in_dtype = k_blk.dtype
-    bias_j = bias_ref[0, 0].astype(jnp.float32)
+    bias_j = bias_ref[...].astype(jnp.float32)        # [G, 1, BK]
     D = k_blk.shape[-1]
     count = qcounts_ref[h, kj]
     k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -317,42 +411,41 @@ def _attn_bwd_dkv_kernel(seed_ref, qcounts_ref, qlut_ref, q_ref, k_ref, v_ref, b
     def body(n, carry):
         dk, dv, db = carry
         qi = qlut_ref[h, kj, n]
-        q_i = q_ref[0, pl.ds(qi * block_q, block_q), :]
-        do_i = do_ref[0, pl.ds(qi * block_q, block_q), :]
-        lse_i = lse_ref[0, 0, pl.ds(qi * block_q, block_q)]
-        delta_i = delta_ref[0, 0, pl.ds(qi * block_q, block_q)]
-        s = jax.lax.dot_general(q_i, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + bias_j[None, :]
+        qrows = pl.ds(qi * block_q, block_q)
+        q_i = q_ref[:, qrows, :]
+        do_i = do_ref[:, qrows, :]
+        lse_i = _stat_columns(lse_ref[:, :, qrows])
+        delta_i = _stat_columns(delta_ref[:, :, qrows])
+        s = _rows_dot(q_i, k_blk, 2, 2) * scale
+        s = s + bias_j
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - lse_i[:, None])
-        dp = jax.lax.dot_general(do_i, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse_i)
+        dp = _rows_dot(do_i, v_blk, 2, 2)
         p_drop = p
         if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref, bh, qi, kj, block_q, block_k, dropout_rate)
+            keep = _dropout_keep(seed_ref, bh0, rows, qi, kj,
+                                 block_q, block_k, dropout_rate)
             p_drop = p * keep
             dp = dp * keep
-        dv = dv + jax.lax.dot_general(p_drop.astype(in_dtype), do_i, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_i[:, None])
-        dk = dk + jax.lax.dot_general(ds.astype(in_dtype), q_i, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        db = db + jnp.sum(ds, axis=0)
+        dv = dv + _rows_dot(p_drop.astype(in_dtype), do_i, 1, 1)
+        ds = p * (dp - delta_i)
+        dk = dk + _rows_dot(ds.astype(in_dtype), q_i, 1, 1)
+        db = db + jnp.sum(ds, axis=1, keepdims=True)
         return dk, dv, db
 
-    zero = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv, db = jax.lax.fori_loop(0, count, body, (zero, zero, jnp.zeros((block_k,), jnp.float32)))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    db_ref[0, 0] = db
+    zero = jnp.zeros((rows, block_k, D), jnp.float32)
+    dk, dv, db = jax.lax.fori_loop(
+        0, count, body, (zero, zero, jnp.zeros((rows, 1, block_k), jnp.float32)))
+    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+    db_ref[...] = db
 
 
 def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts,
                           *, block_q, block_k, causal, interpret=False,
-                          dropout_rate=0.0, seed=None):
+                          dropout_rate=0.0, seed=None, rows=1):
     """Flash backward: returns (dq, dk, dv, dbias[B,S])."""
     B, H, S, D = q.shape
     BH = B * H
@@ -360,8 +453,9 @@ def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts
     qr, kr, vr, dor, outr = rs(q), rs(k), rs(v), rs(g), rs(out)
     scale = 1.0 / float(np.sqrt(D))
     bias_r = jnp.broadcast_to(bias[:, None, :], (B, H, S)).reshape(BH, 1, S)
-    # [BH,1,S] so the (1,1,block) / (1,1,S) blockspecs below are Mosaic-legal
-    # (a 2D (1,block) block on a (BH,S) array is rejected when BH > 1).
+    # [BH,1,S] so the (G,1,block) / (G,1,S) blockspecs below are Mosaic-legal
+    # (a 2D (G,block) block on a (BH,S) array is rejected unless G is
+    # 8-aligned).
     delta = jnp.sum(dor.astype(jnp.float32) * outr.astype(jnp.float32), axis=-1)
     delta_r = delta.reshape(BH, 1, S)
     lse_r = lse.reshape(BH, 1, S)
@@ -369,19 +463,12 @@ def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts
     seed_arr = jnp.zeros((1,), jnp.int32) if seed is None else jnp.asarray(seed, jnp.int32).reshape(1)
 
     # dq: grid over q block rows
+    blk, seq, stat_blk, stat_seq = _group_specs(rows, S, D, block_q)
     dq_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(BH, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, *_: (bh, qi, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, S, D), lambda bh, qi, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda bh, qi, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, *_: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, *_: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, *_: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, *_: (bh, qi, 0)),
+        grid=(BH // rows, S // block_q),
+        in_specs=[blk, seq, seq, stat_seq, blk, stat_blk, stat_blk],
+        out_specs=blk,
     )
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, num_heads=H, block_q=block_q,
@@ -394,23 +481,12 @@ def _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts
     )(seed_arr, jnp.asarray(counts), jnp.asarray(lut), qr, kr, vr, bias_r, dor, lse_r, delta_r)
 
     # dk/dv/dbias: grid over k block columns with the TRANSPOSED LUT
+    blk, seq, stat_blk, stat_seq = _group_specs(rows, S, D, block_k)
     dkv_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(BH, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, S, D), lambda bh, kj, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kj, *_: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kj, *_: (bh, kj, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda bh, kj, *_: (bh, 0, kj)),
-            pl.BlockSpec((1, S, D), lambda bh, kj, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda bh, kj, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda bh, kj, *_: (bh, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, D), lambda bh, kj, *_: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kj, *_: (bh, kj, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda bh, kj, *_: (bh, 0, kj)),
-        ),
+        grid=(BH // rows, S // block_k),
+        in_specs=[seq, blk, blk, stat_blk, seq, stat_seq, stat_seq],
+        out_specs=(blk, blk, stat_blk),
     )
     dk, dv, db = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, num_heads=H, block_q=block_q,
@@ -505,6 +581,12 @@ def _luts_for(layout, H, S, block):
     return lut, counts, qlut, qcounts
 
 
+def _rows_for(layout, q, block):
+    """``rows_per_step`` of a [B, H, S, D] call as the kernels see it."""
+    B, H, S, D = q.shape
+    return rows_per_step(B * H, S, D, q.dtype, layout is None, block)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _attention(q, k, v, bias, seed, layout_key, block, causal, force_ref, dropout_rate):
     layout = _LAYOUTS.get(layout_key) if layout_key is not None else None
@@ -513,11 +595,10 @@ def _attention(q, k, v, bias, seed, layout_key, block, causal, force_ref, dropou
             q, k, v, bias, _expand_layout_mask(layout, q.shape[2], block),
             causal=causal, dropout_rate=dropout_rate, seed=seed,
         )
-    B, H, S, D = q.shape
-    lut, counts, _, _ = _luts_for(layout, H, S, block)
+    lut, counts, _, _ = _luts_for(layout, q.shape[1], q.shape[2], block)
     out, _ = _attention_pallas(
         q, k, v, bias, lut, counts, block_q=block, block_k=block, causal=causal,
-        dropout_rate=dropout_rate, seed=seed,
+        dropout_rate=dropout_rate, seed=seed, rows=_rows_for(layout, q, block),
     )
     return out
 
@@ -534,11 +615,10 @@ def _attention_fwd(q, k, v, bias, seed, layout_key, block, causal, force_ref, dr
             causal=causal, dropout_rate=dropout_rate, seed=seed,
         )
         return out, (q, k, v, bias, seed, None, None)
-    B, H, S, D = q.shape
-    lut, counts, _, _ = _luts_for(layout, H, S, block)
+    lut, counts, _, _ = _luts_for(layout, q.shape[1], q.shape[2], block)
     out, lse = _attention_pallas(
         q, k, v, bias, lut, counts, block_q=block, block_k=block, causal=causal,
-        dropout_rate=dropout_rate, seed=seed,
+        dropout_rate=dropout_rate, seed=seed, rows=_rows_for(layout, q, block),
     )
     return out, (q, k, v, bias, seed, out, lse)
 
@@ -555,12 +635,11 @@ def _attention_bwd(layout_key, block, causal, force_ref, dropout_rate, res, g):
     )
 
     if lse is not None:
-        B, H, S, D = q.shape
-        lut, counts, qlut, qcounts = _luts_for(layout, H, S, block)
+        lut, counts, qlut, qcounts = _luts_for(layout, q.shape[1], q.shape[2], block)
         dq, dk, dv, dbias = _attention_pallas_bwd(
             q, k, v, bias, out, lse, g, lut, counts, qlut, qcounts,
             block_q=block, block_k=block, causal=causal,
-            dropout_rate=dropout_rate, seed=seed,
+            dropout_rate=dropout_rate, seed=seed, rows=_rows_for(layout, q, block),
         )
         return dq, dk, dv, dbias, seed_ct
 

@@ -7,9 +7,12 @@ SURVEY §7 calls for before hand-writing more Pallas.
 Run on a TPU host (one process, it holds the chip):
     python tests/perf/attention_ab.py
 
-Timing contract (see bench.py ``_timed_chain``): each measurement chains N
-data-dependent iterations and ends in one scalar fetch, which waits for the
-whole chain.
+Timing contract: each measurement is ONE jitted program that chains N
+data-dependent forward+backward passes in a ``fori_loop`` and ends in one
+scalar fetch, so a pass of a millisecond is not timed by the host's dispatch
+(which costs about two on a v5e host). The ``rows`` column is the traced
+``Kernels/flash_attention/rows_per_step``: how many (batch, head) rows a
+grid step of the kernels took at that shape.
 """
 
 import os
@@ -23,27 +26,26 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.transformer.attention import flash_attention
+from deepspeed_tpu.ops.transformer.attention import (
+    flash_attention,
+    traced_rows_per_step,
+)
 from deepspeed_tpu.ops.transformer.transformer import _attention_core
 
 
-def timeit(f, args, iters=20):
-    q, k, v = args
-    float(jnp.sum(f(q, k, v).astype(jnp.float32)))  # compile + settle
+ITERS = 20
+
+
+def timeit(chain, args):
+    float(chain(*args))  # compile + settle
     t0 = time.perf_counter()
-    out = None
-    for _ in range(iters):
-        out = f(q, k, v)
-        # thread a data dependency so iteration i+1 cannot start before i
-        # finishes — independent dispatches could overlap on the device
-        # queue and the single final fetch would understate ms/iter
-        q = q + 0 * out[:1, :1, :1, :1]
-    float(jnp.sum(out.astype(jnp.float32)))  # fetch waits for the chain
-    return (time.perf_counter() - t0) / iters * 1e3
+    float(chain(*args))  # the fetch waits for the whole chain
+    return (time.perf_counter() - t0) / ITERS * 1e3
 
 
 def make_fb(attn):
-    @jax.jit
+    """``ITERS`` forward+backward passes of ``attn`` in one program, each
+    fed by the one before so that none can overlap or be folded away."""
     def fb(q, k, v):
         def loss(q, k, v):
             return jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
@@ -51,7 +53,13 @@ def make_fb(attn):
         _, g = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
         return g[0] + g[1] + g[2]
 
-    return fb
+    @jax.jit
+    def chain(q, k, v):
+        q = jax.lax.fori_loop(
+            0, ITERS, lambda _, q: q + 0 * fb(q, k, v)[:1, :1, :1, :1], q)
+        return jnp.sum(q.astype(jnp.float32))
+
+    return chain
 
 
 def xla_attn(q, k, v):
@@ -65,15 +73,16 @@ def main():
     print(f"device: {dev.device_kind} ({dev.platform})")
     rng = np.random.RandomState(0)
     drop_rng = jax.random.PRNGKey(7)
-    print(f"{'B':>4} {'H':>3} {'S':>5} {'pallas ms':>10} {'+drop ms':>9} "
-          f"{'xla ms':>8} {'ratio':>6}")
+    print(f"{'B':>4} {'H':>3} {'S':>5} {'pallas ms':>10} {'rows':>4} "
+          f"{'+drop ms':>9} {'xla ms':>8} {'ratio':>6}")
     for B, H, S in ((64, 16, 128), (16, 16, 512), (4, 16, 2048), (1, 16, 8192)):
         D = 64
         mk = lambda: jnp.asarray(rng.randn(B, H, S, D).astype(np.float32) * 0.1,
                                  jnp.bfloat16)
         q, k, v = mk(), mk(), mk()
         tp = timeit(make_fb(flash_attention), (q, k, v))
-        print(f"{B:>4} {H:>3} {S:>5} {tp:>10.2f} ", end="", flush=True)
+        print(f"{B:>4} {H:>3} {S:>5} {tp:>10.3f} {traced_rows_per_step():>4} ",
+              end="", flush=True)
         # deterministic in-kernel dropout: the reference's stochastic_mode
         # trades determinism for speed — this column shows the deterministic
         # TPU PRNG's actual cost, closing that question with data. Guarded:
@@ -81,14 +90,14 @@ def main():
         try:
             td = timeit(make_fb(lambda q, k, v: flash_attention(
                 q, k, v, dropout_rate=0.1, dropout_rng=drop_rng)), (q, k, v))
-            print(f"{td:>9.2f} ", end="", flush=True)
+            print(f"{td:>9.3f} ", end="", flush=True)
         except Exception:  # noqa: BLE001
             print(f"{'err':>9} ", end="", flush=True)
         try:
             # the naive XLA leg materializes O(S^2) buffers and can OOM HBM
             # at long S — never lose the already-measured pallas number
             tx = timeit(make_fb(xla_attn), (q, k, v))
-            print(f"{tx:>8.2f} {tx / tp:>6.2f}x")
+            print(f"{tx:>8.3f} {tx / tp:>6.2f}x")
         except Exception as e:  # noqa: BLE001
             print(f"{'oom/err':>8} ({type(e).__name__})")
 

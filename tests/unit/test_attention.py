@@ -6,6 +6,8 @@ Mirrors the reference's kernel-vs-dense-reference strategy
 sparse-layout, masked, and causal configurations.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -165,6 +167,122 @@ def test_flash_bwd_sparse_layout():
     layout = (rng.rand(2, 2, 2) < 0.6).astype(np.int64)
     layout[:, :, 0] = 1
     _bwd_check(layout=layout, seed=13)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernels_fwd_bwd(shape, causal, rows, dtype=jnp.float32):
+    """(out, lse, dq, dk, dv, dbias) of the three kernels at ``rows`` (batch,
+    head) rows a grid step, interpret mode, dense layout, with a key bias;
+    plus the dense reference's (out, dq, dk, dv, dbias)."""
+    from deepspeed_tpu.ops.transformer.attention import (
+        _attention_pallas_bwd,
+        _luts_for,
+    )
+
+    B, H, S, D = shape
+    q, k, v = (t.astype(dtype) for t in rand_qkv(B, H, S, D, seed=20))
+    rng = np.random.RandomState(21)
+    bias = jnp.asarray(np.where(rng.rand(B, S) < 0.2, -10000.0, 0.0).astype(np.float32))
+    g = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)).astype(dtype)
+    lut, counts, qlut, qcounts = _luts_for(None, H, S, 128)
+    kw = dict(block_q=128, block_k=128, causal=causal, interpret=True, rows=rows)
+    out, lse = _attention_pallas(q, k, v, bias, lut, counts, **kw)
+    grads = _attention_pallas_bwd(q, k, v, bias, out, lse, g, lut, counts,
+                                  qlut, qcounts, **kw)
+    ref_out, vjp = jax.vjp(
+        lambda q, k, v, b: _attention_reference(q, k, v, b, None, causal=causal),
+        q, k, v, bias)
+    return (out, lse) + tuple(grads), (ref_out,) + tuple(vjp(g))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("shape", [(2, 4, 128, 64), (1, 8, 384, 32)])
+@pytest.mark.parametrize("rows", [1, 2, 8])
+def test_grouped_kernels_equal_one_row_a_step(rows, shape, causal):
+    """G (batch, head) rows a grid step change the schedule, not one bit of
+    a row's result: forward output, lse, dq, dk, dv and dbias of the grouped
+    kernels equal the one-row kernels' bit for bit, and the dense reference
+    within the tolerances the one-row kernels are held to."""
+    got, ref = _kernels_fwd_bwd(shape, causal, rows)
+    if rows > 1:
+        one, _ = _kernels_fwd_bwd(shape, causal, 1)
+        for name, a, b in zip(("out", "lse", "dq", "dk", "dv", "dbias"), got, one):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    out, _, dq, dk, dv, dbias = got
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref[0]), atol=2e-5, rtol=2e-5)
+    for a, b in zip((dq, dk, dv), ref[1:4]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4, rtol=3e-4)
+    np.testing.assert_allclose(np.asarray(dbias), np.asarray(ref[4]), atol=3e-3, rtol=3e-3)
+
+
+def test_grouped_kernels_equal_one_row_a_step_in_bf16():
+    """The cells' dtype: bf16 operands, fp32 scores and accumulators."""
+    got, _ = _kernels_fwd_bwd((2, 4, 128, 64), False, 8, jnp.bfloat16)
+    one, _ = _kernels_fwd_bwd((2, 4, 128, 64), False, 1, jnp.bfloat16)
+    for a, b in zip(got, one):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+def test_rows_per_step_rule():
+    """The rule alone: a power of two that divides the rows of the call,
+    1 for a block-sparse layout, falling as the sequence grows (a row then
+    brings enough work of its own, and its blocks fill the VMEM budget)."""
+    from deepspeed_tpu.ops.transformer.attention import rows_per_step
+
+    bf16 = jnp.bfloat16
+    by_seq = [rows_per_step(1024, S, 64, bf16, True)
+              for S in (128, 512, 2048, 8192)]
+    assert by_seq[0] > 1 and by_seq[-1] == 1
+    assert by_seq == sorted(by_seq, reverse=True)
+    for bh in (1, 2, 12, 15, 20, 256, 1024, 1280):
+        for S in (128, 384, 1024):
+            for dtype in (bf16, jnp.float32):
+                rows = rows_per_step(bh, S, 64, dtype, True)
+                assert rows & (rows - 1) == 0 and bh % rows == 0, (bh, S, rows)
+                assert rows_per_step(bh, S, 64, dtype, False) == 1
+    assert rows_per_step(15, 128, 64, bf16, True) == 1          # B 3, H 5
+    assert rows_per_step(12, 128, 64, bf16, True) == 4
+    # wider rows and wider elements take more VMEM each: never more rows
+    assert rows_per_step(1024, 128, 64, jnp.float32, True) <= by_seq[0]
+    assert rows_per_step(1024, 128, 256, bf16, True) <= by_seq[0]
+
+
+@pytest.mark.parametrize("case,B,H,want", [
+    ("dense", 2, 4, 8),          # every row of the call in one grid step
+    ("odd_rows", 3, 5, 1),       # the preferred 8 does not divide 15
+    ("block_sparse", 2, 4, 1),   # a sparse LUT differs by head
+])
+def test_flash_attention_picks_rows_per_step(monkeypatch, case, B, H, want):
+    """The public entry on the TPU branch (kernels in interpret mode) takes
+    its rows a grid step from the rule, says so in the registry, and still
+    equals the dense (masked) reference, forward and backward."""
+    from deepspeed_tpu.ops.transformer import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        A, "_attention_pallas", functools.partial(A._attention_pallas, interpret=True))
+    monkeypatch.setattr(
+        A, "_attention_pallas_bwd",
+        functools.partial(A._attention_pallas_bwd, interpret=True))
+    q, k, v = rand_qkv(B=B, H=H, S=256, D=32, seed=30)
+    layout = None
+    if case == "block_sparse":
+        layout = np.ones((H, 2, 2), np.int64)
+        layout[::2, 0, 1] = 0                      # heads differ
+    mask = jnp.asarray(np.where(
+        np.random.RandomState(31).rand(B, 256) < 0.2, -10000.0, 0.0).astype(np.float32))
+
+    def loss(q, k, v, **kw):
+        return jnp.sum(A.flash_attention(q, k, v, mask=mask, layout=layout, **kw) ** 2)
+
+    val, g = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert A.traced_rows_per_step() == want
+    val_ref, g_ref = jax.value_and_grad(
+        functools.partial(loss, force_reference=True), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(val_ref), rtol=1e-5)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4, rtol=3e-4)
 
 
 def test_grad_binds_flash_backward_kernels(monkeypatch):
@@ -413,3 +531,34 @@ def test_dropout_seed_fold_is_two_words_and_injective():
     a, b = _fold_dropout_seed(np.int32(123), bh_g, qi_g, kj_g)
     pairs = np.stack([np.asarray(a), np.asarray(b)], axis=1)
     assert len(np.unique(pairs, axis=0)) == len(pairs), "seed fold collision"
+
+
+def test_grouped_dropout_masks_keep_their_rows_identity(monkeypatch):
+    """A row's dropout mask is seeded by its number in the call (its grid
+    step times G plus its place in the group), whatever G: the forward and
+    both backwards of any grouping regenerate what one row a step draws.
+    The TPU PRNG is replaced by a recorder (it only compiles on a chip)."""
+    from deepspeed_tpu.ops.transformer import attention as A
+
+    seeded = []
+    monkeypatch.setattr(A.pltpu, "prng_seed", lambda *words: seeded.append(
+        tuple(int(w) for w in words)))
+    monkeypatch.setattr(A.pltpu, "prng_random_bits", lambda shape: jnp.full(
+        shape, len(seeded) * 2**29, jnp.uint32))
+    seed, qi, kj = 1234, 1, 2
+
+    def row_seed(bh):
+        return tuple(int(w) for w in A._fold_dropout_seed(jnp.int32(seed), bh, qi, kj))
+
+    one = [A._dropout_keep([jnp.int32(seed)], bh, 1, qi, kj, 8, 128, 0.25)
+           for bh in range(8, 12)]
+    assert seeded == [row_seed(bh) for bh in range(8, 12)]
+    seeded.clear()
+    group = A._dropout_keep([jnp.int32(seed)], 8, 4, qi, kj, 8, 128, 0.25)
+    assert seeded == [row_seed(bh) for bh in range(8, 12)]
+    assert group.shape == (4, 8, 128)
+    # bits 1..4 x 2**29 against the threshold 2**30: the first row is dropped
+    assert [float(x) > 0 for x in group[:, 0, 0]] == [False, True, True, True]
+    for g in range(4):
+        np.testing.assert_array_equal(np.asarray(one[g][0]), np.asarray(group[g]))
+

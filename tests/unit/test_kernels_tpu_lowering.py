@@ -56,11 +56,7 @@ def _banded_layout(heads, nb):
     return layout
 
 
-@pytest.mark.parametrize("dropout", [0.0, 0.1])
-def test_training_flash_fwd_bwd_lowers(pallas_path, dropout):
-    """BERT-large seq128 / micro-batch 64: forward kernel plus both
-    backward kernels, with and without in-kernel dropout."""
-    qkv = SDS((64, 16, 128, 64), jnp.bfloat16)
+def _bert_attention_grad(dropout):
     rng = jax.random.PRNGKey(0)
 
     def loss(q, k, v):
@@ -68,8 +64,17 @@ def test_training_flash_fwd_bwd_lowers(pallas_path, dropout):
                               dropout_rng=rng if dropout else None)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    _assert_mosaic(_lower_tpu(jax.grad(loss, argnums=(0, 1, 2)),
-                              qkv, qkv, qkv), 3)
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1])
+def test_training_flash_fwd_bwd_lowers(pallas_path, dropout):
+    """BERT-large seq128 / micro-batch 64: forward kernel plus both
+    backward kernels, with and without in-kernel dropout, several (batch,
+    head) rows a grid step."""
+    qkv = SDS((64, 16, 128, 64), jnp.bfloat16)
+    _assert_mosaic(_lower_tpu(_bert_attention_grad(dropout), qkv, qkv, qkv), 3)
+    assert attn_mod.traced_rows_per_step() > 1
 
 
 def test_training_flash_unaligned_seq_pads_into_kernel(pallas_path):
@@ -87,7 +92,8 @@ def test_training_flash_unaligned_seq_pads_into_kernel(pallas_path):
 
 def test_training_block_sparse_lowers(pallas_path):
     """One block-sparse layout (banded causal, the scalar-prefetch LUT
-    path) at seq512, forward and backward."""
+    path) at seq512, forward and backward: one row a grid step, because a
+    sparse LUT differs by head."""
     qkv = SDS((16, 16, 512, 64), jnp.bfloat16)
     layout = _banded_layout(16, 4)
 
@@ -97,6 +103,7 @@ def test_training_block_sparse_lowers(pallas_path):
 
     _assert_mosaic(_lower_tpu(jax.grad(loss, argnums=(0, 1, 2)),
                               qkv, qkv, qkv), 3)
+    assert attn_mod.traced_rows_per_step() == 1
 
 
 def test_training_flash_lowers_under_a_mesh(pallas_path):
@@ -122,6 +129,7 @@ def test_training_flash_lowers_under_a_mesh(pallas_path):
 
     _assert_mosaic(_lower_tpu(jax.grad(meshed, argnums=(0, 1, 2)),
                               qkv, qkv, qkv), 3)
+    assert attn_mod.traced_rows_per_step() > 1      # grouped by the 256 rows a device holds
     with pytest.raises(NotImplementedError, match="automatically partitioned"):
         _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
 
@@ -188,6 +196,28 @@ def test_serving_kernels_compile_for_v5e():
                        *place(_decode_args(chunk, page_dtype))).compile()
     for dtype in (jnp.float32, jnp.bfloat16):
         _lower_tpu(_band_fn(dtype), *place(_band_args(dtype))).compile()
+
+
+@pytest.mark.slow
+def test_training_flash_kernels_compile_for_v5e(pallas_path):
+    """The three training kernels through the real compiler at the BERT
+    cells' shape (with and without dropout) and at longer sequences, at the
+    rows a grid step the rule gives each: a group that overflows VMEM is
+    refused here, before the chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    dev = SingleDeviceSharding(topo.devices[0])
+    rows = {}
+    for shape, dropout in (((64, 16, 128, 64), 0.0), ((64, 16, 128, 64), 0.1),
+                           ((16, 16, 512, 64), 0.1), ((4, 16, 2048, 64), 0.0),
+                           ((4, 16, 2048, 128), 0.1)):
+        qkv = SDS(shape, jnp.bfloat16, sharding=dev)
+        _lower_tpu(_bert_attention_grad(dropout), qkv, qkv, qkv).compile()
+        rows[shape] = attn_mod.traced_rows_per_step()
+    assert rows[(64, 16, 128, 64)] > rows[(4, 16, 2048, 64)] > 1
 
 
 @pytest.mark.slow
