@@ -1,0 +1,223 @@
+"""What the families whose lanes hold recurrent state have letter for letter
+in common (``families/kimi_linear.py``, ``families/nemotron_h.py``): a
+request holds a lane while its prompt is read, admission zeroes the lane's
+slot of a ``HybridStatePool``, lane churn patches the device's lane vectors,
+one decode step is kept in flight, and the options none of them can honour.
+What differs stays with the family: the state's description, the jitted
+programs and how a prefill call is laid out."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.generation import (
+    DEFAULT_PAGE_TOKENS,
+    resolve_page_tokens,
+)
+from deepspeed_tpu.inference.serving.family import (
+    ServingFamily,
+    UnsupportedOptionError,
+)
+from deepspeed_tpu.inference.serving.kv_pool import PoolExhaustedError
+
+
+@jax.jit  # jaxlint: hot
+def _patch_lanes_jit(tokens, positions, joined, new_tokens, new_positions):
+    """Lane churn: the lanes that ``joined`` take the host's token and
+    position; every other lane keeps what the device has, which is a step
+    ahead of the host while a decode step is in flight."""
+    return (jnp.where(joined, new_tokens, tokens),
+            jnp.where(joined, new_positions, positions))
+
+
+class Prefilling:
+    """A request that holds a lane while its prompt is read in chunks."""
+
+    __slots__ = ("req", "slot", "pos", "prefill_s", "positions_run")
+
+    def __init__(self, req, slot):
+        self.req = req
+        self.slot = slot
+        self.pos = 0
+        self.prefill_s = 0.0
+        self.positions_run = 0
+
+
+class SlotStateFamily(ServingFamily):
+    """A family over a ``HybridStatePool``: one chunked prefill program for
+    every prompt length and one decode program that returns, beside the
+    tokens, three integers of the expert layers' load, read in the same
+    transfer. A subclass names its two programs (``decode_program``,
+    ``prefill_program``: jitted, with ``cfg``, ``page_tokens`` and
+    ``keep_logits`` static), what it caches a token (``cached``, for the
+    refusals' wording), builds the pool and lays out its prefill calls.
+
+    One decode step is kept in flight: a call dispatches step N and then
+    reads back step N - 1, which finished while the host emitted N - 2, so
+    the device does not wait for the host between steps. (Read back at
+    once, a twelfth of every step is dispatch and read-back latency on a
+    shared host, and six runs of the Kimi-Linear cell spread by 0.65% of
+    their rate where 0.5% admits a cell: PERF.md, PR 27.) The device's
+    lane vectors are therefore the truth for lanes that go on; lane churn
+    patches only the lanes that joined. A lane that retires on token N - 1
+    has already been given step N: its row of N is never emitted, its
+    writes go to pages and a slot that are its own until a later program (a
+    reset, a prefill: the device runs them in order) makes them someone
+    else's.
+
+    ``keep_logits`` (tests set it before the first step) makes both programs
+    hand back the logits their token was taken from, in ``last_logits`` and
+    ``last_prefill_logits``; otherwise they are never materialised."""
+
+    decode_program = None
+    prefill_program = None
+    cached = None
+
+    def __init__(self, model_config):
+        self.cfg = model_config
+        self.keep_logits = False
+        self.last_logits = None
+        self.last_prefill_logits = None
+        self._prefilling = []       # requests that hold a lane, in order
+        self._in_flight = None      # (tokens, moe counts, request ids) of N
+        self._on_device = {}        # slot -> request id the device decodes
+
+    def refuse(self, option, why):
+        raise UnsupportedOptionError(
+            f"serving.{option}: the {self.name} family {why}")
+
+    def check_options(self, cfg, params):
+        """The refusals every such family shares; returns the page size the
+        pool will have, for the family's own checks of its chunk."""
+        no = self.refuse
+        if cfg.prefix_cache_mb > 0:
+            no(f"prefix_cache_mb={cfg.prefix_cache_mb}",
+               "has no snapshot of recurrent state to seed a prefix from")
+        if cfg.prefix_spill_mb > 0 or cfg.prefix_spill_dir is not None:
+            no("prefix_spill_mb/prefix_spill_dir",
+               "has no spill codec (the codecs frame keys and values)")
+        if cfg.speculative_k:
+            no(f"speculative_k={cfg.speculative_k}",
+               "cannot roll recurrent state back over rejected drafts")
+        if cfg.attention_impl not in (None, "dense"):
+            no(f"attention_impl={cfg.attention_impl!r}",
+               "has one attention path a program")
+        if cfg.attention_kernel is not None or cfg.kernel_interpret is not None:
+            no("attention_kernel/kernel_interpret",
+               "has no kernel-tier backend")
+        if cfg.mesh_shape is not None:
+            no(f"mesh_shape={cfg.mesh_shape}",
+               "has no tensor-parallel sharding rules")
+        if cfg.partition_rules:
+            no("partition_rules", "has no tensor-parallel sharding rules")
+        dtype = jnp.dtype(params["embed_tokens"]["embedding"].dtype)
+        stored = {"bfloat16": "bf16", "float32": "fp32"}.get(dtype.name)
+        if cfg.kv_cache_dtype != stored:
+            no(f"kv_cache_dtype={cfg.kv_cache_dtype!r}",
+               f"stores {self.cached} in the compute type only "
+               f"({stored!r} for {dtype.name} parameters)")
+        if cfg.fault_injection:
+            no("fault_injection", "has no fault-injection points")
+        return resolve_page_tokens(cfg.kv_page_tokens or DEFAULT_PAGE_TOKENS,
+                                   cfg.max_seq_len or 2 ** 20)
+
+    def refuse_handoff(self):
+        raise UnsupportedOptionError(
+            f"handoff: the {self.name} family has no handoff codec (the "
+            f"codec frames keys and values, not recurrent state)")
+
+    def sentinel_programs(self):
+        return self.decode_program, self.prefill_program
+
+    def prefilling(self):
+        return len(self._prefilling)
+
+    # -- admission -------------------------------------------------------
+    def admit(self, stats):
+        """Give each queued request a free slot and its pages, zero the
+        slot's recurrent state, and let ``advance_prefill`` read its prompt
+        a chunk a step."""
+        loop = self.loop
+        pool = loop.pool
+        while pool.free_slots > 0:
+            req = loop.scheduler.pop_next()
+            if req is None:
+                return
+            try:
+                slot = pool.allocate(loop.alloc_tokens(req))
+            except PoolExhaustedError:
+                loop.scheduler.requeue_front(req)
+                return
+            with (loop.tracer.span("serving/state_reset", cat="serving",
+                                   args={"slot": slot})
+                  if loop.tracer.enabled else telemetry.NULL_SPAN):
+                pool.reset_slot(slot)
+            loop.metrics.record_admission(loop.scheduler.buckets[-1],
+                                          len(req.prompt))
+            req.slot = slot
+            self._prefilling.append(Prefilling(req, slot))
+            stats["admitted"] += 1
+
+    def expire_prefilling(self, stats, now):
+        """Time out the requests whose deadline passed while their prompt
+        was being read."""
+        for st in [s for s in self._prefilling
+                   if s.req.deadline_exceeded(now)]:
+            self._prefilling.remove(st)
+            self.loop.finish_timeout(st.req, phase="prefill")
+            stats["retired"] += 1
+
+    # -- decode ----------------------------------------------------------
+    def upload_lanes(self):
+        """Lane churn. The active mask and the page tables are the host's
+        to say; tokens and positions are patched for the lanes that joined
+        since the last upload and left alone for the rest."""
+        pool, lanes = self.loop.pool, self.loop.lanes
+        joined = np.zeros(pool.max_slots, bool)
+        for slot, req in lanes.requests.items():
+            joined[slot] = self._on_device.get(slot) != req.id
+        self._on_device = {s: r.id for s, r in lanes.requests.items()}
+        host = jax.device_put(
+            (joined, lanes.tokens,
+             np.ascontiguousarray(pool.positions, dtype=np.int32),
+             lanes.active.copy(),
+             np.ascontiguousarray(pool.page_tables)))
+        if lanes.dev_tokens is None:
+            lanes.dev_tokens, lanes.dev_positions = host[1], host[2]
+        else:
+            lanes.dev_tokens, lanes.dev_positions = _patch_lanes_jit(
+                lanes.dev_tokens, lanes.dev_positions, *host[:3])
+        lanes.dev_active, lanes.dev_page_tables = host[3], host[4]
+        lanes.dirty = False
+
+    def decode_step(self, guard):  # jaxlint: hot
+        loop = self.loop
+        pool, lanes = loop.pool, loop.lanes
+        # whose step this is: a slot may change hands before it is read
+        riders = {slot: req.id for slot, req in lanes.requests.items()}
+        with guard:
+            (pool.state, lanes.dev_tokens, lanes.dev_positions,
+             self.last_logits, moe) = self.decode_program(
+                loop.params, pool.state, lanes.dev_tokens,
+                lanes.dev_positions, lanes.dev_active,
+                lanes.dev_page_tables, cfg=self.cfg,
+                page_tokens=pool.page_tokens, keep_logits=self.keep_logits)
+        if self.decode_sentinel is not None:
+            self.decode_sentinel.check()
+        before, self._in_flight = self._in_flight, (lanes.dev_tokens, moe,
+                                                    riders)
+        if before is None:
+            return (), (), 0, 0
+        # the step's single deliberate sync, on the step BEFORE the one just
+        # dispatched: its tokens and, in the same transfer, the three
+        # integers of its expert layers
+        host_tokens, moe = jax.device_get(before[:2])  # jaxlint: disable=JL002(one explicit host read per step)
+        loop.metrics.record_moe(self.cfg.n_moe_layers, *moe.tolist())
+        loop.metrics.record_state_pool(
+            pool.slots_in_use, pool.pages_in_use, pool.slot_bytes(),
+            pool.paged_bytes())
+        lanes.tokens = host_tokens.copy()
+        return ([slot for slot, req in lanes.requests.items()
+                 if before[2].get(slot) == req.id],
+                host_tokens[:, None].tolist(), 0, 0)

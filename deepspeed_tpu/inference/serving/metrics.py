@@ -102,13 +102,15 @@ class ServingMetrics:
         # program hands back beside its tokens: expert layers run (layers x
         # decode steps), token-expert picks that fell on held experts, held
         # experts with at least one token, the busiest held expert's tokens
-        # (the last two summed over layers and steps); chunks of chunked
-        # prefill; and last-value gauges of the hybrid state pool
+        # (the last two summed over layers and steps); calls of a chunked
+        # prefill program and the rows of them that carried a prompt; and
+        # last-value gauges of the hybrid state pool
         self.moe_layer_steps = 0
         self.moe_picks_here = 0
         self.moe_experts_touched = 0
         self.moe_expert_load_max = 0
         self.prefill_chunks = 0
+        self.prefill_chunk_rows = 0
         self.state_slots_in_use = 0
         self.latent_pages_in_use = 0
         self.state_pool_bytes = 0
@@ -146,9 +148,14 @@ class ServingMetrics:
                          tokens / prefill_s, self.prefill_calls)
         self._record("Serving/prefill_batch", requests, self.prefill_calls)
 
-    def record_prefill_chunk(self):
-        """One call of a chunked prefill program (whatever its rows)."""
+    def record_prefill_chunk(self, rows=1, empty_positions=0):
+        """One call of a chunked prefill program: ``rows`` of it carried a
+        prompt's tokens; ``empty_positions`` are the positions of the rows
+        that carried none, which the program computed all the same (a
+        prompt's own rows are counted when it ends, ``record_prefill``)."""
         self.prefill_chunks += 1
+        self.prefill_chunk_rows += rows
+        self.prefill_positions_run += empty_positions
 
     def record_moe(self, layer_steps, picks_here, experts_touched,
                    expert_load_max):
@@ -391,6 +398,7 @@ class ServingMetrics:
             "moe_experts_touched": self.moe_experts_touched,
             "moe_expert_load_max": self.moe_expert_load_max,
             "prefill_chunks": self.prefill_chunks,
+            "prefill_chunk_rows": self.prefill_chunk_rows,
             "state_slots_in_use": self.state_slots_in_use,
             "latent_pages_in_use": self.latent_pages_in_use,
             "state_pool_bytes": self.state_pool_bytes,

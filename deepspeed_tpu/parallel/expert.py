@@ -121,25 +121,51 @@ def sigmoid_topk_routing(x, router, bias, k, scaling=1.0, renormalize=True):
     return idx.astype(jnp.int32), w * scaling
 
 
-def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
-    """The part of a routed SwiGLU expert layer that the experts held here
-    give: ``sum over picks p of token t with idx[t, p] held of weights[t, p]
-    * SwiGLU_e(x_t)``. No capacity, no dropped token: the picks are grouped
-    by expert, each group padded up to a multiple of ``tile`` rows, and a
-    loop runs over the tiles in use (its trip count is data, the shapes are
-    not), reading one expert's weights a tile. An expert no token picked is
-    never read; a pick of an expert held elsewhere costs nothing here.
+def expert_function(weights):
+    """An expert's function, chosen by the matrices it is given:
+    ``apply(rows, matrix)`` is the float32 result, ``matrix(name)`` handing
+    it one of the expert's matrices. ``{gate_proj, up_proj, down_proj}`` is
+    a SwiGLU, ``down(silu(gate x) * up x)``; ``{up_proj, down_proj}`` alone
+    a squared ReLU, ``down(relu(up x)^2)``. The routed experts and a shared
+    expert go through the same function; their widths may differ."""
+    def mm(a, w):
+        return jnp.matmul(a, w, preferred_element_type=jnp.float32)
 
-    x [T, d]; experts {gate_proj, up_proj [E_h, d, f], down_proj [E_h, f,
-    d]}; idx, weights [T, k]; held (first, count) among the router's
-    experts; live [T] bool marks real tokens (None = all). Returns
-    (y [T, d] float32, counts [3] int32: picks that fell on held experts,
-    held experts with at least one token, the busiest one's tokens)."""
+    def swiglu(r, matrix):
+        a = mm(r, matrix("gate_proj"))
+        b = mm(r, matrix("up_proj"))
+        return mm((jax.nn.silu(a) * b).astype(r.dtype), matrix("down_proj"))
+
+    def relu2(r, matrix):
+        a = jax.nn.relu(mm(r, matrix("up_proj")))
+        return mm(jnp.square(a).astype(r.dtype), matrix("down_proj"))
+
+    return swiglu if "gate_proj" in weights else relu2
+
+
+def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
+    """The part of a routed expert layer that the experts held here give:
+    ``sum over picks p of token t with idx[t, p] held of weights[t, p] *
+    Expert_e(x_t)``, the expert's function being what ``expert_function``
+    reads off ``experts``. No capacity, no dropped token: the picks are
+    grouped by expert, each group padded up to a multiple of ``tile`` rows,
+    and a loop runs over the tiles in use (its trip count is data, the
+    shapes are not), reading one expert's weights a tile. An expert no token
+    picked is never read; a pick of an expert held elsewhere costs nothing
+    here.
+
+    x [T, d]; experts {gate_proj (SwiGLU only), up_proj [E_h, d, f],
+    down_proj [E_h, f, d]}; idx, weights [T, k]; held (first, count) among
+    the router's experts; live [T] bool marks real tokens (None = all).
+    Returns (y [T, d] float32, counts [3] int32: picks that fell on held
+    experts, held experts with at least one token, the busiest one's
+    tokens)."""
     T, d = x.shape
     k = idx.shape[1]
     first, n_held = held
-    wg, wu, wd = experts["gate_proj"], experts["up_proj"], experts["down_proj"]
-    assert wg.shape[0] == n_held, (wg.shape, held)
+    apply = expert_function(experts)
+    assert experts["up_proj"].shape[0] == n_held, (experts["up_proj"].shape,
+                                                   held)
     M = T * k
     e = idx.reshape(M) - first
     here = (e >= 0) & (e < n_held)
@@ -171,13 +197,8 @@ def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
     def one_tile(i, out):
         ex = tile_expert[i]
         r = jax.lax.dynamic_slice_in_dim(rows, i * tile, tile, axis=0)
-        a = jnp.matmul(r, jax.lax.dynamic_index_in_dim(wg, ex, 0, False),
-                       preferred_element_type=jnp.float32)
-        b = jnp.matmul(r, jax.lax.dynamic_index_in_dim(wu, ex, 0, False),
-                       preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(a) * b).astype(x.dtype)
-        y = jnp.matmul(h, jax.lax.dynamic_index_in_dim(wd, ex, 0, False),
-                       preferred_element_type=jnp.float32)
+        y = apply(r, lambda name: jax.lax.dynamic_index_in_dim(
+            experts[name], ex, 0, False))
         return jax.lax.dynamic_update_slice_in_dim(out, y, i * tile, axis=0)
 
     out = jax.lax.fori_loop(0, n_tiles, one_tile,
@@ -201,8 +222,10 @@ def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
     its exchange, and nothing stands in for it.
 
     params: {gate: {kernel [d, E], e_score_correction_bias [E]},
-    experts: {gate_proj, up_proj, down_proj}, shared_experts (optional):
-    {gate_proj/kernel, up_proj/kernel, down_proj/kernel}}. x [T, d].
+    experts: {gate_proj, up_proj, down_proj} or {up_proj, down_proj},
+    shared_experts (optional): the same names with ``/kernel``, of a width
+    of its own}; ``expert_function`` reads each one's function off its
+    matrices. x [T, d].
     Returns (y [T, d] in x's type, counts [3] int32 as ``held_experts_ffn``
     gives them)."""
     with jax.named_scope("moe_route"):
@@ -216,13 +239,7 @@ def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
     if "shared_experts" in params:
         with jax.named_scope("moe_shared"):
             sp = params["shared_experts"]
-            a = jnp.matmul(x, sp["gate_proj"]["kernel"],
-                           preferred_element_type=jnp.float32)
-            b = jnp.matmul(x, sp["up_proj"]["kernel"],
-                           preferred_element_type=jnp.float32)
-            y = y + jnp.matmul((jax.nn.silu(a) * b).astype(x.dtype),
-                               sp["down_proj"]["kernel"],
-                               preferred_element_type=jnp.float32)
+            y = y + expert_function(sp)(x, lambda name: sp[name]["kernel"])
     return y.astype(x.dtype), stats
 
 

@@ -260,3 +260,65 @@ def test_zero2_update_compiles_for_v5e_2x2():
         tree(jnp.bfloat16), SDS((), jnp.float32, sharding=rep)).lower(
         lowering_platforms=("tpu",)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+@pytest.mark.slow
+def test_nemotron_h_programs_compile_for_v5e_at_the_cells_size():
+    """The two programs of the Nemotron-H serving family at the benchmark
+    cell's own size (128 lanes of 3,072 positions, 16 rows of 128 a prefill
+    call, bfloat16 parameters), for one described v5e chip: they fit, and
+    neither copies the 1.07 GB SSM pool or the key and value pools (a
+    scatter or a one-column update made XLA re-lay Kimi-Linear's pool twice
+    a step, PERF.md PR 27). About 25 s; nothing runs."""
+    import json
+    import os
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmarks.models import nemotron_h_serve
+    from benchmarks.refs import nemotron_h_ref as ref
+    from benchmarks.refs import weights as weights_mod
+    from deepspeed_tpu.inference.serving.families import nemotron_h as fam
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron3_nano_30b_serve_ep2.json")) as f:
+        cfg = json.load(f)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    dev = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: SDS(shape, dtype, sharding=dev)
+    params = weights_mod.nest({k: sds(v, jnp.bfloat16)
+                               for k, v in ref.weight_shapes(cfg).items()})
+    m = nemotron_h_serve.model_config(cfg)
+    serving = cfg["serving"]
+    B, pt = serving["max_slots"], serving["kv_page_tokens"]
+    mp = serving["max_seq_len"] // pt
+    R = serving["prefill_chunk_tokens"] // m.chunk_size
+    pages = B * mp + 1
+    state = {"ssm": sds((4, B, 64, 64, 128), jnp.float32),
+             "conv": sds((4, B, 3, m.conv_dim), jnp.bfloat16),
+             "k": sds((1, pages, m.kv_width, pt), jnp.bfloat16),
+             "v": sds((1, pages, m.kv_width, pt), jnp.bfloat16)}
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    static = dict(cfg=m, page_tokens=pt, keep_logits=False)
+    programs = {
+        "decode": fam._nemotron_decode_step_jit.lower(
+            params, state, i32(B), i32(B), sds((B,), jnp.bool_), i32(B, mp),
+            **static),
+        "prefill": fam._nemotron_prefill_chunk_jit.lower(
+            params, state, i32(R, m.chunk_size), i32(R), i32(R), i32(R),
+            i32(R, mp), **static)}
+    pool_copy = re.compile(
+        rf"= (f32\[4,{B},64,64,128\]|bf16\[1,{pages},{m.kv_width},{pt}\])"
+        rf"\S* copy\(")
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes < 8.0e9, name
+        assert mem.temp_size_in_bytes < 1.0e9, name
+        assert mem.alias_size_in_bytes > 1.4e9, name     # the state, donated
+        assert not pool_copy.search(compiled.as_text()), name

@@ -221,3 +221,59 @@ def test_expert_shardings_lays_out_params():
     assert sh2["dense"]["w2"].spec == PartitionSpec()
     assert sh2["moe"]["w1"].spec == PartitionSpec(DATA_AXIS, None, None)
     assert sh2["moe"]["router"].spec == PartitionSpec()
+
+
+# -- the expert's function is data of the one grouping loop -----------------
+
+def _plain_expert(kind, x, w):
+    """Each function in its own plain form, one expert's matrices."""
+    if kind == "swiglu":
+        return (jax.nn.silu(x @ w["gate_proj"]) * (x @ w["up_proj"])
+                ) @ w["down_proj"]
+    return jnp.square(jax.nn.relu(x @ w["up_proj"])) @ w["down_proj"]
+
+
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+@pytest.mark.parametrize("shared_width", [None, 24, 40])
+def test_held_experts_loop_runs_the_function_its_matrices_name(
+        kind, shared_width):
+    """Three matrices are a SwiGLU, two a squared ReLU, through the same
+    grouping loop and for a shared expert of a width of its own; each
+    against its plain form, every token by every pick."""
+    from deepspeed_tpu.parallel.expert import (
+        sigmoid_moe_ffn, sigmoid_topk_routing)
+
+    d, f, E, k, T = 16, 24, 6, 2, 40
+    names = {"swiglu": ("gate_proj", "up_proj", "down_proj"),
+             "relu2": ("up_proj", "down_proj")}[kind]
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 16))
+
+    def matrices(width, lead=()):
+        return {n: 0.3 * jax.random.normal(
+            next(keys), lead + ((width, d) if n == "down_proj" else
+                                (d, width))) for n in names}
+
+    params = {"gate": {"kernel": jax.random.normal(next(keys), (d, E)),
+                       "e_score_correction_bias": jnp.zeros(E)},
+              "experts": matrices(f, (E,))}
+    if shared_width:
+        params["shared_experts"] = {
+            n: {"kernel": w} for n, w in matrices(shared_width).items()}
+    x = jax.random.normal(next(keys), (T, d))
+    y, stats = jax.jit(sigmoid_moe_ffn, static_argnames=(
+        "k", "scaling", "renormalize", "held", "tile"))(
+            params, x, k=k, scaling=1.5, renormalize=True, held=(0, E),
+            tile=8)
+    idx, w = sigmoid_topk_routing(x, params["gate"]["kernel"], jnp.zeros(E),
+                                  k, 1.5)
+    want = jnp.zeros((T, d))
+    for t in range(T):
+        for j in range(k):
+            one = {n: params["experts"][n][int(idx[t, j])] for n in names}
+            want = want.at[t].add(w[t, j] * _plain_expert(kind, x[t], one))
+    if shared_width:
+        want = want + _plain_expert(
+            kind, x, {n: params["shared_experts"][n]["kernel"]
+                      for n in names})
+    np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-4)
+    assert int(stats[0]) == T * k
