@@ -2560,7 +2560,8 @@ def _attn_impl_label():
 
 def _attn_rows_per_step():
     """(batch, head) rows a grid step of the flash kernels that step was
-    traced with took; 0 where the jnp reference ran."""
+    traced with took; 0 where no kernel was traced (the materialised path
+    or the jnp reference ran)."""
     from deepspeed_tpu.ops.transformer.attention import traced_rows_per_step
 
     return traced_rows_per_step()

@@ -6,7 +6,8 @@ shows (one chip, or the four-chip host):
 
 - **kernels**: every Pallas kernel the repo ships, compiled natively and
   compared on device with its XLA or jnp twin at the shapes the two models
-  below use;
+  below use (the training attention's materialised path, which BERT-large
+  takes at seq 128 without dropout, beside the flash kernels);
 - **train**: ``deepspeed_tpu.initialize`` on BERT-large (seq 128, micro-batch
   64 per chip, bf16, dropout, activation checkpointing, ZeRO-2 when there is
   more than one device), a few warm-up steps and then measured
@@ -87,29 +88,33 @@ def _banded_causal_layout(heads, n_blocks):
     return layout
 
 
-def _flash_legs(report, shapes, block, dropout_rate, native, seed):
-    """Training flash attention through its public entry point: forward and
-    both backward kernels against the dense jnp reference, with and without
-    in-kernel dropout, plus one block-sparse layout at the longest shape."""
+def _flash_legs(report, shapes, dense_shape, block, dropout_rate, native, seed):
+    """Training attention through its public entry point. ``shapes`` are
+    calls the entry's rule leaves to the flash kernels (their float32 scores
+    are over its budget): forward and both backward kernels against the
+    dense jnp reference, with and without in-kernel dropout, plus one
+    block-sparse layout at the longest shape. ``dense_shape`` is a call the
+    rule sends to the materialised path, checked the same way."""
     import jax
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.transformer import attention as attn
 
     rng = np.random.RandomState(seed)
-    traced_before = attn.trace_counts()
 
     def sq_loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
 
-    for B, H, S, D in shapes:
-        tag = f"flash_s{S}"
-        q, k, v = (jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
+    def against_reference(tag, shape, wanted):
+        """Output and gradients of the entry at ``shape`` against the
+        reference; raises (``native``) unless the rule chose ``wanted``."""
+        q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                    for _ in range(3))
         with jax.default_matmul_precision("highest"):
             want = attn.attention_reference(q, k, v)
             want_g = jax.grad(sq_loss(attn.attention_reference),
                               argnums=(0, 1, 2))(q, k, v)
+        traced_before = attn.trace_counts()
         got = attn.flash_attention(q, k, v)
         got_g = jax.grad(sq_loss(attn.flash_attention),
                          argnums=(0, 1, 2))(q, k, v)
@@ -117,6 +122,18 @@ def _flash_legs(report, shapes, block, dropout_rate, native, seed):
         _check(f"{tag}_fwd", _rel_err(got, want), 3e-2, report)
         _check(f"{tag}_bwd", max(_rel_err(a, b)
                                  for a, b in zip(got_g, want_g)), 5e-2, report)
+        traced = attn.traced_implementation(since=traced_before)
+        if native and traced != wanted:
+            raise AssertionError(
+                f"{tag}: flash_attention at {shape} traced {traced!r}, "
+                f"wanted {wanted!r}")
+        return q, k, v, got
+
+    against_reference(f"attention_dense_s{dense_shape[2]}", dense_shape, "dense")
+
+    for B, H, S, D in shapes:
+        tag = f"flash_s{S}"
+        q, k, v, got = against_reference(tag, (B, H, S, D), "pallas")
         report[f"{tag}_rows_per_step"] = attn.traced_rows_per_step()
 
         # dropout: same key, same mask; a different output than without it;
@@ -182,10 +199,6 @@ def _flash_legs(report, shapes, block, dropout_rate, native, seed):
         raise AssertionError(
             "a block-sparse layout was given several rows a grid step: "
             "its LUT differs by head")
-
-    if native and attn.trace_counts()[0] == traced_before[0]:
-        raise AssertionError(
-            "flash_attention never lowered to its Pallas kernels")
 
 
 def _serving_kernel_legs(report, heads, head_dim, page_tokens, native, seed):
@@ -262,14 +275,17 @@ def _serving_kernel_legs(report, heads, head_dim, page_tokens, native, seed):
                 f"wanted ('pallas', {interp})")
 
 
-def kernel_phase(*, flash_shapes, heads, head_dim, page_tokens, native,
-                 flash_block=128, dropout_rate=0.1, seed=0):
+def kernel_phase(*, flash_shapes, dense_shape, heads, head_dim, page_tokens,
+                 native, flash_block=128, dropout_rate=0.1, seed=0):
     """Compile and check every Pallas kernel. ``flash_shapes`` are
-    (B, H, S, D) training-attention shapes, shortest first (S a multiple
-    of ``flash_block``, the block-sparse layout's tile); the serving
-    kernels run at ``heads`` x ``head_dim`` over ``page_tokens`` pages."""
+    (B, H, S, D) training-attention shapes the entry's rule leaves to the
+    kernels, shortest first (S a multiple of ``flash_block``, the
+    block-sparse layout's tile), ``dense_shape`` one it sends to the
+    materialised path; the serving kernels run at ``heads`` x ``head_dim``
+    over ``page_tokens`` pages."""
     report = {}
-    _flash_legs(report, flash_shapes, flash_block, dropout_rate, native, seed)
+    _flash_legs(report, flash_shapes, dense_shape, flash_block, dropout_rate,
+                native, seed)
     _serving_kernel_legs(report, heads, head_dim, page_tokens, native, seed)
     return report
 
@@ -300,9 +316,11 @@ def train_phase(cfg, *, seq_len, micro_batch, warmup, steps, native, seed=0):
     """BERT pretraining through ``deepspeed_tpu.initialize`` and
     ``engine.train_step`` on every device. Raises unless every loss is
     finite, the last is below the first, nothing compiled after warm-up and
-    (``native``) the attention that was traced is the Pallas kernel; on
-    several devices also unless ZeRO's state is sharded over all of them and
-    memory is spread evenly."""
+    (``native``) the attention that was traced is the one the entry's rule
+    names for this call (the kernels with attention dropout, the
+    materialised path without at BERT-large's seq 128); on several devices
+    also unless ZeRO's state is sharded over all of them and memory is
+    spread evenly."""
     import jax
     import jax.numpy as jnp
 
@@ -359,14 +377,20 @@ def train_phase(cfg, *, seq_len, micro_batch, warmup, steps, native, seed=0):
     if not losses[-1] < losses[0]:
         raise AssertionError(f"loss did not fall: {losses}")
     attention = attn.traced_implementation(since=traced_before)
-    if native and attention != "pallas":
+    wanted = "dense" if attn.materialises_scores(
+        micro_batch, cfg.num_attention_heads, seq_len, seq_len, True,
+        cfg.attention_probs_dropout_prob) else "pallas"
+    if native and attention != wanted:
         raise AssertionError(
-            f"train step traced attention {attention!r}, wanted 'pallas'")
+            f"train step traced attention {attention!r}, wanted {wanted!r}")
 
     report = {
         "params": n_params, "global_batch": global_batch, "seq_len": seq_len,
         "devices": n_dev, "attention": attention,
-        "attention_rows_per_step": attn.traced_rows_per_step(),
+        # the gauge keeps the last traced kernels' rows: theirs only if
+        # this phase traced kernels
+        "attention_rows_per_step": (attn.traced_rows_per_step()
+                                    if attention == "pallas" else 0),
         "losses": [round(x, 4) for x in losses],
         "init_and_first_step_s": round(compile_s, 1),
         "step_ms": [round(t * 1e3, 1) for t in times],
@@ -614,9 +638,13 @@ def main():
     t0 = time.perf_counter()
     bert = BertConfig.bert_large(checkpoint_policy="dots")
     gpt2 = GPT2Config.gpt2_large()
+    # BERT-large's own attention call (seq 128, micro-batch 64) holds 64 MiB
+    # of float32 scores and takes the materialised path when dropout is off;
+    # the kernels are checked at twice that batch and at seq 512
     report = kernel_phase(
-        flash_shapes=[(64, bert.num_attention_heads, 128, 64),
+        flash_shapes=[(128, bert.num_attention_heads, 128, 64),
                       (16, bert.num_attention_heads, 512, 64)],
+        dense_shape=(64, bert.num_attention_heads, 128, 64),
         heads=gpt2.num_attention_heads,
         head_dim=gpt2.hidden_size // gpt2.num_attention_heads,
         page_tokens=128, native=True)

@@ -23,16 +23,35 @@ load-balanced-LUT design of the reference's Triton kernels re-tiled for the
 MXU (128-lane blocks instead of 16/32). Memory stays O(S*D + nnz_blocks) —
 scores never materialize.
 
-The backward pass on the TPU path runs dedicated flash backward Pallas
+The backward pass of the kernels runs dedicated flash backward Pallas
 kernels (``_attn_bwd_dq_kernel`` / ``_attn_bwd_dkv_kernel``): dq streams the
 row LUT, dk/dv/dbias stream the transposed (column) LUT, recomputing p from
 the saved log-sum-exp residual so memory stays O(S*D). On non-TPU backends
 the dense jnp reference path runs fwd and bwd (same numerics, dense-masked).
-On a TPU nothing switches implementation behind the caller's back: a dense
-sequence that is not a block multiple is padded up to one (pad keys masked,
-pad queries sliced off) and still runs the kernels; only an explicit
-``force_reference=True`` selects the jnp path there. Which path was traced is
-counted in the shared metrics registry (``Kernels/flash_attention/*_traces``).
+
+On a TPU the public entry chooses between TWO algorithms, by a rule on what
+it can see in the call and nothing else (``materialises_scores``: the layout,
+the call's rows and lengths on one device, the dropout rate; no option, no
+environment variable):
+
+- the streaming kernels above, for every block-sparse layout, for every call
+  with dropout (the kernels draw their masks from the chip's generator) and
+  for every dense call whose scores are too large to hold, and
+- the **materialised path** (``_attention_dense``) where the float32 scores
+  of the device's call fit ``_SCORE_BUDGET`` (64 MiB: BERT-large at seq 128
+  and micro-batch 64): QK^T, scale, bias, mask, softmax and PV as plain
+  ``jnp`` operations (the kernels' mathematics at the kernels' precision),
+  a chain XLA keeps in on-chip memory, autodiff differentiates and the
+  remat policy sees, 2.5-3 times faster than three kernel launches
+  (PERF.md section 6, PR 32). It needs no ``shard_map``: GSPMD partitions
+  einsums over a sharded batch.
+
+A dense sequence that is not a block multiple and goes to the kernels is
+padded up to one (pad keys masked, pad queries sliced off); only an explicit
+``force_reference=True`` selects the float32 jnp reference on a TPU. Which
+implementation was traced is counted in the shared metrics registry
+(``Kernels/flash_attention/{pallas,dense,reference}_traces``;
+``traced_implementation()`` names it).
 
 Several devices: GSPMD cannot partition a Mosaic kernel, so under a mesh the
 kernels ``shard_map`` themselves over the mesh in context at trace time (the
@@ -58,6 +77,7 @@ DEFAULT_BLOCK = 128
 # Trace-time tallies of the implementation each flash_attention call lowered
 # to: what bench.py and chip_smoke.py read to report the attention that RAN.
 PALLAS_TRACES = "Kernels/flash_attention/pallas_traces"
+DENSE_TRACES = "Kernels/flash_attention/dense_traces"
 REFERENCE_TRACES = "Kernels/flash_attention/reference_traces"
 # Trace-time gauge: the (batch, head) rows a grid step of the last traced
 # kernels handles (``rows_per_step``).
@@ -70,23 +90,23 @@ def _count_trace(tag):
 
 
 def trace_counts():
-    """(Pallas, reference) flash_attention traces in this process so far."""
+    """(Pallas kernels, materialised path, float32 reference) traces of
+    flash_attention in this process so far."""
     counters = telemetry.get_registry()
-    return (counters.counter(PALLAS_TRACES).value,
-            counters.counter(REFERENCE_TRACES).value)
+    return tuple(counters.counter(tag).value
+                 for tag in (PALLAS_TRACES, DENSE_TRACES, REFERENCE_TRACES))
 
 
-def traced_implementation(since=(0, 0)):
+def traced_implementation(since=(0, 0, 0)):
     """Which implementation flash_attention lowered to since the
     ``trace_counts()`` snapshot ``since``: "pallas" (the TPU kernels),
-    "reference" (the dense jnp path), "mixed" when both were, "none" when it
-    was never traced — so a report never attributes one implementation's
+    "dense" (the materialised path the rule chose), "reference" (the
+    float32 jnp path), "mixed" when more than one was, "none" when it was
+    never traced — so a report never attributes one implementation's
     numbers to another."""
-    pallas, reference = (now - then
-                         for now, then in zip(trace_counts(), since))
-    if pallas and reference:
-        return "mixed"
-    return "pallas" if pallas else ("reference" if reference else "none")
+    traced = [name for name, now, then in zip(
+        ("pallas", "dense", "reference"), trace_counts(), since) if now > then]
+    return "mixed" if len(traced) > 1 else (traced[0] if traced else "none")
 
 
 def traced_rows_per_step():
@@ -280,6 +300,32 @@ def rows_per_step(bh, S, D, dtype, dense, block=DEFAULT_BLOCK):
     while bh % (2 * rows) == 0 and 2 * rows * per_row <= _VMEM_BUDGET:
         rows *= 2
     return rows
+
+
+# Float32 scores a call may hold on one device and still take the materialised
+# path: the most for which the chip's compiler keeps the whole chain (scores,
+# probabilities and their gradients) in on-chip memory. Compiled for a v5e,
+# forward+backward, the chain's HBM temporaries are 2 MB at 64 MiB of scores
+# ([64,16,128,128], [16,16,256,256] and [4,16,512,512] alike), 40 MB at 80 MiB
+# and 1.4 times the scores from 96 MiB up; timed on the chip it is 2.5-3.1
+# times faster than the kernels at the three 64 MiB shapes and 1.5 times
+# SLOWER at 256 MiB and 1 GiB, where the scores travel to HBM and back
+# (``tests/perf/attention_ab.py``; PERF.md section 6, PR 32).
+_SCORE_BUDGET = 64 * 2**20
+
+
+def materialises_scores(B, H, S_q, S_k, dense, dropout_rate=0.0):
+    """Whether a call of ``B`` x ``H`` (batch, head) rows ON ONE DEVICE,
+    ``S_q`` queries against ``S_k`` keys, runs the materialised path
+    (``_attention_dense``) in place of the streaming kernels: a dense
+    layout (a LUT is the kernels' whole point), no dropout (a mask drawn
+    with ``jax.random`` costs four times the attention itself: 2.50 ms
+    against the kernels' 1.27 at BERT's shape) and float32 scores within
+    ``_SCORE_BUDGET``. The causal flag is not asked: the materialised path
+    won by the same factor with it and without."""
+    if not dense or dropout_rate > 0.0:
+        return False
+    return B * H * S_q * S_k * 4 <= _SCORE_BUDGET
 
 
 def _group_specs(rows, S, D, block):
@@ -559,6 +605,31 @@ def attention_reference(q, k, v, mask=None, causal=False):
     return jnp.einsum("bhst,bhtd->bhsd", probs, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _attention_dense(q, k, v, bias, *, causal):
+    """The materialised path: the kernels' mathematics at the kernels'
+    precision as plain operations, for calls ``materialises_scores`` takes.
+    Matmul operands stay in the input dtype and accumulate in float32;
+    scale, key bias and causal mask apply to the float32 scores; the softmax
+    statistics are float32; the probabilities are rounded to the input
+    dtype only as the PV operand; a row with no admissible key gives 0.
+    No custom VJP: autodiff differentiates it (``dbias`` included) and a
+    remat policy sees its einsums, which carry batch dimensions."""
+    _count_trace(DENSE_TRACES)
+    S = q.shape[2]
+    scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    s = jnp.einsum("bhsd,bhtd->bhst", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = s + bias[:, None, None, :].astype(jnp.float32)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+    m = jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m)
+    probs = jnp.where(m > -1e29, p / jnp.sum(p, axis=-1, keepdims=True), 0.0)
+    out = jnp.einsum("bhst,bhtd->bhsd", probs.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
 def _expand_layout_mask(layout, S, block):
     if layout is None:
         return None
@@ -668,6 +739,28 @@ def _register_layout(layout):
     return key
 
 
+def _context_axes(shard_heads):
+    """(mesh in context at trace time, its axes that are still automatic,
+    the axis a call's batch is split over, the axis its heads are): batch
+    over ``data``, heads over ``model`` unless ``shard_heads`` is off, each
+    only while automatic. Axes that are already manual (the caller sits in
+    its own shard_map) are the caller's, and its shapes are per device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = [name for name, kind in zip(mesh.axis_names, mesh.axis_types)
+            if kind == AxisType.Auto]
+    b_ax = DATA_AXIS if DATA_AXIS in auto else None
+    h_ax = MODEL_AXIS if MODEL_AXIS in auto and shard_heads else None
+    return mesh, auto, b_ax, h_ax
+
+
+def _rows_on_one_device(B, H, shard_heads):
+    """(batch, heads) of a ``[B, H, ...]`` call that one device of the mesh
+    in context holds."""
+    mesh, _, b_ax, h_ax = _context_axes(shard_heads)
+    return (B // (mesh.shape[b_ax] if b_ax else 1),
+            H // (mesh.shape[h_ax] if h_ax else 1))
+
+
 def _shard_over_context_mesh(attend, q_shape, shard_heads, has_seed):
     """Make ``attend(q, k, v, bias[, seed])`` run per device under a mesh.
 
@@ -682,17 +775,12 @@ def _shard_over_context_mesh(attend, q_shape, shard_heads, has_seed):
     are the caller's. With one device under automatic axes ``attend`` is
     returned as it is. ``shard_heads=False`` keeps the heads together (a
     block-sparse LUT is indexed by GLOBAL head)."""
-    mesh = jax.sharding.get_abstract_mesh()
-    auto = [name for name, kind in zip(mesh.axis_names, mesh.axis_types)
-            if kind == AxisType.Auto]
+    mesh, auto, b_ax, h_ax = _context_axes(shard_heads)
     if all(mesh.shape[name] == 1 for name in auto):
         return attend
-    b_ax = DATA_AXIS if DATA_AXIS in auto else None
-    h_ax = MODEL_AXIS if MODEL_AXIS in auto and shard_heads else None
     axes = tuple(ax for ax in (b_ax, h_ax) if ax is not None)
-    local_bh = q_shape[0] * q_shape[1]
-    for ax in axes:
-        local_bh //= mesh.shape[ax]
+    local_b, local_h = _rows_on_one_device(q_shape[0], q_shape[1], shard_heads)
+    local_bh = local_b * local_h
 
     def local(q, k, v, bias, *seed):
         if has_seed:
@@ -760,6 +848,10 @@ def flash_attention(q, k, v, mask=None, layout=None, block=DEFAULT_BLOCK,
     if force_reference or not _on_tpu():
         return _attention(q, k, v, bias, seed, key, block, causal, True,
                           float(dropout_rate))
+
+    if materialises_scores(*_rows_on_one_device(B, H, layout is None), S, S,
+                           layout is None, dropout_rate):
+        return _attention_dense(q, k, v, bias, causal=causal)
 
     def attend(q, k, v, bias, seed=None):
         return _attention(q, k, v, bias, seed, key, block, causal, False,

@@ -93,9 +93,11 @@ def _attention_core(q, k, v, mask, dropout_ratio, deterministic, dropout_rng,
     """Scaled masked attention softmax + PV.
 
     The reference implements this as fused CUDA softmax/dropout kernels
-    (csrc/transformer/softmax_kernels.cu, seq<=8K). On TPU this dispatches to a
-    Pallas flash-attention kernel when available; otherwise an XLA-fused jnp
-    path (still one fused softmax on TPU).
+    (csrc/transformer/softmax_kernels.cu, seq<=8K). On TPU this goes to
+    ``flash_attention``, which runs the Pallas flash kernels or, for a call
+    whose float32 scores fit on the chip, the same mathematics as plain
+    operations XLA fuses (``attention.materialises_scores``); masks it
+    cannot express fall through to the jnp chain below.
 
     Shapes: q,k,v = [B, H, S, D]; mask = [B, 1, 1, S] additive key bias;
     ``causal`` applies autoregressive masking (in-kernel on the fused path).

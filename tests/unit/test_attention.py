@@ -260,6 +260,8 @@ def test_flash_attention_picks_rows_per_step(monkeypatch, case, B, H, want):
     from deepspeed_tpu.ops.transformer import attention as A
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    # the kernels are under test: no call is small enough to hold its scores
+    monkeypatch.setattr(A, "_SCORE_BUDGET", 0)
     monkeypatch.setattr(
         A, "_attention_pallas", functools.partial(A._attention_pallas, interpret=True))
     monkeypatch.setattr(
@@ -295,6 +297,7 @@ def test_grad_binds_flash_backward_kernels(monkeypatch):
     from deepspeed_tpu.ops.transformer import attention as A
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(A, "_SCORE_BUDGET", 0)     # the kernels are under test
     monkeypatch.setattr(
         A, "_attention_pallas", functools.partial(A._attention_pallas, interpret=True)
     )
@@ -562,3 +565,256 @@ def test_grouped_dropout_masks_keep_their_rows_identity(monkeypatch):
     for g in range(4):
         np.testing.assert_array_equal(np.asarray(one[g][0]), np.asarray(group[g]))
 
+
+
+# ---------------------------------------------------------------------------
+# the materialised path, and the rule that chooses it
+# ---------------------------------------------------------------------------
+
+def _dense_case(case, dtype):
+    """(q, k, v, bias, causal) of one call of the materialised path."""
+    S = {"short_of_a_block": 100}.get(case, 128)
+    B = 3
+    q, k, v = (t.astype(dtype) for t in rand_qkv(B=B, H=2, S=S, D=32, seed=40))
+    rng = np.random.RandomState(41)
+    bias = np.zeros((B, S), np.float32)
+    if case in ("key_bias", "short_of_a_block", "causal"):
+        bias = np.where(rng.rand(B, S) < 0.2, -10000.0, 0.0).astype(np.float32)
+    if case == "masked_row":
+        bias[1] = -1e30           # every key of the second sequence is out
+        bias[2, ::3] = -1e30
+    return q, k, v, jnp.asarray(bias), case == "causal"
+
+
+@pytest.mark.parametrize("dtype,fwd_tol,bwd_tol", [
+    (jnp.float32, 2e-5, 2e-4),
+    (jnp.bfloat16, 2e-2, 5e-2),      # the kernels' own, against the same oracle
+])
+@pytest.mark.parametrize("case", ["plain", "key_bias", "masked_row",
+                                  "short_of_a_block", "causal"])
+def test_materialised_path_matches_reference(case, dtype, fwd_tol, bwd_tol):
+    """``_attention_dense`` against the float32 oracle: output, dq, dk, dv
+    and dbias, with a key bias, with a sequence whose every key is masked
+    (its rows give 0 and take no gradient), at a length that is no block
+    multiple (nothing is padded) and under the causal mask."""
+    from deepspeed_tpu.ops.transformer.attention import _attention_dense
+
+    q, k, v, bias, causal = _dense_case(case, dtype)
+
+    def loss(fn, q, k, v, bias):
+        out = fn(q, k, v, bias)
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    dense = lambda *a: _attention_dense(*a, causal=causal)
+    oracle = lambda *a: _attention_reference(*a, None, causal=causal)
+    (_, out), got = jax.value_and_grad(
+        functools.partial(loss, dense), argnums=(0, 1, 2, 3), has_aux=True)(
+            q, k, v, bias)
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    (_, want_out), want = jax.value_and_grad(
+        functools.partial(loss, oracle), argnums=(0, 1, 2, 3), has_aux=True)(
+            *f32, bias)
+    assert out.dtype == dtype and all(g.dtype == dtype for g in got[:3])
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want_out), atol=fwd_tol, rtol=fwd_tol)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), atol=bwd_tol,
+            rtol=bwd_tol, err_msg=name)
+    if case == "masked_row":
+        assert not np.asarray(out[1], np.float32).any()
+        assert not any(np.asarray(g[1], np.float32).any() for g in got[:3])
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_materialised_path_holds_the_kernels_precision(causal):
+    """In bf16 the materialised path keeps float32 wherever the kernels do
+    (scores, statistics, the softmax's backward) and rounds the matmuls'
+    operands and results as they do: output and all four gradients lie
+    within 1% of the kernels' (interpret mode) by norm, at scores so large
+    that a softmax over scores rounded to bf16 is several times further."""
+    from deepspeed_tpu.ops.transformer.attention import (
+        _attention_dense, _attention_pallas_bwd, _luts_for)
+    from deepspeed_tpu.ops.transformer.transformer import _attention_core
+
+    B, H, S = 2, 2, 128
+    q, k, v = (jnp.asarray(t * 10.0, jnp.bfloat16)
+               for t in rand_qkv(B=B, H=H, S=S, D=64, seed=50))
+    bias = jnp.asarray(np.where(
+        np.random.RandomState(51).rand(B, S) < 0.2, -10000.0, 0.0), jnp.float32)
+    lut, counts, qlut, qcounts = _luts_for(None, H, S, 128)
+    out_k, lse = _attention_pallas(q, k, v, bias, lut, counts, block_q=128,
+                                   block_k=128, causal=causal, interpret=True)
+    g = (out_k * 2).astype(jnp.bfloat16)
+    want = (out_k,) + _attention_pallas_bwd(
+        q, k, v, bias, out_k, lse, g, lut, counts, qlut, qcounts,
+        block_q=128, block_k=128, causal=causal, interpret=True)
+
+    def gaps(attend):
+        out, vjp = jax.vjp(attend, q, k, v, bias)
+        f32 = lambda t: np.asarray(t, np.float32)
+        return [float(np.linalg.norm(f32(a) - f32(b)) / np.linalg.norm(f32(b)))
+                for a, b in zip((out,) + vjp(g), want)]
+
+    dense = gaps(lambda *a: _attention_dense(*a, causal=causal))
+    assert max(dense) < 0.01, dense
+    # the instrument: scores rounded to the input dtype ahead of the softmax
+    # (``_attention_core``'s tail without the kernel) are not that close
+    rounded = gaps(lambda q, k, v, bias: _attention_core(
+        q, k, v, bias[:, None, None, :].astype(q.dtype), 0.0, True, None,
+        use_pallas=False, causal=causal))
+    assert rounded[1] > 0.02 and rounded[2] > 0.02, rounded
+
+
+_MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("call,want", [
+    # (B, H, S_q, S_k) on one device, then what the call says of itself
+    (dict(B=64, H=16, S_q=128, S_k=128), True),         # both BERT cells: 64 MiB
+    (dict(B=65, H=16, S_q=128, S_k=128), False),        # 65 MiB: over the edge
+    (dict(B=1, H=1, S_q=4096, S_k=4096), True),         # the edge again, one row
+    (dict(B=1, H=1, S_q=4096, S_k=4097), False),
+    (dict(B=16, H=16, S_q=256, S_k=256), True),         # 64 MiB at two key blocks
+    (dict(B=4, H=16, S_q=512, S_k=512), True),          # and at four
+    (dict(B=16, H=16, S_q=512, S_k=512), False),        # 256 MiB
+    (dict(B=1, H=16, S_q=8192, S_k=8192), False),       # 4 GiB: the kernels' case
+    (dict(B=2, H=20, S_q=1024, S_k=1024), False),       # GPT-2 large, 160 MiB
+    (dict(B=2, H=4, S_q=100, S_k=100), True),           # no block multiple
+    (dict(B=64, H=16, S_q=128, S_k=128, dense=False), False),   # any LUT
+    (dict(B=1, H=1, S_q=128, S_k=128, dense=False), False),
+    (dict(B=64, H=16, S_q=128, S_k=128, dropout_rate=0.1), False),
+    (dict(B=1, H=1, S_q=128, S_k=128, dropout_rate=0.01), False),
+])
+def test_materialises_scores_rule(call, want):
+    """The rule alone, a pure function of what a call shows: a dense call
+    without dropout whose float32 scores on one device fit ``_SCORE_BUDGET``
+    (64 MiB, the edge on both sides) takes the materialised path; a
+    block-sparse layout and a call with dropout never do."""
+    from deepspeed_tpu.ops.transformer import attention as A
+
+    assert A._SCORE_BUDGET == 64 * _MIB
+    assert A.materialises_scores(**dict(dict(dense=True), **call)) is want
+
+
+@pytest.fixture()
+def tpu_branch(monkeypatch):
+    """The public entry as a TPU sees it (kernels in interpret mode), over a
+    metrics registry of its own."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.ops.transformer import attention as A
+    from deepspeed_tpu.telemetry.registry import MetricsRegistry
+
+    monkeypatch.setattr(telemetry, "_registry", MetricsRegistry())
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        A, "_attention_pallas", functools.partial(A._attention_pallas, interpret=True))
+    monkeypatch.setattr(
+        A, "_attention_pallas_bwd",
+        functools.partial(A._attention_pallas_bwd, interpret=True))
+    return A
+
+
+def test_the_three_tallies_name_what_was_traced(tpu_branch, monkeypatch):
+    """Each trace of the public entry counts under the implementation it
+    lowered to, ``traced_implementation`` names it since a snapshot, and the
+    kernels' rows-a-step gauge stays 0 until a kernel is traced."""
+    A = tpu_branch
+    q, k, v = rand_qkv(B=2, H=2, S=128, D=32, seed=60)
+    mask = jnp.zeros((2, 128), jnp.float32)
+    assert A.traced_implementation() == "none"
+    start = A.trace_counts()
+    assert start == (0, 0, 0)
+
+    out = A.flash_attention(q, k, v, mask=mask)
+    assert A.trace_counts() == (0, 1, 0)
+    assert A.traced_implementation() == A.traced_implementation(since=start) == "dense"
+    assert A.traced_rows_per_step() == 0
+
+    after_dense = A.trace_counts()
+    ref = A.flash_attention(q, k, v, mask=mask, force_reference=True)
+    assert A.trace_counts() == (0, 1, 1)
+    assert A.traced_implementation(since=after_dense) == "reference"
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+    after_reference = A.trace_counts()
+    monkeypatch.setattr(A, "_SCORE_BUDGET", 2 * 2 * 128 * 128 * 4 - 1)
+    kern = A.flash_attention(q, k, v, mask=mask)      # one byte over: kernels
+    assert A.trace_counts() == (1, 1, 1)
+    assert A.traced_implementation(since=after_reference) == "pallas"
+    assert A.traced_implementation(since=start) == "mixed"
+    assert A.traced_rows_per_step() == 4
+    np.testing.assert_allclose(np.asarray(out), np.asarray(kern), atol=2e-5)
+
+
+def _sparsity_layouts():
+    from deepspeed_tpu.ops import sparse_attention as sa
+
+    return {cls.__name__: cls for cls in (
+        sa.DenseSparsityConfig, sa.FixedSparsityConfig,
+        sa.VariableSparsityConfig, sa.BigBirdSparsityConfig,
+        sa.BSLongformerSparsityConfig)}
+
+
+@pytest.mark.parametrize("config", sorted(_sparsity_layouts()))
+def test_a_sparsity_layout_never_takes_the_materialised_path(tpu_branch, config):
+    """Every sparsity configuration's layout, the all-ones one included,
+    runs the LUT kernels at a size whose scores would fit many times over."""
+    A = tpu_branch
+    layout = _sparsity_layouts()[config](num_heads=2, block=16).make_layout(128)
+    q, k, v = rand_qkv(B=1, H=2, S=128, D=32, seed=61)
+    before = A.trace_counts()
+    out = A.flash_attention(q, k, v, layout=layout, block=16)
+    assert A.traced_implementation(since=before) == "pallas"
+    assert A.traced_rows_per_step() == 1
+    ref = A.flash_attention(q, k, v, layout=layout, block=16, force_reference=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("case,kw,want", [
+    ("key_bias", {}, "dense"),
+    ("causal", {"causal": True}, "dense"),          # won with and without
+    ("dropout", {"dropout_rate": 0.1, "dropout_rng": jax.random.PRNGKey(3)},
+     "pallas"),                                     # lost: the kernels' masks
+])
+def test_the_public_entry_follows_the_rule(tpu_branch, case, kw, want):
+    """What the rule decided on the chip, seen from the entry: a causal call
+    takes the materialised path like a plain one, a call with dropout the
+    kernels; the materialised results are the float32 oracle's."""
+    A = tpu_branch
+    q, k, v = rand_qkv(B=2, H=2, S=128, D=32, seed=70)
+    mask = jnp.asarray(np.where(
+        np.random.RandomState(71).rand(2, 128) < 0.2, -10000.0, 0.0), jnp.float32)
+    before = A.trace_counts()
+    jax.make_jaxpr(lambda q, k, v: A.flash_attention(q, k, v, mask=mask, **kw))(
+        q, k, v)
+    assert A.traced_implementation(since=before) == want
+    if case != "dropout":       # the chip's generator has no interpret mode
+        out = A.flash_attention(q, k, v, mask=mask, **kw)
+        ref = A.flash_attention(q, k, v, mask=mask, force_reference=True, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_the_rule_counts_the_rows_one_device_holds(tpu_branch, monkeypatch):
+    """Under a mesh the rule sees the call as one device will: a batch whose
+    scores are over the budget whole and within it split four ways over
+    ``data`` takes the materialised path, with no shard_map around it."""
+    from jax.sharding import Mesh
+
+    A = tpu_branch
+    q, k, v = rand_qkv(B=8, H=2, S=128, D=32, seed=72)
+    monkeypatch.setattr(A, "_SCORE_BUDGET", 2 * 2 * 128 * 128 * 4)    # 2 of 8
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 4, 1),
+                ("pipe", "data", "model"))
+
+    def attend(q, k, v):
+        return A.flash_attention(q, k, v)
+
+    before = A.trace_counts()
+    whole = jax.make_jaxpr(attend)(q, k, v)
+    assert A.traced_implementation(since=before) == "pallas"
+    before = A.trace_counts()
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        split = jax.make_jaxpr(attend)(q, k, v)
+    assert A.traced_implementation(since=before) == "dense"
+    assert "shard_map" not in str(split) and "pallas_call" in str(whole)

@@ -23,9 +23,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 
 def test_kernel_phase_tiny():
     report = chip_smoke.kernel_phase(
-        flash_shapes=[(1, 2, 32, 16)], flash_block=16,
-        heads=2, head_dim=16, page_tokens=8, native=False)
+        flash_shapes=[(1, 2, 32, 16)], dense_shape=(1, 2, 16, 16),
+        flash_block=16, heads=2, head_dim=16, page_tokens=8, native=False)
     assert {"flash_s32_fwd", "flash_sparse_bwd", "flash_dropout_bwd_mask",
+            "attention_dense_s16_fwd", "attention_dense_s16_bwd",
             "decode_fp32_step", "decode_bf16_step", "decode_int8_step",
             "decode_fp32_chunk", "band_float32", "band_bfloat16"} <= set(report)
 

@@ -34,6 +34,27 @@ def _lower_tpu(fn, *args):
     return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
 
 
+def _text_without_locations(lowered):
+    """The lowered module's text with each Mosaic kernel's serialized body
+    (base64 MLIR bytecode, which embeds source lines and differs from one
+    trace to the next) replaced by its MLIR without debug locations."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([^\\]*)\\22', body,
+                  lowered.as_text())
+
+
 def _assert_mosaic(lowered, n_kernels):
     text = lowered.as_text()
     assert text.count("tpu_custom_call") >= n_kernels, (
@@ -56,35 +77,71 @@ def _banded_layout(heads, nb):
     return layout
 
 
-def _bert_attention_grad(dropout):
+def _bert_attention_grad(dropout, **kw):
     rng = jax.random.PRNGKey(0)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, dropout_rate=dropout,
-                              dropout_rng=rng if dropout else None)
+                              dropout_rng=rng if dropout else None, **kw)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     return jax.grad(loss, argnums=(0, 1, 2))
 
 
-@pytest.mark.parametrize("dropout", [0.0, 0.1])
-def test_training_flash_fwd_bwd_lowers(pallas_path, dropout):
-    """BERT-large seq128 / micro-batch 64: forward kernel plus both
-    backward kernels, with and without in-kernel dropout, several (batch,
-    head) rows a grid step."""
-    qkv = SDS((64, 16, 128, 64), jnp.bfloat16)
-    _assert_mosaic(_lower_tpu(_bert_attention_grad(dropout), qkv, qkv, qkv), 3)
+# Calls the rule (``materialises_scores``) leaves to the kernels, each by
+# what it shows of itself: dropout at BERT-large's seq 128 / micro-batch 64;
+# twice that batch (128 MiB of float32 scores on the device); seq 512.
+_KERNEL_CALLS = {
+    "s128_dropout": ((64, 16, 128, 64), 0.1),
+    "s128_batch128": ((128, 16, 128, 64), 0.0),
+    "s512": ((16, 16, 512, 64), 0.0),
+    "s512_dropout": ((16, 16, 512, 64), 0.1),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_KERNEL_CALLS))
+def test_training_flash_fwd_bwd_lowers(pallas_path, monkeypatch, call):
+    """Forward kernel plus both backward kernels, with and without
+    in-kernel dropout, several (batch, head) rows a grid step; and the rule
+    costs such a call nothing: the lowered text, source locations
+    aside, is what it is with the rule switched off, which is the entry as
+    it was before the rule (PERF.md PR 32 compares it with the parent
+    commit's own the same way)."""
+    shape, dropout = _KERNEL_CALLS[call]
+    qkv = SDS(shape, jnp.bfloat16)
+    lowered = _lower_tpu(_bert_attention_grad(dropout), qkv, qkv, qkv)
+    _assert_mosaic(lowered, 3)
     assert attn_mod.traced_rows_per_step() > 1
+    monkeypatch.setattr(attn_mod, "materialises_scores", lambda *a, **k: False)
+    without = _lower_tpu(_bert_attention_grad(dropout), qkv, qkv, qkv)
+    text = _text_without_locations(lowered)
+    assert "stable_mosaic" in text and text == _text_without_locations(without)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_training_attention_at_bert_large_s128_lowers_to_no_kernel(
+        pallas_path, causal):
+    """BERT-large seq128 / micro-batch 64 without dropout: 64 MiB of float32
+    scores, the rule's edge. The materialised path: no Mosaic call, float32
+    scores out of bf16 operands."""
+    qkv = SDS((64, 16, 128, 64), jnp.bfloat16)
+    before = attn_mod.trace_counts()
+    text = _lower_tpu(_bert_attention_grad(0.0, causal=causal),
+                      qkv, qkv, qkv).as_text()
+    assert attn_mod.traced_implementation(since=before) == "dense"
+    assert "tpu_custom_call" not in text
+    assert ("(tensor<64x16x128x64xbf16>, tensor<64x16x128x64xbf16>) -> "
+            "tensor<64x16x128x128xf32>") in text
 
 
 def test_training_flash_unaligned_seq_pads_into_kernel(pallas_path):
-    """S=100 on a TPU is padded to a block multiple and still runs the
-    kernel — it must not switch to the jnp reference."""
-    qkv = SDS((2, 4, 100, 64), jnp.bfloat16)
+    """S=200 with scores over the budget is padded to a block multiple and
+    runs the kernel — it must not switch to the jnp reference."""
+    qkv = SDS((64, 16, 200, 64), jnp.bfloat16)
     lowered = _lower_tpu(lambda q, k, v: flash_attention(q, k, v, causal=True),
                          qkv, qkv, qkv)
     _assert_mosaic(lowered, 1)
-    assert lowered.out_info.shape == (2, 4, 100, 64)
+    assert lowered.out_info.shape == (64, 16, 200, 64)
     with pytest.raises(ValueError, match="block-sparse"):
         flash_attention(*(jnp.zeros((1, 4, 100, 64), jnp.bfloat16),) * 3,
                         layout=_banded_layout(4, 1))
@@ -201,7 +258,8 @@ def test_serving_kernels_compile_for_v5e():
 @pytest.mark.slow
 def test_training_flash_kernels_compile_for_v5e(pallas_path):
     """The three training kernels through the real compiler at the BERT
-    cells' shape (with and without dropout) and at longer sequences, at the
+    cells' sequence (with dropout, and without it at twice the batch: calls
+    ``materialises_scores`` leaves to them) and at longer sequences, at the
     rows a grid step the rule gives each: a group that overflows VMEM is
     refused here, before the chip."""
     from jax.experimental import topologies
@@ -211,11 +269,13 @@ def test_training_flash_kernels_compile_for_v5e(pallas_path):
                                         topology_name="v5e:2x2")
     dev = SingleDeviceSharding(topo.devices[0])
     rows = {}
-    for shape, dropout in (((64, 16, 128, 64), 0.0), ((64, 16, 128, 64), 0.1),
+    for shape, dropout in (((128, 16, 128, 64), 0.0), ((64, 16, 128, 64), 0.1),
                            ((16, 16, 512, 64), 0.1), ((4, 16, 2048, 64), 0.0),
                            ((4, 16, 2048, 128), 0.1)):
         qkv = SDS(shape, jnp.bfloat16, sharding=dev)
+        before = attn_mod.trace_counts()
         _lower_tpu(_bert_attention_grad(dropout), qkv, qkv, qkv).compile()
+        assert attn_mod.traced_implementation(since=before) == "pallas"
         rows[shape] = attn_mod.traced_rows_per_step()
     assert rows[(64, 16, 128, 64)] > rows[(4, 16, 2048, 64)] > 1
 

@@ -135,10 +135,14 @@ def test_ulysses_attention_masked():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_ulysses_pallas_kernel_under_shard_map(monkeypatch):
-    """Ulysses' local attention routes through the Pallas flash kernel on the
-    TPU path; exercise pallas_call (interpret mode) INSIDE shard_map on the
-    virtual mesh and match the reference path."""
+@pytest.mark.parametrize("path", ["kernels", "materialised"])
+def test_ulysses_pallas_kernel_under_shard_map(monkeypatch, path):
+    """Ulysses' local attention goes through ``flash_attention`` on the TPU
+    path, whose rule sees the LOCAL call (one head of 1,024 tokens here: 4 MiB
+    of scores, the materialised path's plain operations, which need nothing
+    inside a shard_map); with the budget at 0 it is the Pallas flash kernel:
+    exercise pallas_call (interpret mode) INSIDE shard_map on the virtual
+    mesh. Either way the result matches the reference path."""
     import functools
 
     from deepspeed_tpu.ops.transformer import attention as A
@@ -163,9 +167,16 @@ def test_ulysses_pallas_kernel_under_shard_map(monkeypatch):
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(A, "_attention_pallas", spy)
+    if path == "kernels":
+        monkeypatch.setattr(A, "_SCORE_BUDGET", 0)
 
+    before = A.trace_counts()
     got = ulysses_attention(q, k, v, mesh=mesh, causal=True)
-    assert calls["n"] >= 1, "Pallas kernel not exercised under shard_map"
+    if path == "kernels":
+        assert calls["n"] >= 1, "Pallas kernel not exercised under shard_map"
+    else:
+        assert calls["n"] == 0
+        assert A.traced_implementation(since=before) == "dense"
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
 
 

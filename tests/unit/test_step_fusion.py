@@ -200,12 +200,13 @@ class TestDonationPins:
 # the loss under a sharded batch
 # ---------------------------------------------------------------------------
 
-def _tiny_bert_zero2(vocab_size, hidden, layers, **config):
+def _tiny_bert_zero2(vocab_size, hidden, layers, seq=64, dp=4, **config):
     """(engine, stacked batch) of a tiny BertForPreTraining under ZeRO-2
-    over 4 devices: 32 x 64 = 2048 rows, 4 chunks of the loss's 512."""
+    over 4 devices: 32 x 64 = 2048 rows, 4 chunks of the loss's 512
+    (``dp=1``: the same batch on one device, no ZeRO)."""
     from deepspeed_tpu.models.bert import BertConfig, init_bert
 
-    B, S = 32, 64
+    B, S = 32, seq
     model, params = init_bert(BertConfig(
         vocab_size=vocab_size, hidden_size=hidden, num_hidden_layers=layers,
         num_attention_heads=4, intermediate_size=2 * hidden,
@@ -213,11 +214,11 @@ def _tiny_bert_zero2(vocab_size, hidden, layers, **config):
         attention_probs_dropout_prob=0.0), batch_size=2, seq_len=S)
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, model_parameters=params, config_params={
-            "train_batch_size": B, "train_micro_batch_size_per_gpu": B // 4,
+            "train_batch_size": B, "train_micro_batch_size_per_gpu": B // dp,
             "gradient_accumulation_steps": 1,
             "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-            "zero_optimization": {"stage": 2},
-            "mesh": {"data_parallel_size": 4}, **config})
+            "zero_optimization": {"stage": 2 if dp > 1 else 0},
+            "mesh": {"data_parallel_size": dp}, **config})
     ids = np.zeros((B, S), np.int32)
     return engine, [engine._shard_stacked(jnp.asarray(x)[None]) for x in
                     (ids, ids, ids + 1, ids, np.zeros((B,), np.int32))]
@@ -316,14 +317,11 @@ def _tiny_bert_zero2_step(mesh_of=None):
         engine, *stacked, mesh=mesh_of and mesh_of(engine.mesh))
 
 
-def test_zero2_flat_shard_stays_out_of_the_loss_loops_on_described_v5e():
-    """Compiled for four described v5e chips, neither loop of the chunked
-    loss holds a collective: each chunk's partial kernel gradient is summed
-    locally and reduced once after the loop. Before the gradients were
-    pinned to their parameters' layout, ZeRO-2's flat ``P('data')`` shard
-    reached back into the backward loop's carry, split the word table's
-    gradient over the vocabulary, and the loop reduce-scattered it (with a
-    halo exchange, 250 / 4 being uneven) once a chunk."""
+@pytest.fixture()
+def v5e_mesh_of():
+    """``mesh_of(cpu_mesh)``: the same mesh over four described v5e chips
+    (described, not attached: the installed TPU compiler compiles for them
+    and nothing runs)."""
     import os
 
     from jax.experimental import topologies
@@ -339,18 +337,78 @@ def test_zero2_flat_shard_stays_out_of_the_loss_loops_on_described_v5e():
     # cannot be read back without the chip: keep it out of one
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        text = _tiny_bert_zero2_step(lambda cpu: Mesh(
-            np.array(topo.devices).reshape(cpu.devices.shape),
-            cpu.axis_names))
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
+    yield lambda cpu: Mesh(
+        np.array(topo.devices[:cpu.devices.size]).reshape(cpu.devices.shape),
+        cpu.axis_names)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def test_zero2_flat_shard_stays_out_of_the_loss_loops_on_described_v5e(v5e_mesh_of):
+    """Compiled for four described v5e chips, neither loop of the chunked
+    loss holds a collective: each chunk's partial kernel gradient is summed
+    locally and reduced once after the loop. Before the gradients were
+    pinned to their parameters' layout, ZeRO-2's flat ``P('data')`` shard
+    reached back into the backward loop's carry, split the word table's
+    gradient over the vocabulary, and the loop reduce-scattered it (with a
+    halo exchange, 250 / 4 being uneven) once a chunk."""
+    text = _tiny_bert_zero2_step(v5e_mesh_of)
     loops = _loss_loops(text)
     assert len(loops) == 2, "the loss's forward and backward scans"
     assert [_collectives(lines) for lines in loops] == [[], []]
     # the instrument sees a collective in a loop when there is one: the
     # encoder's backward loop reduces its layer's gradients
     assert any(_collectives(lines) for lines in _loop_bodies(text))
+
+
+def _collective_kinds(text):
+    """Every collective of a compiled HLO text as (result, opcode, replica
+    groups), sorted: what the program sends, whatever its operands are
+    called."""
+    found = []
+    for head in _collectives(text.splitlines()):
+        op = _COLLECTIVE.search(head)
+        opcode = op.group(1) if op else "fusion"     # an all-reduce-scatter
+        result = head.partition(" = ")[2].partition(f" {opcode}")[0]
+        groups = re.search(r"replica_groups=([^ ]+?),? ", head + " ")
+        found.append((result, opcode, groups and groups.group(1)))
+    return sorted(found, key=str)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_bert_step_at_seq_128_runs_the_materialised_attention(
+        monkeypatch, v5e_mesh_of, chips):
+    """BERT's fused step at seq 128 (dropout 0, as the benchmark's cells run
+    it), compiled for described v5e chips: the rule sends the attention to
+    the materialised path, so the program holds no Mosaic call, and over
+    four chips it sends what the step with the kernels sends and nothing
+    else (no gather of q, k, v or of the scores: plain einsums over a
+    batch-sharded q need no shard_map)."""
+    from deepspeed_tpu.ops.transformer import attention as attn
+
+    def step(**patches):
+        # the model is initialised here, on the CPU; only the step's trace
+        # takes the TPU's branch
+        engine, stacked = _tiny_bert_zero2(
+            256, 64, 2, seq=128, dp=chips, bf16={"enabled": True},
+            activation_checkpointing={"enabled": True})
+        with monkeypatch.context() as on_tpu:
+            on_tpu.setattr(attn, "_on_tpu", lambda: True)
+            for name, value in patches.items():
+                on_tpu.setattr(attn, name, value)
+            before = attn.trace_counts()
+            text = _compiled_text(engine, *stacked,
+                                  mesh=v5e_mesh_of(engine.mesh))
+            return text, attn.traced_implementation(since=before)
+
+    text, traced = step()
+    assert traced == "dense"
+    assert "tpu_custom_call" not in text
+    # the same step with the rule switched off, as the parent ran it
+    kernels, traced = step(materialises_scores=lambda *a, **k: False)
+    assert traced == "pallas"
+    assert kernels.count("tpu_custom_call") >= 3    # the instrument sees one
+    assert _collective_kinds(text) == _collective_kinds(kernels)
+    assert bool(_collective_kinds(text)) == (chips > 1)
 
 
 def test_zero2_flat_shard_does_not_split_the_loss_loops_carry_on_cpu():
