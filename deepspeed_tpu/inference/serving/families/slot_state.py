@@ -1,10 +1,15 @@
-"""What the families whose lanes hold recurrent state have letter for letter
-in common (``families/kimi_linear.py``, ``families/nemotron_h.py``): a
-request holds a lane while its prompt is read, admission zeroes the lane's
-slot of a ``HybridStatePool``, lane churn patches the device's lane vectors,
-one decode step is kept in flight, and the options none of them can honour.
-What differs stays with the family: the state's description, the jitted
-programs and how a prefill call is laid out."""
+"""What the families whose lanes hold a slot's state have letter for letter
+in common (``families/kimi_linear.py``, ``families/nemotron_h.py``,
+``families/laguna.py``): a request holds a lane while its prompt is read,
+admission claims the lane's slot and pages of a ``HybridStatePool`` (and
+zeroes what the pool says a new occupant must not inherit), lane churn
+patches the device's lane vectors, one decode step is kept in flight, and
+the options none of them can honour. ``RowPrefillFamily`` adds the prefill
+call that two of them lay out alike: several prompts a call, in rows. What
+differs stays with the family: the state's description and the jitted
+programs."""
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -93,13 +98,13 @@ class SlotStateFamily(ServingFamily):
         no = self.refuse
         if cfg.prefix_cache_mb > 0:
             no(f"prefix_cache_mb={cfg.prefix_cache_mb}",
-               "has no snapshot of recurrent state to seed a prefix from")
+               "has no snapshot of a slot's state to seed a prefix from")
         if cfg.prefix_spill_mb > 0 or cfg.prefix_spill_dir is not None:
             no("prefix_spill_mb/prefix_spill_dir",
                "has no spill codec (the codecs frame keys and values)")
         if cfg.speculative_k:
             no(f"speculative_k={cfg.speculative_k}",
-               "cannot roll recurrent state back over rejected drafts")
+               "cannot roll a slot's state back over rejected drafts")
         if cfg.attention_impl not in (None, "dense"):
             no(f"attention_impl={cfg.attention_impl!r}",
                "has one attention path a program")
@@ -125,7 +130,7 @@ class SlotStateFamily(ServingFamily):
     def refuse_handoff(self):
         raise UnsupportedOptionError(
             f"handoff: the {self.name} family has no handoff codec (the "
-            f"codec frames keys and values, not recurrent state)")
+            f"codec frames pages of keys and values, not a slot's state)")
 
     def sentinel_programs(self):
         return self.decode_program, self.prefill_program
@@ -135,9 +140,11 @@ class SlotStateFamily(ServingFamily):
 
     # -- admission -------------------------------------------------------
     def admit(self, stats):
-        """Give each queued request a free slot and its pages, zero the
-        slot's recurrent state, and let ``advance_prefill`` read its prompt
-        a chunk a step."""
+        """Give each queued request a free slot and its pages, zero what of
+        the slot's state a new occupant must not inherit (recurrent state;
+        nothing where a position mask hides the previous occupant), and let
+        ``advance_prefill`` read its prompt a chunk a step. A request that
+        finds no pages waits at the head of the queue, and is counted."""
         loop = self.loop
         pool = loop.pool
         while pool.free_slots > 0:
@@ -147,12 +154,15 @@ class SlotStateFamily(ServingFamily):
             try:
                 slot = pool.allocate(loop.alloc_tokens(req))
             except PoolExhaustedError:
+                # a slot is free (the loop's condition): pages are not
                 loop.scheduler.requeue_front(req)
+                loop.metrics.record_page_wait()
                 return
-            with (loop.tracer.span("serving/state_reset", cat="serving",
-                                   args={"slot": slot})
-                  if loop.tracer.enabled else telemetry.NULL_SPAN):
-                pool.reset_slot(slot)
+            if pool.reset_names:
+                with (loop.tracer.span("serving/state_reset", cat="serving",
+                                       args={"slot": slot})
+                      if loop.tracer.enabled else telemetry.NULL_SPAN):
+                    pool.reset_slot(slot)
             loop.metrics.record_admission(loop.scheduler.buckets[-1],
                                           len(req.prompt))
             req.slot = slot
@@ -221,3 +231,121 @@ class SlotStateFamily(ServingFamily):
         return ([slot for slot, req in lanes.requests.items()
                  if before[2].get(slot) == req.id],
                 host_tokens[:, None].tolist(), 0, 0)
+
+
+# Decode steps a prompt may wait for a prefill call's rows to fill. The
+# program's shape is fixed, so a call costs the same with one row in use or
+# all of them (it reads every expert either way), and every lane waits for
+# it; a held prompt costs its own lane a token a step.
+PREFILL_HOLD_STEPS = 16
+
+
+class RowPrefillFamily(SlotStateFamily):
+    """A ``SlotStateFamily`` whose prefill call runs ``prefill_chunk_tokens``
+    positions as ``rows`` rows of ``row_tokens`` tokens (``build`` sets
+    both). The prompts being read take rows in the order they were
+    admitted, each as many as its remaining tokens need while rows are
+    left, so a call holds several prompts, a long prompt advances by
+    several rows in one call and no prompt is padded by more than a row. A
+    call is held back, for at most ``PREFILL_HOLD_STEPS`` steps and only
+    while lanes decode, until the prompts waiting fill its rows. The
+    program takes ``(params, state, ids [R, T], slots [R], starts [R], lens
+    [R], page_tables [R, mp])``; an empty row carries ``max_slots`` for its
+    slot, which no write reaches."""
+
+    row_tokens = None
+    rows = None
+
+    def __init__(self, model_config):
+        super().__init__(model_config)
+        self._held = 0              # steps the waiting prompts were held
+
+    def _rows_waiting(self):
+        T = self.row_tokens
+        return sum(-(-(len(st.req.prompt) - st.pos) // T)
+                   for st in self._prefilling)
+
+    def advance_prefill(self, stats, now):
+        """One call of the chunked prefill program over the next rows of
+        the prompts being read, longest-waiting first. A request whose
+        prompt ends in this call takes its first token and joins the decode
+        lanes."""
+        if not self._prefilling:
+            return now
+        top = now
+        loop = self.loop
+        pool = loop.pool
+        self.expire_prefilling(stats, now)
+        if not self._prefilling:
+            return now
+        R, T = self.rows, self.row_tokens
+        if (self._rows_waiting() < R and loop.lanes.requests
+                and self._held < PREFILL_HOLD_STEPS):
+            self._held += 1
+            return now
+        self._held = 0
+        ids = np.zeros((R, T), np.int32)
+        slots = np.full(R, pool.max_slots, np.int32)    # no slot: no write
+        starts = np.zeros(R, np.int32)
+        lens = np.zeros(R, np.int32)
+        tables = np.zeros((R, pool.page_tables.shape[1]), np.int32)
+        riders = []                 # (request's state, tokens, last row)
+        r = 0
+        for st in self._prefilling:
+            if r == R:
+                break
+            part = st.req.prompt[st.pos:st.pos + (R - r) * T]
+            n = -(-len(part) // T)
+            flat = np.zeros(n * T, np.int32)
+            flat[:len(part)] = part
+            ids[r:r + n] = flat.reshape(n, T)
+            slots[r:r + n] = st.slot
+            starts[r:r + n] = st.pos + T * np.arange(n)
+            lens[r:r + n] = np.minimum(T, len(part) - T * np.arange(n))
+            tables[r:r + n] = pool.page_tables[st.slot]
+            r += n
+            riders.append((st, len(part), r - 1))
+        ends = [st.pos + took >= len(st.req.prompt) for st, took, _ in riders]
+        cspan = (loop.tracer.span(
+                     "serving/prefill_chunk", cat="serving",
+                     args={"request_ids": [st.req.id for st, _, _ in riders],
+                           "rows": r,
+                           "tokens": sum(took for _, took, _ in riders)})
+                 if loop.tracer.enabled else telemetry.NULL_SPAN)
+        t0 = time.monotonic()
+        for st, _, _ in riders:
+            if st.pos == 0:
+                loop.metrics.record_queue_wait(t0 - st.req.submit_time)
+        with cspan:
+            pool.state, first, self.last_prefill_logits = (
+                self.prefill_program(
+                    loop.params, pool.state,
+                    *jax.device_put((ids, slots, starts, lens, tables)),
+                    cfg=self.cfg, page_tokens=pool.page_tokens,
+                    keep_logits=self.keep_logits))
+            if self.prefill_sentinel is not None:
+                self.prefill_sentinel.check()
+            # the one read-back of a call, and only of a call that ends a
+            # prompt: the first tokens are the TTFT endpoints
+            first_host = np.asarray(first) if any(ends) else None
+        now = time.monotonic()
+        loop.prefill_ran()
+        stats["prefill_chunks"] += 1
+        loop.metrics.record_prefill_chunk(rows=r, empty_positions=(R - r) * T)
+        # the call's time is counted once, shared by the tokens it read
+        per_token = (now - t0) / sum(took for _, took, _ in riders)
+        for (st, took, last_row), ended in zip(riders, ends):
+            st.positions_run += -(-took // T) * T
+            st.pos += took
+            st.prefill_s += per_token * took
+            if not ended:
+                continue
+            self._prefilling.remove(st)
+            loop.metrics.record_prefill(
+                tokens=len(st.req.prompt), reused_tokens=0, requests=1,
+                prefill_s=st.prefill_s, positions_run=st.positions_run)
+            pool.positions[st.slot] = len(st.req.prompt)
+            stats["retired"] += loop.first_token(
+                st.req, st.slot, int(first_host[last_row]), now)
+        loop.metrics.admit_time_s += now - top
+        return now

@@ -6,7 +6,7 @@ the scheduler, expiry, which request rides which slot, stamp/emit/retire,
 guard, the injector hooks and the threads. What a lane's state is and which
 jitted programs fill and advance it is a ``ServingFamily``
 (``families/gpt2.py``, ``families/kimi_linear.py``,
-``families/nemotron_h.py``). The arrows point one
+``families/nemotron_h.py``, ``families/laguna.py``). The arrows point one
 way: the loop calls the family through the methods below, and a family calls
 back only this short public list of the loop it was built for:
 
@@ -26,13 +26,14 @@ No family reads a name of the loop that starts with ``_``
 Adding a family: its own file under ``families/``, one line in
 ``family_for``, and a pool class in ``kv_pool.py`` only if its state is of a
 new kind (``HybridStatePool`` takes paged rows and slot arrays by
-description; ``families/slot_state.py`` holds what the families over it
-share).
+description, and which of the slot arrays a new occupant must find zeroed;
+``families/slot_state.py`` holds what the families over it share).
 """
 
 import numpy as np
 
 from deepspeed_tpu.models.kimi_linear import KimiLinearConfig
+from deepspeed_tpu.models.laguna import LagunaConfig
 from deepspeed_tpu.models.nemotron_h import NemotronHConfig
 from deepspeed_tpu.profiling.sentinels import CompileSentinel
 
@@ -149,5 +150,9 @@ def family_for(model_config):
         from deepspeed_tpu.inference.serving.families.nemotron_h import (
             NemotronHFamily)
         return NemotronHFamily(model_config)
+    if isinstance(model_config, LagunaConfig):
+        from deepspeed_tpu.inference.serving.families.laguna import (
+            LagunaFamily)
+        return LagunaFamily(model_config)
     from deepspeed_tpu.inference.serving.families.gpt2 import GPT2Family
     return GPT2Family(model_config)

@@ -607,21 +607,33 @@ class HybridStatePool(PagedSlots):
       such as 576 would be padded), reached through the lane's page table
       like the KV pool's pages (a latent-attention cache);
     - *slot* arrays ``[layers, max_slots, ...]``: a fixed-size state a
-      lane (recurrent state, convolution tails).
+      lane (recurrent state, convolution tails, a window layer's ring of
+      keys and values).
 
-    Admission needs a free slot AND pages (``allocate``, inherited), and
-    then ``reset_slot``: a recurrent layer has no position mask that could
-    hide the previous occupant, so the slot's rows are zeroed when a lane
-    is reused. ``state`` is the ``{name: array}`` dict the programs take
-    and give back whole (donated)."""
+    So state of two lifetimes lives behind the one allocator: pages, which
+    a request claims for its own span out of the ``pool_tokens`` budget and
+    gives back when it retires, and a slot's arrays, which are the lane's
+    for as long as the pool stands. Admission needs a free slot AND pages
+    (``allocate``, inherited), and then ``reset_slot`` for the slot arrays
+    named in ``reset`` (default: all of them): a recurrent layer has no
+    position mask that could hide the previous occupant, so its rows are
+    zeroed when a lane is reused; a ring that is read behind a position
+    mask needs no reset, and its family says so. ``state`` is the ``{name:
+    array}`` dict the programs take and give back whole (donated)."""
 
     def __init__(self, max_slots, max_seq_len, paged, slotted,
-                 page_tokens=None, pool_tokens=None):
+                 page_tokens=None, pool_tokens=None, reset=None):
         """``paged``: {name: (layers, width, dtype)}; ``slotted``:
-        {name: (layers, per-slot shape, dtype)}."""
+        {name: (layers, per-slot shape, dtype)}; ``reset``: the slot
+        arrays ``reset_slot`` zeroes (None: all)."""
         super().__init__(max_slots, max_seq_len, page_tokens, pool_tokens)
         self.paged_names = tuple(paged)
         self.slot_names = tuple(slotted)
+        self.reset_names = (self.slot_names if reset is None
+                            else tuple(reset))
+        if set(self.reset_names) - set(self.slot_names):
+            raise ValueError(f"reset={self.reset_names} names no slot array "
+                             f"of {self.slot_names}")
         self.state = {}
         for name, (layers, width, dtype) in paged.items():
             self.state[name] = jnp.zeros(
@@ -638,13 +650,15 @@ class HybridStatePool(PagedSlots):
                                    for n in self.slot_names))
 
     def reset_slot(self, slot):
-        """Zero ``slot``'s rows of every slot array (in place: the arrays
-        are donated)."""
+        """Zero ``slot``'s rows of the slot arrays in ``reset_names`` (in
+        place: the arrays are donated)."""
         if slot in self._free:
             raise PageStateError(
                 f"reset of slot {slot} which is not allocated")
+        if not self.reset_names:
+            return
         zeroed = _zero_slot_jit(
-            {n: self.state[n] for n in self.slot_names}, jnp.int32(slot))
+            {n: self.state[n] for n in self.reset_names}, jnp.int32(slot))
         self.state.update(zeroed)
         self.slot_resets += 1
 

@@ -103,14 +103,21 @@ class ServingMetrics:
         # decode steps), token-expert picks that fell on held experts, held
         # experts with at least one token, the busiest held expert's tokens
         # (the last two summed over layers and steps); calls of a chunked
-        # prefill program and the rows of them that carried a prompt; and
-        # last-value gauges of the hybrid state pool
+        # prefill program and the rows of them that carried a prompt;
+        # what a family whose step's bytes go with its contexts says its
+        # decode steps attended to (the active lanes' positions, summed a
+        # step) and held (pages in use, summed a step); admission passes
+        # that ended for want of pages under a ``kv_pool_tokens`` budget;
+        # and last-value gauges of the hybrid state pool
         self.moe_layer_steps = 0
         self.moe_picks_here = 0
         self.moe_experts_touched = 0
         self.moe_expert_load_max = 0
         self.prefill_chunks = 0
         self.prefill_chunk_rows = 0
+        self.decode_context_tokens = 0
+        self.pool_pages_in_use_steps = 0
+        self.page_waits = 0
         self.state_slots_in_use = 0
         self.latent_pages_in_use = 0
         self.state_pool_bytes = 0
@@ -165,6 +172,18 @@ class ServingMetrics:
         self.moe_picks_here += picks_here
         self.moe_experts_touched += experts_touched
         self.moe_expert_load_max += expert_load_max
+
+    def record_attended(self, context_tokens, pages_in_use):
+        """One decode step of a family whose attention reads a lane's whole
+        context: the positions its active lanes hold, and the pages in use
+        (``decode_context_tokens``, ``pool_pages_in_use_steps``)."""
+        self.decode_context_tokens += int(context_tokens)
+        self.pool_pages_in_use_steps += int(pages_in_use)
+
+    def record_page_wait(self):
+        """An admission pass ended with a slot free and the head of the
+        queue waiting for pages."""
+        self.page_waits += 1
 
     def record_state_pool(self, slots_in_use, pages_in_use, slot_bytes,
                           paged_bytes):
@@ -399,6 +418,9 @@ class ServingMetrics:
             "moe_expert_load_max": self.moe_expert_load_max,
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_rows": self.prefill_chunk_rows,
+            "decode_context_tokens": self.decode_context_tokens,
+            "pool_pages_in_use_steps": self.pool_pages_in_use_steps,
+            "page_waits": self.page_waits,
             "state_slots_in_use": self.state_slots_in_use,
             "latent_pages_in_use": self.latent_pages_in_use,
             "state_pool_bytes": self.state_pool_bytes,

@@ -360,7 +360,7 @@ def _blocks_of_pages(page_tables, key_block, page_tokens):
 
 
 def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
-                page_tokens):
+                page_tokens, rotate=None, gate=None):
     """Attention over ``R`` rows: a row's keys and values are written to
     its prompt's pages first (whole pages, each with one in-place update),
     then every query attends the prompt's rows up to its own position, a
@@ -368,7 +368,12 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
     whole ``[La, pages, KV * hd, page_tokens]`` arrays and ``n`` this
     block's row of them. Returns ``(y, k_pool, v_pool)``. What a page holds
     beyond the prompt's end is overwritten by decode before it can be
-    attended."""
+    attended. ``cfg`` is read for ``num_attention_heads``,
+    ``num_key_value_heads`` and ``head_dim`` only. A model with positions
+    gives ``rotate(q, k, positions) -> (q, k)`` (keys are cached rotated),
+    one with an output gate ``gate(ctx [..., Q * hd]) -> ctx``, applied
+    ahead of ``o_proj`` (``models/laguna.py``); without them nothing is
+    traced for either."""
     R, T, _ = x.shape
     kvh, hd = cfg.num_key_value_heads, cfg.head_dim
     J = cfg.num_attention_heads // kvh
@@ -378,6 +383,8 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
     per_row = T // pt
     pos = starts[:, None] + jnp.arange(T)[None, :]                   # [R, T]
     q, k, v = _gqa_project(p, cfg, x)
+    if rotate is not None:
+        q, k = rotate(q, k, pos)
     logical = starts[:, None] // pt + jnp.arange(per_row)[None, :]
     dest = jnp.where((lens[:, None] > 0) & (logical < mp),
                      jnp.take_along_axis(
@@ -423,16 +430,19 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
 
     ctx = _online_softmax_loop(n_blocks, block, (R, kvh, J, T), hd)
     ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * hd)
+    if gate is not None:
+        ctx = gate(ctx)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
             k_pool, v_pool)
 
 
 def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
-               page_tokens):
+               page_tokens, rotate=None, gate=None):
     """Attention for one token of every lane over the lane's pages. ``x [B,
     d]``; the new key and value are written at ``positions`` (each lane's
     page read, given its new column and written back whole, in place)
-    before they are attended."""
+    before they are attended. ``rotate`` and ``gate`` as in
+    ``gqa_prefill``."""
     Bn = x.shape[0]
     kvh, hd = cfg.num_key_value_heads, cfg.head_dim
     J = cfg.num_attention_heads // kvh
@@ -442,6 +452,8 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     phys = jnp.where(active & (positions < mp * pt),
                      page_tables[jnp.arange(Bn), logical], 0)
     q, k, v = _gqa_project(p, cfg, x)
+    if rotate is not None:
+        q, k = rotate(q, k, positions)
     column = jnp.arange(pt)[None, None, :] == (positions % pt)[:, None, None]
     k_pages = jnp.where(column, k.astype(k_pool.dtype)[:, :, None],
                         k_pool[n, phys])
@@ -480,8 +492,11 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
         return s, weigh
 
     ctx = _online_softmax_loop(n_blocks, block, (Bn, kvh, J), hd)
-    return (_dot(ctx.reshape(Bn, kvh * J * hd).astype(x.dtype),
-                 p["o_proj"]["kernel"]).astype(x.dtype), k_pool, v_pool)
+    ctx = ctx.reshape(Bn, kvh * J * hd)
+    if gate is not None:
+        ctx = gate(ctx)
+    return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
+            k_pool, v_pool)
 
 
 # -- the two programs -------------------------------------------------------

@@ -110,11 +110,13 @@ def sigmoid_topk_routing(x, router, bias, k, scaling=1.0, renormalize=True):
     float32 (``highest`` matmul precision: a TPU would otherwise round the
     operands), the ``k`` largest of ``s + bias`` are picked, and a pick
     weighs ``s / sum of the picked s * scaling`` (``bias`` chooses, it does
-    not weigh). x [T, d]; returns (idx [T, k] int32, weights [T, k] f32)."""
+    not weigh; ``None`` for a router that has none). x [T, d]; returns
+    (idx [T, k] int32, weights [T, k] f32)."""
     s = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    _, idx = jax.lax.top_k(
+        s if bias is None else s + bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -221,7 +223,7 @@ def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
     would add is their part of the sum: on one chip the layer runs without
     its exchange, and nothing stands in for it.
 
-    params: {gate: {kernel [d, E], e_score_correction_bias [E]},
+    params: {gate: {kernel [d, E], e_score_correction_bias [E] (optional)},
     experts: {gate_proj, up_proj, down_proj} or {up_proj, down_proj},
     shared_experts (optional): the same names with ``/kernel``, of a width
     of its own}; ``expert_function`` reads each one's function off its
@@ -231,7 +233,7 @@ def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
     with jax.named_scope("moe_route"):
         idx, w = sigmoid_topk_routing(
             x, params["gate"]["kernel"],
-            params["gate"]["e_score_correction_bias"], k, scaling,
+            params["gate"].get("e_score_correction_bias"), k, scaling,
             renormalize)
     with jax.named_scope("moe_experts"):
         y, stats = held_experts_ffn(x, params["experts"], idx, w, held, tile,

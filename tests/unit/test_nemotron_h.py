@@ -21,8 +21,10 @@ from benchmarks.refs import nemotron_h_ref as ref
 from benchmarks.refs import weights as weights_mod
 from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
 from deepspeed_tpu.inference.serving.families.nemotron_h import (
-    PREFILL_HOLD_STEPS,
     NemotronHFamily,
+)
+from deepspeed_tpu.inference.serving.families.slot_state import (
+    PREFILL_HOLD_STEPS,
 )
 from deepspeed_tpu.inference.serving.family import UnsupportedOptionError
 from deepspeed_tpu.models import nemotron_h as nh
@@ -166,6 +168,9 @@ def test_engine_logits_match_the_reference_forward_pass(slow_decay):
     assert worst < 2e-4, worst
     snap = eng.metrics.snapshot()
     assert snap["prefill_tokens"] == sum(lengths)
+    # a call that read several prompts counts its time once
+    assert 0 < snap["prefill_time_s"] <= snap["admit_time_s"] < (
+        snap["loop_busy_s"] - snap["decode_time_s"])
     assert snap["prefill_chunks"] == len(calls)
     assert snap["prefill_chunk_rows"] == sum(
         int((c[2] > 0).sum()) for c in calls)

@@ -62,7 +62,7 @@ def test_the_loop_imports_no_model_code():
 
 def test_there_are_families_to_hold_to_the_contract():
     names = {os.path.basename(p) for p in FAMILY_FILES}
-    assert {"gpt2.py", "kimi_linear.py", "nemotron_h.py",
+    assert {"gpt2.py", "kimi_linear.py", "nemotron_h.py", "laguna.py",
             "slot_state.py"} <= names, names
 
 
@@ -162,6 +162,8 @@ HOMES = {
     "_kimi_prefill_chunk_jit": "families/kimi_linear.py",
     "_nemotron_decode_step_jit": "families/nemotron_h.py",
     "_nemotron_prefill_chunk_jit": "families/nemotron_h.py",
+    "_laguna_decode_step_jit": "families/laguna.py",
+    "_laguna_prefill_chunk_jit": "families/laguna.py",
     "_install_pages": "kv_pool.py",
     "_zero_slot": "kv_pool.py",
 }
@@ -199,4 +201,5 @@ def test_every_family_program_and_decode_step_is_a_marked_hot_loop():
             if not any("jaxlint: hot" in lines[at - 1]
                        for at in (node.lineno, node.lineno - 1)):
                 unmarked.append(f"{os.path.basename(path)}:{node.name}")
-    assert unmarked == [] and seen >= 13 + 1 + 2 + 2 + 2 + 1, (unmarked, seen)
+    assert unmarked == [] and seen >= 13 + 1 + 2 + 2 + 2 + 3 + 1, (
+        unmarked, seen)
