@@ -90,17 +90,15 @@ class LagunaFamily(RowPrefillFamily):
         assert pool.page_tokens == page, (pool.page_tokens, page)
         self.row_tokens = page
         self.rows = int(cfg.prefill_chunk_tokens) // page
+        self.paged_attn_layers = n_full
         loop.metrics.record_state_pool(0, 0, pool.slot_bytes(),
                                        pool.paged_bytes())
         return params, pool
 
-    def decode_step(self, guard):  # jaxlint: hot
-        """The step of ``SlotStateFamily``, and what the step attends to
-        and holds, for the roofline's and the pool's readers: a full layer
-        reads every position its active lanes hold."""
-        pool = self.loop.pool
-        # host mirrors both: the allocator's positions and its page count
-        self.loop.metrics.record_attended(
-            pool.positions[list(self.loop.lanes.requests)].sum(),
-            pool.pages_in_use)
-        return super().decode_step(guard)
+    def count_attended(self, held):
+        """Also what the step attends to and holds, for the roofline's and
+        the pool's readers: a full layer reads every position its active
+        lanes hold."""
+        super().count_attended(held)
+        self.loop.metrics.record_attended(held.sum(),
+                                          self.loop.pool.pages_in_use)

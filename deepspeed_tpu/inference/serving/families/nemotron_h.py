@@ -78,6 +78,7 @@ class NemotronHFamily(RowPrefillFamily):
             page_tokens=cfg.kv_page_tokens, pool_tokens=cfg.kv_pool_tokens)
         self.row_tokens = m.chunk_size
         self.rows = int(cfg.prefill_chunk_tokens) // m.chunk_size
+        self.paged_attn_layers = n_attn
         loop.metrics.record_state_pool(0, 0, pool.slot_bytes(),
                                        pool.paged_bytes())
         return params, pool

@@ -25,6 +25,7 @@ from deepspeed_tpu.inference.serving.family import (
     UnsupportedOptionError,
 )
 from deepspeed_tpu.inference.serving.kv_pool import PoolExhaustedError
+from deepspeed_tpu.models.nemotron_h import decode_key_span
 
 
 @jax.jit  # jaxlint: hot
@@ -241,7 +242,9 @@ PREFILL_HOLD_STEPS = 16
 
 
 class RowPrefillFamily(SlotStateFamily):
-    """A ``SlotStateFamily`` whose prefill call runs ``prefill_chunk_tokens``
+    """A ``SlotStateFamily`` whose attention layers over pages are
+    ``models/nemotron_h.py``'s (``paged_attn_layers`` of them, which
+    ``build`` sets) and whose prefill call runs ``prefill_chunk_tokens``
     positions as ``rows`` rows of ``row_tokens`` tokens (``build`` sets
     both). The prompts being read take rows in the order they were
     admitted, each as many as its remaining tokens need while rows are
@@ -255,10 +258,26 @@ class RowPrefillFamily(SlotStateFamily):
 
     row_tokens = None
     rows = None
+    paged_attn_layers = None
 
     def __init__(self, model_config):
         super().__init__(model_config)
         self._held = 0              # steps the waiting prompts were held
+
+    def decode_step(self, guard):  # jaxlint: hot
+        """The step of ``SlotStateFamily``, and what it attends to, counted
+        from the allocator's host mirror of the lanes' positions (a step
+        behind the device's, which costs no read-back)."""
+        self.count_attended(
+            self.loop.pool.positions[list(self.loop.lanes.requests)])
+        return super().decode_step(guard)
+
+    def count_attended(self, held):
+        """``held [lanes]`` positions of the active lanes: how much of the
+        lanes x blocks rectangle the step's paged attention walks."""
+        self.loop.metrics.record_attn_blocks(
+            held // decode_key_span(self.loop.pool.page_tokens) + 1,
+            self.paged_attn_layers)
 
     def _rows_waiting(self):
         T = self.row_tokens

@@ -106,7 +106,10 @@ class ServingMetrics:
         # prefill program and the rows of them that carried a prompt;
         # what a family whose step's bytes go with its contexts says its
         # decode steps attended to (the active lanes' positions, summed a
-        # step) and held (pages in use, summed a step); admission passes
+        # step) and held (pages in use, summed a step); the (lane, key
+        # block) pairs the paged decode attention of a family's step walked,
+        # beside the lanes x blocks to the longest lane's end that a walk
+        # of every lane alike would have read; admission passes
         # that ended for want of pages under a ``kv_pool_tokens`` budget;
         # and last-value gauges of the hybrid state pool
         self.moe_layer_steps = 0
@@ -117,6 +120,8 @@ class ServingMetrics:
         self.prefill_chunk_rows = 0
         self.decode_context_tokens = 0
         self.pool_pages_in_use_steps = 0
+        self.decode_attn_blocks_walked = 0
+        self.decode_attn_blocks_dense = 0
         self.page_waits = 0
         self.state_slots_in_use = 0
         self.latent_pages_in_use = 0
@@ -179,6 +184,18 @@ class ServingMetrics:
         (``decode_context_tokens``, ``pool_pages_in_use_steps``)."""
         self.decode_context_tokens += int(context_tokens)
         self.pool_pages_in_use_steps += int(pages_in_use)
+
+    def record_attn_blocks(self, blocks, layers):
+        """One decode step of a family whose paged attention walks a work
+        list (``models/nemotron_h.py::gqa_decode``): ``blocks`` key blocks
+        each active lane owns, in each of ``layers`` layers. Beside the
+        pairs walked, the rectangle they are cut from: every lane to the
+        longest one's end (``decode_attn_blocks_walked``,
+        ``decode_attn_blocks_dense``)."""
+        if len(blocks):
+            self.decode_attn_blocks_walked += layers * int(blocks.sum())
+            self.decode_attn_blocks_dense += (layers * len(blocks)
+                                              * int(blocks.max()))
 
     def record_page_wait(self):
         """An admission pass ended with a slot free and the head of the
@@ -420,6 +437,8 @@ class ServingMetrics:
             "prefill_chunk_rows": self.prefill_chunk_rows,
             "decode_context_tokens": self.decode_context_tokens,
             "pool_pages_in_use_steps": self.pool_pages_in_use_steps,
+            "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
+            "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
             "page_waits": self.page_waits,
             "state_slots_in_use": self.state_slots_in_use,
             "latent_pages_in_use": self.latent_pages_in_use,

@@ -26,8 +26,10 @@ serving engine jits, laid out as ``models/nemotron_h.py``'s:
   positions the call read of each prompt.
 - ``decode_step``: one token for every active lane. A window layer writes
   position ``p`` at ``p mod sliding_window`` of the lane's ring and reads
-  the ring once; a full layer gathers the lane's pages
-  (``nemotron_h.gqa_decode``).
+  the ring once; a full layer walks the (lane, block of 512 keys) pairs
+  its active lanes own, a tile of pairs' pages gathered at a time
+  (``nemotron_h.gqa_decode``), so its bytes follow the sum of the lanes'
+  contexts and not the longest one's.
 
 ``state`` is ``{"k", "v": [Lf, pages, kv_heads * head_dim, page_tokens]``
 (the full layers' pages, as Nemotron-H's), ``"wk", "wv": [Lw, slots, W /

@@ -20,7 +20,8 @@ serving engine jits:
   keys and values written as whole pages, attention over the row's own
   pages. One program serves every prompt length.
 - ``decode_step``: one token for every active lane, Mamba-2 by the
-  recurrence and attention over the lane's pages.
+  recurrence and attention over the lane's pages (a work list of the
+  lanes' blocks of keys: ``gqa_decode``).
 
 ``state`` is ``{"ssm": [Lm, slots, H, P, N] float32, "conv": [Lm, slots,
 K-1, conv_dim], "k", "v": [La, pages, kv_heads * head_dim, page_tokens]}``
@@ -46,6 +47,7 @@ from deepspeed_tpu.parallel import expert as expert_mod
 
 PREFILL_KEY_BLOCK = 512     # keys a row attends at a time in prefill
 DECODE_KEY_BLOCK = 512      # keys a lane attends at a time in decode
+_TILE_BYTES = 32 << 20      # keys and values a tile of decode pairs gathers
 _MM = dict(precision=jax.lax.Precision.HIGHEST,
            preferred_element_type=jnp.float32)
 
@@ -436,13 +438,57 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
             k_pool, v_pool)
 
 
+def decode_key_span(page_tokens):
+    """Keys in one block of a lane's decode attention: the pages that hold
+    ``DECODE_KEY_BLOCK`` tokens, at least one."""
+    return max(1, DECODE_KEY_BLOCK // page_tokens) * page_tokens
+
+
+def decode_work_list(positions, active, span, nblk, bound):
+    """The (lane, key block) pairs a decode step attends, lane by lane: an
+    active lane at position ``p`` owns blocks ``0 .. p // span`` (at most
+    the ``nblk`` its page table holds), an inactive lane none. A prefix sum
+    over the lanes' counts lays them out, as ``expert.held_experts_ffn``
+    lays out its tiles: ``bound`` pairs of static shape, of which the first
+    ``n_pairs`` exist. Returns ``(lane [bound], block [bound], live
+    [bound], n_pairs)``; a pair that does not exist reads lane and block
+    0."""
+    owned = jnp.where(active, jnp.clip(positions // span + 1, 0, nblk), 0)
+    lane_end = jnp.cumsum(owned)
+    i = jnp.arange(bound)
+    n_pairs = jnp.minimum(lane_end[-1], bound)
+    live = i < n_pairs
+    # the lane whose run of pairs holds i: those that ended at or before it
+    lane = jnp.where(live, jnp.searchsorted(lane_end, i, side="right",
+                                            method="compare_all"), 0)
+    block = jnp.where(live, i - (lane_end - owned)[lane], 0)
+    return lane, block, live, n_pairs
+
+
+def pairs_per_tile(bound, pair_bytes):
+    """Pairs one iteration of the decode attention's loop gathers: the
+    power of two whose keys and values come nearest ``_TILE_BYTES`` from
+    below (an iteration has to move tens of megabytes to stream), and no
+    more than the list can hold."""
+    g = max(1, _TILE_BYTES // pair_bytes)
+    return max(1, min(1 << (g.bit_length() - 1), bound))
+
+
 def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
                page_tokens, rotate=None, gate=None):
     """Attention for one token of every lane over the lane's pages. ``x [B,
     d]``; the new key and value are written at ``positions`` (each lane's
     page read, given its new column and written back whole, in place)
     before they are attended. ``rotate`` and ``gate`` as in
-    ``gqa_prefill``."""
+    ``gqa_prefill``.
+
+    What is walked is the work list of ``decode_work_list``: the (lane,
+    block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, a tile of them an
+    iteration, so a step reads the sum of the lanes' contexts and not every
+    lane up to the longest one's end. An iteration gathers its pairs' pages
+    from the pool and leaves each pair's masked partial softmax (running
+    max, sum and weighted values, float32); the partials of a lane's pairs,
+    in one tile or in several, are combined after the loop."""
     Bn = x.shape[0]
     kvh, hd = cfg.num_key_value_heads, cfg.head_dim
     J = cfg.num_attention_heads // kvh
@@ -469,30 +515,55 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
 
     k_pool, v_pool = jax.lax.fori_loop(0, Bn, put, (k_pool, v_pool))
     tables, bp = _blocks_of_pages(page_tables, DECODE_KEY_BLOCK, pt)
-    end = jnp.max(jnp.where(active, positions + 1, 0))
-    n_blocks = (end + bp * pt - 1) // (bp * pt)
+    span, nblk = decode_key_span(pt), tables.shape[1] // bp
+    # lanes hold pages of their own, so their blocks are at most the pool's
+    # and, a lane, a partial last one and the one a retired lane's step in
+    # flight runs past its span
+    bound = min(Bn * nblk, -(-k_pool.shape[1] // bp) + 2 * Bn)
+    G = pairs_per_tile(
+        bound, 2 * bp * kvh * hd * pt * jnp.dtype(k_pool.dtype).itemsize)
+    bound = -(-bound // G) * G
+    lane, blk, live, n_pairs = decode_work_list(positions, active, span, nblk,
+                                                bound)
+    pair_pages = tables.reshape(Bn, nblk, bp)[lane, blk]            # [P, bp]
+    # the last key of its block a pair attends; none where there is no pair
+    pair_last = jnp.where(live, positions[lane] - blk * span, -1)
+    pair_q = q[lane]
     scale = hd ** -0.5
 
-    def block(j):
-        pages = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
-        kb = k_pool[n, pages].astype(x.dtype).reshape(Bn, bp, kvh, hd, pt)
-        vb = v_pool[n, pages].astype(x.dtype).reshape(Bn, bp, kvh, hd, pt)
-        kpos = j * bp * pt + jnp.arange(bp * pt)
-        s = jnp.einsum("bgjd,bngdp->bgjnp", q, kb,
+    def tile(i, parts):
+        at = i * G
+        pages = jax.lax.dynamic_slice_in_dim(pair_pages, at, G)
+        qt = jax.lax.dynamic_slice_in_dim(pair_q, at, G)
+        last = jax.lax.dynamic_slice_in_dim(pair_last, at, G)
+        kb = k_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, hd, pt)
+        vb = v_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, hd, pt)
+        s = jnp.einsum("bgjd,bngdp->bgjnp", qt, kb,
                        preferred_element_type=jnp.float32).reshape(
-                           Bn, kvh, J, bp * pt) * scale
-        s = jnp.where(kpos[None, None, None, :]
-                      <= positions[:, None, None, None], s, -1e30)
+                           G, kvh, J, span) * scale
+        ok = jnp.arange(span)[None, None, None, :] <= last[:, None, None, None]
+        m = jnp.max(jnp.where(ok, s, -1e30), axis=-1)
+        pr = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
+        acc = jnp.einsum("bgjnp,bngdp->bgjd",
+                         pr.astype(x.dtype).reshape(G, kvh, J, bp, pt), vb,
+                         preferred_element_type=jnp.float32)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(whole, part, at, 0)
+                     for whole, part in zip(parts, (m, jnp.sum(pr, -1), acc)))
 
-        def weigh(pr):
-            return jnp.einsum(
-                "bgjnp,bngdp->bgjd",
-                pr.astype(x.dtype).reshape(Bn, kvh, J, bp, pt), vb,
-                preferred_element_type=jnp.float32)
-        return s, weigh
-
-    ctx = _online_softmax_loop(n_blocks, block, (Bn, kvh, J), hd)
-    ctx = ctx.reshape(Bn, kvh * J * hd)
+    m, l, acc = jax.lax.fori_loop(
+        0, (n_pairs + G - 1) // G, tile,
+        (jnp.full((bound, kvh, J), -1e30, jnp.float32),
+         jnp.zeros((bound, kvh, J), jnp.float32),
+         jnp.zeros((bound, kvh, J, hd), jnp.float32)))
+    # by lane: the running max, each pair rescaled to it, the sums
+    mine = (lane[None, :] == jnp.arange(Bn)[:, None]) & live[None, :]  # [B, P]
+    m_lane = jnp.max(jnp.where(mine[..., None, None], m[None], -1e30), axis=1)
+    w = jnp.exp(m - m_lane[lane])
+    l_lane = jnp.einsum("bp,pgj->bgj", mine.astype(jnp.float32), l * w, **_MM)
+    ctx = jnp.einsum("bp,pgjd->bgjd", mine.astype(jnp.float32),
+                     acc * w[..., None], **_MM)
+    ctx = (ctx / jnp.maximum(l_lane, 1e-30)[..., None]).reshape(
+        Bn, kvh * J * hd)
     if gate is not None:
         ctx = gate(ctx)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),

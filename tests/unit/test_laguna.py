@@ -464,6 +464,48 @@ def test_a_request_that_finds_no_pages_waits_at_the_head_of_the_queue():
     assert eng.metrics.snapshot()["page_waits"] >= 2
 
 
+# -- the counters of the decode attention's work list ---------------------
+
+@pytest.mark.parametrize("family, layers", [("laguna", 2), ("nemotron_h", 1)])
+def test_the_attention_block_counters_count_what_a_hand_made_schedule_owes(
+        family, layers, monkeypatch):
+    """Two decode steps of three lanes of known lengths (slots 0, 1 and 3;
+    slot 2 is free and what its position reads is nobody's), through the
+    family's own ``decode_step`` with the program's dispatch taken out: the
+    pairs walked are each lane's blocks of 512 keys in each paged attention
+    layer, the rectangle is every lane to the longest one's end."""
+    from types import SimpleNamespace
+
+    from deepspeed_tpu.inference.serving.families import slot_state
+    from deepspeed_tpu.inference.serving.families.nemotron_h import (
+        NemotronHFamily,
+    )
+    from deepspeed_tpu.inference.serving.metrics import ServingMetrics
+
+    monkeypatch.setattr(slot_state.SlotStateFamily, "decode_step",
+                        lambda self, guard: ((), (), 0, 0))
+    fam = (LagunaFamily if family == "laguna" else NemotronHFamily)(None)
+    fam.paged_attn_layers = layers
+    metrics = ServingMetrics()
+    pool = SimpleNamespace(positions=np.array([5, 511, 9999, 1100]),
+                           page_tokens=ROW, pages_in_use=0)
+    fam.loop = SimpleNamespace(
+        pool=pool, metrics=metrics,
+        lanes=SimpleNamespace(requests={0: None, 1: None, 3: None}))
+    before = metrics.snapshot()
+    assert (before["decode_attn_blocks_walked"],
+            before["decode_attn_blocks_dense"]) == (0, 0)
+    fam.decode_step(None)                 # blocks 1, 1, 3
+    pool.positions[[0, 1, 3]] += 1        # 511 -> 512: a second block
+    fam.decode_step(None)                 # blocks 1, 2, 3
+    snap = metrics.snapshot()
+    assert snap["decode_attn_blocks_walked"] == layers * (5 + 6)
+    assert snap["decode_attn_blocks_dense"] == layers * (3 * 3 + 3 * 3)
+    fam.loop.lanes.requests = {}          # a step with no lane owes nothing
+    fam.decode_step(None)
+    assert metrics.snapshot()["decode_attn_blocks_dense"] == layers * 18
+
+
 # -- (g) each unsupported option raises, by name -----------------------------
 
 UNSUPPORTED = {

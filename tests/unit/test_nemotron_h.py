@@ -392,6 +392,135 @@ def test_grouped_query_decode_over_pages_matches_the_full_form():
                                atol=2e-5, rtol=2e-4)
 
 
+# -- (c2) decode over ragged lanes: the work list of (lane, block) pairs -----
+
+SPAN = 512                        # keys a pair holds: nh.DECODE_KEY_BLOCK
+RAGGED = {
+    # name: (positions, active, pairs a tile (None: the rule's), extras)
+    "a lane at position 0": ([0, 5, 700], [1, 1, 1], None, False),
+    "a block's last key and the next one's first":
+        ([511, 512, 513, 1023, 1024], [1] * 5, None, False),
+    "an inactive lane between active ones":
+        ([600, 300, 40], [1, 0, 1], None, True),
+    "one lane 30 blocks long beside lanes of one":
+        ([100, 15000, 511, 3], [1] * 4, None, False),
+    "more pairs than a tile": ([1500, 15000, 20, 900], [1] * 4, 4, True),
+    "a lane's pairs in two tiles": ([1024, 1024, 7], [1] * 3, 2, False),
+    "fewer pairs than a tile": ([30, 2], [1, 1], 8, False),
+    "no lane active": ([30, 600], [0, 0], None, True),
+    "every lane in its last block": ([1535, 1535], [1, 1], None, True),
+}
+
+
+@pytest.mark.parametrize("case", list(RAGGED))
+def test_decode_over_ragged_lanes_matches_a_dense_softmax_a_lane(
+        case, monkeypatch):
+    """``gqa_decode`` over lanes of unlike lengths against each lane's own
+    softmax over its keys, all at once in float32: the pool holds random
+    keys and values at the lane's positions so far, the step adds its own.
+    With ``rotate`` and ``gate`` given (any functions of the right shape)
+    and without."""
+    positions, active, per_tile, extras = RAGGED[case]
+    _, params, mcfg = make()
+    p = params["layers"]["6"]["mixer"]
+    pt, kvw, hd = 16, mcfg.kv_width, mcfg.head_dim
+    if per_tile is not None:
+        pair_bytes = 2 * SPAN * kvw * 4
+        monkeypatch.setattr(nh, "_TILE_BYTES", per_tile * pair_bytes)
+    B = len(positions)
+    rng = np.random.default_rng(5)
+    owned = [-(-(n + 1) // pt) for n in positions]
+    mp = -(-max(owned) // 32) * 32 + 3          # not a whole block of pages
+    tables = np.zeros((B, mp), np.int32)
+    first = np.cumsum([1] + owned)
+    for b in range(B):
+        tables[b, :owned[b]] = first[b] + np.arange(owned[b])
+    pool = rng.normal(size=(2, 1, first[-1], kvw, pt)).astype(np.float32)
+    x = jnp.asarray(rng.normal(size=(B, 64)), jnp.float32)
+    pos = jnp.asarray(positions, jnp.int32)
+    rotate = gate = None
+    if extras:
+        rotate = lambda q, k, at: (
+            q * jnp.cos(0.1 * at)[:, None, None, None],
+            k * (1 + jnp.sin(0.01 * at))[:, None])
+        gate = lambda ctx: ctx * jnp.linspace(0.5, 1.5, ctx.shape[-1])
+    y, k_out, v_out = jax.jit(
+        lambda x, k, v: nh.gqa_decode(
+            p, mcfg, x, k, v, 0, jnp.asarray(tables), pos,
+            jnp.asarray(active, bool), pt, rotate=rotate, gate=gate))(
+        x, jnp.asarray(pool[0]), jnp.asarray(pool[1]))
+    q, k_new, v_new = nh._gqa_project(p, mcfg, x)
+    if rotate is not None:
+        q, k_new = rotate(q, k_new, pos)
+    for b in range(B):
+        if not active[b]:
+            continue
+        n = positions[b]
+        rows = [np.swapaxes(pool[i, 0, tables[b, :owned[b]]], 1, 2).reshape(
+            -1, kvw)[:n] for i in (0, 1)]
+        keys = np.concatenate([rows[0], np.asarray(k_new[b])[None]])
+        vals = np.concatenate([rows[1], np.asarray(v_new[b])[None]])
+        keys, vals = (a.reshape(n + 1, -1, hd) for a in (keys, vals))
+        s = np.einsum("gjd,ngd->gjn", np.asarray(q[b], np.float64),
+                      keys.astype(np.float64)) * hd ** -0.5
+        w = np.exp(s - s.max(-1, keepdims=True))
+        ctx = np.einsum("gjn,ngd->gjd", w / w.sum(-1, keepdims=True),
+                        vals.astype(np.float64)).reshape(-1)
+        if gate is not None:
+            ctx = np.asarray(gate(jnp.asarray(ctx, jnp.float32)))
+        want = ctx.astype(np.float32) @ np.asarray(p["o_proj"]["kernel"])
+        np.testing.assert_allclose(y[b], want, atol=2e-5, rtol=2e-4,
+                                   err_msg=f"lane {b}")
+        # the step's own column, and nothing else of the lane's last page
+        page = np.array(k_out[0, tables[b, n // pt]])
+        np.testing.assert_allclose(page[:, n % pt], k_new[b], atol=1e-6)
+        page[:, n % pt] = pool[0, 0, tables[b, n // pt]][:, n % pt]
+        np.testing.assert_array_equal(page, pool[0, 0, tables[b, n // pt]])
+    assert np.isfinite(np.asarray(y)).all()
+
+
+def test_the_work_list_holds_each_active_lanes_blocks_and_no_others():
+    """The pairs the decode attention walks are ``sum(ceil((pos + 1) /
+    512))`` over the active lanes, lane by lane and block by block; when
+    the longest lane grows by a block the list grows by that one pair and
+    every other lane's pairs stay as they were (the walk to the longest
+    lane's end grew by a block for EVERY lane)."""
+    span, nblk, bound = SPAN, 32, 96
+
+    def pairs(positions, active):
+        lane, block, live, n = jax.jit(
+            nh.decode_work_list, static_argnums=(2, 3, 4))(
+                jnp.asarray(positions, jnp.int32), jnp.asarray(active, bool),
+                span, nblk, bound)
+        n = int(n)
+        assert np.asarray(live).tolist() == [True] * n + [False] * (bound - n)
+        assert not np.asarray(lane)[n:].any() and not np.asarray(block)[n:].any()
+        return list(zip(np.asarray(lane)[:n].tolist(),
+                        np.asarray(block)[:n].tolist()))
+
+    positions, active = [0, 511, 512, 14000, 300, 1023], [1, 1, 1, 1, 0, 1]
+    got = pairs(positions, active)
+    assert len(got) == sum(-(-(n + 1) // span) for n, a in zip(
+        positions, active) if a) == 1 + 1 + 2 + 28 + 2
+    assert got == [(b, j) for b, (n, a) in enumerate(zip(positions, active))
+                   if a for j in range(n // span + 1)]
+    grown = list(positions)
+    grown[3] += span
+    after = pairs(grown, active)
+    assert len(after) == len(got) + 1
+    assert [pr for pr in after if pr[0] != 3] == [pr for pr in got
+                                                  if pr[0] != 3]
+    # a lane past its table's width owns the table's blocks and no more; a
+    # list that cannot hold every pair holds the first ``bound``
+    assert len(pairs([40000, 10], [1, 1])) == nblk + 1
+    assert len(pairs([16383] * 4, [1] * 4)) == bound
+    # the tiles the loop runs follow the pairs, not the longest lane
+    G = nh.pairs_per_tile(bound, 2 << 20)
+    assert G == 16 and nh.pairs_per_tile(8, 1 << 10) == 8
+    assert nh.pairs_per_tile(bound, 3 << 20) == 8       # 10 fit: 8 is taken
+    assert -(-len(got) // G) == 3 == -(-len(after) // G)
+
+
 # -- (d) the shares add up to the uncut block -------------------------------
 
 def test_the_two_shares_add_up_to_the_uncut_expert_block():
