@@ -206,8 +206,6 @@ class ServingEngine:
                                 read_rss_mb=self._guard_rss_mb,
                                 listener=self._on_mem_pressure_level)
             if cfg.host_mem_watermark_mb > 0 else None)
-        # edge-trigger memo for the serving/spill_corrupt instant
-        self._spill_corrupt_seen = 0
         # pool-pressure relief: one evict+shed attempt per exhaustion
         # event (satellite: requeue-after-relief instead of plain requeue)
         self._pool_relief_attempts = 0
@@ -223,6 +221,17 @@ class ServingEngine:
         # at each token it emits, so the next gap knows whether a prefill
         # ran inside it (stalled by cause, not by a threshold)
         self._prefill_seq = 0
+        # the loop's waits for the device (``launched`` / ``read_back``):
+        # the stamp of a decode launch no read has closed yet, the stamp
+        # and kind of the read that left the device with nothing to run,
+        # and whether a prefill program stands before the newest decode
+        # program dispatched and before the one ahead of it
+        self._launched_at = None
+        self._dry_since = None
+        self._dry_kind = None
+        self._prefill_unread = False
+        self._behind_newest = False
+        self._behind_earlier = False
         self._loop_thread = None
         self._stop = threading.Event()
         self._draining = False              # planned restart: admit nothing
@@ -666,6 +675,8 @@ class ServingEngine:
             slots, rows, accepted, proposed = self.family.decode_step(guard)
             step_s = time.monotonic() - t0
             read_back = t0 + step_s
+            # a step in flight with nothing to read yet
+            self._dispatched(read_back)
             if span_args is not None:
                 span_args["accepted"] = accepted
             dspan.__exit__(None, None, None)
@@ -717,8 +728,80 @@ class ServingEngine:
             end = time.monotonic()
             self.metrics.record_iteration(
                 end - top, end - read_back if read_back is not None else 0.0)
+        else:
+            # out of work: from here on the device is dry for want of
+            # requests, which is not the loop's to account for
+            self._dry_spell_ends(top)
         espan.__exit__(None, None, None)
         return stats
+
+    # -- the loop's waits for the device --------------------------------
+    def launched(self, kind):
+        """A family calls this directly before it calls a decode or a
+        prefill program (``kind`` "decode" or "prefill"; once before the
+        several programs of one step): a decode step's dispatch, the
+        upload of the call's arguments included, is timed from here, and
+        a dry spell ends here."""
+        now = time.monotonic()
+        self._dispatched(now)
+        self._dry_spell_ends(now)
+        if kind == "prefill":
+            self._prefill_unread = True
+        else:
+            self._launched_at = now
+            self._behind_earlier = self._behind_newest
+            self._behind_newest = self._prefill_unread
+            self._prefill_unread = False
+
+    def read_back(self, tree, kind, newest, fetch=True):  # jaxlint: hot
+        """The one place where the loop thread waits for the device:
+        ``jax.device_get(tree)``, or with ``fetch=False`` only the wait
+        until ``tree`` is ready (the settle after installs), timed on both
+        sides. ``kind`` is the program's whose output this is ("prefix_kv"
+        for the prefix cache's copy of a prompt's K/V, which is waited for
+        here and counted as no program's read); ``newest`` says it is the
+        output of the last program dispatched, so that on return nothing
+        dispatched is left to run and the device is dry until the next
+        ``launched`` (the slices, installs, lane patches and slot resets
+        dispatched meanwhile are well under a millisecond of device time
+        each and count as dry). A decode read that is not the
+        newest reads the step ahead of the one just dispatched; it counts
+        as behind a prefill when a prefill program that nothing has read
+        back was dispatched ahead of the step it reads."""
+        t0 = time.monotonic()
+        self._dispatched(t0)
+        with (self.tracer.span("serving/read_back", cat="serving",
+                               args={"kind": kind, "newest": newest})
+              if self.tracer.enabled else telemetry.NULL_SPAN):
+            if fetch:
+                tree = jax.device_get(tree)  # jaxlint: disable=JL002(the loop's one explicit host read)
+            else:
+                jax.block_until_ready(tree)  # jaxlint: disable=JL002(the settle after installs, accounted to admission)
+        t1 = time.monotonic()
+        self.metrics.record_read(
+            kind, t1 - t0,
+            self._behind_newest if newest else self._behind_earlier)
+        if newest:
+            # everything dispatched has run: no prefill stands before
+            # anything, and unless a spell is open already one begins
+            self._prefill_unread = False
+            self._behind_newest = self._behind_earlier = False
+            if self._dry_since is None:
+                self._dry_since, self._dry_kind = t1, kind
+        return tree
+
+    def _dispatched(self, at):
+        """Close the open decode launch's dispatch at the stamp ``at``."""
+        if self._launched_at is not None:
+            self.metrics.decode_dispatch_s += at - self._launched_at
+            self._launched_at = None
+
+    def _dry_spell_ends(self, at):
+        """Close the open dry spell, if there is one, at the stamp ``at``."""
+        if self._dry_since is not None:
+            self.metrics.record_dry_spell(self._dry_kind,
+                                          at - self._dry_since)
+            self._dry_since = None
 
     def _slo_values(self):
         """SLO inputs: the live serving snapshot under ``Serving/*`` plus

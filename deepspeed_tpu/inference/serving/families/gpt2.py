@@ -1226,6 +1226,7 @@ class GPT2Family(ServingFamily):
         # directly (a bool() cast here reads as a device sync to JL002)
         full, win, kfull, kwin = self._lane_classes()
         pt, qmode = pool.page_tokens, self._qmode
+        loop.launched("decode")
         with guard:
             if np.any(full):
                 if qmode is not None:
@@ -1248,7 +1249,7 @@ class GPT2Family(ServingFamily):
                                **self._kernel_statics("pallas_sparse"))
         self._check_decode_sentinels()
         # the step's single deliberate sync: EOS checks need the tokens
-        host_tokens = jax.device_get(lanes.dev_tokens)  # jaxlint: disable=JL002(one explicit host read per step)
+        host_tokens = loop.read_back(lanes.dev_tokens, "decode", newest=True)
         lanes.tokens = host_tokens.copy()
         slots = list(lanes.requests)
         if self._lane_history is not None:
@@ -1268,6 +1269,7 @@ class GPT2Family(ServingFamily):
         full, win, kfull, kwin = self._lane_classes()
         pt, qmode = pool.page_tokens, self._qmode
         got = []                        # (class mask, (oracle, accepted))
+        loop.launched("decode")
         with guard:
             if np.any(full):
                 if qmode is not None:
@@ -1293,11 +1295,12 @@ class GPT2Family(ServingFamily):
         # the step's single deliberate sync: the emit loop needs the
         # oracle tokens and per-lane acceptance counts (one tuple read
         # even when several class programs ran)
-        host = jax.device_get(tuple(out for _, out in got))  # jaxlint: disable=JL002(one explicit host read per step)
+        host = loop.read_back(tuple(out for _, out in got), "decode",
+                              newest=True)
         oracle, accepted = host[0]
         if len(got) > 1:
             # overlay each later class's lanes onto the first's result
-            # (every active lane is in exactly one class); device_get
+            # (every active lane is in exactly one class); the read-back
             # already landed host numpy — no copies here
             oracle = oracle.copy()
             accepted = accepted.copy()
@@ -1479,11 +1482,13 @@ class GPT2Family(ServingFamily):
                 for i, (_, reuse, entry, _) in enumerate(plan) if reuse > 0])
 
         t0 = time.monotonic()
+        loop.launched("prefill")
         k, v, first = self._run_prefill(impl, init_k, init_v,
                                         self._put_host(ids),
                                         self._put_host(starts),
                                         self._put_host(lens))
-        first_host = np.asarray(first)             # sync: TTFT endpoint
+        # sync: TTFT endpoint
+        first_host = loop.read_back(first, "prefill", newest=True)
         prefill_s = time.monotonic() - t0
         loop.prefill_ran()
         # every row of the bucket runs, whatever the group's size
@@ -1509,7 +1514,7 @@ class GPT2Family(ServingFamily):
         # settle the queued lane installs here so they are accounted to
         # admission, not silently absorbed into the next decode step's
         # measured latency
-        pool.k.block_until_ready()
+        loop.read_back(pool.k, "prefill", newest=True, fetch=False)
         ispan.__exit__(None, None, None)
         pspan.__exit__(None, None, None)
         return len(plan), retired
@@ -1607,6 +1612,7 @@ class GPT2Family(ServingFamily):
         if st.pos == st.reuse:                     # the first chunk
             metrics.record_queue_wait(t0 - req.submit_time)
         with cspan:
+            loop.launched("prefill")
             st.k, st.v, first = self._run_prefill(
                 impl, st.k, st.v, self._put_host(ids),
                 self._put_host(np.asarray([st.pos], np.int32)),
@@ -1620,7 +1626,8 @@ class GPT2Family(ServingFamily):
             st.prefill_s += now - t0
             metrics.admit_time_s += now - top
             return now
-        first_tok = int(np.asarray(first)[0])      # sync: TTFT endpoint
+        # sync: TTFT endpoint
+        first_tok = int(loop.read_back(first, "prefill", newest=True)[0])
         st.prefill_s += time.monotonic() - t0
         now = time.monotonic()
         metrics.admit_time_s += now - top
@@ -1679,8 +1686,10 @@ class GPT2Family(ServingFamily):
         # L >= 2 layers the backends' hidden states (hence deep-layer
         # KV) differ in low bits, so cross-backend seeding would break
         # the per-backend bitwise oracle
-        pk = np.asarray(k[:, lane, :, :n])
-        pv = np.asarray(v[:, lane, :, :n])
+        # a copy, not a program's output: the helper's wait and span, but
+        # no read in the prefill reads' mean
+        pk, pv = self.loop.read_back(
+            (k[:, lane, :, :n], v[:, lane, :, :n]), "prefix_kv", newest=True)
         if self.loop.pool.kv_cache_dtype == "int8":
             pk, k_scale = quantize_kv_np(pk)
             pv, v_scale = quantize_kv_np(pv)

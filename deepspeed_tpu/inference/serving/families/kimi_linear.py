@@ -109,6 +109,7 @@ class KimiLinearFamily(SlotStateFamily):
         if st.pos == 0:
             loop.metrics.record_queue_wait(t0 - req.submit_time)
         with cspan:
+            loop.launched("prefill")
             pool.state, first, self.last_prefill_logits = (
                 self.prefill_program(
                     loop.params, pool.state, *jax.device_put(
@@ -122,7 +123,8 @@ class KimiLinearFamily(SlotStateFamily):
                 self.prefill_sentinel.check()
             # the one read-back of a chunk, and only of a chunk that ends
             # a prompt: the first token is the TTFT endpoint
-            first_host = int(np.asarray(first)[0]) if ends else None
+            first_host = (int(loop.read_back(first, "prefill", newest=True)[0])
+                          if ends else None)
         now = time.monotonic()
         loop.prefill_ran()
         stats["prefill_chunks"] += 1

@@ -207,6 +207,7 @@ class SlotStateFamily(ServingFamily):
         pool, lanes = loop.pool, loop.lanes
         # whose step this is: a slot may change hands before it is read
         riders = {slot: req.id for slot, req in lanes.requests.items()}
+        loop.launched("decode")
         with guard:
             (pool.state, lanes.dev_tokens, lanes.dev_positions,
              self.last_logits, moe) = self.decode_program(
@@ -223,7 +224,7 @@ class SlotStateFamily(ServingFamily):
         # the step's single deliberate sync, on the step BEFORE the one just
         # dispatched: its tokens and, in the same transfer, the three
         # integers of its expert layers
-        host_tokens, moe = jax.device_get(before[:2])  # jaxlint: disable=JL002(one explicit host read per step)
+        host_tokens, moe = loop.read_back(before[:2], "decode", newest=False)
         loop.metrics.record_moe(self.cfg.n_moe_layers, *moe.tolist())
         loop.metrics.record_state_pool(
             pool.slots_in_use, pool.pages_in_use, pool.slot_bytes(),
@@ -336,6 +337,7 @@ class RowPrefillFamily(SlotStateFamily):
             if st.pos == 0:
                 loop.metrics.record_queue_wait(t0 - st.req.submit_time)
         with cspan:
+            loop.launched("prefill")
             pool.state, first, self.last_prefill_logits = (
                 self.prefill_program(
                     loop.params, pool.state,
@@ -346,7 +348,8 @@ class RowPrefillFamily(SlotStateFamily):
                 self.prefill_sentinel.check()
             # the one read-back of a call, and only of a call that ends a
             # prompt: the first tokens are the TTFT endpoints
-            first_host = np.asarray(first) if any(ends) else None
+            first_host = (loop.read_back(first, "prefill", newest=True)
+                          if any(ends) else None)
         now = time.monotonic()
         loop.prefill_ran()
         stats["prefill_chunks"] += 1

@@ -11,7 +11,17 @@ configured the recorder is still useful as a cheap in-process stats
 object (``snapshot()``).
 """
 
+from bisect import bisect_left
 from collections import deque
+
+# Upper edges, in microseconds, of the buckets the plain decode reads are
+# counted in (``record_read``): a quarter of a millisecond doubling to 512
+# ms, and one bucket above. A fixed list: nothing grows with the reads.
+READ_EDGES_US = tuple(250 * 2 ** i for i in range(12))
+_READ_EDGES_S = tuple(us * 1e-6 for us in READ_EDGES_US)
+READ_BUCKETS = tuple(str(us) for us in READ_EDGES_US) + ("inf",)
+_READ_KEYS = tuple((f"decode_reads_le_us_{b}", f"decode_read_s_le_us_{b}")
+                   for b in READ_BUCKETS)
 
 # TTFT percentile window: newest samples win once full (a long-running
 # server's p95 should describe current traffic, not hour-old compiles).
@@ -57,6 +67,30 @@ class ServingMetrics:
         self.loop_busy_s = 0.0
         self.decode_host_s = 0.0
         self.admit_time_s = 0.0
+        # the loop's waits for the device, on the stamps of the one helper
+        # every blocking read goes through (``ServingEngine.launched`` /
+        # ``read_back``): decode_dispatch_s from ``launched("decode")`` to
+        # the read's start (or to the call's end where the call reads
+        # nothing), <kind>_reads and <kind>_read_wait_s the blocking reads
+        # themselves, by the kind of program, "decode" or "prefill". A
+        # decode read that had a prefill program's device time inside its
+        # wait is in the totals only; the others, the plain reads, fall
+        # each into one bucket of ``READ_EDGES_US`` by their length
+        # (``_plain_reads`` counts, ``_plain_read_s`` seconds), so the
+        # reads behind a prefill are the totals less the buckets' sums.
+        # dry_after_<kind>_s: from the return of a read after which nothing
+        # dispatched is left to run to the next ``launched``, by the kind
+        # of the read that began the spell
+        self.decode_dispatch_s = 0.0
+        self.decode_reads = 0
+        self.decode_read_wait_s = 0.0
+        self.prefill_reads = 0
+        self.prefill_read_wait_s = 0.0
+        self._plain_reads = [0] * len(READ_BUCKETS)
+        self._plain_read_s = [0.0] * len(READ_BUCKETS)
+        self.dry_after_decode_s = 0.0
+        self.dry_after_prefill_s = 0.0
+        self.dry_spells_after_prefill = 0
         # submit() to prefill dispatch, per admitted request
         self.queue_wait_s = 0.0
         self.queue_waits = 0
@@ -230,6 +264,32 @@ class ServingMetrics:
         decode step ran)."""
         self.loop_busy_s += busy_s
         self.decode_host_s += decode_host_s
+
+    def record_read(self, kind, wait_s, behind_prefill=False):
+        """One blocking read of the loop thread: ``wait_s`` from its start
+        to its return. A decode read whose wait held a prefill program
+        (``behind_prefill``) stays out of the buckets. A read of another
+        kind than a program's output (the prefix cache's copy of a
+        prompt's K/V) is counted nowhere."""
+        if kind == "prefill":
+            self.prefill_reads += 1
+            self.prefill_read_wait_s += wait_s
+        elif kind == "decode":
+            self.decode_reads += 1
+            self.decode_read_wait_s += wait_s
+            if not behind_prefill:
+                b = bisect_left(_READ_EDGES_S, wait_s)
+                self._plain_reads[b] += 1
+                self._plain_read_s[b] += wait_s
+
+    def record_dry_spell(self, kind, dry_s):
+        """The loop knew the device had no model program queued for
+        ``dry_s`` seconds after a read of ``kind``."""
+        if kind == "decode":
+            self.dry_after_decode_s += dry_s
+        else:
+            self.dry_spells_after_prefill += 1
+            self.dry_after_prefill_s += dry_s
 
     def record_prefix_lookup(self, hit):
         if hit:
@@ -412,6 +472,15 @@ class ServingMetrics:
             "loop_busy_s": self.loop_busy_s,
             "decode_host_s": self.decode_host_s,
             "admit_time_s": self.admit_time_s,
+            # the loop's waits for the device (docs/observability.md)
+            "decode_dispatch_s": self.decode_dispatch_s,
+            "decode_reads": self.decode_reads,
+            "decode_read_wait_s": self.decode_read_wait_s,
+            "prefill_reads": self.prefill_reads,
+            "prefill_read_wait_s": self.prefill_read_wait_s,
+            "dry_after_decode_s": self.dry_after_decode_s,
+            "dry_after_prefill_s": self.dry_after_prefill_s,
+            "dry_spells_after_prefill": self.dry_spells_after_prefill,
             "queue_wait_s": self.queue_wait_s,
             "queue_waits": self.queue_waits,
             "token_gaps": self.token_gaps,
@@ -467,6 +536,11 @@ class ServingMetrics:
             rss = self._host_rss_mb_fn()
             if rss is not None:
                 snap["host_rss_mb"] = rss
+        # the plain decode reads by length, a key an upper edge
+        for (count_key, seconds_key), n, secs in zip(
+                _READ_KEYS, self._plain_reads, self._plain_read_s):
+            snap[count_key] = n
+            snap[seconds_key] = secs
         # flattened per-bucket admitted-prompt-length histogram: numeric
         # keys so export_to's gauge filter picks them up unchanged
         for bucket in sorted(self._admitted_by_bucket):

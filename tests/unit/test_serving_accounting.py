@@ -9,6 +9,7 @@ and the price of the accounting (clock reads per iteration) is pinned.
 
 import glob
 import threading
+import types
 
 import pytest
 
@@ -19,6 +20,11 @@ from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
 from deepspeed_tpu.inference.serving import engine as engine_mod
 from deepspeed_tpu.inference.serving.families import gpt2 as gpt2_mod
+from deepspeed_tpu.inference.serving.families import (
+    kimi_linear as kimi_mod,
+    slot_state as slot_state_mod,
+)
+from deepspeed_tpu.inference.serving import metrics as metrics_mod
 from deepspeed_tpu.inference.serving import scheduler as scheduler_mod
 from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2
 from deepspeed_tpu.telemetry import trace as trace_mod
@@ -29,6 +35,14 @@ from deepspeed_tpu.telemetry.trace import NULL_SPAN
 # prefill_s and the install stamp more in one that also admits a batch
 PARENT_READS_DECODE = 4
 PARENT_READS_ADMIT_AND_DECODE = 7
+# PR 25's accounts: the end of the iteration, and the admission's end
+ACCOUNT_READS_DECODE = 1
+ACCOUNT_READS_ADMIT_AND_DECODE = 2
+# the loop's account of its waits for the device (PR 37): ``launched`` reads
+# the clock once and ``read_back`` twice, so three reads a decode call, three
+# a prefill call and two more for the settle behind a batch's installs
+WAIT_READS_DECODE = 3
+WAIT_READS_ADMIT_AND_DECODE = 3 + 3 + 2
 
 PREFILL_S, DECODE_S, EMIT_S = 0.2, 0.05, 0.001
 
@@ -126,12 +140,15 @@ def test_counters_on_a_scripted_clock(model, clock):
     assert m.queue_wait_s == pytest.approx(2.5)
     assert m.prefill_positions_run == 3 * 4        # all rows x bucket 4
     assert m.prefill_tokens == 3
-    assert reads_admit <= PARENT_READS_ADMIT_AND_DECODE + 2
+    assert reads_admit == (PARENT_READS_ADMIT_AND_DECODE
+                           + ACCOUNT_READS_ADMIT_AND_DECODE
+                           + WAIT_READS_ADMIT_AND_DECODE)
 
     stats, reads_decode = _step(eng, clock)
     assert stats == {"admitted": 0, "decoded": 1, "retired": 0,
                      "prefill_chunks": 0}
-    assert reads_decode <= PARENT_READS_DECODE + 2
+    assert reads_decode == (PARENT_READS_DECODE + ACCOUNT_READS_DECODE
+                            + WAIT_READS_DECODE)
     assert m.stalled_gaps == 0 and m.token_gaps == 2
 
     # B arrives while A decodes: its prefill (bucket 8) stalls A's next gap
@@ -204,6 +221,260 @@ def test_chunked_prefill_counts_its_chunks(model, clock):
     assert m.loop_busy_s >= m.decode_time_s + m.prefill_time_s
 
 
+# -- the loop's waits for the device (``launched`` / ``read_back``) ---------
+
+DISPATCH_S = {"decode": 0.002, "prefill": 0.003}
+READ_S = {"decode": 0.02, "prefill": 0.2}
+SETTLE_S = 0.004
+
+
+@pytest.fixture
+def waits(monkeypatch):
+    """The scripted clock with the loop's waits scripted apart: calling a
+    program takes ``DISPATCH_S`` of its kind (the device is asynchronous: a
+    call returns when the program is queued), a blocking read takes what
+    ``script.read_s`` holds for it (``READ_S`` of the kind of the program
+    dispatched last unless a test says otherwise) and the settle
+    ``SETTLE_S``. ``script.reads`` lists the reads made, in order."""
+    c = Clock()
+    for mod in (engine_mod, gpt2_mod, scheduler_mod, slot_state_mod,
+                kimi_mod):
+        monkeypatch.setattr(mod, "time", c)
+    script = types.SimpleNamespace(clock=c, read_s=[], reads=[], kind=None)
+
+    def program(fn, kind):
+        def call(*a, **k):
+            c.advance(DISPATCH_S[kind])
+            script.kind = kind
+            return fn(*a, **k)
+        return call
+
+    def device_get(tree):
+        seconds = script.read_s.pop(0) if script.read_s \
+            else READ_S[script.kind]
+        script.reads.append(seconds)
+        c.advance(seconds)
+        return jax.device_get(tree)
+
+    def block_until_ready(tree):
+        c.advance(SETTLE_S)
+        return jax.block_until_ready(tree)
+
+    # the engine's ``jax``: the two calls that wait, scripted
+    monkeypatch.setattr(engine_mod, "jax", types.SimpleNamespace(
+        device_get=device_get, block_until_ready=block_until_ready,
+        profiler=jax.profiler))
+    monkeypatch.setattr(gpt2_mod, "_prefill_batch_jit",
+                        program(gpt2_mod._prefill_batch_jit, "prefill"))
+    monkeypatch.setattr(gpt2_mod, "_decode_step_jit",
+                        program(gpt2_mod._decode_step_jit, "decode"))
+    family = kimi_mod.KimiLinearFamily
+    monkeypatch.setattr(family, "prefill_program", staticmethod(
+        program(family.prefill_program, "prefill")))
+    monkeypatch.setattr(family, "decode_program", staticmethod(
+        program(family.decode_program, "decode")))
+    return script
+
+
+def _bucket(seconds):
+    """The key suffix of the bucket a plain read of ``seconds`` is in."""
+    for edge in metrics_mod.READ_EDGES_US:
+        if seconds * 1e6 <= edge:
+            return str(edge)
+    return "inf"
+
+
+def _behind_prefill(m):
+    """``(reads, seconds)`` of the decode reads that stood behind a prefill
+    program, as an operator derives them: the totals less the buckets."""
+    snap = m.snapshot()
+    return (snap["decode_reads"] - sum(snap[f"decode_reads_le_us_{b}"]
+                                       for b in metrics_mod.READ_BUCKETS),
+            snap["decode_read_wait_s"] - sum(snap[f"decode_read_s_le_us_{b}"]
+                                             for b in metrics_mod.READ_BUCKETS))
+
+
+def test_a_synchronous_steps_waits_on_a_scripted_clock(model, waits):
+    """GPT-2's step: every read is of the program dispatched last, so each
+    opens a dry spell that the next launch of either kind closes."""
+    eng = _engine(model)
+    clock, m = waits.clock, eng.metrics
+
+    def on_token(_rid, _tok):
+        clock.advance(EMIT_S)
+
+    a = eng.submit([1, 2, 3], max_new_tokens=6, stream_cb=on_token)
+    eng.step()                      # admits A and decodes its second token
+    # the first tokens' read, then the settle behind the lane installs
+    assert m.prefill_reads == 2
+    assert m.prefill_read_wait_s == pytest.approx(READ_S["prefill"] + SETTLE_S)
+    # first-token read to the decode launch: A's callback and the settle
+    assert m.dry_spells_after_prefill == 1
+    assert m.dry_after_prefill_s == pytest.approx(EMIT_S + SETTLE_S)
+    assert m.decode_dispatch_s == pytest.approx(DISPATCH_S["decode"])
+    assert m.decode_reads == 1 and _behind_prefill(m)[0] == 0
+    assert m.dry_after_decode_s == 0.0  # the decode read's spell is open
+
+    clock.advance(0.5)              # the caller pauses: the device is dry
+    eng.step()
+    # decode read to the next launch: one callback and the caller's pause
+    assert m.dry_after_decode_s == pytest.approx(EMIT_S + 0.5)
+
+    waits.read_s.append(0.150)      # one read comes back late
+    eng.step()
+    eng.drain(max_steps=20)
+    assert a.result(timeout=1)
+    steps = m.decode_steps
+    assert steps == 5 and m.decode_reads == steps
+    assert m.decode_dispatch_s == pytest.approx(steps * DISPATCH_S["decode"])
+    assert m.decode_read_wait_s == pytest.approx(
+        (steps - 1) * READ_S["decode"] + 0.150)
+    # dispatch and read are the whole call here: the family's tail (a copy,
+    # a tolist) takes no scripted time
+    assert m.decode_dispatch_s + m.decode_read_wait_s <= m.decode_time_s \
+        + 1e-9
+    assert m.decode_time_s == pytest.approx(
+        m.decode_dispatch_s + m.decode_read_wait_s)
+    assert m.prefill_time_s == pytest.approx(
+        DISPATCH_S["prefill"] + READ_S["prefill"])
+    snap = m.snapshot()
+    assert _bucket(READ_S["decode"]) == "32000" and _bucket(0.150) == "256000"
+    assert snap["decode_reads_le_us_32000"] == steps - 1
+    assert snap["decode_read_s_le_us_32000"] == pytest.approx(
+        (steps - 1) * READ_S["decode"])
+    assert snap["decode_reads_le_us_256000"] == 1
+    assert snap["decode_read_s_le_us_256000"] == pytest.approx(0.150)
+    assert sum(snap[f"decode_reads_le_us_{b}"]
+               for b in metrics_mod.READ_BUCKETS) == steps
+    # the last spell ends where the loop runs out of work, not at the next
+    # request: an idle loop's device is dry for want of requests
+    eng.step()
+    dry = m.dry_after_decode_s
+    assert dry == pytest.approx(0.5 + steps * EMIT_S)
+    clock.advance(30.0)
+    eng.step()
+    b = eng.submit([4, 5], max_new_tokens=2)
+    eng.drain(max_steps=10)
+    assert b.result(timeout=1)
+    assert m.dry_after_decode_s == pytest.approx(dry)
+    for key in ("decode_dispatch_s", "decode_reads", "decode_read_wait_s",
+                "prefill_reads", "prefill_read_wait_s", "dry_after_decode_s",
+                "dry_after_prefill_s", "dry_spells_after_prefill"):
+        assert m.snapshot()[key] == getattr(m, key), key
+
+
+def test_a_chunk_that_is_not_read_back_stands_before_the_next_decode_read(
+        model, waits):
+    """GPT-2's chunked prefill reads only its last chunk back: the decode
+    read behind a mid chunk holds that chunk's device time, and stays out
+    of the buckets."""
+    eng = _engine(model, prefill_chunk_tokens=4, prompt_buckets=(4, 8, 16))
+    m = eng.metrics
+    a = eng.submit([1, 2, 3], max_new_tokens=8)
+    eng.step()
+    b = eng.submit(list(range(1, 11)), max_new_tokens=2)   # three chunks
+    eng.step()                  # reserves the chunk lane, decodes A
+    assert _behind_prefill(m)[0] == 0
+    waits.read_s.extend([0.07, 0.07])
+    eng.step()                  # chunk 1, not read; A's read stands behind it
+    eng.step()                  # chunk 2, likewise
+    assert _behind_prefill(m) == (2, pytest.approx(0.14))
+    eng.step()                  # chunk 3 is read back: nothing stands before
+    assert _behind_prefill(m)[0] == 2
+    eng.drain(max_steps=50)
+    assert a.result(timeout=1) and b.result(timeout=1)
+    snap = m.snapshot()
+    assert sum(snap[f"decode_reads_le_us_{e}"]
+               for e in metrics_mod.READ_BUCKETS) == m.decode_reads - 2
+    assert snap["decode_reads_le_us_128000"] == 0
+
+
+def test_the_prefix_caches_copy_is_no_prefill_read(model, waits):
+    """With the prefix cache on, an inserted prompt's K/V is copied to the
+    host through the helper (its wait, its span), but it is no program's
+    output: the prefill reads' mean stays what the loop stood behind a
+    prefill program, whatever the cache's hit rate."""
+    eng = _engine(model, prefix_cache_mb=4.0)
+    m = eng.metrics
+    waits.read_s.extend([READ_S["prefill"], 0.05])   # first tokens, the copy
+    a = eng.submit([1, 2, 3], max_new_tokens=2)
+    eng.step()
+    assert waits.reads[:2] == [READ_S["prefill"], 0.05]
+    assert eng.prefix_cache.stats()["entries"] == 1
+    # the first tokens' read and the settle, as with the cache off
+    assert m.prefill_reads == 2
+    assert m.prefill_read_wait_s == pytest.approx(READ_S["prefill"] + SETTLE_S)
+    # the copy falls in the spell the first tokens' read began
+    assert m.dry_spells_after_prefill == 1
+    assert m.dry_after_prefill_s == pytest.approx(0.05 + SETTLE_S)
+    eng.drain(max_steps=10)
+    assert a.result(timeout=1)
+
+
+def test_a_step_in_flights_waits_on_a_scripted_clock(waits):
+    """A family that keeps a decode step in flight (Kimi-Linear, tiny): the
+    first call reads nothing, a read is of the step BEFORE the one just
+    dispatched, a prefill call that ends no prompt is not read back and its
+    device time is inside the read of the step dispatched behind it, and a
+    first-token read opens a dry spell that the next launch closes."""
+    from tests.unit import test_kimi_linear as tiny
+
+    _, params, mcfg = tiny.make()
+    eng = tiny.engine(params, mcfg)
+    clock, m = waits.clock, eng.metrics
+    chunk = tiny.CHUNK
+
+    def on_token(_rid, _tok):
+        clock.advance(EMIT_S)
+
+    a = eng.submit(list(range(1, 11)), max_new_tokens=12, stream_cb=on_token)
+    eng.step()                      # admits A: a slot, no program yet
+    assert m.dry_spells_after_prefill == m.prefill_reads == 0
+    eng.step()                      # A's one chunk ends its prompt; step 1
+    assert m.prefill_reads == 1
+    assert m.prefill_read_wait_s == pytest.approx(READ_S["prefill"])
+    # the first token's read to the launch of step 1: A's callback
+    assert m.dry_spells_after_prefill == 1
+    assert m.dry_after_prefill_s == pytest.approx(EMIT_S)
+    # step 1 is dispatched and nothing is read: its dispatch ends the call
+    assert m.decode_steps == 1 and m.decode_reads == 0
+    assert m.decode_dispatch_s == pytest.approx(DISPATCH_S["decode"])
+    assert m.decode_time_s == pytest.approx(DISPATCH_S["decode"])
+
+    # B's prompt takes three chunks: two are not read back
+    b = eng.submit(list(range(1, 2 * chunk + 9)), max_new_tokens=3)
+    eng.step()                      # admits B; step 2, reads step 1
+    assert m.decode_reads == 1
+    eng.step()                      # chunk 1 (no read); step 3, reads step 2
+    assert m.prefill_reads == 1 and m.prefill_chunks == 2
+    # step 2 was queued before the chunk: its read is a plain one
+    assert m.decode_reads == 2 and _behind_prefill(m)[0] == 0
+    waits.read_s.append(0.26)
+    eng.step()                      # chunk 2 (no read); step 4, reads step 3
+    # step 3 was queued behind chunk 1, which nothing had read back
+    assert _behind_prefill(m) == (1, pytest.approx(0.26))
+    eng.step()                      # chunk 3 ends B's prompt: read back
+    assert m.prefill_reads == 2
+    # everything queued before that read has run: step 4's read is plain
+    assert m.decode_reads == 4 and _behind_prefill(m)[0] == 1
+    # no read but a first token's left the device dry: a step was in flight
+    assert m.dry_spells_after_prefill == 2
+    assert m.dry_after_decode_s == 0.0
+    eng.drain(max_steps=40)
+    assert a.result(timeout=1) and b.result(timeout=1)
+    snap = m.snapshot()
+    plain = m.decode_reads - 1
+    assert snap["decode_reads_le_us_32000"] == plain
+    assert snap["decode_reads_le_us_512000"] == 0      # the 0.26 s stayed out
+    assert m.decode_read_wait_s == pytest.approx(
+        plain * READ_S["decode"] + 0.26)
+    assert m.decode_dispatch_s == pytest.approx(
+        m.decode_steps * DISPATCH_S["decode"])
+    # the call beyond its dispatch and its read: the family's host tail
+    assert m.decode_dispatch_s + m.decode_read_wait_s <= m.decode_time_s \
+        + 1e-9
+
+
 def test_accounting_grows_no_container(model, clock):
     """Nothing the counters keep grows with the tokens served."""
     eng = _engine(model)
@@ -221,8 +492,15 @@ def test_accounting_grows_no_container(model, clock):
     after["_ttft_window"] -= 1      # one sample per REQUEST, bounded
     assert after == before
     for key in ("loop_busy_s", "decode_host_s", "admit_time_s",
-                "queue_wait_s", "token_gap_s", "stalled_gap_s"):
+                "queue_wait_s", "token_gap_s", "stalled_gap_s",
+                "decode_dispatch_s", "decode_read_wait_s",
+                "prefill_read_wait_s", "dry_after_decode_s",
+                "dry_after_prefill_s"):
         assert type(getattr(eng.metrics, key)) is float, key
+    # the reads' buckets are a fixed list, filled and not lengthened
+    n = len(metrics_mod.READ_BUCKETS)
+    assert before["_plain_reads"] == before["_plain_read_s"] == n == 13
+    assert sum(eng.metrics._plain_reads) == eng.metrics.decode_reads > 20
 
 
 def test_disarmed_tracer_allocates_no_span(model, monkeypatch):
@@ -254,10 +532,52 @@ def test_disarmed_tracer_allocates_no_span(model, monkeypatch):
     names = {e["name"] for e in tracer.events()}
     assert {"serving/admission", "serving/prefill_batch", "serving/install",
             "serving/upload_lanes", "serving/decode_step",
-            "serving/emit"} <= names
+            "serving/read_back", "serving/emit"} <= names
     # the same spans went to the annotation factory, scalars only
     assert {"serving/install", "serving/emit"} <= set(annotated)
     assert len(made) == len(annotated)
+
+
+def test_every_request_ends_in_one_of_two_instants(model, clock):
+    """``serving/retire`` for a request that ran to its end,
+    ``serving/retire_timeout`` with the phase its deadline passed in for the
+    others: queued (never admitted) and decoding."""
+    telemetry.configure(True)
+    eng = _engine(model, max_slots=1)
+    done = eng.submit([1, 2, 3], max_new_tokens=2)
+    waiting = eng.submit([4, 5], max_new_tokens=2, timeout_s=0.01)
+    eng.drain(max_steps=10)
+    slow = eng.submit([6, 7], max_new_tokens=8, timeout_s=1.0)
+    eng.step()
+    clock.advance(2.0)
+    eng.drain(max_steps=10)
+    assert done.result(timeout=1)
+    for fut in (waiting, slow):
+        with pytest.raises(scheduler_mod.RequestTimeoutError):
+            fut.result(timeout=1)
+    ends = [(e["name"], e["args"]) for e in telemetry.get_tracer().events()
+            if e["name"].startswith("serving/retire")]
+    assert [n for n, _ in ends].count("serving/retire") == 1
+    timeouts = {a["phase"]: a for n, a in ends
+                if n == "serving/retire_timeout"}
+    assert sorted(timeouts) == ["decoding", "queued"]
+    assert timeouts["queued"]["tokens"] == 0
+    assert timeouts["decoding"]["tokens"] >= 1
+    assert eng.metrics.requests_timed_out == 2
+
+
+def test_a_corrupt_spill_entry_leaves_an_instant(model):
+    """The spill tier's listener: counted, and one ``serving/spill_corrupt``
+    instant with the running total when the tracer is armed."""
+    eng = _engine(model)
+    eng._on_spill_event("spill_corrupt")        # disarmed: counted only
+    assert len(telemetry.get_tracer()) == 0
+    telemetry.configure(True)
+    eng._on_spill_event("spill_corrupt")
+    ev, = [e for e in telemetry.get_tracer().events()
+           if e["name"] == "serving/spill_corrupt"]
+    assert ev["ph"] == "i" and ev["args"] == {"total": 2}
+    assert eng.metrics.snapshot()["spill_corrupt_total"] == 2
 
 
 def test_span_hands_scalars_to_the_annotation():
@@ -462,3 +782,12 @@ def test_armed_spans_land_in_the_profiler_trace(model, tmpdir):
     # scalar arguments ride along; request-id lists do not
     _, stats = spans["serving/decode_step"][0]
     assert stats.get("active") == 1 and "request_ids" not in stats
+    # each decode step's blocking read lies inside the step's span, and the
+    # prefill's two (first tokens, settle) outside every decode step
+    steps = sorted(span for span, _ in spans["serving/decode_step"])
+    reads = {"decode": [], "prefill": []}
+    for span, stats in spans["serving/read_back"]:
+        reads[stats["kind"]].append(span)
+    assert len(reads["decode"]) == 3 and len(reads["prefill"]) == 2
+    for (start, end), (r_start, r_end) in zip(steps, sorted(reads["decode"])):
+        assert start <= r_start <= r_end <= end
