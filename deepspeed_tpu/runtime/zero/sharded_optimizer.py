@@ -13,7 +13,8 @@ Capability parity with the reference's ``FP16_DeepSpeedZeroOptimizer_Stage1``
   constraint (stage 2 → XLA emits reduce-scatter over ICI; stage 1 keeps the
   all-reduce + local slice); the inner optimizer (Adam/LAMB) runs elementwise on
   the local shard; the updated master re-assembles via XLA's all-gather when the
-  replicated params are rebuilt.
+  replicated params are rebuilt (an all-gather only because the flat vector is
+  padded to whole lane tiles a rank: ``flat_pad_multiple``).
 - Optimizer state (m, v) lives only on the shard — the stage-1/2 memory win.
 - ``cpu_offload=True`` (ZeRO-Offload, reference stage2.py:743-900,1416-1427)
   runs the inner step on host over pinned numpy buffers via
@@ -63,6 +64,33 @@ DEFAULT_BUCKET_SIZE = 500000000
 # honest — offload traffic is explicit and greppable, never implicit.
 OFFLOAD_D2H = register_allowed_transfer("zero/offload_d2h")
 OFFLOAD_H2D = register_allowed_transfer("zero/offload_h2d")
+
+# Trace-time gauges of the stage-1/2 parameter gather, recorded when
+# ``update()`` is traced: the bytes a rank receives a step and the element
+# size that travels (2 where the shard is cast to bf16 ahead of the gather).
+PARAM_GATHER_BYTES = "ZeRO/param_gather_bytes"
+PARAM_GATHER_ITEMSIZE = "ZeRO/param_gather_itemsize"
+
+# lanes of a TPU vector tile's minor dimension
+LANE_TILE = 128
+
+
+def flat_pad_multiple(dp):
+    """The multiple ZeRO's flat vector is padded to: ``dp`` whole 128-lane
+    tiles, so that a rank's shard is a whole number of lane tiles.
+
+    A shard that is not, the TPU compiler does not gather: it writes each
+    rank's shard into a zero-filled buffer of the whole vector's length and
+    all-reduces that (twice an all-gather's bytes on the wire, plus the fill
+    and the update-slice). Compiled for four described v5e chips, BERT-large's
+    flat vector padded to ``dp`` (336,232,260 elements, shards of 84,058,065)
+    gathers as ``all-reduce bf16[336232260]``; padded to ``dp x 128``
+    (336,232,448, shards of 84,058,112 = 128 x 656,704) as ``all-gather
+    bf16[336232448]``. Shards that are multiples of 2, 8, 16 or 64 and not of
+    128 still all-reduce; 128 and not 256 gathers. The rule reads ``dp`` and
+    nothing else."""
+    return dp * LANE_TILE
+
 
 # Edge-triggered, per process: flips on the FIRST grad leaf whose async D2H
 # could not be kicked, so benches on backends without copy_to_host_async
@@ -445,7 +473,7 @@ class ZeroShardedOptimizer:
                 self._param_shardings = zero3_param_shardings(self.mesh, params)
         flat = flatten_dense_tensors(params, jnp.float32)
         self._numel = int(flat.shape[0])
-        flat, _ = pad_to_multiple(flat, self.dp)
+        flat = self._pad_flat(flat)
         self._padded = int(flat.shape[0])
         if self.cpu_offload:
             # ZeRO-Offload: master AND optimizer state live on host only — no
@@ -471,8 +499,28 @@ class ZeroShardedOptimizer:
         parts = [jnp.full((n,), s, jnp.float32)
                  for n, s in zip(sizes, self._leaf_decay_scales)]
         mask = jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.float32)
-        mask, _ = pad_to_multiple(mask, self.dp)
+        mask = self._pad_flat(mask)
         return jax.lax.with_sharding_constraint(mask, self._shard_sharding())
+
+    def _pad_flat(self, flat):
+        """``flat`` zero-padded to the flat vector's length (whole lane
+        tiles a rank: ``flat_pad_multiple``)."""
+        return pad_to_multiple(flat, flat_pad_multiple(self.dp))[0]
+
+    def _note_param_gather(self, travels):
+        """Trace-time gauges of the stage-1/2 parameter gather: the bytes a
+        rank receives a step (the other ranks' shards) and the element size
+        that travels."""
+        itemsize = jnp.dtype(travels).itemsize
+        gauges = telemetry.get_registry()
+        gauges.gauge(
+            PARAM_GATHER_BYTES, help="bytes a rank receives a step in ZeRO-1/2's "
+            "parameter gather, as the last traced update() laid it out"
+        ).set((self.dp - 1) * (self._padded // self.dp) * itemsize)
+        gauges.gauge(
+            PARAM_GATHER_ITEMSIZE, help="bytes an element of ZeRO-1/2's "
+            "parameter gather travels as (2: cast to bf16 ahead of it)"
+        ).set(itemsize)
 
     # -- device path (jit-traceable) --------------------------------------
     def update(self, grads, opt_state, params, lr=None):
@@ -485,11 +533,20 @@ class ZeroShardedOptimizer:
         ``P('data')`` from travelling backwards through the concatenate and
         the accumulator into the carries of the model's backward loops,
         where it once made the loss reduce-scatter its kernel gradient once
-        a chunk."""
+        a chunk.
+
+        Stages 1/2 hand the parameters back whole on every rank: the updated
+        master, one shard a rank, is gathered once. Two things keep that one
+        all-gather of the parameters' own dtype and not an all-reduce of a
+        zero-padded copy of the vector, or a gather of float32: the flat
+        vector's length (``flat_pad_multiple``: whole lane tiles a rank) and
+        the cast ahead of the gather, pinned to the shard's layout
+        (``tests/unit/test_step_fusion.py`` reads both in the step compiled
+        for four described v5e chips)."""
         treedef, shapes, dtypes, _ = self._spec
 
         flat_grads = flatten_dense_tensors(grads, jnp.float32)
-        flat_grads, _ = pad_to_multiple(flat_grads, self.dp)
+        flat_grads = self._pad_flat(flat_grads)
         if self.stage >= 2 and self.reduce_scatter:
             # Stage 2: gradient partitioning — only the owner shard persists.
             flat_grads = jax.lax.with_sharding_constraint(flat_grads, self._shard_sharding())
@@ -500,7 +557,7 @@ class ZeroShardedOptimizer:
             # fp32 compute: derive the local master slice from the (fp32)
             # params — XLA materializes only this rank's shard transiently.
             master = flatten_dense_tensors(params, jnp.float32)
-            master, _ = pad_to_multiple(master, self.dp)
+            master = self._pad_flat(master)
             master = jax.lax.with_sharding_constraint(master, self._shard_sharding())
         if getattr(self.inner, "no_decay_names", None) and \
                 getattr(self.inner, "weight_decay", 0.0) != 0.0:
@@ -528,12 +585,23 @@ class ZeroShardedOptimizer:
                 jax.lax.with_sharding_constraint, new_params, self._param_shardings
             )
         else:
-            # Stages 1/2: XLA inserts the all-gather over ICI here (the
-            # reference's sharded sequential all_gather, stage2.py:1444-1477).
+            # Stages 1/2: the whole padded vector goes from one shard a rank
+            # to replicated, and XLA writes that as ONE all-gather over ICI
+            # (the reference's sharded sequential all_gather,
+            # stage2.py:1444-1477) because the shard is whole lane tiles
+            # (``flat_pad_multiple``; a ragged shard is all-reduced inside a
+            # zero-filled copy of the vector instead). Where every leaf has
+            # one dtype the shard is cast to it BEFORE it travels, pinned as
+            # a shard so the cast cannot slide behind the gather: bf16 moves
+            # half of float32's bytes, and the cast is elementwise, so every
+            # parameter gets the bits gather-then-cast gives. Mixed leaves
+            # gather in float32 and cast a leaf at a time.
+            travels = out_dtypes[0] if len(set(out_dtypes)) == 1 else jnp.float32
+            self._note_param_gather(travels)
+            shard = jax.lax.with_sharding_constraint(
+                new_master.astype(travels), self._shard_sharding())
             full = jax.lax.with_sharding_constraint(
-                new_master[: self._numel],
-                train_sharding(self.mesh, "zero/gathered")
-            )
+                shard, train_sharding(self.mesh, "zero/gathered"))
             new_params = unflatten_dense_tensors(full, treedef, shapes, out_dtypes)
         if not self.keep_master:
             new_master = jnp.zeros((0,), jnp.float32)

@@ -249,6 +249,78 @@ def test_zero_elastic_checkpoint_cross_dp(tmpdir, zero_stage, load_dp, variant):
     )
 
 
+def _moments(engine):
+    """Adam's moments as the engine's logical (unpadded) shards merge."""
+    shards = engine.optimizer.shard_state_dicts(engine.opt_state)
+    merged = [np.concatenate([np.atleast_1d(s["inner"][i]) for s in shards])
+              for i in range(len(shards[0]["inner"]))]
+    return [m for m in merged if m.shape[0] == shards[0]["numel"]]
+
+
+@pytest.mark.parametrize("save_rule,load_rule", [("dp", "dp_x_128"),
+                                                 ("dp_x_128", "dp")])
+@pytest.mark.parametrize("save_dp,load_dp", [(4, 4), (2, 4)])
+@pytest.mark.parametrize("variant", ["bf16", "offload"])
+def test_zero_checkpoint_crosses_the_flat_vectors_padding(
+        tmpdir, monkeypatch, save_rule, load_rule, save_dp, load_dp, variant):
+    """A checkpoint written while the flat vector was padded to ``dp`` alone
+    loads into today's layout (``dp`` whole lane tiles), and the reverse:
+    shards are cut at ``padded // dp`` and stored unpadded up to ``numel``,
+    so the padding is never in a file. SimpleModel's 544 elements under
+    ``dp`` 4 leave the last rank's shard wholly past ``numel`` today: it
+    stores an empty slice."""
+    import contextlib
+
+    from deepspeed_tpu.runtime.zero import sharded_optimizer as so
+
+    rules = {"dp": lambda dp: dp, "dp_x_128": so.flat_pad_multiple}
+    save_dir = str(tmpdir.join("ckpt"))
+
+    @contextlib.contextmanager
+    def padded_by(rule):
+        # read at init and whenever a step is traced
+        with monkeypatch.context() as m:
+            m.setattr(so, "flat_pad_multiple", rules[rule])
+            yield
+
+    with padded_by(save_rule):
+        engine = make_simple_engine(tmpdir, _cfg_dp(2, save_dp, variant))
+        _train_steps(engine, 3)
+    numel, multiple = engine.optimizer._numel, rules[save_rule](save_dp)
+    assert engine.optimizer._padded == -(-numel // multiple) * multiple
+    shards = engine.optimizer.shard_state_dicts(engine.opt_state)
+    assert sum(s["flat_master"].shape[0] for s in shards) == numel
+    if save_rule == "dp_x_128" and save_dp == 4:
+        assert shards[-1]["flat_master"].shape[0] == 0
+    engine.save_checkpoint(save_dir)
+    saved_params = jax.device_get(engine.params)
+    saved_master, saved_moments = _merged_master(engine), _moments(engine)
+    assert len(saved_moments) == 2
+
+    with padded_by(load_rule):
+        engine2 = make_simple_engine(                       # different init
+            tmpdir, _cfg_dp(2, load_dp, variant), seed=99)
+        tag, _ = engine2.load_checkpoint(save_dir)
+    assert tag is not None
+    assert engine2.optimizer._padded != engine.optimizer._padded
+    _tree_equal(engine2.params, saved_params)
+    np.testing.assert_array_equal(_merged_master(engine2), saved_master)
+    for got, want in zip(_moments(engine2), saved_moments):
+        np.testing.assert_array_equal(got, want)
+    if variant != "offload":
+        # whole tiles a rank, zeros behind numel
+        master = np.asarray(engine2.opt_state.flat_master)
+        assert master.shape[0] == engine2.optimizer._padded
+        assert not master[numel:].any()
+
+    with padded_by(save_rule):
+        l1 = _train_steps(engine, 3, seed=17)
+    with padded_by(load_rule):
+        l2 = _train_steps(engine2, 3, seed=17)
+    np.testing.assert_allclose(float(jax.device_get(l1)),
+                               float(jax.device_get(l2)), rtol=2e-3)
+
+
 def test_zero_checkpoint_shard_files(tmpdir):
     save_dir = str(tmpdir.join("ckpt"))
     engine = make_simple_engine(tmpdir, _cfg(zero_stage=2, fp16=True))

@@ -360,6 +360,31 @@ def test_zero2_flat_shard_stays_out_of_the_loss_loops_on_described_v5e(v5e_mesh_
     assert any(_collectives(lines) for lines in _loop_bodies(text))
 
 
+def test_zero2_parameter_gather_is_one_bf16_all_gather_on_described_v5e(v5e_mesh_of):
+    """Compiled for four described v5e chips, ZeRO-2's step rebuilds the
+    parameters with an all-gather of the bf16 shards of the padded flat
+    vector. A shard that is not whole 128-lane tiles (the flat vector padded
+    to ``dp`` alone: this model's numel is no multiple of 512) the TPU
+    compiler instead copies into a zero-filled vector of the whole length
+    and all-reduces: twice the bytes, and once the largest operation of
+    BERT-large's four-chip step."""
+    engine, stacked = _tiny_bert_zero2(250, 64, 2, bf16={"enabled": True})
+    text = _compiled_text(engine, *stacked, mesh=v5e_mesh_of(engine.mesh))
+    numel, padded, dp = (engine.optimizer._numel, engine.optimizer._padded,
+                         engine.optimizer.dp)
+    assert numel % (dp * 128), "the model must need the padding"
+    kinds = _collective_kinds(text)
+    whole = (f"[{padded}]", f"[{dp},1,{padded // dp}]")
+    gathers = [result for result, opcode, _ in kinds
+               if opcode == "all-gather" and any(w in result for w in whole)]
+    assert len(gathers) == 1 and gathers[0].startswith("bf16["), kinds
+    # nothing of the flat vector's length is all-reduced, at the new padding
+    # or at the old (numel rounded up to dp)
+    flat = {f"[{n}]" for n in (numel, -(-numel // dp) * dp, padded)}
+    assert not [k for k in kinds if k[1] == "all-reduce"
+                and any(n in k[0] for n in flat)], kinds
+
+
 def _collective_kinds(text):
     """Every collective of a compiled HLO text as (result, opcode, replica
     groups), sorted: what the program sends, whatever its operands are
@@ -504,6 +529,108 @@ def test_two_microbatches_give_the_doubled_batchs_update(case):
     np.testing.assert_allclose(loss_two, loss_one, rtol=1e-5)
     for a, b in zip(_leaves(one.params), _leaves(two.params)):
         np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# ZeRO's parameter gather: whole lane tiles a rank, cast before it travels
+# ---------------------------------------------------------------------------
+
+def _odd_engine(stage, dp, precision):
+    """An engine over a two-layer MLP of 63 parameters (an odd numel) whose
+    loss counts only rows with a nonzero input, fed batches with ONE such
+    row: every sum over rows or devices then adds zeros to one term, so no
+    reduction order rounds and engines over 1 and 4 devices see the same
+    gradient bits."""
+    import flax.linen as nn
+
+    class OddMLP(nn.Module):
+        @nn.compact
+        def __call__(self, x, y):
+            h = nn.relu(nn.Dense(7, name="a")(x))
+            err = (nn.Dense(3, use_bias=False, name="b")(h) - y) ** 2
+            live = jnp.abs(x).sum(-1, keepdims=True) > 0
+            return jnp.sum(err * live) / 16
+
+    zeros = np.zeros((16, 5), np.float32), np.zeros((16, 3), np.float32)
+    model = OddMLP()
+    params = model.init(jax.random.PRNGKey(0), *zeros)
+    config = {
+        "train_batch_size": 16, "train_micro_batch_size_per_gpu": 16 // dp,
+        "gradient_accumulation_steps": 1,
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
+        "mesh": {"data_parallel_size": dp}}
+    if stage:
+        config["zero_optimization"] = {"stage": stage}
+    if precision == "bf16":
+        config["bf16"] = {"enabled": True}
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=params, config_params=config)
+    rng = np.random.RandomState(0)
+    for step in range(3):
+        x, y = (a.copy() for a in zeros)
+        row = (5 * step) % 16       # a row of device 0, then 1, then 2
+        x[row], y[row] = rng.randn(5), rng.randn(3)
+        engine.train_step([(x, y)])
+    return engine
+
+
+@pytest.mark.parametrize("precision", ["bf16", "fp32"])
+@pytest.mark.parametrize("stage", [1, 2])
+def test_zero_steps_over_an_odd_numel_give_the_unsharded_parameters(
+        stage, precision):
+    """ZeRO-1/2 over four devices, the flat vector of an odd numel padded to
+    whole lane tiles a rank and (bf16) the shard cast before it is gathered:
+    after three steps the parameters are, bit for bit, those of the engine
+    without ZeRO over the same four devices and, under bf16, over one
+    device. (fp32 compute over ONE device is held to 1e-6 only: XLA's CPU
+    matmul rounds a 16-row operand's products otherwise than a 4-row one's,
+    stage 0 included.)"""
+    engine = _odd_engine(stage, 4, precision)
+    opt = engine.optimizer
+    assert (opt._numel, opt._padded) == (63, 512)
+    as_stored = jnp.bfloat16 if precision == "bf16" else jnp.float32
+    for dp in (4, 1):
+        base = _odd_engine(0, dp, precision)
+        for got, want in zip(_leaves(engine.params), _leaves(
+                jax.tree_util.tree_map(lambda p: p.astype(as_stored),
+                                       base.params))):
+            assert got.dtype == want.dtype
+            if precision == "fp32" and dp == 1:
+                np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+            else:
+                np.testing.assert_array_equal(got, want)
+    # zeros behind numel, in the master and in both moments
+    state = jax.device_get(engine.opt_state)
+    flats = [x for x in jax.tree_util.tree_leaves(state)
+             if getattr(x, "shape", ()) == (512,)]
+    assert len(flats) == (3 if precision == "bf16" else 2)
+    assert not any(np.asarray(x)[63:].any() for x in flats)
+    if precision == "bf16":
+        # gathered-then-cast is cast-then-gathered: each parameter is its
+        # slice of the float32 master, rounded
+        want = np.asarray(state.flat_master[:63].astype(jnp.bfloat16))
+        got = np.concatenate([x.reshape(-1) for x in _leaves(engine.params)])
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("stage,precision,itemsize", [
+    (2, "bf16", 2), (1, "bf16", 2), (2, "fp32", 4)])
+def test_param_gather_gauges_read_what_the_rule_predicts(
+        stage, precision, itemsize):
+    """Tracing ``update()`` records the bytes a rank receives in the
+    parameter gather, the other ranks' shards of the padded vector in the
+    dtype that travels, and that dtype's size."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.runtime.zero import sharded_optimizer as so
+
+    gauges = telemetry.get_registry()
+    for name in (so.PARAM_GATHER_BYTES, so.PARAM_GATHER_ITEMSIZE):
+        gauges.gauge(name).set(-1)
+    engine = _odd_engine(stage, 4, precision)
+    assert so.flat_pad_multiple(4) == 512
+    assert engine.optimizer._padded == 512
+    assert gauges.gauge(so.PARAM_GATHER_ITEMSIZE).value == itemsize
+    assert gauges.gauge(so.PARAM_GATHER_BYTES).value == 3 * 128 * itemsize
 
 
 # ---------------------------------------------------------------------------
