@@ -11,6 +11,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.inference.generation import (
     DEFAULT_PAGE_TOKENS,
@@ -47,7 +48,10 @@ class LagunaFamily(RowPrefillFamily):
     """Laguna through the shared loop. A full-attention layer's keys and
     values live in pages (the key-value heads side by side in a paged row);
     a window layer's in a ring of ``sliding_window`` positions a lane, among
-    the pool's slot arrays. A ring is read behind a position mask, which
+    the pool's slot arrays. The pool is described from the configuration's
+    ``cache_widths``, a width a name (``k``, ``v``, ``wk``, ``wv``: Laguna's
+    four are one number; ``families/mimo_v2.py`` runs this class over four
+    that differ). A ring is read behind a position mask, which
     hides whatever a previous occupant left, so admission zeroes nothing
     (``reset=()``). Admission, lane churn and the decode step kept in flight
     are ``SlotStateFamily``'s, the prefill call of several prompts in rows
@@ -78,19 +82,21 @@ class LagunaFamily(RowPrefillFamily):
         # a ring in blocks of one page, laid out as pages are (tokens last)
         page = resolve_page_tokens(cfg.kv_page_tokens or DEFAULT_PAGE_TOKENS,
                                    loop.max_seq_len)
-        ring = (m.sliding_window // page, m.kv_width, page)
+        widths = m.cache_widths
         pool = HybridStatePool(
             cfg.max_slots, loop.max_seq_len,
-            paged={"k": (n_full, m.kv_width, dtype),
-                   "v": (n_full, m.kv_width, dtype)},
-            slotted={"wk": (n_window, ring, dtype),
-                     "wv": (n_window, ring, dtype)},
+            paged={name: (n_full, widths[name], dtype)
+                   for name in ("k", "v")},
+            slotted={name: (n_window, (m.sliding_window // page,
+                                       widths[name], page), dtype)
+                     for name in ("wk", "wv")},
             page_tokens=cfg.kv_page_tokens, pool_tokens=cfg.kv_pool_tokens,
             reset=())
         assert pool.page_tokens == page, (pool.page_tokens, page)
         self.row_tokens = page
         self.rows = int(cfg.prefill_chunk_tokens) // page
         self.paged_attn_layers = n_full
+        self.ring_layers = n_window
         loop.metrics.record_state_pool(0, 0, pool.slot_bytes(),
                                        pool.paged_bytes())
         return params, pool
@@ -98,7 +104,10 @@ class LagunaFamily(RowPrefillFamily):
     def count_attended(self, held):
         """Also what the step attends to and holds, for the roofline's and
         the pool's readers: a full layer reads every position its active
-        lanes hold."""
+        lanes hold, a window layer what of its ring is behind the mask."""
         super().count_attended(held)
-        self.loop.metrics.record_attended(held.sum(),
-                                          self.loop.pool.pages_in_use)
+        metrics = self.loop.metrics
+        metrics.record_attended(held.sum(), self.loop.pool.pages_in_use)
+        metrics.record_ring_positions(
+            self.ring_layers
+            * np.minimum(held + 1, self.cfg.sliding_window).sum())

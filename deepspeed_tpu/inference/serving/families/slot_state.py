@@ -1,6 +1,7 @@
 """What the families whose lanes hold a slot's state have letter for letter
 in common (``families/kimi_linear.py``, ``families/nemotron_h.py``,
-``families/laguna.py``): a request holds a lane while its prompt is read,
+``families/laguna.py`` and, through it, ``families/mimo_v2.py``): a request
+holds a lane while its prompt is read,
 admission claims the lane's slot and pages of a ``HybridStatePool`` (and
 zeroes what the pool says a new occupant must not inherit), lane churn
 patches the device's lane vectors, one decode step is kept in flight, and

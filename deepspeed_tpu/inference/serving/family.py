@@ -6,7 +6,8 @@ the scheduler, expiry, which request rides which slot, stamp/emit/retire,
 guard, the injector hooks and the threads. What a lane's state is and which
 jitted programs fill and advance it is a ``ServingFamily``
 (``families/gpt2.py``, ``families/kimi_linear.py``,
-``families/nemotron_h.py``, ``families/laguna.py``). The arrows point one
+``families/nemotron_h.py``, ``families/laguna.py``,
+``families/mimo_v2.py``). The arrows point one
 way: the loop calls the family through the methods below, and a family calls
 back only this short public list of the loop it was built for:
 
@@ -34,6 +35,7 @@ import numpy as np
 
 from deepspeed_tpu.models.kimi_linear import KimiLinearConfig
 from deepspeed_tpu.models.laguna import LagunaConfig
+from deepspeed_tpu.models.mimo_v2 import MiMoV2Config
 from deepspeed_tpu.models.nemotron_h import NemotronHConfig
 from deepspeed_tpu.profiling.sentinels import CompileSentinel
 
@@ -154,5 +156,9 @@ def family_for(model_config):
         from deepspeed_tpu.inference.serving.families.laguna import (
             LagunaFamily)
         return LagunaFamily(model_config)
+    if isinstance(model_config, MiMoV2Config):
+        from deepspeed_tpu.inference.serving.families.mimo_v2 import (
+            MiMoV2Family)
+        return MiMoV2Family(model_config)
     from deepspeed_tpu.inference.serving.families.gpt2 import GPT2Family
     return GPT2Family(model_config)

@@ -154,6 +154,7 @@ class ServingMetrics:
         self.prefill_chunk_rows = 0
         self.decode_context_tokens = 0
         self.pool_pages_in_use_steps = 0
+        self.decode_ring_positions = 0
         self.decode_attn_blocks_walked = 0
         self.decode_attn_blocks_dense = 0
         self.page_waits = 0
@@ -218,6 +219,12 @@ class ServingMetrics:
         (``decode_context_tokens``, ``pool_pages_in_use_steps``)."""
         self.decode_context_tokens += int(context_tokens)
         self.pool_pages_in_use_steps += int(pages_in_use)
+
+    def record_ring_positions(self, positions):
+        """One decode step of a family with window layers: the positions
+        its active lanes' rings hold behind their mask, summed over the
+        window layers (``decode_ring_positions``)."""
+        self.decode_ring_positions += int(positions)
 
     def record_attn_blocks(self, blocks, layers):
         """One decode step of a family whose paged attention walks a work
@@ -506,6 +513,7 @@ class ServingMetrics:
             "prefill_chunk_rows": self.prefill_chunk_rows,
             "decode_context_tokens": self.decode_context_tokens,
             "pool_pages_in_use_steps": self.pool_pages_in_use_steps,
+            "decode_ring_positions": self.decode_ring_positions,
             "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
             "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
             "page_waits": self.page_waits,

@@ -36,11 +36,16 @@ serving engine jits, laid out as ``models/nemotron_h.py``'s:
 page_tokens, kv_heads * head_dim, page_tokens]}`` (a ring of ``W =
 sliding_window`` positions a lane for each window layer, in blocks laid out
 as pages are, tokens last: given the tokens first, XLA re-laid the whole
-array on the way into every decode step's scores). Ring slot ``j`` (block
-``j / page_tokens``, column ``j % page_tokens``) of a lane at position ``p``
-holds position ``p - (p - j) mod W``; the
-mask hides it where that is negative, which is all that a previous occupant
-of the lane can have left there. So a lane needs no reset. The router is
+array on the way into every decode step's scores). The window functions
+below take a layer's shape, its window and what the model does to queries,
+keys and the context as arguments, so a model whose values are narrower
+than its keys, whose window layers have key-value heads of their own or
+whose softmax has a learned sink (``models/mimo_v2.py``) runs them too:
+each of the four arrays is then as wide as its own projection makes it.
+Ring slot ``j`` (block ``j / page_tokens``, column ``j % page_tokens``) of a
+lane at position ``p`` holds position ``p - (p - j) mod W``; the mask hides
+it where that is negative, which is all that a previous occupant of the
+lane can have left there. So a lane needs no reset. The router is
 float32 whatever the parameters' type; keys are cached rotated.
 """
 
@@ -88,12 +93,15 @@ class RopeSpec:
 
 @dataclass(frozen=True)
 class AttentionShape:
-    """What ``nemotron_h``'s grouped-query functions read of a
-    configuration, for one layer."""
+    """What ``nemotron_h``'s grouped-query functions and the window
+    functions below read of a configuration, for one layer: its query
+    heads, its key-value heads, the size of a query or key head and the
+    size of a value head."""
 
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
+    v_head_dim: int
 
 
 @dataclass(frozen=True)
@@ -178,7 +186,8 @@ class LagunaConfig:
         """Layer ``l``'s head counts, as the grouped-query functions read
         them."""
         return AttentionShape(self.num_attention_heads_per_layer[l],
-                              self.num_key_value_heads, self.head_dim)
+                              self.num_key_value_heads, self.head_dim,
+                              self.head_dim)
 
     def rope(self, l):
         return self.rope_window if self.is_window(l) else self.rope_full
@@ -204,9 +213,17 @@ class LagunaConfig:
 
     @property
     def kv_width(self):
-        """Values a token of one layer caches for keys (and as many for
-        values): the key-value heads side by side."""
+        """Values a token of one layer caches for keys, and as many for
+        values (one head size and one key-value head count serve every
+        layer): the key-value heads side by side."""
         return self.num_key_value_heads * self.head_dim
+
+    @property
+    def cache_widths(self):
+        """{name of a pool array: values a token caches there}, as the
+        family describes the pool: the full layers' ``k`` and ``v`` pages
+        and the window layers' ``wk`` and ``wv`` rings."""
+        return dict.fromkeys(("k", "v", "wk", "wv"), self.kv_width)
 
 
 # -- rotary positions -------------------------------------------------------
@@ -258,19 +275,24 @@ def apply_rope(spec, x, positions):
         axis=-1).astype(x.dtype)
 
 
-def _rotate(cfg, l):
-    """``rotate(q, k, positions)`` for layer ``l``: ``q [..., KV, J, hd]``,
-    ``k [..., KV * hd]`` as ``_gqa_project`` gives them."""
-    spec = cfg.rope(l)
-    scope = "rope_window" if cfg.is_window(l) else "rope_full"
-
+def rotary(spec, shape, scope):
+    """``rotate(q, k, positions)`` by ``spec`` for a layer of ``shape``:
+    ``q [..., KV, J, hd]``, ``k [..., KV * hd]`` as ``_gqa_project`` gives
+    them; traced under the name ``scope``."""
     def rotate(q, k, positions):
         with jax.named_scope(scope):
-            heads = k.shape[:-1] + (cfg.num_key_value_heads, cfg.head_dim)
+            heads = k.shape[:-1] + (shape.num_key_value_heads, shape.head_dim)
             return (apply_rope(spec, q, positions),
                     apply_rope(spec, k.reshape(heads), positions).reshape(
                         k.shape))
     return rotate
+
+
+def _rotate(cfg, l):
+    """Layer ``l``'s ``rotate``: YaRN over half a head in a full layer,
+    plain frequencies over the whole head in a window layer."""
+    return rotary(cfg.rope(l), cfg.attention(l),
+                  "rope_window" if cfg.is_window(l) else "rope_full")
 
 
 def _gate(p, cfg, l, x):
@@ -289,11 +311,35 @@ def _gate(p, cfg, l, x):
 
 # -- window layers: a ring a lane -------------------------------------------
 
-def window_prefill(p, cfg, l, x, wk, wv, n, slots, starts, lens):
-    """A window layer over ``R`` rows of ``T`` tokens. ``wk``, ``wv`` the
-    whole ``[Lw, slots, W / T, KV * hd, T]`` rings and ``n`` this layer's
-    row of them; ``W % T == 0`` and every ``starts`` a multiple of ``T``, so
-    a row is one block of its ring. A query at position ``s`` attends to
+def _sink_softmax(s, sink):
+    """Softmax of masked scores ``s [B, KV, J, ..., keys]`` over the keys.
+    With ``sink [KV, J]``, one learned logit a query head, the sink joins
+    the maximum and the denominator and weighs nothing: the extra column of
+    a softmax that is dropped afterwards. (A row that is all mask gives
+    zeros then, and not the uniform weights a plain softmax gives; both are
+    rows no caller reads.)"""
+    if sink is None:
+        return jax.nn.softmax(s, axis=-1)
+    with jax.named_scope("attend_window_sink"):
+        sink = sink.astype(jnp.float32).reshape(
+            sink.shape + (1,) * (s.ndim - 2 - sink.ndim))
+        m = jnp.maximum(jnp.max(s, axis=-1), sink)
+        e = jnp.exp(s - m[..., None])
+        return e / (jnp.sum(e, axis=-1) + jnp.exp(sink - m))[..., None]
+
+
+def window_prefill(p, shape, x, wk, wv, n, slots, starts, lens, *, window,
+                   rotate, gate=None, sink=None):
+    """A window layer of ``shape`` (an ``AttentionShape``: keys of ``hd``,
+    values of ``vd``) and ``W = window`` over ``R`` rows of ``T`` tokens.
+    ``wk`` the whole ``[Lw, slots, W / T, KV * hd, T]`` rings, ``wv`` the
+    whole ``[Lw, slots, W / T, KV * vd, T]`` ones and ``n`` this layer's
+    row of them; ``W % T == 0`` (``W == T`` is a ring of one block) and
+    every ``starts`` a multiple of ``T``, so a row is one block of its
+    ring. ``rotate`` and ``gate`` as in ``nemotron_h.gqa_prefill``; ``sink
+    [KV, J]`` one learned logit a query head that takes part of the
+    softmax's mass and adds no value (None: a plain softmax, and nothing
+    traced for it). A query at position ``s`` attends to
     positions ``(s - W, s]``: the row's own tokens up to its own, and the
     ``W`` positions before the row, which are the rows of the same prompt
     before it in the call where those reach, and what the lane's ring held
@@ -302,16 +348,16 @@ def window_prefill(p, cfg, l, x, wk, wv, n, slots, starts, lens):
     ``W`` positions of the call are written to its ring, each row with one
     in-place update. Returns ``(y, wk, wv)``."""
     R, T, _ = x.shape
-    W = cfg.sliding_window
-    shape = cfg.attention(l)
-    kvh, hd = shape.num_key_value_heads, shape.head_dim
+    W = window
+    kvh, hd, vd = (shape.num_key_value_heads, shape.head_dim,
+                   shape.v_head_dim)
     J = shape.num_attention_heads // kvh
-    assert W % T == 0 and wk.shape[2:] == (W // T, kvh * hd, T), (
-        W, T, wk.shape)
+    assert W % T == 0 and wk.shape[2:] == (W // T, kvh * hd, T) and (
+        wv.shape[2:] == (W // T, kvh * vd, T)), (W, T, wk.shape, wv.shape)
     back = W // T                       # rows that reach into a row's window
     pos = starts[:, None] + jnp.arange(T)[None, :]                   # [R, T]
     q, k, v = _gqa_project(p, shape, x)
-    q, k = _rotate(cfg, l)(q, k, pos)
+    q, k = rotate(q, k, pos)
     follows, _ = row_links(slots, starts, lens, T)
     # the first row of each row's prompt in this call, and where the call
     # stops reading that prompt
@@ -326,20 +372,21 @@ def window_prefill(p, cfg, l, x, wk, wv, n, slots, starts, lens):
     from_call = reach >= first[:, None]
     held_at = (starts[:, None] // T + jnp.arange(back)[None, :]) % back
 
-    def blocks(new, ring):
-        """``[R, back + 1, KV, hd, T]``: the window before each row, then
-        the row itself, a block's tokens last as the ring holds them."""
-        own = jnp.swapaxes(new, 1, 2)                        # [R, KV*hd, T]
+    def blocks(new, ring, width):
+        """``[R, back + 1, KV, width, T]``: the window before each row,
+        then the row itself, a block's tokens last as the ring holds
+        them."""
+        own = jnp.swapaxes(new, 1, 2)                     # [R, KV*width, T]
         in_call = own[jnp.clip(reach, 0, R - 1)]
         held = jnp.take_along_axis(
             ring[n, lane], held_at[:, :, None, None], axis=1).astype(new.dtype)
         before = jnp.where(from_call[:, :, None, None], in_call, held)
         return jnp.concatenate([before, own[:, None]], axis=1).reshape(
-            R, back + 1, kvh, hd, T), own
+            R, back + 1, kvh, width, T), own
 
     with jax.named_scope("attend_window"):
-        keys, k_own = blocks(k, wk)
-        vals, v_own = blocks(v, wv)
+        keys, k_own = blocks(k, wk, hd)
+        vals, v_own = blocks(v, wv, vd)
         s = jnp.einsum("rtgjd,rngdp->rgjtnp", q, keys,
                        preferred_element_type=jnp.float32).reshape(
                            R, kvh, J, T, (back + 1) * T) * hd ** -0.5
@@ -352,13 +399,12 @@ def window_prefill(p, cfg, l, x, wk, wv, n, slots, starts, lens):
             & (starts[:, None, None] - W + jnp.arange(W)[None, None, :] >= 0),
             jnp.broadcast_to((jnp.arange(T)[None, :] <= t)[None], (R, T, T)),
         ], axis=2)
-        pr = jax.nn.softmax(jnp.where(ok[:, None, None], s, -1e30), axis=-1)
+        pr = _sink_softmax(jnp.where(ok[:, None, None], s, -1e30), sink)
         ctx = jnp.einsum(
             "rgjtnp,rngdp->rtgjd",
             pr.astype(x.dtype).reshape(R, kvh, J, T, back + 1, T), vals,
             preferred_element_type=jnp.float32)
-    ctx = ctx.reshape(R, T, kvh * J * hd)
-    gate = _gate(p, cfg, l, x)
+    ctx = ctx.reshape(R, T, kvh * J * vd)
     if gate is not None:
         ctx = gate(ctx)
     y = _dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype)
@@ -374,7 +420,8 @@ def window_prefill(p, cfg, l, x, wk, wv, n, slots, starts, lens):
         out = []
         for ring, new in zip(rings, (k_own, v_own)):
             at = (n, lane[r], block_of[r], 0, 0)
-            old = jax.lax.dynamic_slice(ring, at, (1, 1, 1, kvh * hd, T))
+            old = jax.lax.dynamic_slice(ring, at,
+                                        (1, 1, 1, new.shape[1], T))
             block = jnp.where(keep[r][None, :], new[r].astype(ring.dtype),
                               old[0, 0, 0])
             out.append(jax.lax.dynamic_update_slice(
@@ -385,22 +432,24 @@ def window_prefill(p, cfg, l, x, wk, wv, n, slots, starts, lens):
     return y, wk, wv
 
 
-def window_decode(p, cfg, l, x, wk, wv, n, positions, active):
+def window_decode(p, shape, x, wk, wv, n, positions, active, *, window,
+                  rotate, gate=None, sink=None):
     """A window layer for one token of every lane (lane ``b`` is slot
-    ``b``). ``x [B, d]``; the new key and value go to column ``positions %
-    T`` of block ``(positions % W) / T`` of the lane's ring (the block read,
+    ``b``); ``shape``, ``window``, ``rotate``, ``gate`` and ``sink`` as in
+    ``window_prefill``. ``x [B, d]``; the new key and value go to column
+    ``positions % T`` of block ``(positions % W) / T`` of the lane's ring (the block read,
     given its new column and written back whole, in place; an inactive
     lane's ring is left as it was: its prompt may be half read), then the
     ring is read once: slot ``j`` holds position ``p - (p - j) % W``,
     hidden where that is negative."""
     Bn = x.shape[0]
-    W = cfg.sliding_window
-    shape = cfg.attention(l)
-    kvh, hd = shape.num_key_value_heads, shape.head_dim
+    W = window
+    kvh, hd, vd = (shape.num_key_value_heads, shape.head_dim,
+                   shape.v_head_dim)
     J = shape.num_attention_heads // kvh
     back, T = wk.shape[2], wk.shape[4]
     q, k, v = _gqa_project(p, shape, x)
-    q, k = _rotate(cfg, l)(q, k, positions)
+    q, k = rotate(q, k, positions)
     at = positions % W
     lanes = jnp.arange(Bn)
     column = (jnp.arange(T)[None, None, :] == (at % T)[:, None, None]) & (
@@ -422,19 +471,18 @@ def window_decode(p, cfg, l, x, wk, wv, n, positions, active):
     wk, wv = jax.lax.fori_loop(0, Bn, put, (wk, wv))
     with jax.named_scope("attend_window"):
         kb = wk[n, :Bn].astype(x.dtype).reshape(Bn, back, kvh, hd, T)
-        vb = wv[n, :Bn].astype(x.dtype).reshape(Bn, back, kvh, hd, T)
+        vb = wv[n, :Bn].astype(x.dtype).reshape(Bn, back, kvh, vd, T)
         s = jnp.einsum("bgjd,bngdp->bgjnp", q, kb,
                        preferred_element_type=jnp.float32).reshape(
                            Bn, kvh, J, W) * hd ** -0.5
         held = positions[:, None] - (positions[:, None]
                                      - jnp.arange(W)[None, :]) % W
-        pr = jax.nn.softmax(jnp.where((held >= 0)[:, None, None], s, -1e30),
-                            axis=-1)
+        pr = _sink_softmax(jnp.where((held >= 0)[:, None, None], s, -1e30),
+                           sink)
         ctx = jnp.einsum("bgjnp,bngdp->bgjd",
                          pr.astype(x.dtype).reshape(Bn, kvh, J, back, T), vb,
                          preferred_element_type=jnp.float32)
-    ctx = ctx.reshape(Bn, kvh * J * hd)
-    gate = _gate(p, cfg, l, x)
+    ctx = ctx.reshape(Bn, kvh * J * vd)
     if gate is not None:
         ctx = gate(ctx)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
@@ -483,9 +531,10 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
         x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
         p = lp["self_attn"]
         if cfg.is_window(l):
-            y, wk, wv = window_prefill(p, cfg, l, x, wk, wv,
-                                       cfg.window_index[l], slots, starts,
-                                       lens)
+            y, wk, wv = window_prefill(
+                p, cfg.attention(l), x, wk, wv, cfg.window_index[l], slots,
+                starts, lens, window=cfg.sliding_window,
+                rotate=_rotate(cfg, l), gate=_gate(p, cfg, l, x))
         else:
             with jax.named_scope("attend_full"):
                 y, k_pool, v_pool = gqa_prefill(
@@ -519,8 +568,10 @@ def decode_step(params, cfg, state, tokens, positions, active, page_tables,
         x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
         p = lp["self_attn"]
         if cfg.is_window(l):
-            y, wk, wv = window_decode(p, cfg, l, x, wk, wv,
-                                      cfg.window_index[l], positions, active)
+            y, wk, wv = window_decode(
+                p, cfg.attention(l), x, wk, wv, cfg.window_index[l],
+                positions, active, window=cfg.sliding_window,
+                rotate=_rotate(cfg, l), gate=_gate(p, cfg, l, x))
         else:
             with jax.named_scope("attend_full"):
                 y, k_pool, v_pool = gqa_decode(

@@ -165,9 +165,16 @@ class NemotronHConfig:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
     @property
+    def v_head_dim(self):
+        """A value head is as wide as a key head here; the grouped-query
+        functions below read the two apart."""
+        return self.head_dim
+
+    @property
     def kv_width(self):
-        """Values a token of one attention block caches for keys (and as
-        many for values): the key-value heads side by side."""
+        """Values a token of one attention block caches for keys, and as
+        many for values (one head size serves both): the key-value heads
+        side by side."""
         return self.num_key_value_heads * self.head_dim
 
 
@@ -344,7 +351,9 @@ def mamba_decode(p, cfg, x, S0, tail, active):
 
 def _gqa_project(p, cfg, x):
     """``q [..., KV, Q/KV, hd]`` (query head ``j`` reads key-value head ``j
-    // (Q/KV)``) and the row cached a token: ``k, v [..., KV * hd]``."""
+    // (Q/KV)``) and the rows cached a token: ``k [..., KV * hd]`` and ``v
+    [..., KV * vd]``, each as wide as its projection makes it (``vd =
+    cfg.v_head_dim`` may differ from ``hd = cfg.head_dim``)."""
     kvh, hd = cfg.num_key_value_heads, cfg.head_dim
     q = _dot(x, p["q_proj"]["kernel"]).astype(x.dtype).reshape(
         x.shape[:-1] + (kvh, cfg.num_attention_heads // kvh, hd))
@@ -366,18 +375,21 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
     """Attention over ``R`` rows: a row's keys and values are written to
     its prompt's pages first (whole pages, each with one in-place update),
     then every query attends the prompt's rows up to its own position, a
-    block of pages at a time. ``x [R, T, d]``; ``k_pool``, ``v_pool`` the
-    whole ``[La, pages, KV * hd, page_tokens]`` arrays and ``n`` this
-    block's row of them. Returns ``(y, k_pool, v_pool)``. What a page holds
-    beyond the prompt's end is overwritten by decode before it can be
-    attended. ``cfg`` is read for ``num_attention_heads``,
-    ``num_key_value_heads`` and ``head_dim`` only. A model with positions
-    gives ``rotate(q, k, positions) -> (q, k)`` (keys are cached rotated),
-    one with an output gate ``gate(ctx [..., Q * hd]) -> ctx``, applied
-    ahead of ``o_proj`` (``models/laguna.py``); without them nothing is
-    traced for either."""
+    block of pages at a time. ``x [R, T, d]``; ``k_pool`` the whole ``[La,
+    pages, KV * hd, page_tokens]`` array, ``v_pool`` the whole ``[La, pages,
+    KV * vd, page_tokens]`` one and ``n`` this block's row of them. Returns
+    ``(y, k_pool, v_pool)``. What a page holds beyond the prompt's end is
+    overwritten by decode before it can be attended. ``cfg`` is read for
+    ``num_attention_heads``, ``num_key_value_heads``, ``head_dim`` (queries
+    and keys: the scores are over ``hd``) and ``v_head_dim`` (values: the
+    context and the partial sums are over ``vd``) only. A model with
+    positions gives ``rotate(q, k, positions) -> (q, k)`` (keys are cached
+    rotated), one that multiplies something onto the context ahead of
+    ``o_proj`` gives ``gate(ctx [..., Q * vd]) -> ctx`` (``models/
+    laguna.py``'s gate a head, ``models/mimo_v2.py``'s value scale);
+    without them nothing is traced for either."""
     R, T, _ = x.shape
-    kvh, hd = cfg.num_key_value_heads, cfg.head_dim
+    kvh, hd, vd = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
     J = cfg.num_attention_heads // kvh
     pt = page_tokens
     mp = page_tables.shape[1]
@@ -394,7 +406,7 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
 
     def as_pages(rows, pool):
         return jnp.swapaxes(rows.astype(pool.dtype).reshape(
-            R, per_row, pt, kvh * hd), 2, 3)
+            R, per_row, pt, rows.shape[-1]), 2, 3)
 
     k_new, v_new = as_pages(k, k_pool), as_pages(v, v_pool)
 
@@ -415,7 +427,7 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
     def block(j):
         pages = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
         kb = k_pool[n, pages].astype(x.dtype).reshape(R, bp, kvh, hd, pt)
-        vb = v_pool[n, pages].astype(x.dtype).reshape(R, bp, kvh, hd, pt)
+        vb = v_pool[n, pages].astype(x.dtype).reshape(R, bp, kvh, vd, pt)
         kpos = j * bp * pt + jnp.arange(bp * pt)
         s = jnp.einsum("rtgjd,rngdp->rgjtnp", q, kb,
                        preferred_element_type=jnp.float32).reshape(
@@ -430,8 +442,8 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
                 preferred_element_type=jnp.float32)
         return s, weigh
 
-    ctx = _online_softmax_loop(n_blocks, block, (R, kvh, J, T), hd)
-    ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * hd)
+    ctx = _online_softmax_loop(n_blocks, block, (R, kvh, J, T), vd)
+    ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * vd)
     if gate is not None:
         ctx = gate(ctx)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
@@ -466,8 +478,9 @@ def decode_work_list(positions, active, span, nblk, bound):
 
 
 def pairs_per_tile(bound, pair_bytes):
-    """Pairs one iteration of the decode attention's loop gathers: the
-    power of two whose keys and values come nearest ``_TILE_BYTES`` from
+    """Pairs one iteration of the decode attention's loop gathers
+    (``pair_bytes``: a pair's keys and its values, each at its own width):
+    the power of two whose keys and values come nearest ``_TILE_BYTES`` from
     below (an iteration has to move tens of megabytes to stream), and no
     more than the list can hold."""
     g = max(1, _TILE_BYTES // pair_bytes)
@@ -479,18 +492,18 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     """Attention for one token of every lane over the lane's pages. ``x [B,
     d]``; the new key and value are written at ``positions`` (each lane's
     page read, given its new column and written back whole, in place)
-    before they are attended. ``rotate`` and ``gate`` as in
-    ``gqa_prefill``.
+    before they are attended. ``rotate``, ``gate`` and the two head sizes
+    (keys of ``hd``, values of ``vd``) as in ``gqa_prefill``.
 
     What is walked is the work list of ``decode_work_list``: the (lane,
     block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, a tile of them an
     iteration, so a step reads the sum of the lanes' contexts and not every
     lane up to the longest one's end. An iteration gathers its pairs' pages
     from the pool and leaves each pair's masked partial softmax (running
-    max, sum and weighted values, float32); the partials of a lane's pairs,
-    in one tile or in several, are combined after the loop."""
+    max, sum and weighted values ``[..., vd]``, float32); the partials of a
+    lane's pairs, in one tile or in several, are combined after the loop."""
     Bn = x.shape[0]
-    kvh, hd = cfg.num_key_value_heads, cfg.head_dim
+    kvh, hd, vd = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
     J = cfg.num_attention_heads // kvh
     pt = page_tokens
     mp = page_tables.shape[1]
@@ -521,7 +534,7 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     # flight runs past its span
     bound = min(Bn * nblk, -(-k_pool.shape[1] // bp) + 2 * Bn)
     G = pairs_per_tile(
-        bound, 2 * bp * kvh * hd * pt * jnp.dtype(k_pool.dtype).itemsize)
+        bound, bp * kvh * (hd + vd) * pt * jnp.dtype(k_pool.dtype).itemsize)
     bound = -(-bound // G) * G
     lane, blk, live, n_pairs = decode_work_list(positions, active, span, nblk,
                                                 bound)
@@ -537,7 +550,7 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
         qt = jax.lax.dynamic_slice_in_dim(pair_q, at, G)
         last = jax.lax.dynamic_slice_in_dim(pair_last, at, G)
         kb = k_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, hd, pt)
-        vb = v_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, hd, pt)
+        vb = v_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, vd, pt)
         s = jnp.einsum("bgjd,bngdp->bgjnp", qt, kb,
                        preferred_element_type=jnp.float32).reshape(
                            G, kvh, J, span) * scale
@@ -554,7 +567,7 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
         0, (n_pairs + G - 1) // G, tile,
         (jnp.full((bound, kvh, J), -1e30, jnp.float32),
          jnp.zeros((bound, kvh, J), jnp.float32),
-         jnp.zeros((bound, kvh, J, hd), jnp.float32)))
+         jnp.zeros((bound, kvh, J, vd), jnp.float32)))
     # by lane: the running max, each pair rescaled to it, the sums
     mine = (lane[None, :] == jnp.arange(Bn)[:, None]) & live[None, :]  # [B, P]
     m_lane = jnp.max(jnp.where(mine[..., None, None], m[None], -1e30), axis=1)
@@ -563,7 +576,7 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     ctx = jnp.einsum("bp,pgjd->bgjd", mine.astype(jnp.float32),
                      acc * w[..., None], **_MM)
     ctx = (ctx / jnp.maximum(l_lane, 1e-30)[..., None]).reshape(
-        Bn, kvh * J * hd)
+        Bn, kvh * J * vd)
     if gate is not None:
         ctx = gate(ctx)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),

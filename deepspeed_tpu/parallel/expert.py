@@ -145,7 +145,8 @@ def expert_function(weights):
     return swiglu if "gate_proj" in weights else relu2
 
 
-def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
+def held_experts_ffn(x, experts, idx, weights, held, tile, live=None,
+                     every_expert=False):
     """The part of a routed expert layer that the experts held here give:
     ``sum over picks p of token t with idx[t, p] held of weights[t, p] *
     Expert_e(x_t)``, the expert's function being what ``expert_function``
@@ -153,8 +154,12 @@ def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
     grouped by expert, each group padded up to a multiple of ``tile`` rows,
     and a loop runs over the tiles in use (its trip count is data, the
     shapes are not), reading one expert's weights a tile. An expert no token
-    picked is never read; a pick of an expert held elsewhere costs nothing
-    here.
+    picked is never read, unless ``every_expert`` gives each held expert at
+    least one tile (of empty rows where nothing picked it): the call then
+    reads the same weights whatever the router chose, for a caller whose
+    few rows touch nearly every held expert anyway and whose step should
+    take the same time from one routing to the next. A pick of an expert
+    held elsewhere costs nothing here.
 
     x [T, d]; experts {gate_proj (SwiGLU only), up_proj [E_h, d, f],
     down_proj [E_h, f, d]}; idx, weights [T, k]; held (first, count) among
@@ -175,7 +180,8 @@ def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
         here = here & jnp.repeat(live, k)
     e = jnp.where(here, e, n_held)                   # n_held = "not here"
     counts = jnp.zeros(n_held + 1, jnp.int32).at[e].add(1)[:n_held]
-    padded = -(-counts // tile) * tile
+    tiled = jnp.maximum(counts, 1) if every_expert else counts
+    padded = -(-tiled // tile) * tile
     group_end = jnp.cumsum(padded)
     group_start = group_end - padded
     # a pick's row: its group's start plus its rank among the group's picks
@@ -214,7 +220,7 @@ def held_experts_ffn(x, experts, idx, weights, held, tile, live=None):
 
 
 def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
-                    tile):
+                    tile, every_expert=False):
     """One chip's share of a sigmoid-routed expert layer with a shared
     expert: the router scores every expert of the published count and picks
     ``k`` a token; this chip adds up what the experts it holds
@@ -227,7 +233,7 @@ def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
     experts: {gate_proj, up_proj, down_proj} or {up_proj, down_proj},
     shared_experts (optional): the same names with ``/kernel``, of a width
     of its own}; ``expert_function`` reads each one's function off its
-    matrices. x [T, d].
+    matrices. x [T, d]; ``every_expert`` as ``held_experts_ffn`` takes it.
     Returns (y [T, d] in x's type, counts [3] int32 as ``held_experts_ffn``
     gives them)."""
     with jax.named_scope("moe_route"):
@@ -237,7 +243,7 @@ def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
             renormalize)
     with jax.named_scope("moe_experts"):
         y, stats = held_experts_ffn(x, params["experts"], idx, w, held, tile,
-                                    live)
+                                    live, every_expert)
     if "shared_experts" in params:
         with jax.named_scope("moe_shared"):
             sp = params["shared_experts"]

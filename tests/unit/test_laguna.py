@@ -316,8 +316,10 @@ def test_a_zeroed_gate_halves_every_heads_output(layer):
             size=(3, 3, W // ROW, 32, ROW)), jnp.float32),) * 2
 
         def run(cfg):
-            return lg.window_decode(p, cfg, layer, x, *rings, 0, positions,
-                                    active)[0]
+            return lg.window_decode(
+                p, cfg.attention(layer), x, *rings, 0, positions, active,
+                window=W, rotate=lg._rotate(cfg, layer),
+                gate=lg._gate(p, cfg, layer, x))[0]
     else:
         pools = (jnp.asarray(np.random.default_rng(7).normal(
             size=(2, 10, 32, ROW)), jnp.float32),) * 2
@@ -355,13 +357,17 @@ def test_window_rows_in_one_call_match_the_reference_layer():
     lens = jnp.asarray([16, 16, 16, 16, 7, 0, 9], jnp.int32)
     rings = (jnp.asarray(rng.normal(size=(3, 3, W // ROW, 32, ROW)),
                          jnp.float32),) * 2
+    how = dict(window=W, rotate=lg._rotate(mcfg, layer))
+    shape = mcfg.attention(layer)
     # the earlier call of the prompt in slot 1: three full rows
     _, wk, wv = lg.window_prefill(
-        p, mcfg, layer, earlier, *rings, n, jnp.asarray([1, 1, 1], jnp.int32),
+        p, shape, earlier, *rings, n, jnp.asarray([1, 1, 1], jnp.int32),
         jnp.asarray([0, 16, 32], jnp.int32),
-        jnp.asarray([16, 16, 16], jnp.int32))
-    y, wk2, wv2 = lg.window_prefill(p, mcfg, layer, x, wk, wv, n, slots,
-                                    starts, lens)
+        jnp.asarray([16, 16, 16], jnp.int32), **how,
+        gate=lg._gate(p, mcfg, layer, earlier))
+    y, wk2, wv2 = lg.window_prefill(p, shape, x, wk, wv, n, slots, starts,
+                                    lens, **how,
+                                    gate=lg._gate(p, mcfg, layer, x))
     layer_ref = jax.jit(lambda w, x: ref.attention(w, x, D, layer, "f32"))
     flat_rows = lambda a, rows: a[np.asarray(rows)].reshape(-1, 64)  # noqa: E731
     cases = (  # (slot, the whole prompt so far, of which this call read)
@@ -484,7 +490,11 @@ def test_the_attention_block_counters_count_what_a_hand_made_schedule_owes(
 
     monkeypatch.setattr(slot_state.SlotStateFamily, "decode_step",
                         lambda self, guard: ((), (), 0, 0))
-    fam = (LagunaFamily if family == "laguna" else NemotronHFamily)(None)
+    if family == "laguna":
+        fam = LagunaFamily(lg.LagunaConfig.from_dict(CFG))
+        fam.ring_layers = 3
+    else:
+        fam = NemotronHFamily(None)
     fam.paged_attn_layers = layers
     metrics = ServingMetrics()
     pool = SimpleNamespace(positions=np.array([5, 511, 9999, 1100]),
@@ -501,6 +511,10 @@ def test_the_attention_block_counters_count_what_a_hand_made_schedule_owes(
     snap = metrics.snapshot()
     assert snap["decode_attn_blocks_walked"] == layers * (5 + 6)
     assert snap["decode_attn_blocks_dense"] == layers * (3 * 3 + 3 * 3)
+    # what the rings hold behind their masks: min(position + 1, 32) a lane
+    # in each of Laguna's three window layers, and nothing for Nemotron-H
+    assert snap["decode_ring_positions"] == (
+        3 * ((6 + 32 + 32) + (7 + 32 + 32)) if family == "laguna" else 0)
     fam.loop.lanes.requests = {}          # a step with no lane owes nothing
     fam.decode_step(None)
     assert metrics.snapshot()["decode_attn_blocks_dense"] == layers * 18

@@ -63,7 +63,7 @@ def test_the_loop_imports_no_model_code():
 def test_there_are_families_to_hold_to_the_contract():
     names = {os.path.basename(p) for p in FAMILY_FILES}
     assert {"gpt2.py", "kimi_linear.py", "nemotron_h.py", "laguna.py",
-            "slot_state.py"} <= names, names
+            "mimo_v2.py", "slot_state.py"} <= names, names
 
 
 @pytest.mark.parametrize(
@@ -164,6 +164,8 @@ HOMES = {
     "_nemotron_prefill_chunk_jit": "families/nemotron_h.py",
     "_laguna_decode_step_jit": "families/laguna.py",
     "_laguna_prefill_chunk_jit": "families/laguna.py",
+    "_mimo_decode_step_jit": "families/mimo_v2.py",
+    "_mimo_prefill_chunk_jit": "families/mimo_v2.py",
     "_install_pages": "kv_pool.py",
     "_zero_slot": "kv_pool.py",
 }
