@@ -63,6 +63,7 @@ from deepspeed_tpu.models.nemotron_h import (
     gqa_prefill,
     row_links,
 )
+from deepspeed_tpu.ops.column_write import write_columns
 from deepspeed_tpu.parallel import expert as expert_mod
 
 FULL, WINDOW = "full_attention", "sliding_attention"
@@ -432,16 +433,52 @@ def window_prefill(p, shape, x, wk, wv, n, slots, starts, lens, *, window,
     return y, wk, wv
 
 
+def _own_softmax(s, own, sink):
+    """Softmax over masked scores ``s [B, KV, J, keys]`` and one more
+    column ``own [B, KV, J]``, the score of a key that is not among
+    ``s``'s; ``sink [KV, J]`` as in ``_sink_softmax``. Returns the weights
+    of ``s``'s keys and the weight of the own column."""
+    m = jnp.maximum(jnp.max(s, axis=-1), own)
+    rest = 0.0
+    if sink is not None:
+        with jax.named_scope("attend_window_sink"):
+            sink = sink.astype(jnp.float32)
+            m = jnp.maximum(m, sink)
+            rest = jnp.exp(sink - m)
+    e, e_own = jnp.exp(s - m[..., None]), jnp.exp(own - m)
+    total = jnp.sum(e, axis=-1) + e_own + rest
+    return e / total[..., None], e_own / total
+
+
+def _ring_write(ring, n, at, new, active):
+    """``new [B, width]`` into slot ``at [B]`` of each active lane's ring,
+    row ``n`` of ``ring [Lw, slots, back, width, T]``: column ``at % T`` of
+    block ``at // T``; an inactive lane's ring stays as it was (its prompt
+    may be half read). A ring of one block IS its lane's block, so the
+    whole layer's rings take their columns in one pass, read, ``where`` and
+    written back at a static index, which XLA does in place: the layer's
+    rings move once each way. Of a ring of several blocks only the block
+    that holds the slot moves (``write_columns``)."""
+    Bn, (back, T) = new.shape[0], (ring.shape[2], ring.shape[4])
+    col = jnp.where(active, at % T, -1)
+    if back > 1:
+        return write_columns(ring, (n, jnp.arange(Bn), at // T), new, col)
+    column = jnp.arange(T)[None, :] == col[:, None]
+    blocks = jnp.where(column[:, None, None, :], new[:, None, :, None],
+                       ring[n, :Bn])
+    return jax.lax.dynamic_update_slice(ring, blocks[None], (n, 0, 0, 0, 0))
+
+
 def window_decode(p, shape, x, wk, wv, n, positions, active, *, window,
                   rotate, gate=None, sink=None):
     """A window layer for one token of every lane (lane ``b`` is slot
     ``b``); ``shape``, ``window``, ``rotate``, ``gate`` and ``sink`` as in
-    ``window_prefill``. ``x [B, d]``; the new key and value go to column
-    ``positions % T`` of block ``(positions % W) / T`` of the lane's ring (the block read,
-    given its new column and written back whole, in place; an inactive
-    lane's ring is left as it was: its prompt may be half read), then the
-    ring is read once: slot ``j`` holds position ``p - (p - j) % W``,
-    hidden where that is negative."""
+    ``window_prefill``. ``x [B, d]``. The ring is read once, as the step
+    found it: slot ``j`` holds position ``p - (p - j) % W``, hidden where
+    that is negative and at ``j = p % W``, which still holds ``p - W``; the
+    new key and value are one more column of the softmax beside it, so the
+    read does not wait for the write. They go to slot ``p % W`` of an
+    active lane's ring in one pass a layer and array (``_ring_write``)."""
     Bn = x.shape[0]
     W = window
     kvh, hd, vd = (shape.num_key_value_heads, shape.head_dim,
@@ -451,37 +488,30 @@ def window_decode(p, shape, x, wk, wv, n, positions, active, *, window,
     q, k, v = _gqa_project(p, shape, x)
     q, k = rotate(q, k, positions)
     at = positions % W
-    lanes = jnp.arange(Bn)
-    column = (jnp.arange(T)[None, None, :] == (at % T)[:, None, None]) & (
-        active[:, None, None])
-
-    def block(ring, new):
-        return jnp.where(column, new.astype(ring.dtype)[:, :, None],
-                         ring[n, lanes, at // T])
-
-    k_block, v_block = block(wk, k), block(wv, v)
-
-    def put(b, rings):
-        where = (n, b, at[b] // T, 0, 0)
-        return (jax.lax.dynamic_update_slice(
-                    rings[0], k_block[b][None, None, None], where),
-                jax.lax.dynamic_update_slice(
-                    rings[1], v_block[b][None, None, None], where))
-
-    wk, wv = jax.lax.fori_loop(0, Bn, put, (wk, wv))
+    k, v = k.astype(wk.dtype), v.astype(wv.dtype)     # as the ring holds them
     with jax.named_scope("attend_window"):
         kb = wk[n, :Bn].astype(x.dtype).reshape(Bn, back, kvh, hd, T)
         vb = wv[n, :Bn].astype(x.dtype).reshape(Bn, back, kvh, vd, T)
+        k_own = k.astype(x.dtype).reshape(Bn, kvh, hd)
+        v_own = v.astype(x.dtype).reshape(Bn, kvh, vd)
         s = jnp.einsum("bgjd,bngdp->bgjnp", q, kb,
                        preferred_element_type=jnp.float32).reshape(
                            Bn, kvh, J, W) * hd ** -0.5
+        s_own = jnp.einsum("bgjd,bgd->bgj", q, k_own,
+                           preferred_element_type=jnp.float32) * hd ** -0.5
         held = positions[:, None] - (positions[:, None]
                                      - jnp.arange(W)[None, :]) % W
-        pr = _sink_softmax(jnp.where((held >= 0)[:, None, None], s, -1e30),
-                           sink)
+        ok = (held >= 0) & (jnp.arange(W)[None, :] != at[:, None])
+        pr, pr_own = _own_softmax(jnp.where(ok[:, None, None], s, -1e30),
+                                  s_own, sink)
         ctx = jnp.einsum("bgjnp,bngdp->bgjd",
                          pr.astype(x.dtype).reshape(Bn, kvh, J, back, T), vb,
                          preferred_element_type=jnp.float32)
+        ctx = ctx + (pr_own.astype(x.dtype).astype(jnp.float32)[..., None]
+                     * v_own.astype(jnp.float32)[:, :, None, :])
+    with jax.named_scope("ring_write"):
+        wk = _ring_write(wk, n, at, k, active)
+        wv = _ring_write(wv, n, at, v, active)
     ctx = ctx.reshape(Bn, kvh * J * vd)
     if gate is not None:
         ctx = gate(ctx)

@@ -43,6 +43,7 @@ from deepspeed_tpu.models.kimi_linear import (
     _online_softmax_loop,
     rms_norm,
 )
+from deepspeed_tpu.ops.column_write import write_columns
 from deepspeed_tpu.parallel import expert as expert_mod
 
 PREFILL_KEY_BLOCK = 512     # keys a row attends at a time in prefill
@@ -490,10 +491,12 @@ def pairs_per_tile(bound, pair_bytes):
 def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
                page_tokens, rotate=None, gate=None):
     """Attention for one token of every lane over the lane's pages. ``x [B,
-    d]``; the new key and value are written at ``positions`` (each lane's
-    page read, given its new column and written back whole, in place)
-    before they are attended. ``rotate``, ``gate`` and the two head sizes
-    (keys of ``hd``, values of ``vd``) as in ``gqa_prefill``.
+    d]``; the new key and value are written at ``positions`` before they are
+    attended: a column of each lane's page, all lanes' pages in one
+    operation an array (``write_columns``: a page is read once and written
+    once, in place; inactive lanes all name the spare page 0). ``rotate``,
+    ``gate`` and the two head sizes (keys of ``hd``, values of ``vd``) as in
+    ``gqa_prefill``.
 
     What is walked is the work list of ``decode_work_list``: the (lane,
     block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, a tile of them an
@@ -513,20 +516,9 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     q, k, v = _gqa_project(p, cfg, x)
     if rotate is not None:
         q, k = rotate(q, k, positions)
-    column = jnp.arange(pt)[None, None, :] == (positions % pt)[:, None, None]
-    k_pages = jnp.where(column, k.astype(k_pool.dtype)[:, :, None],
-                        k_pool[n, phys])
-    v_pages = jnp.where(column, v.astype(v_pool.dtype)[:, :, None],
-                        v_pool[n, phys])
-
-    def put(b, pools):
-        at = (n, phys[b], 0, 0)
-        return (jax.lax.dynamic_update_slice(pools[0], k_pages[b][None, None],
-                                             at),
-                jax.lax.dynamic_update_slice(pools[1], v_pages[b][None, None],
-                                             at))
-
-    k_pool, v_pool = jax.lax.fori_loop(0, Bn, put, (k_pool, v_pool))
+    with jax.named_scope("page_write"):
+        k_pool = write_columns(k_pool, (n, phys), k, positions % pt)
+        v_pool = write_columns(v_pool, (n, phys), v, positions % pt)
     tables, bp = _blocks_of_pages(page_tables, DECODE_KEY_BLOCK, pt)
     span, nblk = decode_key_span(pt), tables.shape[1] // bp
     # lanes hold pages of their own, so their blocks are at most the pool's

@@ -25,6 +25,8 @@ Three layers of coverage, mirroring the tier's contract
    steady-state decode with the Pallas-interpret kernels armed.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -36,6 +38,7 @@ from deepspeed_tpu.inference.generation import generate
 from deepspeed_tpu.inference.serving.families import gpt2 as serving_engine_mod
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.engine import ServingEngine
+from deepspeed_tpu.ops import column_write
 from deepspeed_tpu.kernels.registry import KernelProbeError, KernelRegistry
 from deepspeed_tpu.models.gpt2 import GPT2Config, init_gpt2
 from deepspeed_tpu.profiling import CompileSentinel, transfer_free
@@ -636,3 +639,42 @@ def test_snapshot_exposes_kernel_registry(model):
     snap = kernels.registry_snapshot()
     assert snap["decode_attention"]["calls"]["pallas"] > 0
     assert snap["decode_attention"]["selected"] == "pallas"
+
+
+# ---------------------------------------------------------------------------
+# column_write: one new column in each lane's block, in place
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape, at", [
+    ((2, 9, 24, 16), (1, [3, 0, 5, 0, 8])),                   # pages
+    ((3, 5, 2, 24, 16), (2, [0, 1, 2, 3, 4], [1, 0, 1, 1, 0])),    # rings
+    ((3, 5, 1, 40, 8), (0, [0, 1, 2, 3, 4], [0, 0, 0, 0, 0])),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_column_write_kernel_is_its_plain_twin(shape, at, dtype):
+    """The Pallas kernel (interpreted here) and the gather, ``where`` and
+    scatter the CPU runs write the same pool, bit for bit: column ``col``
+    of each lane's block and nothing else, a lane with ``col`` -1 nothing
+    at all. Lanes 1 and 3 of the paged case both name the spare page 0,
+    which may hold either's column: left out of the comparison, and neither
+    path fails on it."""
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.normal(size=shape), dtype)
+    new = jnp.asarray(rng.normal(size=(5, shape[-2])), dtype)
+    col = jnp.asarray([2, 7, -1, shape[-1] - 1, 0], jnp.int32)
+    n, *index = at
+    index = [jnp.asarray(i, jnp.int32) for i in index]
+    plain, fused = (
+        np.asarray(jax.jit(lambda pool, index, new, col: write(
+            pool, (n, *index), new, col))(pool, index, new, col).astype(
+                jnp.float32))
+        for write in (column_write.write_columns, functools.partial(
+            column_write._write_columns_pallas, interpret=True)))
+    want = np.array(pool.astype(jnp.float32))
+    for b, c in enumerate(np.asarray(col)):
+        if c >= 0:
+            want[(n, *(i[b] for i in index))][:, c] = np.asarray(
+                new.astype(jnp.float32))[b]
+    rows = slice(1, None) if len(index) == 1 else slice(None)  # spare page
+    for got in (plain, fused):
+        np.testing.assert_array_equal(got[:, rows], want[:, rows])

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu import kernels
+from deepspeed_tpu.ops import column_write
 from deepspeed_tpu.ops.transformer import attention as attn_mod
 from deepspeed_tpu.ops.transformer.attention import flash_attention
 
@@ -234,6 +235,29 @@ def test_sparse_attention_lowers():
                               *_band_args(jnp.float32)), 1)
 
 
+# the four arrays a decode step writes a column into: MiMo-V2.5's pages and
+# ring of one block, Laguna's ring of four blocks, Nemotron-H's pages
+_COLUMN_POOLS = [((2, 4097, 768, 128), 128), ((5, 128, 1, 1536, 128), 128),
+                 ((3, 64, 4, 1024, 128), 64), ((1, 4097, 256, 128), 128)]
+
+
+def _column_write_args(shape, lanes, dtype=jnp.bfloat16):
+    i32 = jnp.int32
+    return ([SDS(shape, dtype)] + [SDS((lanes,), i32)] * (len(shape) - 3)
+            + [SDS((lanes, shape[-2]), dtype), SDS((lanes,), i32)])
+
+
+def _column_write_fn(pool, *rest):
+    *index, new, col = rest
+    return column_write._write_columns_pallas(pool, (0, *index), new, col)
+
+
+@pytest.mark.parametrize("shape, lanes", _COLUMN_POOLS)
+def test_column_write_lowers(shape, lanes):
+    _assert_mosaic(_lower_tpu(_column_write_fn,
+                              *_column_write_args(shape, lanes)), 1)
+
+
 @pytest.mark.slow
 def test_serving_kernels_compile_for_v5e():
     """The real compiler: Mosaic + XLA:TPU from the installed libtpu,
@@ -253,6 +277,9 @@ def test_serving_kernels_compile_for_v5e():
                        *place(_decode_args(chunk, page_dtype))).compile()
     for dtype in (jnp.float32, jnp.bfloat16):
         _lower_tpu(_band_fn(dtype), *place(_band_args(dtype))).compile()
+    for shape, lanes in _COLUMN_POOLS:
+        _lower_tpu(_column_write_fn,
+                   *place(_column_write_args(shape, lanes))).compile()
 
 
 @pytest.mark.slow

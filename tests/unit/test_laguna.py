@@ -393,6 +393,103 @@ def test_window_rows_in_one_call_match_the_reference_layer():
     np.testing.assert_array_equal(wk2[2], rings[0][2])
 
 
+# -- (d2) what one decode step writes ----------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("l",))
+def reference_cache(flat, ids, l):
+    """Layer ``l``'s keys (rotated) and values at every position of ``ids
+    [T]`` as the reference computes them: its own ``hidden_states`` cut at
+    ``l`` layers is the layer's input. ``(k [T, KV * hd], v [T, KV * hd])``."""
+    D = ref.dims_of(CFG)
+    h = ref.hidden_states(flat, ids, dict(D, layers=l))
+    x = ref._rms(h, flat[f"layers/{l}/input_layernorm/scale"], D["eps"])
+    spec = dict(D["rope_window"] if D["window_layer"][l] else D["rope_full"])
+    k = ref.rope((x @ flat[f"layers/{l}/self_attn/k_proj/kernel"]).reshape(
+        len(ids), D["kv_heads"], D["head"]), jnp.arange(len(ids)), spec,
+        D["head"])
+    return (k.reshape(len(ids), -1),
+            x @ flat[f"layers/{l}/self_attn/v_proj/kernel"])
+
+
+def check_what_one_decode_step_writes(model, params, mcfg, widths, page,
+                                      cache_of):
+    """Four lanes over a state of random numbers: lane 0 has read 5 tokens,
+    lane 1 ``W + 3`` (its next position wraps the ring), lanes 2 and 3 are
+    inactive and both fall on the spare page 0. After one ``decode_step``
+    of ``model`` (``models/laguna.py`` or ``models/mimo_v2.py``) the state
+    differs from the state before in exactly one column a layer of each
+    active lane's ring (column ``p % T`` of block ``p % W // T``) and of its
+    page ``p // T``, which hold what ``cache_of(ids, layer) -> (k, v)`` says
+    the reference computes at ``p``; an inactive lane's ring, every other
+    block and every other page but the spare one are bit for bit what they
+    were. ``widths`` names what a token caches in each of the pool's four
+    arrays, ``page`` the tokens of a page and of a ring's block."""
+    W, T = mcfg.sliding_window, page
+    back, mp, lanes = W // T, 2 * W // T, 4
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 96, n).astype(np.int32) for n in (5, W + 3)]
+    tables = 1 + np.arange(lanes * mp, dtype=np.int32).reshape(lanes, mp)
+    nf, nw = len(mcfg.full_index), len(mcfg.window_index)
+    shapes = {"k": (nf, 1 + lanes * mp, widths["k"], T),
+              "v": (nf, 1 + lanes * mp, widths["v"], T),
+              "wk": (nw, lanes, back, widths["wk"], T),
+              "wv": (nw, lanes, back, widths["wv"], T)}
+    state = {name: jnp.asarray(rng.normal(size=shape), jnp.float32)
+             for name, shape in shapes.items()}
+    rows = [(b, start, min(T, len(ids) - start))
+            for b, ids in enumerate(prompts) for start in range(0, len(ids), T)]
+    ids = np.zeros((len(rows), T), np.int32)
+    for r, (b, start, n) in enumerate(rows):
+        ids[r, :n] = prompts[b][start:start + n]
+    slots, starts, lens = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
+    state, first, _ = jax.jit(
+        lambda *a: model.prefill_chunk(params, mcfg, *a, page_tokens=T))(
+            state, jnp.asarray(ids), slots, starts, lens,
+            jnp.asarray(tables)[slots])
+    ends = np.cumsum([-(-len(ids) // T) for ids in prompts]) - 1
+    tokens = np.asarray([first[ends[0]], first[ends[1]], 7, 9], np.int32)
+    positions = np.asarray([5, W + 3, 2, 2], np.int32)
+    active = np.asarray([True, True, False, False])
+    before = {name: np.asarray(x) for name, x in state.items()}
+    after, *_ = jax.jit(
+        lambda *a: model.decode_step(params, mcfg, *a, page_tokens=T))(
+            state, jnp.asarray(tokens), jnp.asarray(positions),
+            jnp.asarray(active), jnp.asarray(tables))
+    after = {name: np.asarray(x) for name, x in after.items()}
+    written = {name: np.zeros(x.shape, bool) for name, x in before.items()}
+    for l in range(mcfg.num_hidden_layers):
+        window = mcfg.is_window(l)
+        names = ("wk", "wv") if window else ("k", "v")
+        n = (mcfg.window_index if window else mcfg.full_index)[l]
+        for b in (0, 1):
+            p = int(positions[b])
+            whole = jnp.asarray(np.append(prompts[b], tokens[b]))
+            at = ((n, b, p % W // T) if window
+                  else (n, tables[b, p // T]))
+            for name, want in zip(names, cache_of(whole, l)):
+                np.testing.assert_allclose(after[name][at][:, p % T],
+                                           want[p], atol=1e-5)
+                written[name][at][:, p % T] = True
+                if window and p >= W:       # the oldest slot, overwritten
+                    np.testing.assert_allclose(before[name][at][:, p % T],
+                                               want[p - W], atol=1e-5)
+    for name in before:
+        changed = after[name] != before[name]
+        if name in ("k", "v"):
+            changed[:, 0] = False           # the spare page: anyone's
+        assert not (changed & ~written[name]).any(), name
+        assert (changed | ~written[name]).all(), name
+
+
+@pytest.mark.parametrize("page", [32, 16, 8])
+def test_a_decode_step_writes_one_column_a_lane_and_nothing_else(page):
+    """A ring of one block (pages of 32), of two and of four."""
+    flat, params, mcfg = make()
+    check_what_one_decode_step_writes(
+        lg, params, mcfg, dict.fromkeys(("k", "v", "wk", "wv"), 32), page,
+        lambda ids, l: reference_cache(flat, ids, l))
+
+
 # -- (e) the expert layer, whole and in shares -------------------------------
 
 def test_all_experts_held_equals_the_references_whole_layer():

@@ -18,6 +18,7 @@ window's mass), so that leaving them out shows."""
 import dataclasses
 import functools
 import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -30,17 +31,17 @@ from benchmarks.refs import mimo_v2_ref as ref
 from benchmarks.refs import weights as weights_mod
 from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
 from deepspeed_tpu.inference.serving.families import laguna as laguna_family
+from deepspeed_tpu.inference.serving.families import mimo_v2 as mimo_family
 from deepspeed_tpu.inference.serving.families import (
     nemotron_h as nemotron_family,
 )
-from deepspeed_tpu.inference.serving.families.mimo_v2 import MiMoV2Family
 from deepspeed_tpu.inference.serving.family import UnsupportedOptionError
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import laguna as lg
 from deepspeed_tpu.models import mimo_v2 as mm
 from deepspeed_tpu.models import nemotron_h as nh
 from deepspeed_tpu.parallel import expert as expert_mod
-from tests.unit import test_laguna, test_nemotron_h
+from tests.unit import test_laguna, test_nemotron_h, test_step_fusion
 
 PATTERN = [0, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1, 0]     # the published one's start
 CFG = {
@@ -174,7 +175,7 @@ def test_engine_logits_match_the_reference_forward_pass(call_rows):
     flat, params, mcfg = make()
     call = call_rows * ROW
     eng = engine(params, mcfg, prefill_chunk_tokens=call)
-    assert isinstance(eng.family, MiMoV2Family)
+    assert isinstance(eng.family, mimo_family.MiMoV2Family)
     assert (eng.family.rows, eng.family.row_tokens) == (call_rows, ROW)
     rng = np.random.default_rng(1)
     lengths = (10, ROW, 150, W + 1, call + 3, 5, 70, 2 * ROW)
@@ -217,7 +218,7 @@ def test_the_ring_counter_counts_what_a_hand_made_step_owes(monkeypatch):
 
     monkeypatch.setattr(slot_state.SlotStateFamily, "decode_step",
                         lambda self, guard: ((), (), 0, 0))
-    fam = MiMoV2Family(model_config())
+    fam = mimo_family.MiMoV2Family(model_config())
     fam.paged_attn_layers, fam.ring_layers = 2, 5
     metrics = ServingMetrics()
     fam.loop = SimpleNamespace(
@@ -382,6 +383,36 @@ def test_the_cache_holds_keys_and_values_of_their_own_widths():
     np.testing.assert_allclose(lg.rope_inv_freq(mcfg.rope(0), 24)[0][1],
                                1e7 ** -0.25, rtol=1e-12)
     assert lg.rope_inv_freq(mm.MiMoV2Config().rope(0), 192)[1] == 64
+
+
+# -- (c2) what one decode step writes ----------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("l",))
+def reference_cache(flat, ids, l):
+    """Layer ``l``'s keys (rotated) and values (unscaled, as the program
+    caches them) at every position of ``ids [T]`` as the reference computes
+    them: its own ``hidden_states`` cut at ``l`` layers is the layer's
+    input. ``(k [T, KV * 24], v [T, KV * 16])``."""
+    D = ref.dims_of(CFG)
+    kind = "swa" if D["window_layer"][l] else "full"
+    _, g, hd, _ = D[kind]
+    h = ref.hidden_states(flat, ids, dict(D, layers=l))
+    x = ref._rms(h, flat[f"layers/{l}/input_layernorm/scale"], D["eps"])
+    k = ref.rope((x @ flat[f"layers/{l}/self_attn/k_proj/kernel"]).reshape(
+        len(ids), g, hd), jnp.arange(len(ids)), D["theta_" + kind],
+        D["rotary_factor"])
+    return (k.reshape(len(ids), -1),
+            x @ flat[f"layers/{l}/self_attn/v_proj/kernel"])
+
+
+@pytest.mark.parametrize("page", [16, 8])
+def test_a_decode_step_writes_one_column_a_lane_and_nothing_else(page):
+    """A ring of one block (pages of 16, as published: window = page) and
+    of two; keys and values of their own widths in all four arrays."""
+    flat, params, mcfg = make()
+    test_laguna.check_what_one_decode_step_writes(
+        mm, params, mcfg, mcfg.cache_widths, page,
+        lambda ids, l: reference_cache(flat, ids, l))
 
 
 # -- (d) the share tied to the model ------------------------------------------
@@ -626,16 +657,22 @@ def test_background_loop_streams_tokens():
 
 SLOTS, PAGES, MP, ROWS = 3, 9, 4, 4
 # sha256 (first 16 hex digits) of ``jit(...).lower(...).as_text()`` at the
-# sizes of the two families' own unit tests, read at commit 61d9b87 (PR 39, the parent of the PR that gave the
-# grouped-query and window functions a value width and a sink) with jax
-# 0.9.0. The text carries no names or locations, so a refactor that traces
-# the same operations in the same order keeps it. A change that is MEANT to
-# alter these programs, or a jax that prints them otherwise, reads the four
-# anew (the test's message prints them).
+# sizes of the two families' own unit tests, with jax 0.9.0. The two prefill
+# programs were read at commit 61d9b87 (PR 39, the parent of the PR that
+# gave the grouped-query and window functions a value width and a sink) and
+# have held since. The two decode programs were read anew in PR 41, which
+# was meant to alter them (a decode step writes its new keys and values in
+# one operation a layer and array, and a window layer attends the ring as
+# it found it plus its own key); that the prefill values passed that PR
+# untouched is its proof that no prefill program moved. The text carries no
+# names or locations, so a refactor that traces the same operations in the
+# same order keeps it. A change that is MEANT to alter these programs, or a
+# jax that prints them otherwise, reads them anew (the test's message
+# prints them).
 PARENT_TEXT = {
-    "laguna_decode": "d65b2139894b9eb1",
+    "laguna_decode": "b837a006efe47cca",
     "laguna_prefill": "a6705ed92545bbca",
-    "nemotron_decode": "26f8f72085525e53",
+    "nemotron_decode": "d441420ad3e1c1bc",
     "nemotron_prefill": "483ba7f75d40b702",
 }
 
@@ -644,7 +681,8 @@ def _sds(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
 
 
-def _lowered_text(which):
+def _lowered(which):
+    """``<family>_<program>`` traced at the tiny shapes, not compiled."""
     i32 = jnp.int32
     family, program = which.split("_")
     args = ((_sds((SLOTS,), i32), _sds((SLOTS,), i32),
@@ -661,6 +699,14 @@ def _lowered_text(which):
                  "wv": _sds((3, SLOTS, 2, 32, ROW))}
         fn = (laguna_family._laguna_decode_step_jit if program == "decode"
               else laguna_family._laguna_prefill_chunk_jit)
+    elif family == "mimo":
+        cfg = model_config()
+        shapes = ref.weight_shapes(CFG)
+        state = {name: _sds(((5, SLOTS, 1) if name[0] == "w" else (2, PAGES))
+                            + (width, ROW))
+                 for name, width in cfg.cache_widths.items()}
+        fn = (mimo_family._mimo_decode_step_jit if program == "decode"
+              else mimo_family._mimo_prefill_chunk_jit)
     else:
         cfg = test_nemotron_h.model_config(test_nemotron_h.CFG)
         shapes = nemotron_h_ref.weight_shapes(test_nemotron_h.CFG)
@@ -672,8 +718,12 @@ def _lowered_text(which):
               if program == "decode"
               else nemotron_family._nemotron_prefill_chunk_jit)
     params = weights_mod.nest({k: _sds(v) for k, v in shapes.items()})
-    return fn.lower(params, state, *args, cfg=cfg, page_tokens=ROW,
-                    keep_logits=False).as_text()
+    return state, fn.lower(params, state, *args, cfg=cfg, page_tokens=ROW,
+                           keep_logits=False)
+
+
+def _lowered_text(which):
+    return _lowered(which)[1].as_text()
 
 
 @pytest.mark.parametrize("which", sorted(PARENT_TEXT))
@@ -687,3 +737,26 @@ def test_the_other_families_lowered_programs_are_the_parents_text(which):
     assert "stablehlo" in text and len(text) > 100000
     got = hashlib.sha256(text.encode()).hexdigest()[:16]
     assert got == PARENT_TEXT[which], (which, got)
+
+
+@pytest.mark.parametrize("which", ["laguna_decode", "mimo_decode",
+                                   "nemotron_decode"])
+def test_no_loop_of_a_decode_program_writes_a_pool_array(which):
+    """A decode step's new keys and values reach a ring or a page in one
+    operation a layer and array (a whole layer's blocks at a static index,
+    or one scatter of whole blocks), never lane by lane: in the program as
+    traced (a ring of two blocks in Laguna's, of one in MiMo-V2's, pages
+    alone in Nemotron-H's) no loop's body updates anything of a pool
+    array's shape. The loops that are there (the work list's tiles, the
+    experts' tiles) write buffers of their own."""
+    state, lowered = _lowered(which)
+    text = lowered.compiler_ir(dialect="hlo").as_hlo_text()
+    pools = {"f32[" + ",".join(map(str, x.shape)) + "]"
+             for name, x in state.items() if name in ("k", "v", "wk", "wv")}
+    loops = test_step_fusion._loop_bodies(text)
+    assert loops and any(shape in text for shape in pools)
+    writes = [l for lines in loops for l in lines
+              if re.search(r"\s(dynamic-update-slice|scatter)\(", l)]
+    assert writes, "the work list's loop writes its partial sums"
+    assert not [l for l in writes
+                if l.split("=")[1].split()[0].split("{")[0] in pools]

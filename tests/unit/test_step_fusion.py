@@ -253,10 +253,12 @@ _COLLECTIVE = re.compile(
 
 
 def _computations(text):
-    """Compiled HLO text as {computation name: its instruction lines}."""
+    """HLO text, compiled or as traced (whose computations are headed by
+    their name alone), as {computation name: its instruction lines}."""
     comps, name = {}, None
     for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$", line)
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*->.*)?\{\s*$",
+                        line)
         if head:
             name = head.group(1)
             comps[name] = []
