@@ -228,7 +228,7 @@ class SlotStateFamily(ServingFamily):
         host_tokens, moe = loop.read_back(before[:2], "decode", newest=False)
         loop.metrics.record_moe(self.cfg.n_moe_layers, *moe.tolist())
         loop.metrics.record_state_pool(
-            pool.slots_in_use, pool.pages_in_use, pool.slot_bytes(),
+            pool.state_slots_in_use, pool.pages_in_use, pool.slot_bytes(),
             pool.paged_bytes())
         lanes.tokens = host_tokens.copy()
         return ([slot for slot, req in lanes.requests.items()
@@ -246,7 +246,9 @@ PREFILL_HOLD_STEPS = 16
 class RowPrefillFamily(SlotStateFamily):
     """A ``SlotStateFamily`` whose attention layers over pages are
     ``models/nemotron_h.py``'s (``paged_attn_layers`` of them, which
-    ``build`` sets) and whose prefill call runs ``prefill_chunk_tokens``
+    ``build`` sets; a family whose paged attention walks no work list,
+    ``families/keye.py``, counts what it reads in a ``count_attended`` of
+    its own) and whose prefill call runs ``prefill_chunk_tokens``
     positions as ``rows`` rows of ``row_tokens`` tokens (``build`` sets
     both). The prompts being read take rows in the order they were
     admitted, each as many as its remaining tokens need while rows are

@@ -33,6 +33,7 @@ description, and which of the slot arrays a new occupant must find zeroed;
 
 import numpy as np
 
+from deepspeed_tpu.models.keye import KeyeConfig
 from deepspeed_tpu.models.kimi_linear import KimiLinearConfig
 from deepspeed_tpu.models.laguna import LagunaConfig
 from deepspeed_tpu.models.mimo_v2 import MiMoV2Config
@@ -160,5 +161,8 @@ def family_for(model_config):
         from deepspeed_tpu.inference.serving.families.mimo_v2 import (
             MiMoV2Family)
         return MiMoV2Family(model_config)
+    if isinstance(model_config, KeyeConfig):
+        from deepspeed_tpu.inference.serving.families.keye import KeyeFamily
+        return KeyeFamily(model_config)
     from deepspeed_tpu.inference.serving.families.gpt2 import GPT2Family
     return GPT2Family(model_config)

@@ -605,7 +605,11 @@ class HybridStatePool(PagedSlots):
       axis): ``width`` values a token, a page's tokens along the last
       axis (they fill the chip's 128-wide tiles exactly, where a width
       such as 576 would be padded), reached through the lane's page table
-      like the KV pool's pages (a latent-attention cache);
+      like the KV pool's pages (a latent-attention cache); or, described
+      by a token's shape instead of a width, ``[layers, n_pages,
+      page_tokens, *shape]``: a page's tokens first, each a block of its
+      own (``(8, 128)`` is one tile of the chip's memory), for a model
+      that fetches single tokens out of its pages and not whole pages;
     - *slot* arrays ``[layers, max_slots, ...]``: a fixed-size state a
       lane (recurrent state, convolution tails, a window layer's ring of
       keys and values).
@@ -623,8 +627,9 @@ class HybridStatePool(PagedSlots):
 
     def __init__(self, max_slots, max_seq_len, paged, slotted,
                  page_tokens=None, pool_tokens=None, reset=None):
-        """``paged``: {name: (layers, width, dtype)}; ``slotted``:
-        {name: (layers, per-slot shape, dtype)}; ``reset``: the slot
+        """``paged``: {name: (layers, width or a token's shape, dtype)};
+        ``slotted``: {name: (layers, per-slot shape, dtype)}, which may be
+        empty (a model whose state is pages only); ``reset``: the slot
         arrays ``reset_slot`` zeroes (None: all)."""
         super().__init__(max_slots, max_seq_len, page_tokens, pool_tokens)
         self.paged_names = tuple(paged)
@@ -636,8 +641,9 @@ class HybridStatePool(PagedSlots):
                              f"of {self.slot_names}")
         self.state = {}
         for name, (layers, width, dtype) in paged.items():
-            self.state[name] = jnp.zeros(
-                (layers, self.n_pages, width, self.page_tokens), dtype)
+            page = ((width, self.page_tokens) if isinstance(width, int)
+                    else (self.page_tokens,) + tuple(width))
+            self.state[name] = jnp.zeros((layers, self.n_pages) + page, dtype)
         for name, (layers, shape, dtype) in slotted.items():
             self.state[name] = jnp.zeros(
                 (layers, self.max_slots) + tuple(shape), dtype)
@@ -661,6 +667,12 @@ class HybridStatePool(PagedSlots):
             {n: self.state[n] for n in self.reset_names}, jnp.int32(slot))
         self.state.update(zeroed)
         self.slot_resets += 1
+
+    @property
+    def state_slots_in_use(self):
+        """Slots whose slot arrays hold an occupant's state: the lanes in
+        use, or none where the pool has no slot array (pages only)."""
+        return self.slots_in_use if self.slot_names else 0
 
     def paged_bytes(self):
         return self._paged_bytes

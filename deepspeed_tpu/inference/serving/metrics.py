@@ -155,6 +155,8 @@ class ServingMetrics:
         self.decode_context_tokens = 0
         self.pool_pages_in_use_steps = 0
         self.decode_ring_positions = 0
+        self.dsa_keys_scored = 0
+        self.dsa_keys_attended = 0
         self.decode_attn_blocks_walked = 0
         self.decode_attn_blocks_dense = 0
         self.page_waits = 0
@@ -225,6 +227,15 @@ class ServingMetrics:
         its active lanes' rings hold behind their mask, summed over the
         window layers (``decode_ring_positions``)."""
         self.decode_ring_positions += int(positions)
+
+    def record_selected(self, keys_scored, keys_attended):
+        """One decode step of a family whose attention reads what a learned
+        indexer selects: the keys its indexers scored, summed over layers
+        and active lanes (every position a lane holds), and the keys its
+        attention then read (``min(context, topk)`` a lane a layer):
+        ``dsa_keys_scored``, ``dsa_keys_attended``."""
+        self.dsa_keys_scored += int(keys_scored)
+        self.dsa_keys_attended += int(keys_attended)
 
     def record_attn_blocks(self, blocks, layers):
         """One decode step of a family whose paged attention walks a work
@@ -514,6 +525,8 @@ class ServingMetrics:
             "decode_context_tokens": self.decode_context_tokens,
             "pool_pages_in_use_steps": self.pool_pages_in_use_steps,
             "decode_ring_positions": self.decode_ring_positions,
+            "dsa_keys_scored": self.dsa_keys_scored,
+            "dsa_keys_attended": self.dsa_keys_attended,
             "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
             "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
             "page_waits": self.page_waits,

@@ -512,7 +512,7 @@ def _ffn(lp, cfg, i, x, live, tile):
     fell on held experts, held experts touched, the busiest one's tokens."""
     if not cfg.layer_is_moe(i):
         return swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
-    return expert_mod.sigmoid_moe_ffn(
+    return expert_mod.routed_moe_ffn(
         lp["mlp"], x, live, k=cfg.num_experts_per_token,
         scaling=cfg.routed_scaling_factor,
         renormalize=cfg.moe_renormalize, held=cfg.experts_held, tile=tile)

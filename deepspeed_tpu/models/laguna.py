@@ -524,10 +524,10 @@ def window_decode(p, shape, x, wk, wv, n, positions, active, *, window,
 def _ffn(lp, cfg, l, x, live, tile):
     """Layer ``l``'s FFN over flat tokens ``x [N, d]``; ``live [N]`` says
     which tokens are real. Returns ``(y, counts [3] int32)`` as
-    ``expert.sigmoid_moe_ffn`` gives them (zeros for a dense layer)."""
+    ``expert.routed_moe_ffn`` gives them (zeros for a dense layer)."""
     if not cfg.is_moe(l):
         return swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
-    return expert_mod.sigmoid_moe_ffn(
+    return expert_mod.routed_moe_ffn(
         lp["mlp"], x, live, k=cfg.num_experts_per_tok,
         scaling=cfg.moe_routed_scaling_factor, renormalize=True,
         held=(0, cfg.num_experts), tile=tile)

@@ -254,10 +254,10 @@ def _attention(cfg, l, p):
 def _ffn(lp, cfg, l, x, live, tile, every_expert=False):
     """Layer ``l``'s FFN over flat tokens ``x [N, d]``; ``live [N]`` says
     which tokens are real. Returns ``(y, counts [3] int32)`` as
-    ``expert.sigmoid_moe_ffn`` gives them (zeros for a dense layer)."""
+    ``expert.routed_moe_ffn`` gives them (zeros for a dense layer)."""
     if not cfg.is_moe(l):
         return swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
-    return expert_mod.sigmoid_moe_ffn(
+    return expert_mod.routed_moe_ffn(
         lp["mlp"], x, live, k=cfg.num_experts_per_tok,
         scaling=cfg.routed_scaling_factor or 1.0,
         renormalize=cfg.norm_topk_prob, held=cfg.experts_held, tile=tile,

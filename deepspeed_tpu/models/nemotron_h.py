@@ -580,8 +580,8 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
 def _experts(lp, cfg, x, live, tile):
     """An expert block over flat tokens ``x [N, d]``; ``live [N]`` says
     which tokens are real. Returns ``(y, counts [3] int32)`` as
-    ``expert.sigmoid_moe_ffn`` gives them."""
-    return expert_mod.sigmoid_moe_ffn(
+    ``expert.routed_moe_ffn`` gives them."""
+    return expert_mod.routed_moe_ffn(
         lp["mixer"], x, live, k=cfg.num_experts_per_tok,
         scaling=cfg.routed_scaling_factor, renormalize=cfg.norm_topk_prob,
         held=cfg.experts_held, tile=tile)

@@ -102,7 +102,8 @@ def moe_ffn(params, x, capacity):
 
 
 # ---------------------------------------------------------------------------
-# Sigmoid top-k routing without capacity; a chip's share of the experts
+# Top-k routing without capacity (sigmoid or softmax scores); a chip's share
+# of the experts
 # ---------------------------------------------------------------------------
 
 def sigmoid_topk_routing(x, router, bias, k, scaling=1.0, renormalize=True):
@@ -121,6 +122,21 @@ def sigmoid_topk_routing(x, router, bias, k, scaling=1.0, renormalize=True):
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), w * scaling
+
+
+def softmax_topk_routing(x, router, k, renormalize=True):
+    """Softmax router over ALL experts (the Qwen3-MoE family's, which Keye-VL
+    shares): ``p = softmax(x W_r)`` in float32 at ``highest`` matmul
+    precision, the ``k`` largest are picked and a pick weighs ``p / sum of
+    the picked p`` (``renormalize``) or ``p`` itself. No bias, no scaling.
+    x [T, d]; returns (idx [T, k] int32, weights [T, k] f32)."""
+    p = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(p, k)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w
 
 
 def expert_function(weights):
@@ -219,28 +235,38 @@ def held_experts_ffn(x, experts, idx, weights, held, tile, live=None,
     return y, stats
 
 
-def sigmoid_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
-                    tile, every_expert=False):
-    """One chip's share of a sigmoid-routed expert layer with a shared
-    expert: the router scores every expert of the published count and picks
-    ``k`` a token; this chip adds up what the experts it holds
-    (``held = (first, count)``) give, plus the shared expert, which every
-    chip of the deployment computes alike. What the other chips' experts
-    would add is their part of the sum: on one chip the layer runs without
-    its exchange, and nothing stands in for it.
+def routed_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
+                   tile, every_expert=False, scoring="sigmoid"):
+    """One chip's share of a routed expert layer, with a shared expert
+    where the parameters have one: the router scores every expert of the
+    published count and picks ``k`` a token, by ``scoring``: ``"sigmoid"``
+    (``sigmoid_topk_routing``: a correction bias that chooses where the
+    parameters hold one, ``scaling`` on the weights) or ``"softmax"``
+    (``softmax_topk_routing``: no bias, and ``scaling`` must be 1). This
+    chip adds up what the experts it holds (``held = (first, count)``)
+    give, plus the shared expert, which every chip of the deployment
+    computes alike. What the other chips' experts would add is their part
+    of the sum: on one chip the layer runs without its exchange, and
+    nothing stands in for it.
 
-    params: {gate: {kernel [d, E], e_score_correction_bias [E] (optional)},
-    experts: {gate_proj, up_proj, down_proj} or {up_proj, down_proj},
-    shared_experts (optional): the same names with ``/kernel``, of a width
-    of its own}; ``expert_function`` reads each one's function off its
-    matrices. x [T, d]; ``every_expert`` as ``held_experts_ffn`` takes it.
-    Returns (y [T, d] in x's type, counts [3] int32 as ``held_experts_ffn``
-    gives them)."""
+    params: {gate: {kernel [d, E], e_score_correction_bias [E] (optional,
+    sigmoid only)}, experts: {gate_proj, up_proj, down_proj} or {up_proj,
+    down_proj}, shared_experts (optional): the same names with ``/kernel``,
+    of a width of its own}; ``expert_function`` reads each one's function
+    off its matrices. x [T, d]; ``every_expert`` as ``held_experts_ffn``
+    takes it. Returns (y [T, d] in x's type, counts [3] int32 as
+    ``held_experts_ffn`` gives them)."""
+    gate = params["gate"]
     with jax.named_scope("moe_route"):
-        idx, w = sigmoid_topk_routing(
-            x, params["gate"]["kernel"],
-            params["gate"].get("e_score_correction_bias"), k, scaling,
-            renormalize)
+        if scoring == "sigmoid":
+            idx, w = sigmoid_topk_routing(
+                x, gate["kernel"], gate.get("e_score_correction_bias"), k,
+                scaling, renormalize)
+        elif scoring == "softmax":
+            assert scaling == 1.0 and "e_score_correction_bias" not in gate
+            idx, w = softmax_topk_routing(x, gate["kernel"], k, renormalize)
+        else:
+            raise ValueError(f"scoring {scoring!r}: sigmoid or softmax")
     with jax.named_scope("moe_experts"):
         y, stats = held_experts_ffn(x, params["experts"], idx, w, held, tile,
                                     live, every_expert)

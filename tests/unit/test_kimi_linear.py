@@ -49,7 +49,7 @@ CFG = {
 }
 CHUNK = 64
 
-moe_ffn = jax.jit(expert_mod.sigmoid_moe_ffn, static_argnames=(
+moe_ffn = jax.jit(expert_mod.routed_moe_ffn, static_argnames=(
     "k", "scaling", "renormalize", "held", "tile"))
 
 

@@ -54,7 +54,7 @@ CFG = {
 ROW = 16                          # a page, which is a row of a prefill call
 W = CFG["sliding_window"]
 
-moe_ffn = jax.jit(expert_mod.sigmoid_moe_ffn, static_argnames=(
+moe_ffn = jax.jit(expert_mod.routed_moe_ffn, static_argnames=(
     "k", "scaling", "renormalize", "held", "tile"))
 
 

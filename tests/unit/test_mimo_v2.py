@@ -67,7 +67,7 @@ ROW = 16                          # a page, a prefill row and the window
 W = CFG["sliding_window"]
 PUBLISHED = 16                    # experts the router scores
 
-moe_ffn = jax.jit(expert_mod.sigmoid_moe_ffn, static_argnames=(
+moe_ffn = jax.jit(expert_mod.routed_moe_ffn, static_argnames=(
     "k", "scaling", "renormalize", "held", "tile"))
 
 

@@ -241,7 +241,7 @@ def test_held_experts_loop_runs_the_function_its_matrices_name(
     grouping loop and for a shared expert of a width of its own; each
     against its plain form, every token by every pick."""
     from deepspeed_tpu.parallel.expert import (
-        sigmoid_moe_ffn, sigmoid_topk_routing)
+        routed_moe_ffn, sigmoid_topk_routing)
 
     d, f, E, k, T = 16, 24, 6, 2, 40
     names = {"swiglu": ("gate_proj", "up_proj", "down_proj"),
@@ -260,7 +260,7 @@ def test_held_experts_loop_runs_the_function_its_matrices_name(
         params["shared_experts"] = {
             n: {"kernel": w} for n, w in matrices(shared_width).items()}
     x = jax.random.normal(next(keys), (T, d))
-    y, stats = jax.jit(sigmoid_moe_ffn, static_argnames=(
+    y, stats = jax.jit(routed_moe_ffn, static_argnames=(
         "k", "scaling", "renormalize", "held", "tile"))(
             params, x, k=k, scaling=1.5, renormalize=True, held=(0, E),
             tile=8)

@@ -45,7 +45,7 @@ CFG = {
 ROW = CFG["chunk_size"]
 CALL = 4 * ROW                    # positions a prefill call runs: 4 rows
 
-moe_ffn = jax.jit(expert_mod.sigmoid_moe_ffn, static_argnames=(
+moe_ffn = jax.jit(expert_mod.routed_moe_ffn, static_argnames=(
     "k", "scaling", "renormalize", "held", "tile"))
 
 

@@ -63,7 +63,7 @@ def test_the_loop_imports_no_model_code():
 def test_there_are_families_to_hold_to_the_contract():
     names = {os.path.basename(p) for p in FAMILY_FILES}
     assert {"gpt2.py", "kimi_linear.py", "nemotron_h.py", "laguna.py",
-            "mimo_v2.py", "slot_state.py"} <= names, names
+            "mimo_v2.py", "keye.py", "slot_state.py"} <= names, names
 
 
 @pytest.mark.parametrize(
@@ -166,6 +166,8 @@ HOMES = {
     "_laguna_prefill_chunk_jit": "families/laguna.py",
     "_mimo_decode_step_jit": "families/mimo_v2.py",
     "_mimo_prefill_chunk_jit": "families/mimo_v2.py",
+    "_keye_decode_step_jit": "families/keye.py",
+    "_keye_prefill_chunk_jit": "families/keye.py",
     "_install_pages": "kv_pool.py",
     "_zero_slot": "kv_pool.py",
 }
