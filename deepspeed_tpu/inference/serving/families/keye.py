@@ -41,6 +41,18 @@ def _keye_decode_step_jit(params, state, tokens, positions, active,
     return state, tokens, positions, logits if keep_logits else None, moe
 
 
+def count_prefill_blocks(metrics, starts, lens, *, page_tokens, layers):
+    """What a prefill call's attention walks, from the rows the host laid
+    out (``starts [R]``, ``lens [R]``, 0 an empty row): the key blocks each
+    row's own prompt reaches, and every row to the longest one's end, each
+    summed over ``layers`` (``ServingMetrics.record_prefill_blocks``)."""
+    span = ky.prefill_key_span(page_tokens)
+    blocks = np.where(lens > 0, -(-(starts.astype(np.int64) + lens) // span),
+                      0)
+    metrics.record_prefill_blocks(layers * blocks.sum(),
+                                  layers * len(blocks) * blocks.max())
+
+
 class KeyeFamily(RowPrefillFamily):
     """Keye-VL through the shared loop. The pool is described from the
     configuration's ``cache_widths``: ``kv`` a token's tile of key and
@@ -78,6 +90,11 @@ class KeyeFamily(RowPrefillFamily):
         loop.metrics.record_state_pool(0, 0, pool.slot_bytes(),
                                        pool.paged_bytes())
         return params, pool
+
+    def count_prefill(self, starts, lens):
+        count_prefill_blocks(self.loop.metrics, starts, lens,
+                             page_tokens=self.row_tokens,
+                             layers=self.cfg.num_hidden_layers)
 
     def count_attended(self, held):
         """What the step's indexers score and what its attention then

@@ -283,6 +283,11 @@ class RowPrefillFamily(SlotStateFamily):
             held // decode_key_span(self.loop.pool.page_tokens) + 1,
             self.paged_attn_layers)
 
+    def count_prefill(self, starts, lens):
+        """``starts [R]``, ``lens [R]`` of the prefill call about to run
+        (0: an empty row): a family that counts what the call walks does it
+        here, on the host."""
+
     def _rows_waiting(self):
         T = self.row_tokens
         return sum(-(-(len(st.req.prompt) - st.pos) // T)
@@ -339,6 +344,7 @@ class RowPrefillFamily(SlotStateFamily):
         for st, _, _ in riders:
             if st.pos == 0:
                 loop.metrics.record_queue_wait(t0 - st.req.submit_time)
+        self.count_prefill(starts, lens)
         with cspan:
             loop.launched("prefill")
             pool.state, first, self.last_prefill_logits = (

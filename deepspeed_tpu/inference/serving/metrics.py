@@ -157,6 +157,8 @@ class ServingMetrics:
         self.decode_ring_positions = 0
         self.dsa_keys_scored = 0
         self.dsa_keys_attended = 0
+        self.dsa_prefill_blocks_walked = 0
+        self.dsa_prefill_blocks_dense = 0
         self.decode_attn_blocks_walked = 0
         self.decode_attn_blocks_dense = 0
         self.page_waits = 0
@@ -236,6 +238,15 @@ class ServingMetrics:
         ``dsa_keys_scored``, ``dsa_keys_attended``."""
         self.dsa_keys_scored += int(keys_scored)
         self.dsa_keys_attended += int(keys_attended)
+
+    def record_prefill_blocks(self, walked, dense):
+        """One prefill call of such a family: the key blocks its rows' own
+        prompts reach, summed over rows and layers (what
+        ``ops/paged_prefill.py``'s kernel walks), and rows x the longest
+        row's blocks (what the walk in plain operations runs):
+        ``dsa_prefill_blocks_walked``, ``dsa_prefill_blocks_dense``."""
+        self.dsa_prefill_blocks_walked += int(walked)
+        self.dsa_prefill_blocks_dense += int(dense)
 
     def record_attn_blocks(self, blocks, layers):
         """One decode step of a family whose paged attention walks a work
@@ -527,6 +538,8 @@ class ServingMetrics:
             "decode_ring_positions": self.decode_ring_positions,
             "dsa_keys_scored": self.dsa_keys_scored,
             "dsa_keys_attended": self.dsa_keys_attended,
+            "dsa_prefill_blocks_walked": self.dsa_prefill_blocks_walked,
+            "dsa_prefill_blocks_dense": self.dsa_prefill_blocks_dense,
             "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
             "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
             "page_waits": self.page_waits,

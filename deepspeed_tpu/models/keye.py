@@ -33,8 +33,10 @@ serving engine jits, laid out as ``models/laguna.py``'s:
   writes each row as one page, scores the rows' queries against their
   prompts' indexer keys a block of pages at a time, finds each query's
   ``topk``-th largest score exactly (bisection over the scores' bits), and
-  walks the key blocks as ``nemotron_h.gqa_prefill`` does with the
-  selection as its mask.
+  walks the key blocks with the selection as its mask: on a TPU in one
+  kernel a layer that keeps a block's scores in VMEM
+  (``ops/paged_prefill.py``), elsewhere as ``nemotron_h.gqa_prefill``
+  does.
 - ``decode_step``: one token for every active lane. A layer scores the
   lane's indexer keys, takes the exact top ``topk`` (``lax.top_k``: the
   lower position first among equal scores) and **fetches those positions'
@@ -77,6 +79,7 @@ from deepspeed_tpu.models.nemotron_h import (
     _blocks_of_pages,
     _gqa_project,
 )
+from deepspeed_tpu.ops import paged_prefill
 from deepspeed_tpu.ops.column_write import write_columns
 from deepspeed_tpu.parallel import expert as expert_mod
 
@@ -328,6 +331,105 @@ def _tiles(cfg, k, v, dtype):
                             v.reshape(lead + (kvh, hd))], axis=-2).astype(dtype)
 
 
+def prefill_key_span(page_tokens):
+    """Positions in a key block of the prefill walk (whole pages)."""
+    return max(1, PREFILL_KEY_BLOCK // page_tokens) * page_tokens
+
+
+def _selected(j, uj, least, ties_left, ties_before, below):
+    """The selection within key block ``j``, ``bool [span, T]`` (keys first,
+    the row's queries last: as ``ops/paged_prefill.py`` takes a mask): a key
+    whose score ``uj [span, T]`` is above its query's threshold ``least [1,
+    T]``, and of the keys exactly at it the first ``ties_left [1, T]`` of the
+    whole row, ``ties_before [blocks, T]`` of which lie in earlier blocks
+    (``below [span, span]`` is ``i >= j``: a product with it counts a block's
+    ties up to each key, exactly). A key after the query scored ``-inf``,
+    which is below every threshold. Traced inside ``paged_prefill``'s
+    kernel, and by the plain walk."""
+    tie = uj == least
+    nth = jnp.dot(below, tie.astype(below.dtype),
+                  preferred_element_type=jnp.float32).astype(jnp.int32)
+    here = jax.lax.broadcasted_iota(jnp.int32, ties_before.shape, 0) == j
+    nth = nth + jnp.sum(jnp.where(here, ties_before, 0), axis=0,
+                        keepdims=True)
+    return (uj > least) | (tie & (nth <= ties_left))
+
+
+def _attend_blocks(q, kv_pool, n, tables, bp, n_blocks, selection):
+    """The walk in plain operations, the twin of ``paged_prefill``'s kernel
+    for every backend but a TPU (and every shape but its own): ``n_blocks``
+    key blocks of ``bp`` pages of row ``n`` of ``kv_pool`` under the
+    selection (``_selected``'s operands, a row each), every row to the
+    call's longest, as ``nemotron_h.gqa_prefill`` walks its own. A block's
+    float32 scores ``[R, KV, J, T, span]`` are an array in memory here.
+    Returns the context ``[R, KV, J, T, hd]`` float32."""
+    R, T, kvh, J, hd = q.shape
+    u, least, ties_left, ties_before, below = selection
+    span = below.shape[0]
+    scale = hd ** -0.5
+
+    def block(j):
+        pages = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+        kvb = kv_pool[n, pages].astype(q.dtype)        # [R, bp, pt, 2KV, hd]
+        s = jnp.einsum("rtgjd,rnpgd->rgjtnp", q, kvb[..., :kvh, :],
+                       preferred_element_type=jnp.float32).reshape(
+                           R, kvh, J, T, span) * scale
+        uj = jax.lax.dynamic_slice_in_dim(u, j * span, span, axis=1)
+        ok = jax.vmap(_selected, in_axes=(None, 0, 0, 0, 0, None))(
+            j, uj, least, ties_left, ties_before, below)     # [R, span, T]
+        s = jnp.where(jnp.swapaxes(ok, 1, 2)[:, None, None], s, -1e30)
+
+        def weigh(pr):
+            return jnp.einsum(
+                "rgjtnp,rnpgd->rgjtd",
+                pr.astype(q.dtype).reshape(R, kvh, J, T, -1, kvb.shape[2]),
+                kvb[..., kvh:, :], preferred_element_type=jnp.float32)
+        return s, weigh
+
+    return _online_softmax_loop(n_blocks, block, (R, kvh, J, T), hd)
+
+
+def attend_selected(q, kv_pool, n, tables, bp, starts, lens, u, topk):
+    """The second half of ``dsa_prefill``: ``q [R, T, KV, J, hd]`` attends,
+    of row ``n`` of ``kv_pool`` under ``tables [R, blocks * bp]``, the
+    ``min(topk, position + 1)`` keys with the largest ``u [R, T, S]``
+    (sortable scores; a key after its query holds ``-inf``'s image or
+    less), the lower position first among equals. The threshold and the
+    tie counts in plain operations (``dsa_select``), then the walk
+    (``dsa_attend``): on a TPU at the shapes it takes, one kernel a layer
+    that keeps a block's scores in VMEM and walks each row's own blocks
+    (``ops/paged_prefill.py``); anywhere else ``_attend_blocks``. Returns
+    the context ``[R, KV, J, T, hd]``."""
+    R, T = q.shape[:2]
+    span = bp * T
+    pos = starts[:, None] + jnp.arange(T)[None, :]                   # [R, T]
+    with jax.named_scope("dsa_select"):
+        take = jnp.minimum(topk, pos + 1)                            # [R, T]
+        least = kth_largest(u, take)[..., None]
+        # of the keys that score exactly ``least`` only the first few are
+        # taken, lower positions first: as many as the keys above it leave
+        # of ``take``; counted a block here and a key inside its block
+        ties_left = take - jnp.sum((u > least).astype(jnp.int32), axis=-1)
+        ties = jnp.sum((u == least).reshape(R, T, -1, span)
+                       .astype(jnp.int32), axis=-1)
+        ties_before = jnp.cumsum(ties, axis=-1) - ties       # [R, T, blocks]
+        # as ``_selected`` takes them: keys first, the row's queries last
+        selection = (jnp.swapaxes(u, 1, 2), jnp.swapaxes(least, 1, 2),
+                     ties_left[:, None, :], jnp.swapaxes(ties_before, 1, 2),
+                     jnp.tril(jnp.ones((span, span), q.dtype)))  # [i >= j]
+    with jax.named_scope("dsa_attend"):
+        if paged_prefill.usable(q, kv_pool):
+            # a row walks its own prompt's blocks; none, where it is empty
+            counts = jnp.where(lens > 0, (starts + lens + span - 1) // span, 0)
+            return paged_prefill.attend_pages(
+                q, kv_pool, n, tables, counts, _selected,
+                keyed=selection[:1], rowed=selection[1:4],
+                shared=selection[4:], block_pages=bp)
+        end = jnp.max(jnp.where(lens > 0, starts + lens, 0))
+        return _attend_blocks(q, kv_pool, n, tables, bp,
+                              (end + span - 1) // span, selection)
+
+
 def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
                 page_tokens):
     """Attention under the indexer's selection over ``R`` rows of one page
@@ -338,11 +440,11 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
     every query scores its prompt's indexer keys up to its own position, a
     block of pages at a time (``dsa_index``), its ``min(topk, position +
     1)``-th largest score is found (``dsa_select``), and the keys and values
-    are walked block by block as ``nemotron_h.gqa_prefill`` walks them,
-    attending where the score is above that threshold and, of the keys
-    that score exactly the threshold, to the lowest positions that fill the
-    count: the set ``lax.top_k`` gives the decode step (``dsa_attend``).
-    Returns ``(y, kv_pool, ik_pool)``."""
+    are walked block by block, attending where the score is above that
+    threshold and, of the keys that score exactly the threshold, to the
+    lowest positions that fill the count: the set ``lax.top_k`` gives the
+    decode step (``dsa_attend``; both in ``attend_selected``). Returns
+    ``(y, kv_pool, ik_pool)``."""
     R, T, _ = x.shape
     shape = cfg.attention
     kvh, hd = shape.num_key_value_heads, shape.head_dim
@@ -389,43 +491,8 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
         u = jax.lax.fori_loop(
             0, n_blocks, score,
             jnp.full((R, T, tables.shape[1] * pt), _LOWEST, jnp.int32))
-    with jax.named_scope("dsa_select"):
-        take = jnp.minimum(cfg.topk, pos + 1)                        # [R, T]
-        least = kth_largest(u, take)[..., None]
-        # of the keys that score exactly ``least`` only the first few are
-        # taken, lower positions first: as many as the keys above it leave
-        # of ``take``; counted a block here and a key inside its block
-        ties_left = take - jnp.sum((u > least).astype(jnp.int32), axis=-1)
-        ties = jnp.sum((u == least).reshape(R, T, -1, span)
-                       .astype(jnp.int32), axis=-1)
-        ties_before = jnp.cumsum(ties, axis=-1) - ties       # [R, T, blocks]
-        upto = jnp.triu(jnp.ones((span, span), x.dtype))     # [i <= j]
-    scale = hd ** -0.5
-
-    def block(j):
-        kvb = kv_pool[n, pages_of(j)].astype(x.dtype)  # [R, bp, pt, 2KV, hd]
-        s = jnp.einsum("rtgjd,rnpgd->rgjtnp", q, kvb[..., :kvh, :],
-                       preferred_element_type=jnp.float32).reshape(
-                           R, kvh, J, T, span) * scale
-        # a key after the query scored -inf, which is below every threshold
-        uj = jax.lax.dynamic_slice_in_dim(u, j * span, span, axis=2)
-        tie = uj == least
-        nth = jnp.einsum("rts,sz->rtz", tie.astype(x.dtype), upto,
-                         preferred_element_type=jnp.float32).astype(
-                             jnp.int32) + jax.lax.dynamic_index_in_dim(
-                                 ties_before, j, axis=2)
-        ok = (uj > least) | (tie & (nth <= ties_left[..., None]))
-        s = jnp.where(ok[:, None, None], s, -1e30)
-
-        def weigh(pr):
-            return jnp.einsum(
-                "rgjtnp,rnpgd->rgjtd",
-                pr.astype(x.dtype).reshape(R, kvh, J, T, bp, pt),
-                kvb[..., kvh:, :], preferred_element_type=jnp.float32)
-        return s, weigh
-
-    with jax.named_scope("dsa_attend"):
-        ctx = _online_softmax_loop(n_blocks, block, (R, kvh, J, T), hd)
+    ctx = attend_selected(q, kv_pool, n, tables, bp, starts, lens, u,
+                          cfg.topk)
     ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * hd)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
             kv_pool, ik_pool)

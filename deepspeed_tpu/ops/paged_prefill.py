@@ -1,0 +1,196 @@
+"""Attention of rows of queries over their prompts' pages, under a mask the
+caller forms, as one kernel: a block's scores never leave the chip's VMEM.
+
+A chunked prefill call attends ``R`` rows of ``T`` queries, each row to its
+own prompt's cached positions, found through the row's page table. Walked in
+plain operations (``models/nemotron_h.py::gqa_prefill``, and until PR 43
+``models/keye.py::dsa_prefill``) a block of keys costs an array of float32
+scores with a query axis, a key axis and the heads, which goes through HBM
+three times: the walk then runs at the memory's rate and a twentieth of the
+matrix unit's (``PERF.md``, PR 42). ``attend_pages`` is the same walk as a
+Pallas kernel whose grid is ``(rows, key blocks)``:
+
+- **pages are read where they lie.** ``pool [L, pages, pt, rows, hd]`` holds
+  a token as one ``(rows, hd)`` tile, its key heads and then its value
+  heads. A grid step fetches the ``block_pages`` pages of a block at the
+  indices the row's table gives, as scalars ahead of the grid, each once for
+  every head. Head ``h`` of the block is row ``h`` of every token's tile: in
+  VMEM the tile's 16-bit rows lie in pairs in 32-bit words, so a strided
+  load of the words (one row pair of every token) and a shift give the
+  head's ``[span, hd]`` matrix exactly, and no gathered copy exists in HBM;
+- **the walk is as long as the row needs.** The grid covers every block of
+  the table; ``counts[r]`` blocks are the row's own, a step beyond them does
+  nothing and names the block before, so nothing is fetched for it. One
+  compiled program whatever the rows hold;
+- **the mask is the caller's.** ``allowed(j, *blocks)`` is traced inside the
+  kernel and gives ``bool [span, T]`` for key block ``j``, one mask for all
+  heads, from the blocks of its own operands: ``keyed`` arrays ``[R, S,
+  T]`` are handed a block of ``span`` keys at a time, ``rowed`` arrays ``[R,
+  w, T]`` a row at a time, ``shared`` arrays whole. Keys first and the
+  row's queries last, because that is how XLA lays an array of scores a
+  (query, key) out when it is free to (128 queries are one lane tile, sums
+  over keys then run down the sublanes): the operands reach the kernel as
+  they lie, without a transposed copy. A causal bound, a window, a
+  selection: the kernel knows of none of them;
+- **keys run down the sublanes inside the kernel too.** A step's scores are
+  ``[span, J x T]``, a group's queries side by side on the lanes (the
+  queries go in as ``[hd, J x T]``, the context comes out so, transposed in
+  plain operations on either side), so the running maximum and sum are a
+  row of ``J x T`` numbers, eight vector registers, where a column would be
+  128 of one lane each, and the reductions over keys are elementwise. On
+  the chip (``PERF.md``, PR 43) this form took three quarters of the time
+  of the one with queries down the sublanes;
+- **the mathematics are the walk's**: query-key products of the operands'
+  16-bit values accumulated in float32 and scaled there, masked keys at
+  ``-1e30``, running maximum, exponent and sums in float32, probabilities
+  rounded to the values' type ahead of their product, float32 accumulation,
+  one division at the end. Only the context leaves the kernel.
+
+The kernel is for a TPU and for the shapes it was compiled and measured at
+(``usable``): 16-bit pages of 128 tokens whose tiles are ``(8, 128)``. Its
+plain twin is the caller's own walk (``models/keye.py::dsa_prefill`` keeps
+it for every other backend and shape), and ``tests/unit/
+test_keye_prefill_kernel.py`` holds the two together with ``interpret=True``.
+"""
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_MASKED = -1e30             # a key that may not be attended scores this
+
+
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def usable(q, pool):
+    """Whether ``attend_pages`` takes ``q [R, T, KV, J, hd]`` over ``pool
+    [L, pages, pt, rows, hd]``: on a TPU, 16-bit values, a head of 128, a
+    page of 128 tokens that is also a row of queries, and a token's tile of
+    8 rows (4 key heads and 4 value heads: one ``(8, 128)`` tile in the
+    chip's memory, whose row pairs are its 32-bit words)."""
+    return (_on_tpu() and q.dtype == pool.dtype == jnp.bfloat16
+            and q.shape[-1] == pool.shape[-1] == 128
+            and q.shape[1] == pool.shape[2] == 128
+            and pool.shape[3] == 8 == 2 * q.shape[2])
+
+
+def _head(pages, h):
+    """Row ``h`` (an index, traced or not) of every token's tile of the
+    block's pages: ``[span, hd]`` float32, exactly. A page's words hold rows
+    ``2 i`` (the low half) and ``2 i + 1`` of a tile."""
+    shift = (16 * (1 - h % 2)).astype(jnp.uint32)
+    out = []
+    for ref in pages:
+        pt, rows, hd = ref.shape[-3:]
+        words = ref.bitcast(jnp.uint32).reshape(pt * rows // 2, hd)[
+            pl.ds(h // 2, pt, stride=rows // 2), :]
+        out.append(pltpu.bitcast((words << shift) & jnp.uint32(0xFFFF0000),
+                                 jnp.float32))
+    return jnp.concatenate(out, axis=0)
+
+
+def attend_pages(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
+                 shared=(), *, block_pages, interpret=False):
+    """``q [R, T, KV, J, hd]`` (query head ``(g, i)`` reads key-value head
+    ``g``) over row ``n`` of ``pool [L, pages, pt, 2 KV, hd]``; ``tables [R,
+    blocks * block_pages]`` the rows' page tables, ``counts [R]`` how many
+    key blocks of ``span = block_pages * pt`` positions a row walks (0: the
+    row's context is zero). ``allowed(j, *keyed blocks [span, T], *rowed
+    blocks [w, T], *shared) -> bool [span, T]`` masks block ``j``; a query no
+    key is allowed in its walked blocks reads an average of their values, as
+    the plain walk gives it. Returns the context ``[R, KV, J, T, hd]`` in
+    ``q``'s type."""
+    R, T, kvh, J, hd = q.shape
+    pt = pool.shape[2]
+    bp = block_pages
+    span = bp * pt
+    nb = tables.shape[1] // bp
+    assert tables.shape[1] == nb * bp and pool.shape[3] == 2 * kvh, (
+        tables.shape, bp, pool.shape)
+    scale = hd ** -0.5
+    rows = J * T
+
+    def kernel(tab_ref, cnt_ref, q_ref, *refs):
+        del tab_ref
+        pages, refs = refs[:bp], refs[bp:]
+        given, refs = refs[:-4], refs[-4:]
+        out_ref, m_ref, l_ref, acc_ref = refs
+        r, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _start():
+            m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        @pl.when(j < cnt_ref[r])
+        def _walk():
+            ok = allowed(j, *(ref[...] for ref in given))
+            # one mask for a group's J heads, whose queries lie side by side
+            bias = jnp.tile(jnp.where(ok, 0.0, _MASKED).astype(jnp.float32),
+                            (1, J))                          # [span, rows]
+
+            def group(g, _):
+                k = _head(pages, g).astype(q_ref.dtype)      # [span, hd]
+                v = _head(pages, kvh + g).T.astype(q_ref.dtype)  # [hd, span]
+                # a masked key's score is below float32's sight of -1e30,
+                # so the sum is -1e30
+                s = jnp.dot(k, q_ref[g],
+                            preferred_element_type=jnp.float32) * scale + bias
+                m = m_ref[g]
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                keep = jnp.exp(m - m_new)
+                pr = jnp.exp(s - m_new)
+                l_ref[g] = l_ref[g] * keep + jnp.sum(pr, 0, keepdims=True)
+                acc_ref[g] = acc_ref[g] * keep + jnp.dot(
+                    v, pr.astype(v.dtype), preferred_element_type=jnp.float32)
+                m_ref[g] = m_new
+
+            # a loop and not four copies: a quarter of the program's text
+            jax.lax.fori_loop(0, kvh, group, None)
+
+        @pl.when(j == nb - 1)
+        def _emit():
+            out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                            ).astype(out_ref.dtype)
+
+    def block_of(r, j, cnt):
+        # a step beyond the row's blocks names the block before it
+        return jnp.maximum(jnp.minimum(j, cnt[r] - 1), 0)
+
+    def page(i):
+        return pl.BlockSpec(
+            (1, 1) + pool.shape[2:],
+            lambda r, j, tab, cnt: (n, tab[r, block_of(r, j, cnt) * bp + i],
+                                    0, 0, 0))
+
+    heads = pl.BlockSpec((None, kvh, hd, rows),
+                         lambda r, j, tab, cnt: (r, 0, 0, 0))
+    in_specs = [heads] + [page(i) for i in range(bp)]
+    in_specs += [pl.BlockSpec((None, span, T),
+                              lambda r, j, tab, cnt: (r, block_of(r, j, cnt), 0))
+                 for _ in keyed]
+    in_specs += [pl.BlockSpec((None,) + a.shape[1:],
+                              lambda r, j, tab, cnt: (r, 0, 0)) for a in rowed]
+    in_specs += [pl.BlockSpec(a.shape, lambda r, j, tab, cnt, nd=a.ndim:
+                              (0,) * nd) for a in shared]
+    ctx = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((R, kvh, hd, rows), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(R, nb), in_specs=in_specs,
+            out_specs=heads,
+            scratch_shapes=[pltpu.VMEM((kvh, 1, rows), jnp.float32),
+                            pltpu.VMEM((kvh, 1, rows), jnp.float32),
+                            pltpu.VMEM((kvh, hd, rows), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=96 * 2 ** 20),
+        interpret=interpret, name="paged_prefill_attention",
+    )(tables.astype(jnp.int32), counts.astype(jnp.int32),
+      jnp.moveaxis(q, (1, 4), (4, 2)).reshape(R, kvh, hd, rows),
+      *([pool] * bp), *keyed, *rowed, *shared)
+    return jnp.moveaxis(ctx.reshape(R, kvh, hd, J, T), 2, 4)

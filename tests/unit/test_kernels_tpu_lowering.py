@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu import kernels
-from deepspeed_tpu.ops import column_write
+from deepspeed_tpu.models import keye as keye_mod
+from deepspeed_tpu.ops import column_write, paged_prefill
 from deepspeed_tpu.ops.transformer import attention as attn_mod
 from deepspeed_tpu.ops.transformer.attention import flash_attention
 
@@ -258,8 +259,37 @@ def test_column_write_lowers(shape, lanes):
                               *_column_write_args(shape, lanes)), 1)
 
 
+def _prefill_walk_args(rows=16, pages=5121, table=128):
+    """Keye-VL's cell: 16 rows of 128 queries, 32 heads of 128 on 4
+    key-value heads, a pool of 6 layers, tables of 128 pages."""
+    i32, bf = jnp.int32, jnp.bfloat16
+    return [SDS((rows, 128, 4, 8, 128), bf), SDS((6, pages, 128, 8, 128), bf),
+            SDS((rows, table), i32), SDS((rows,), i32), SDS((rows,), i32),
+            SDS((rows, 128, table * 128), i32)]
+
+
+def _prefill_walk_fn(q, pool, tables, starts, lens, u):
+    return keye_mod.attend_selected(q, pool, 3, tables, 4, starts, lens, u,
+                                    2048)
+
+
+def test_paged_prefill_lowers_under_the_selection(monkeypatch):
+    """``ops/paged_prefill.py`` with Keye-VL's mask traced inside it, at the
+    cell's shapes: one Mosaic call, and the lowered walk holds no array
+    with a query axis, a key axis and the heads."""
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
+    lowered = _lower_tpu(_prefill_walk_fn, *_prefill_walk_args())
+    _assert_mosaic(lowered, 1)
+    assert "x4x8x128x512xf32" not in lowered.as_text()
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: False)
+    # (a function of its own: the first one's trace is cached)
+    plain = _lower_tpu(lambda *args: _prefill_walk_fn(*args),
+                       *_prefill_walk_args()).as_text()
+    assert "tpu_custom_call" not in plain and "x4x8x128x512xf32" in plain
+
+
 @pytest.mark.slow
-def test_serving_kernels_compile_for_v5e():
+def test_serving_kernels_compile_for_v5e(monkeypatch):
     """The real compiler: Mosaic + XLA:TPU from the installed libtpu,
     against a v5e topology description, for both serving kernels in
     every storage dtype. Catches what lowering cannot (an accumulator
@@ -280,6 +310,9 @@ def test_serving_kernels_compile_for_v5e():
     for shape, lanes in _COLUMN_POOLS:
         _lower_tpu(_column_write_fn,
                    *place(_column_write_args(shape, lanes))).compile()
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
+    _lower_tpu(lambda *args: _prefill_walk_fn(*args),
+               *place(_prefill_walk_args())).compile()
 
 
 @pytest.mark.slow

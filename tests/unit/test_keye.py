@@ -629,3 +629,40 @@ def test_a_siblings_lowered_decode_program_is_the_parents_text():
     assert "stablehlo" in text and len(text) > 100000
     got = hashlib.sha256(text.encode()).hexdigest()[:16]
     assert got == MIMO_DECODE_AT_PARENT, got
+
+
+def test_the_cells_prefill_program_holds_no_scores_of_a_key_block(monkeypatch):
+    """``_keye_prefill_chunk_jit`` lowered for a TPU at the cell's own
+    shapes (16 rows of 128, 6 layers, 16 experts held, tables of 128 pages):
+    a layer's walk is one Mosaic call, and no float32 array of the program
+    is as large as a key block's scores were (16 rows x 32 heads x 128
+    queries x 512 keys, 134 MB: ``PERF.md``, PR 42), none at all has a
+    query axis, a key axis and the heads."""
+    import json
+    import re
+
+    from benchmarks.models import keye_serve
+    from deepspeed_tpu.ops import paged_prefill
+
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
+    with open("benchmarks/configs/keye_vl2_30b_serve_ep8.json") as f:
+        cfg = json.load(f)
+    m = keye_serve.model_config(cfg)
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    params = weights_mod.nest({k: sds(tuple(v))
+                               for k, v in ref.weight_shapes(cfg).items()})
+    R, L, pages, mp, i32 = 16, m.num_hidden_layers, 5121, 128, jnp.int32
+    state = {"kv": sds((L, pages, 128, 8, 128)),
+             "ik": sds((L, pages, 64, 128))}
+    text = keye_family._keye_prefill_chunk_jit.trace(
+        params, state, sds((R, 128), i32), sds((R,), i32), sds((R,), i32),
+        sds((R,), i32), sds((R, mp), i32), cfg=m, page_tokens=128,
+        keep_logits=False).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == L
+    shapes = {tuple(int(d) for d in dims.split("x"))
+              for dims in re.findall(r"tensor<([0-9x]+)xf32>", text)}
+    # a key axis: a block of 512 keys, or the table's 16,384 positions,
+    # behind the rows' axis
+    keyed = {s for s in shapes if s[0] == R and {512, 128 * mp} & set(s[1:])}
+    assert keyed and max(map(np.prod, keyed)) < 16 * 32 * 128 * 512, keyed
+    assert not re.search(r"tensor<16x4x8x128x\d+xf32>|x4x8x128x512x", text)
