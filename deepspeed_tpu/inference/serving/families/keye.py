@@ -53,6 +53,20 @@ def count_prefill_blocks(metrics, starts, lens, *, page_tokens, layers):
                                   layers * len(blocks) * blocks.max())
 
 
+def count_decode_blocks(metrics, context, *, topk, lanes, table_positions,
+                        layers):
+    """What a decode step's attention walks of the tiles its selection
+    fetched, from the positions the active lanes hold (``context
+    [active]``, the new one among them): the blocks each lane's selection
+    fills, and the ``K = min(topk, table_positions)`` slots of every one of
+    the ``lanes`` the step has, each summed over ``layers``
+    (``ServingMetrics.record_decode_blocks``)."""
+    K = min(topk, table_positions)
+    metrics.record_decode_blocks(
+        layers * ky.decode_blocks(np.minimum(context, K)).sum(),
+        layers * lanes * ky.decode_blocks(K))
+
+
 class KeyeFamily(RowPrefillFamily):
     """Keye-VL through the shared loop. The pool is described from the
     configuration's ``cache_widths``: ``kv`` a token's tile of key and
@@ -108,3 +122,8 @@ class KeyeFamily(RowPrefillFamily):
         metrics.record_selected(
             m.num_hidden_layers * context.sum(),
             m.num_hidden_layers * np.minimum(context, m.topk).sum())
+        pool = self.loop.pool
+        count_decode_blocks(metrics, context, topk=m.topk,
+                            lanes=pool.max_slots, table_positions=(
+                                pool.pages_per_lane * pool.page_tokens),
+                            layers=m.num_hidden_layers)

@@ -159,6 +159,8 @@ class ServingMetrics:
         self.dsa_keys_attended = 0
         self.dsa_prefill_blocks_walked = 0
         self.dsa_prefill_blocks_dense = 0
+        self.dsa_decode_blocks_walked = 0
+        self.dsa_decode_blocks_dense = 0
         self.decode_attn_blocks_walked = 0
         self.decode_attn_blocks_dense = 0
         self.page_waits = 0
@@ -247,6 +249,15 @@ class ServingMetrics:
         ``dsa_prefill_blocks_walked``, ``dsa_prefill_blocks_dense``."""
         self.dsa_prefill_blocks_walked += int(walked)
         self.dsa_prefill_blocks_dense += int(dense)
+
+    def record_decode_blocks(self, walked, dense):
+        """One decode step of such a family: the blocks of selected
+        positions its active lanes' selections fill, summed over lanes and
+        layers (what ``ops/paged_prefill.py::attend_tiles`` walks), and
+        every lane's every block (what the two products in plain operations
+        read): ``dsa_decode_blocks_walked``, ``dsa_decode_blocks_dense``."""
+        self.dsa_decode_blocks_walked += int(walked)
+        self.dsa_decode_blocks_dense += int(dense)
 
     def record_attn_blocks(self, blocks, layers):
         """One decode step of a family whose paged attention walks a work
@@ -540,6 +551,8 @@ class ServingMetrics:
             "dsa_keys_attended": self.dsa_keys_attended,
             "dsa_prefill_blocks_walked": self.dsa_prefill_blocks_walked,
             "dsa_prefill_blocks_dense": self.dsa_prefill_blocks_dense,
+            "dsa_decode_blocks_walked": self.dsa_decode_blocks_walked,
+            "dsa_decode_blocks_dense": self.dsa_decode_blocks_dense,
             "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
             "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
             "page_waits": self.page_waits,

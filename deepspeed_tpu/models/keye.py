@@ -42,7 +42,12 @@ serving engine jits, laid out as ``models/laguna.py``'s:
   lower position first among equal scores) and **fetches those positions'
   keys and values and no others**: what a step reads of its cache is the
   indexer's keys of every position and ``min(context, topk)`` positions'
-  keys and values, not the context's.
+  keys and values, not the context's. The fetched tiles ``[B, K, 2 KV,
+  hd]`` are attended as they lie (``attend_chosen``): on a TPU by one
+  kernel a layer that reads a block of them once for all heads
+  (``ops/paged_prefill.py::attend_tiles``), elsewhere, and at any shape
+  the kernel was not built for, by two products that XLA lays each half
+  of the tiles out again for.
 
 ``state`` is ``{"kv": [L, pages, page_tokens, 2 x KV, head_dim], "ik": [L,
 pages, indexer_head_dim, page_tokens]}``: pages only, no slot array. The
@@ -498,6 +503,38 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
             kv_pool, ik_pool)
 
 
+def decode_blocks(selected):
+    """Blocks of ``paged_prefill.TILE_SPAN`` positions that hold the
+    ``selected`` positions (``min(context, topk)`` a lane) at the head of a
+    lane's row, where ``lax.top_k`` puts them: what a decode step's
+    attention walks. Plain arithmetic: the program's arrays and the host's
+    counts alike."""
+    return (selected + paged_prefill.TILE_SPAN - 1) // paged_prefill.TILE_SPAN
+
+
+def attend_chosen(q, tiles, chosen, positions, active):
+    """The second half of ``dsa_decode``: ``q [B, KV, J, hd]`` attends,
+    of ``tiles [B, K, 2 KV, hd]`` (the tiles of the positions the lanes
+    selected, in ``lax.top_k``'s order), those that are ``chosen [B, K]``.
+    On a TPU at the shapes it takes, one kernel a layer that reads a block
+    of tiles once for all heads and takes each head out of it in VMEM
+    (``ops/paged_prefill.py::attend_tiles``), a lane walking the blocks its
+    selection fills and an inactive lane none (its context is zero there);
+    anywhere else the two products over all ``K``, for which the tiles'
+    halves are laid out again head-major. Returns the context ``[B, KV, J,
+    hd]``."""
+    kvh, hd = q.shape[1], q.shape[-1]
+    if paged_prefill.tiles_usable(q, tiles):
+        counts = jnp.where(active, decode_blocks(
+            jnp.minimum(positions + 1, tiles.shape[1])), 0)
+        return paged_prefill.attend_tiles(q, tiles, chosen, counts)
+    a = jnp.einsum("bgjd,bkgd->bgjk", q, tiles[:, :, :kvh],
+                   preferred_element_type=jnp.float32) * hd ** -0.5
+    a = jax.nn.softmax(jnp.where(chosen[:, None, None], a, -1e30), axis=-1)
+    return jnp.einsum("bgjk,bkgd->bgjd", a.astype(q.dtype), tiles[:, :, kvh:],
+                      preferred_element_type=jnp.float32)
+
+
 def dsa_decode(p, cfg, x, kv_pool, ik_pool, n, page_tables, positions, active,
                page_tokens):
     """Attention under the indexer's selection for one token of every lane.
@@ -508,10 +545,10 @@ def dsa_decode(p, cfg, x, kv_pool, ik_pool, n, page_tables, positions, active,
     the lane's pages of ``ik``, every one of them), the exact top ``topk``
     of the positions up to its own (``dsa_select``) and attention over
     those positions' tiles, which are fetched one by one and are all the
-    step reads of ``kv`` (``dsa_attend``)."""
+    step reads of ``kv`` (``dsa_attend``: the gather, then
+    ``attend_chosen``)."""
     Bn = x.shape[0]
     shape = cfg.attention
-    kvh, hd = shape.num_key_value_heads, shape.head_dim
     pt = page_tokens
     mp = page_tables.shape[1]
     logical = jnp.clip(positions // pt, 0, mp - 1)
@@ -538,12 +575,7 @@ def dsa_decode(p, cfg, x, kv_pool, ik_pool, n, page_tables, positions, active,
             (at // pt)[:, :, None] == jnp.arange(mp)[None, None, :],
             page_tables[:, None, :], 0), axis=-1)
         tiles = kv_pool[n, page, at % pt].astype(x.dtype)    # [B, K, 2KV, hd]
-        a = jnp.einsum("bgjd,bkgd->bgjk", q, tiles[:, :, :kvh],
-                       preferred_element_type=jnp.float32) * hd ** -0.5
-        a = jax.nn.softmax(jnp.where(chosen[:, None, None], a, -1e30), axis=-1)
-        ctx = jnp.einsum("bgjk,bkgd->bgjd", a.astype(x.dtype),
-                         tiles[:, :, kvh:],
-                         preferred_element_type=jnp.float32)
+        ctx = attend_chosen(q, tiles, chosen, positions, active)
     ctx = ctx.reshape(Bn, -1)
     return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
             kv_pool, ik_pool)

@@ -1,5 +1,10 @@
-"""Attention of rows of queries over their prompts' pages, under a mask the
-caller forms, as one kernel: a block's scores never leave the chip's VMEM.
+"""Attention over a cache whose tokens are tiles, a token's key heads and
+value heads together as one ``(rows, hd)`` tile, as two kernels that read the
+tiles where they lie and take a head out of them in VMEM (``_head``):
+``attend_pages``, rows of queries over their prompts' pages under a mask the
+caller forms (a prefill call: a block's scores never leave the chip's VMEM),
+and ``attend_tiles``, one query a lane over the tiles a selection gathered (a
+decode step: the gathered array is never laid out again).
 
 A chunked prefill call attends ``R`` rows of ``T`` queries, each row to its
 own prompt's cached positions, found through the row's page table. Walked in
@@ -51,6 +56,33 @@ The kernel is for a TPU and for the shapes it was compiled and measured at
 plain twin is the caller's own walk (``models/keye.py::dsa_prefill`` keeps
 it for every other backend and shape), and ``tests/unit/
 test_keye_prefill_kernel.py`` holds the two together with ``interpret=True``.
+
+A decode step attends, a lane, the ``K`` positions its indexer selected,
+whose tiles one gather has laid side by side: ``tiles [B, K, rows, hd]``, in
+``lax.top_k``'s order, so the positions that are not chosen (a lane that
+holds fewer than ``K``) are the tail of the row. In plain operations
+(``models/keye.py::attend_chosen``, and until PR 44 ``dsa_decode``) the two
+products want the heads ahead of the positions, so each half of the array is
+copied to ``[B, KV, K, hd]`` first, a shuffle of sublanes of 134 MB in and
+134 MB out (``PERF.md``, PRs 43 and 44: 1.6 ms a layer for the products and
+their copies, beside 1.9 for the gather). ``attend_tiles`` is the same
+attention as a kernel whose grid is ``(lanes, blocks of TILE_SPAN
+positions)``: a step fetches a block of tiles once for all heads (2 MB);
+``_head`` gives key head ``g`` and value head ``KV + g`` of it as it gives
+them of a page (a block has a page's layout: tokens, rows, ``hd``); the
+group's ``J`` queries are the rows of both products, the keys and then the
+values the matrix unit's stationary operand, neither transposed; a lane
+walks the ``counts[b]`` blocks its selection fills, an inactive lane none,
+and a step beyond them fetches nothing; the mathematics are the two
+products' (16-bit operands, float32 accumulation and scale, ``-1e30`` where
+not chosen, float32 maximum, exponent and sum, running over blocks,
+probabilities rounded to the values' type). On the chip (``PERF.md``, PR
+44) blocks of 1,024 took four fifths of the time of blocks of 512, and a
+draft with no unpacking (the raw block against all the queries, the rows of
+other groups masked) the same time at eight times the exponents. For a TPU
+and the measured shapes (``tiles_usable``); the plain twin is
+``attend_chosen``'s other half, and ``tests/unit/test_keye_decode_kernel.py``
+holds the two together.
 """
 
 import jax
@@ -75,6 +107,13 @@ def usable(q, pool):
             and q.shape[-1] == pool.shape[-1] == 128
             and q.shape[1] == pool.shape[2] == 128
             and pool.shape[3] == 8 == 2 * q.shape[2])
+
+
+def _block_of(i, j, counts):
+    """The block grid step ``(i, j)`` names: row (or lane) ``i``'s ``j``-th,
+    and beyond its ``counts[i]`` blocks the last of them again, so that
+    nothing is fetched for a step that does nothing."""
+    return jnp.maximum(jnp.minimum(j, counts[i] - 1), 0)
 
 
 def _head(pages, h):
@@ -157,21 +196,17 @@ def attend_pages(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
             out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                             ).astype(out_ref.dtype)
 
-    def block_of(r, j, cnt):
-        # a step beyond the row's blocks names the block before it
-        return jnp.maximum(jnp.minimum(j, cnt[r] - 1), 0)
-
     def page(i):
         return pl.BlockSpec(
             (1, 1) + pool.shape[2:],
-            lambda r, j, tab, cnt: (n, tab[r, block_of(r, j, cnt) * bp + i],
+            lambda r, j, tab, cnt: (n, tab[r, _block_of(r, j, cnt) * bp + i],
                                     0, 0, 0))
 
     heads = pl.BlockSpec((None, kvh, hd, rows),
                          lambda r, j, tab, cnt: (r, 0, 0, 0))
     in_specs = [heads] + [page(i) for i in range(bp)]
     in_specs += [pl.BlockSpec((None, span, T),
-                              lambda r, j, tab, cnt: (r, block_of(r, j, cnt), 0))
+                              lambda r, j, tab, cnt: (r, _block_of(r, j, cnt), 0))
                  for _ in keyed]
     in_specs += [pl.BlockSpec((None,) + a.shape[1:],
                               lambda r, j, tab, cnt: (r, 0, 0)) for a in rowed]
@@ -194,3 +229,94 @@ def attend_pages(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
       jnp.moveaxis(q, (1, 4), (4, 2)).reshape(R, kvh, hd, rows),
       *([pool] * bp), *keyed, *rowed, *shared)
     return jnp.moveaxis(ctx.reshape(R, kvh, hd, J, T), 2, 4)
+
+
+TILE_SPAN = 1024            # selected positions in a block of ``attend_tiles``
+
+
+def tiles_usable(q, tiles):
+    """Whether ``attend_tiles`` takes ``q [B, KV, J, hd]`` over ``tiles [B,
+    K, rows, hd]``: on a TPU, 16-bit values, a head of 128, a token's tile of
+    8 rows (as ``usable`` asks of a page's) and ``K`` whole blocks of
+    ``TILE_SPAN`` positions."""
+    return (_on_tpu() and q.dtype == tiles.dtype == jnp.bfloat16
+            and q.shape[-1] == tiles.shape[-1] == 128
+            and tiles.shape[2] == 8 == 2 * q.shape[1]
+            and tiles.shape[1] % TILE_SPAN == 0)
+
+
+def attend_tiles(q, tiles, chosen, counts, *, interpret=False):
+    """One query a lane over the tiles its selection fetched, as they lie:
+    ``q [B, KV, J, hd]`` (query head ``(g, i)`` reads key-value head ``g``),
+    ``tiles [B, K, 2 KV, hd]`` (position ``k`` of lane ``b`` one tile: its key
+    heads, then its value heads), ``chosen [B, K]`` which of them are
+    attended, ``counts [B]`` how many blocks of ``TILE_SPAN`` positions a
+    lane walks (the positions that are not ``chosen`` are the tail of its
+    row; 0: the lane's context is zero). Returns the context ``[B, KV, J,
+    hd]`` in ``q``'s type."""
+    B, kvh, J, hd = q.shape
+    K = tiles.shape[1]
+    span = TILE_SPAN
+    nb = K // span
+    assert K == nb * span and tiles.shape[2] == 2 * kvh, (tiles.shape, span)
+    scale = hd ** -0.5
+
+    def kernel(cnt_ref, q_ref, bias_ref, tiles_ref, out_ref, m_ref, l_ref,
+               acc_ref):
+        b, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _start():
+            m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        @pl.when(j < cnt_ref[b])
+        def _walk():
+            bias = bias_ref[...]                             # [1, span]
+
+            def group(g, _):
+                k = _head([tiles_ref], g).astype(q_ref.dtype)        # [span, hd]
+                v = _head([tiles_ref], kvh + g).astype(q_ref.dtype)
+                # the keys are the matrix unit's stationary operand, and
+                # then the values: neither is transposed
+                s = jax.lax.dot_general(
+                    q_ref[g], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale + bias
+                m = m_ref[g]                                 # [J, 1]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                keep = jnp.exp(m - m_new)
+                pr = jnp.exp(s - m_new)                      # [J, span]
+                l_ref[g] = l_ref[g] * keep + jnp.sum(pr, 1, keepdims=True)
+                acc_ref[g] = acc_ref[g] * keep + jnp.dot(
+                    pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
+                m_ref[g] = m_new
+
+            jax.lax.fori_loop(0, kvh, group, None)
+
+        @pl.when(j == nb - 1)
+        def _emit():
+            out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                            ).astype(out_ref.dtype)
+
+    heads = pl.BlockSpec((None, kvh, J, hd), lambda b, j, cnt: (b, 0, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, nb),
+            in_specs=[heads,
+                      pl.BlockSpec((None, 1, span), lambda b, j, cnt:
+                                   (b, 0, _block_of(b, j, cnt))),
+                      pl.BlockSpec((1, span) + tiles.shape[2:],
+                                   lambda b, j, cnt:
+                                   (b, _block_of(b, j, cnt), 0, 0))],
+            out_specs=heads,
+            scratch_shapes=[pltpu.VMEM((kvh, J, 1), jnp.float32),
+                            pltpu.VMEM((kvh, J, 1), jnp.float32),
+                            pltpu.VMEM((kvh, J, hd), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="selected_tiles_attention",
+    )(counts.astype(jnp.int32), q,
+      jnp.where(chosen, 0.0, _MASKED).astype(jnp.float32)[:, None, :], tiles)

@@ -288,6 +288,32 @@ def test_paged_prefill_lowers_under_the_selection(monkeypatch):
     assert "tpu_custom_call" not in plain and "x4x8x128x512xf32" in plain
 
 
+def _chosen_tiles_args(lanes=64, K=2048):
+    """Keye-VL's cell: a decode step's 64 lanes, 2,048 selected tiles of 4
+    key heads and 4 value heads each, 32 query heads."""
+    i32, bf = jnp.int32, jnp.bfloat16
+    return [SDS((lanes, 4, 8, 128), bf), SDS((lanes, K, 8, 128), bf),
+            SDS((lanes, K), jnp.bool_), SDS((lanes,), i32),
+            SDS((lanes,), jnp.bool_)]
+
+
+def test_selected_tiles_attention_lowers_as_the_tiles_lie(monkeypatch):
+    """``paged_prefill.attend_tiles`` through ``keye.attend_chosen`` at the
+    cell's shapes: one Mosaic call that takes the gathered tiles whole, and
+    no product over a half of them; off the TPU the two products, and no
+    kernel."""
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
+    lowered = _lower_tpu(keye_mod.attend_chosen, *_chosen_tiles_args())
+    _assert_mosaic(lowered, 1)
+    text = lowered.as_text()
+    assert "64x2048x4x128xbf16" not in text and "dot_general" not in text
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: False)
+    plain = _lower_tpu(lambda *args: keye_mod.attend_chosen(*args),
+                       *_chosen_tiles_args()).as_text()
+    assert "tpu_custom_call" not in plain
+    assert plain.count("dot_general") == 2 and "64x2048x4x128xbf16" in plain
+
+
 @pytest.mark.slow
 def test_serving_kernels_compile_for_v5e(monkeypatch):
     """The real compiler: Mosaic + XLA:TPU from the installed libtpu,
@@ -313,6 +339,13 @@ def test_serving_kernels_compile_for_v5e(monkeypatch):
     monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
     _lower_tpu(lambda *args: _prefill_walk_fn(*args),
                *place(_prefill_walk_args())).compile()
+    text = _lower_tpu(lambda *args: keye_mod.attend_chosen(*args),
+                      *place(_chosen_tiles_args())).compile().as_text()
+    # the compiled program holds the kernel and no copy of a half of the
+    # tiles, heads first or positions first
+    assert "selected_tiles_attention" in text
+    assert "bf16[64,4,2048,128]" not in text
+    assert "bf16[64,2048,4,128]" not in text
 
 
 @pytest.mark.slow

@@ -666,3 +666,71 @@ def test_the_cells_prefill_program_holds_no_scores_of_a_key_block(monkeypatch):
     keyed = {s for s in shapes if s[0] == R and {512, 128 * mp} & set(s[1:])}
     assert keyed and max(map(np.prod, keyed)) < 16 * 32 * 128 * 512, keyed
     assert not re.search(r"tensor<16x4x8x128x\d+xf32>|x4x8x128x512x", text)
+
+
+def test_the_cells_decode_program_attends_the_tiles_as_they_lie(monkeypatch):
+    """``_keye_decode_step_jit`` lowered for a TPU at the cell's own shapes
+    (64 lanes, 6 layers, 16 experts held, tables of 128 pages, ``K``
+    2,048): a layer's attention is one Mosaic call more than the program
+    holds where the kernel is not taken, the selected tiles are still one
+    gather a layer of ``(1, 1, 1, 8, 128)`` slices of ``kv``, and no
+    product takes a half of them laid out again (``64 x 2048 x 4 x 128``,
+    134 MB a layer and operand: ``PERF.md``, PR 43)."""
+    import json
+    import re
+
+    from benchmarks.models import keye_serve
+    from deepspeed_tpu.ops import paged_prefill
+
+    with open("benchmarks/configs/keye_vl2_30b_serve_ep8.json") as f:
+        cfg = json.load(f)
+    m = keye_serve.model_config(cfg)
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    params = weights_mod.nest({k: sds(tuple(v))
+                               for k, v in ref.weight_shapes(cfg).items()})
+    B, L, pages, mp, i32 = 64, m.num_hidden_layers, 5121, 128, jnp.int32
+    state = {"kv": sds((L, pages, 128, 8, 128)),
+             "ik": sds((L, pages, 64, 128))}
+
+    def lowered():
+        return keye_family._keye_decode_step_jit.trace(
+            params, state, sds((B,), i32), sds((B,), i32), sds((B,), bool),
+            sds((B, mp), i32), cfg=m, page_tokens=128,
+            keep_logits=False).lower(lowering_platforms=("tpu",)).as_text()
+
+    halves = r"tensor<64x2048x4x128xbf16>"
+    plain = lowered()
+    assert len(re.findall(r"stablehlo.dot_general.*" + halves, plain)) == 2 * L
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
+    keye_family._keye_decode_step_jit.clear_cache()  # traced for the CPU
+    text = lowered()
+    keye_family._keye_decode_step_jit.clear_cache()
+    assert (text.count("tpu_custom_call")
+            == plain.count("tpu_custom_call") + L)
+    assert text.count("selected_tiles_attention") >= L
+    gathers = re.findall(r"stablehlo.gather.*?slice_sizes = array<i64: "
+                         r"([0-9, ]+)>.*?\(tensor<([0-9x]+)xbf16>", text)
+    tiles = [g for g in gathers if g[1] == f"{L}x{pages}x128x8x128"]
+    assert len(tiles) == L and {g[0] for g in tiles} == {"1, 1, 1, 8, 128"}
+    assert not re.search(r"stablehlo.dot_general.*" + halves, text)
+    assert halves not in text
+
+
+# The decode program off the TPU, where ``attend_tiles`` is the two products
+# it was: lowered at the parent commit (1f76e72, PR 43) with jax 0.9.0 at the
+# tiny shapes of ``test_a_decode_step_fetches_selected_tiles...``.
+KEYE_DECODE_AT_PARENT = "e322090161d07b3e"
+
+
+def test_off_the_tpu_the_decode_program_is_the_parents_text():
+    _, params, mcfg = make()
+    B, mp, pages = 3, 16, 49
+    state = {"kv": jnp.zeros((3, pages, ROW, 4, 16)),
+             "ik": jnp.zeros((3, pages, 8, ROW))}
+    text = keye_family._keye_decode_step_jit.trace(
+        params, state, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+        jnp.ones(B, bool), jnp.zeros((B, mp), jnp.int32), cfg=mcfg,
+        page_tokens=ROW, keep_logits=False).lower().as_text()
+    assert "stablehlo" in text and len(text) > 100000
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == KEYE_DECODE_AT_PARENT, got
