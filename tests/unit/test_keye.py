@@ -17,7 +17,6 @@ whether it is meant to be or not."""
 
 import dataclasses
 import functools
-import hashlib
 
 import numpy as np
 import pytest
@@ -614,22 +613,9 @@ def test_background_loop_streams_tokens():
     assert got == toks and len(toks) == 12
 
 
-# -- (f) the families that share functions with this one ----------------------
-
-# ``routed_moe_ffn`` took the name of ``sigmoid_moe_ffn`` and a ``scoring``
-# argument, ``HybridStatePool`` a second way to describe a paged array:
-# MiMo-V2's decode program, which goes through both, read at the parent
-# commit (e6120d2, PR 41) with jax 0.9.0 at the tiny shapes of its own unit
-# test. Nemotron-H's and Laguna's four are held in ``test_mimo_v2.py``.
-MIMO_DECODE_AT_PARENT = "b17958c3588aa8fb"
-
-
-def test_a_siblings_lowered_decode_program_is_the_parents_text():
-    text = test_mimo_v2._lowered_text("mimo_decode")
-    assert "stablehlo" in text and len(text) > 100000
-    got = hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert got == MIMO_DECODE_AT_PARENT, got
-
+# -- (f) the programs as lowered ---------------------------------------------
+# (this family's two at the tiny shapes, off the TPU, are held with the other
+# families' in ``test_mimo_v2.py::PARENT_TEXT``)
 
 def test_the_cells_prefill_program_holds_no_scores_of_a_key_block(monkeypatch):
     """``_keye_prefill_chunk_jit`` lowered for a TPU at the cell's own
@@ -714,23 +700,3 @@ def test_the_cells_decode_program_attends_the_tiles_as_they_lie(monkeypatch):
     assert len(tiles) == L and {g[0] for g in tiles} == {"1, 1, 1, 8, 128"}
     assert not re.search(r"stablehlo.dot_general.*" + halves, text)
     assert halves not in text
-
-
-# The decode program off the TPU, where ``attend_tiles`` is the two products
-# it was: lowered at the parent commit (1f76e72, PR 43) with jax 0.9.0 at the
-# tiny shapes of ``test_a_decode_step_fetches_selected_tiles...``.
-KEYE_DECODE_AT_PARENT = "e322090161d07b3e"
-
-
-def test_off_the_tpu_the_decode_program_is_the_parents_text():
-    _, params, mcfg = make()
-    B, mp, pages = 3, 16, 49
-    state = {"kv": jnp.zeros((3, pages, ROW, 4, 16)),
-             "ik": jnp.zeros((3, pages, 8, ROW))}
-    text = keye_family._keye_decode_step_jit.trace(
-        params, state, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
-        jnp.ones(B, bool), jnp.zeros((B, mp), jnp.int32), cfg=mcfg,
-        page_tokens=ROW, keep_logits=False).lower().as_text()
-    assert "stablehlo" in text and len(text) > 100000
-    got = hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert got == KEYE_DECODE_AT_PARENT, got

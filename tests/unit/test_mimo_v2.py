@@ -26,10 +26,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmarks.refs import laguna_ref, nemotron_h_ref
+from benchmarks.refs import (
+    keye_ref,
+    kimi_linear_ref,
+    laguna_ref,
+    nemotron_h_ref,
+)
 from benchmarks.refs import mimo_v2_ref as ref
 from benchmarks.refs import weights as weights_mod
 from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+from deepspeed_tpu.inference.serving.families import keye as keye_family
+from deepspeed_tpu.inference.serving.families import (
+    kimi_linear as kimi_family,
+)
 from deepspeed_tpu.inference.serving.families import laguna as laguna_family
 from deepspeed_tpu.inference.serving.families import mimo_v2 as mimo_family
 from deepspeed_tpu.inference.serving.families import (
@@ -653,25 +662,32 @@ def test_background_loop_streams_tokens():
         eng.stop()
 
 
-# -- (f) the widened functions left the other families' programs alone -------
+# -- (f) the ten slot-state programs are the text they were -------------------
 
-SLOTS, PAGES, MP, ROWS = 3, 9, 4, 4
-# sha256 (first 16 hex digits) of ``jit(...).lower(...).as_text()`` at the
-# sizes of the two families' own unit tests, with jax 0.9.0. The two prefill
-# programs were read at commit 61d9b87 (PR 39, the parent of the PR that
-# gave the grouped-query and window functions a value width and a sink) and
-# have held since. The two decode programs were read anew in PR 41, which
-# was meant to alter them (a decode step writes its new keys and values in
-# one operation a layer and array, and a window layer attends the ring as
-# it found it plus its own key); that the prefill values passed that PR
-# untouched is its proof that no prefill program moved. The text carries no
-# names or locations, so a refactor that traces the same operations in the
-# same order keeps it. A change that is MEANT to alter these programs, or a
-# jax that prints them otherwise, reads them anew (the test's message
-# prints them).
+ROWS = 4
+# sha256 (first 16 hex digits) of ``jit(...).lower(...).as_text()`` of every
+# slot-state family's two programs at the sizes of the family's own unit
+# test, with jax 0.9.0. The text carries no names or locations, so a
+# refactor that traces the same operations in the same order keeps it: a
+# change to a function several families share (the paged grouped-query
+# attention, the window ring, the expert layer, the pool's description)
+# shows here which programs it reached. A change that is MEANT to alter
+# these programs, or a jax that prints them otherwise, reads them anew (the
+# test's message prints them). When each was last read: Laguna's and
+# Nemotron-H's prefill at 61d9b87 (PR 39), their decode in PR 41, which was
+# meant to alter them (one write a layer and array); MiMo-V2's decode at
+# e6120d2 (PR 41), Keye-VL's decode, off the TPU where ``attend_tiles`` is
+# the two products it was, at 1f76e72 (PR 43); MiMo-V2's and Keye-VL's
+# prefill and Kimi-Linear's two at 4ba738f (PR 44).
 PARENT_TEXT = {
+    "keye_decode": "e322090161d07b3e",
+    "keye_prefill": "901fee62511254c4",
+    "kimi_decode": "18215d53219d7b72",
+    "kimi_prefill": "6da4795d13526e68",
     "laguna_decode": "b837a006efe47cca",
     "laguna_prefill": "a6705ed92545bbca",
+    "mimo_decode": "b17958c3588aa8fb",
+    "mimo_prefill": "9c18ea5aec243200",
     "nemotron_decode": "d441420ad3e1c1bc",
     "nemotron_prefill": "483ba7f75d40b702",
 }
@@ -683,57 +699,71 @@ def _sds(shape, dtype=jnp.float32):
 
 def _lowered(which):
     """``<family>_<program>`` traced at the tiny shapes, not compiled."""
-    i32 = jnp.int32
+    # at call time: both import this module for what they share with it
+    from tests.unit import test_keye, test_kimi_linear
+
     family, program = which.split("_")
-    args = ((_sds((SLOTS,), i32), _sds((SLOTS,), i32),
-             _sds((SLOTS,), jnp.bool_), _sds((SLOTS, MP), i32))
-            if program == "decode" else
-            (_sds((ROWS, ROW), i32), _sds((ROWS,), i32), _sds((ROWS,), i32),
-             _sds((ROWS,), i32), _sds((ROWS, MP), i32)))
+    slots, pages, mp, row = 3, 9, 4, ROW
     if family == "laguna":
         cfg = lg.LagunaConfig.from_dict(test_laguna.CFG)
         shapes = laguna_ref.weight_shapes(test_laguna.CFG)
-        state = {"k": _sds((2, PAGES, 32, ROW)),
-                 "v": _sds((2, PAGES, 32, ROW)),
-                 "wk": _sds((3, SLOTS, 2, 32, ROW)),
-                 "wv": _sds((3, SLOTS, 2, 32, ROW))}
-        fn = (laguna_family._laguna_decode_step_jit if program == "decode"
-              else laguna_family._laguna_prefill_chunk_jit)
+        state = {"k": _sds((2, pages, 32, ROW)),
+                 "v": _sds((2, pages, 32, ROW)),
+                 "wk": _sds((3, slots, 2, 32, ROW)),
+                 "wv": _sds((3, slots, 2, 32, ROW))}
+        fns = (laguna_family._laguna_decode_step_jit,
+               laguna_family._laguna_prefill_chunk_jit)
     elif family == "mimo":
         cfg = model_config()
         shapes = ref.weight_shapes(CFG)
-        state = {name: _sds(((5, SLOTS, 1) if name[0] == "w" else (2, PAGES))
+        state = {name: _sds(((5, slots, 1) if name[0] == "w" else (2, pages))
                             + (width, ROW))
                  for name, width in cfg.cache_widths.items()}
-        fn = (mimo_family._mimo_decode_step_jit if program == "decode"
-              else mimo_family._mimo_prefill_chunk_jit)
-    else:
+        fns = (mimo_family._mimo_decode_step_jit,
+               mimo_family._mimo_prefill_chunk_jit)
+    elif family == "nemotron":
         cfg = test_nemotron_h.model_config(test_nemotron_h.CFG)
         shapes = nemotron_h_ref.weight_shapes(test_nemotron_h.CFG)
-        state = {"ssm": _sds((4, SLOTS, 4, 16, 16)),
-                 "conv": _sds((4, SLOTS, 3, cfg.conv_dim)),
-                 "k": _sds((1, PAGES, 32, ROW)),
-                 "v": _sds((1, PAGES, 32, ROW))}
-        fn = (nemotron_family._nemotron_decode_step_jit
-              if program == "decode"
-              else nemotron_family._nemotron_prefill_chunk_jit)
+        state = {"ssm": _sds((4, slots, 4, 16, 16)),
+                 "conv": _sds((4, slots, 3, cfg.conv_dim)),
+                 "k": _sds((1, pages, 32, ROW)),
+                 "v": _sds((1, pages, 32, ROW))}
+        fns = (nemotron_family._nemotron_decode_step_jit,
+               nemotron_family._nemotron_prefill_chunk_jit)
+    elif family == "keye":
+        cfg = test_keye.model_config()
+        shapes = keye_ref.weight_shapes(test_keye.CFG)
+        pages, mp = 49, 16
+        state = {"kv": _sds((3, pages, ROW, 4, 16)),
+                 "ik": _sds((3, pages, 8, ROW))}
+        fns = (keye_family._keye_decode_step_jit,
+               keye_family._keye_prefill_chunk_jit)
+    else:
+        cfg = test_kimi_linear.model_config(test_kimi_linear.CFG)
+        shapes = kimi_linear_ref.weight_shapes(test_kimi_linear.CFG)
+        row = test_kimi_linear.CHUNK         # one prompt's next chunk a call
+        state = {"kda": _sds((4, slots, 2, 16, 16)),
+                 "conv": _sds((4, slots, 3, 3 * cfg.kda_width)),
+                 "latent": _sds((1, pages, cfg.latent_width, ROW))}
+        fns = (kimi_family._kimi_decode_step_jit,
+               kimi_family._kimi_prefill_chunk_jit)
+    i32 = jnp.int32
+    rows = 1 if family == "kimi" else ROWS
+    args = ((_sds((slots,), i32), _sds((slots,), i32),
+             _sds((slots,), jnp.bool_), _sds((slots, mp), i32))
+            if program == "decode" else
+            (_sds((rows, row), i32), _sds((rows,), i32), _sds((rows,), i32),
+             _sds((rows,), i32), _sds((rows, mp), i32)))
     params = weights_mod.nest({k: _sds(v) for k, v in shapes.items()})
-    return state, fn.lower(params, state, *args, cfg=cfg, page_tokens=ROW,
-                           keep_logits=False)
-
-
-def _lowered_text(which):
-    return _lowered(which)[1].as_text()
+    return state, fns[program == "prefill"].lower(
+        params, state, *args, cfg=cfg, page_tokens=ROW, keep_logits=False)
 
 
 @pytest.mark.parametrize("which", sorted(PARENT_TEXT))
-def test_the_other_families_lowered_programs_are_the_parents_text(which):
-    """``gqa_prefill``, ``gqa_decode``, ``window_prefill`` and
-    ``window_decode`` gained a value width, a sink and their model's hooks
-    as arguments: where keys and values are one width and there is no sink,
-    as in Nemotron-H and Laguna, what is traced is what the parent traced,
-    operation for operation."""
-    text = _lowered_text(which)
+def test_the_slot_state_programs_lowered_are_the_parents_text(which):
+    """What a family's jitted program traces, operation for operation, is
+    what it traced when ``PARENT_TEXT`` was read."""
+    text = _lowered(which)[1].as_text()
     assert "stablehlo" in text and len(text) > 100000
     got = hashlib.sha256(text.encode()).hexdigest()[:16]
     assert got == PARENT_TEXT[which], (which, got)

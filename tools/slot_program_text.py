@@ -1,0 +1,133 @@
+"""The ten slot-state serving programs as lowered for a TPU at their cells'
+own shapes: one sha256 a program, of the StableHLO text with the Mosaic
+kernels' bodies stripped of source locations. A refactor that traces the
+same operations in the same order keeps all ten; the control a move of
+shared model code is held to where the CPU's pins
+(``tests/unit/test_mimo_v2.py::PARENT_TEXT``) cannot see, because on a TPU
+``write_columns``, ``attend_pages`` and ``attend_tiles`` take their Pallas
+branches.
+
+    python tools/slot_program_text.py [--out DIR]
+
+Nothing is compiled or run and no array is made (shapes only). On a TPU the
+kernels' branches are taken as the cells take them; anywhere else the
+``_on_tpu`` switches are forced, which the last line says (``forced``).
+Prints one JSON line: ``{"device": ..., "forced": bool, "sha256": {program:
+digest}}``; ``--out`` also keeps the texts.
+"""
+
+import argparse
+import base64
+import glob
+import hashlib
+import importlib
+import json
+import os
+import re
+import sys
+import types
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+
+def _text_without_locations(lowered):
+    """As ``tests/unit/test_kernels_tpu_lowering.py`` strips it: a Mosaic
+    kernel's serialized body embeds source lines."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([^\\]*)\\22', body, lowered.as_text())
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+
+def cell_programs(cfg):
+    """``{name: lowered}`` of a serving configuration's two programs, as
+    its cell builds them: the family ``family_for`` picks, its pool as
+    ``build`` describes it (under ``eval_shape``: no array is made), the
+    weights' shapes of the configuration's reference."""
+    from benchmarks.refs import weights as weights_mod
+    from deepspeed_tpu.inference.serving import ServingConfig
+    from deepspeed_tpu.inference.serving.family import family_for
+
+    adapter = importlib.import_module("benchmarks.models." + cfg["adapter"])
+    ref = importlib.import_module("benchmarks.refs." + cfg["reference"])
+    serving = cfg["serving"]
+    dtype = jnp.dtype(serving["param_dtype"])
+    params = weights_mod.nest({k: _sds(v, dtype)
+                               for k, v in ref.weight_shapes(cfg).items()})
+    model_cfg = adapter.model_config(cfg)
+    family = family_for(model_cfg)
+    loop = types.SimpleNamespace(
+        config=ServingConfig(**{k: serving[k] for k in (
+            "max_slots", "max_seq_len", "kv_cache_dtype", "kv_page_tokens",
+            "kv_pool_tokens", "prefill_chunk_tokens") if k in serving}),
+        max_seq_len=serving["max_seq_len"],
+        metrics=types.SimpleNamespace(record_state_pool=lambda *a: None))
+    pools = []
+
+    def state():
+        pools.append(family.build(loop, params)[1])
+        return pools[0].state
+
+    state = jax.eval_shape(state)
+    pool = pools[0]
+    B, mp, pt = pool.max_slots, pool.pages_per_lane, pool.page_tokens
+    R, T = ((1, family.chunk) if hasattr(family, "chunk")
+            else (family.rows, family.row_tokens))
+    i32 = jnp.int32
+    how = dict(cfg=model_cfg, page_tokens=pt, keep_logits=False)
+    traced = {
+        family.decode_program.__name__: family.decode_program.trace(
+            params, state, _sds((B,), i32), _sds((B,), i32),
+            _sds((B,), jnp.bool_), _sds((B, mp), i32), **how),
+        family.prefill_program.__name__: family.prefill_program.trace(
+            params, state, _sds((R, T), i32), _sds((R,), i32),
+            _sds((R,), i32), _sds((R,), i32), _sds((R, mp), i32), **how),
+    }
+    return {name: t.lower(lowering_platforms=("tpu",))
+            for name, t in traced.items()}
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--out", help="directory to keep the ten texts in")
+    args = ap.parse_args()
+    forced = jax.default_backend() != "tpu"
+    if forced:
+        from deepspeed_tpu.ops import column_write, paged_prefill
+        column_write._on_tpu = paged_prefill._on_tpu = lambda: True
+    digests = {}
+    for path in sorted(glob.glob(os.path.join(
+            REPO_ROOT, "benchmarks", "configs", "*.json"))):
+        with open(path) as f:
+            cfg = json.load(f)
+        if cfg.get("kind") != "serve" or "model_type" not in cfg:
+            continue
+        for name, lowered in cell_programs(cfg).items():
+            text = _text_without_locations(lowered)
+            digests[name] = hashlib.sha256(text.encode()).hexdigest()
+            if args.out:
+                os.makedirs(args.out, exist_ok=True)
+                with open(os.path.join(args.out, name + ".txt"), "w") as f:
+                    f.write(text)
+    dev = jax.devices()[0]
+    print(json.dumps({"device": f"{dev.platform}:{dev.device_kind}",
+                      "forced": forced, "sha256": digests}))
+
+
+if __name__ == "__main__":
+    main()
