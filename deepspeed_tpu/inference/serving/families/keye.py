@@ -82,13 +82,6 @@ class KeyeFamily(RowPrefillFamily):
     decode_program = staticmethod(_keye_decode_step_jit)
     prefill_program = staticmethod(_keye_prefill_chunk_jit)
 
-    def check_options(self, cfg, params):
-        page = super().check_options(cfg, params)
-        if cfg.prefill_chunk_tokens < page or cfg.prefill_chunk_tokens % page:
-            self.refuse(f"prefill_chunk_tokens={cfg.prefill_chunk_tokens}",
-                        f"prefills in rows of one page: a positive multiple "
-                        f"of kv_page_tokens={page}")
-
     def build(self, loop, params):
         self.loop = loop
         m, cfg = self.cfg, loop.config

@@ -1,21 +1,21 @@
-"""MiMo-V2 behind the serving loop (``models/mimo_v2.py``): ``LagunaFamily``
-over the decoder's own two programs. The two models keep the same two
-lifetimes of state in one ``HybridStatePool``, full layers' keys and values
-in pages claimed from the ``kv_pool_tokens`` budget and window layers' in a
-ring a lane that is read behind its position mask (``reset=()``), and the
-same rows-of-several-prompts prefill call; what differs is in the
-configuration, which the pool is described from (``cache_widths``: keys of
-192 and values of 128 a head, 4 key-value heads in a full layer and 8 in a
-window layer give ``k``, ``v``, ``wk`` and ``wv`` four widths), and in the
-programs (a sink in the window layers' softmax, a value scale, a third of a
-head rotated, a sixteenth of the experts held). It refuses what
-``LagunaFamily`` refuses, under its own name."""
+"""MiMo-V2 behind the serving loop (``models/mimo_v2.py``): one chunked
+prefill program that takes several prompts a call and one decode program,
+over a ``HybridStatePool`` of pages for the full-attention layers and a ring
+a lane for the window layers (``families/slot_state.py``'s
+``PagesAndRingsFamily``, which Laguna's family is too). What is this
+model's is in the configuration, which the pool is described from
+(``cache_widths``: keys of 192 and values of 128 a head, 4 key-value heads
+in a full layer and 8 in a window layer give ``k``, ``v``, ``wk`` and ``wv``
+four widths), and in the programs (a sink in the window layers' softmax, a
+value scale, a third of a head rotated, a sixteenth of the experts held)."""
 
 from functools import partial
 
 import jax
 
-from deepspeed_tpu.inference.serving.families.laguna import LagunaFamily
+from deepspeed_tpu.inference.serving.families.slot_state import (
+    PagesAndRingsFamily,
+)
 from deepspeed_tpu.models import mimo_v2 as mm
 
 
@@ -39,10 +39,10 @@ def _mimo_decode_step_jit(params, state, tokens, positions, active,
     return state, tokens, positions, logits if keep_logits else None, moe
 
 
-class MiMoV2Family(LagunaFamily):
-    """MiMo-V2 through the shared loop: ``LagunaFamily``'s pool, admission
-    and prefill rows, with a window of one page (a ring of one block) as
-    published."""
+class MiMoV2Family(PagesAndRingsFamily):
+    """MiMo-V2 through the shared loop: the pool of pages and rings, the
+    prefill rows and admission of ``families/slot_state.py``, with a window
+    of one page (a ring of one block) as published."""
 
     name = "mimo_v2"
     decode_program = staticmethod(_mimo_decode_step_jit)

@@ -16,6 +16,7 @@ from deepspeed_tpu.inference.serving.families.slot_state import (
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import nemotron_h as nh
 
+
 @partial(jax.jit, static_argnames=("cfg", "page_tokens", "keep_logits"),
          donate_argnums=(1,))  # jaxlint: hot
 def _nemotron_prefill_chunk_jit(params, state, ids, slots, starts, lens,
@@ -50,17 +51,15 @@ class NemotronHFamily(RowPrefillFamily):
     decode_program = staticmethod(_nemotron_decode_step_jit)
     prefill_program = staticmethod(_nemotron_prefill_chunk_jit)
 
+    def row_length(self, page):
+        return self.cfg.chunk_size
+
     def check_options(self, cfg, params):
         page = super().check_options(cfg, params)
-        row = self.cfg.chunk_size
-        if cfg.prefill_chunk_tokens < row or cfg.prefill_chunk_tokens % row:
-            self.refuse(f"prefill_chunk_tokens={cfg.prefill_chunk_tokens}",
-                        f"prefills in rows of chunk_size tokens only: a "
-                        f"positive multiple of {row}")
-        if row % page:
+        if self.cfg.chunk_size % page:
             self.refuse(f"kv_page_tokens={page}",
                         f"writes a row's keys and values as whole pages: a "
-                        f"divisor of chunk_size={row}")
+                        f"divisor of chunk_size={self.cfg.chunk_size}")
 
     def build(self, loop, params):
         self.loop = loop

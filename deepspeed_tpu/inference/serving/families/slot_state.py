@@ -1,14 +1,16 @@
 """What the families whose lanes hold a slot's state have letter for letter
 in common (``families/kimi_linear.py``, ``families/nemotron_h.py``,
-``families/laguna.py`` and, through it, ``families/mimo_v2.py``): a request
-holds a lane while its prompt is read,
+``families/laguna.py``, ``families/mimo_v2.py``, ``families/keye.py``): a
+request holds a lane while its prompt is read,
 admission claims the lane's slot and pages of a ``HybridStatePool`` (and
 zeroes what the pool says a new occupant must not inherit), lane churn
 patches the device's lane vectors, one decode step is kept in flight, and
 the options none of them can honour. ``RowPrefillFamily`` adds the prefill
-call that two of them lay out alike: several prompts a call, in rows. What
-differs stays with the family: the state's description and the jitted
-programs."""
+call that four of them lay out alike: several prompts a call, in rows.
+``PagesAndRingsFamily`` adds the pool of two of them: pages for the full
+layers, a ring a lane for the window layers. What differs stays with the
+family: the state's description and the jitted programs. A family's file
+imports this one and none of its siblings."""
 
 import time
 
@@ -25,8 +27,11 @@ from deepspeed_tpu.inference.serving.family import (
     ServingFamily,
     UnsupportedOptionError,
 )
-from deepspeed_tpu.inference.serving.kv_pool import PoolExhaustedError
-from deepspeed_tpu.models.nemotron_h import decode_key_span
+from deepspeed_tpu.inference.serving.kv_pool import (
+    HybridStatePool,
+    PoolExhaustedError,
+)
+from deepspeed_tpu.models.paged_layers import decode_key_span
 
 
 @jax.jit  # jaxlint: hot
@@ -245,20 +250,21 @@ PREFILL_HOLD_STEPS = 16
 
 class RowPrefillFamily(SlotStateFamily):
     """A ``SlotStateFamily`` whose attention layers over pages are
-    ``models/nemotron_h.py``'s (``paged_attn_layers`` of them, which
+    ``models/paged_layers.py``'s (``paged_attn_layers`` of them, which
     ``build`` sets; a family whose paged attention walks no work list,
     ``families/keye.py``, counts what it reads in a ``count_attended`` of
     its own) and whose prefill call runs ``prefill_chunk_tokens``
     positions as ``rows`` rows of ``row_tokens`` tokens (``build`` sets
-    both). The prompts being read take rows in the order they were
-    admitted, each as many as its remaining tokens need while rows are
-    left, so a call holds several prompts, a long prompt advances by
-    several rows in one call and no prompt is padded by more than a row. A
-    call is held back, for at most ``PREFILL_HOLD_STEPS`` steps and only
-    while lanes decode, until the prompts waiting fill its rows. The
-    program takes ``(params, state, ids [R, T], slots [R], starts [R], lens
-    [R], page_tables [R, mp])``; an empty row carries ``max_slots`` for its
-    slot, which no write reaches."""
+    both; a row is ``row_length`` tokens: one page, unless the family's
+    mixer has a chunk of its own). The prompts being read take rows in the
+    order they were admitted, each as many as its remaining tokens need
+    while rows are left, so a call holds several prompts, a long prompt
+    advances by several rows in one call and no prompt is padded by more
+    than a row. A call is held back, for at most ``PREFILL_HOLD_STEPS``
+    steps and only while lanes decode, until the prompts waiting fill its
+    rows. The program takes ``(params, state, ids [R, T], slots [R], starts
+    [R], lens [R], page_tables [R, mp])``; an empty row carries
+    ``max_slots`` for its slot, which no write reaches."""
 
     row_tokens = None
     rows = None
@@ -267,6 +273,19 @@ class RowPrefillFamily(SlotStateFamily):
     def __init__(self, model_config):
         super().__init__(model_config)
         self._held = 0              # steps the waiting prompts were held
+
+    def row_length(self, page):
+        """Tokens of a prefill row where a page holds ``page``."""
+        return page
+
+    def check_options(self, cfg, params):
+        page = super().check_options(cfg, params)
+        row = self.row_length(page)
+        if cfg.prefill_chunk_tokens < row or cfg.prefill_chunk_tokens % row:
+            self.refuse(f"prefill_chunk_tokens={cfg.prefill_chunk_tokens}",
+                        f"prefills in rows of {row} tokens: a positive "
+                        f"multiple of it")
+        return page
 
     def decode_step(self, guard):  # jaxlint: hot
         """The step of ``SlotStateFamily``, and what it attends to, counted
@@ -380,3 +399,67 @@ class RowPrefillFamily(SlotStateFamily):
                 st.req, st.slot, int(first_host[last_row]), now)
         loop.metrics.admit_time_s += now - top
         return now
+
+
+class PagesAndRingsFamily(RowPrefillFamily):
+    """A ``RowPrefillFamily`` over a decoder of full and window layers
+    (``models/paged_layers.py``'s walk: ``families/laguna.py``,
+    ``families/mimo_v2.py``). The pool holds state of two lifetimes: a
+    full-attention layer's keys and values in pages (the key-value heads
+    side by side in a paged row), which a request claims for its own span
+    from the ``kv_pool_tokens`` budget, and a window layer's in a ring of
+    ``sliding_window`` positions a lane, among the pool's slot arrays, which
+    is the lane's whatever its occupant. It is described from the
+    configuration: ``cache_widths``, a width a name (``k``, ``v``, ``wk``,
+    ``wv``: one number in Laguna, four in MiMo-V2), ``full_index``,
+    ``window_index`` and ``sliding_window``. A ring is read behind a
+    position mask, which hides whatever a previous occupant left, so
+    admission zeroes nothing (``reset=()``). A row is one page of tokens."""
+
+    cached = "keys and values"
+
+    def check_options(self, cfg, params):
+        page = super().check_options(cfg, params)
+        if self.cfg.sliding_window % page:
+            self.refuse(f"kv_page_tokens={page}",
+                        f"writes a row into a window layer's ring with one "
+                        f"update: a divisor of "
+                        f"sliding_window={self.cfg.sliding_window}")
+
+    def build(self, loop, params):
+        self.loop = loop
+        m, cfg = self.cfg, loop.config
+        dtype = jnp.dtype(params["embed_tokens"]["embedding"].dtype)
+        n_full, n_window = len(m.full_index), len(m.window_index)
+        # a ring in blocks of one page, laid out as pages are (tokens last)
+        page = resolve_page_tokens(cfg.kv_page_tokens or DEFAULT_PAGE_TOKENS,
+                                   loop.max_seq_len)
+        widths = m.cache_widths
+        pool = HybridStatePool(
+            cfg.max_slots, loop.max_seq_len,
+            paged={name: (n_full, widths[name], dtype)
+                   for name in ("k", "v")},
+            slotted={name: (n_window, (m.sliding_window // page,
+                                       widths[name], page), dtype)
+                     for name in ("wk", "wv")},
+            page_tokens=cfg.kv_page_tokens, pool_tokens=cfg.kv_pool_tokens,
+            reset=())
+        assert pool.page_tokens == page, (pool.page_tokens, page)
+        self.row_tokens = page
+        self.rows = int(cfg.prefill_chunk_tokens) // page
+        self.paged_attn_layers = n_full
+        self.ring_layers = n_window
+        loop.metrics.record_state_pool(0, 0, pool.slot_bytes(),
+                                       pool.paged_bytes())
+        return params, pool
+
+    def count_attended(self, held):
+        """Also what the step attends to and holds, for the roofline's and
+        the pool's readers: a full layer reads every position its active
+        lanes hold, a window layer what of its ring is behind the mask."""
+        super().count_attended(held)
+        metrics = self.loop.metrics
+        metrics.record_attended(held.sum(), self.loop.pool.pages_in_use)
+        metrics.record_ring_positions(
+            self.ring_layers
+            * np.minimum(held + 1, self.cfg.sliding_window).sum())

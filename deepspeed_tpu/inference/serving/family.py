@@ -7,7 +7,7 @@ guard, the injector hooks and the threads. What a lane's state is and which
 jitted programs fill and advance it is a ``ServingFamily``
 (``families/gpt2.py``, ``families/kimi_linear.py``,
 ``families/nemotron_h.py``, ``families/laguna.py``,
-``families/mimo_v2.py``). The arrows point one
+``families/mimo_v2.py``, ``families/keye.py``). The arrows point one
 way: the loop calls the family through the methods below, and a family calls
 back only this short public list of the loop it was built for:
 
@@ -28,7 +28,10 @@ Adding a family: its own file under ``families/``, one line in
 ``family_for``, and a pool class in ``kv_pool.py`` only if its state is of a
 new kind (``HybridStatePool`` takes paged rows and slot arrays by
 description, and which of the slot arrays a new occupant must find zeroed;
-``families/slot_state.py`` holds what the families over it share).
+``families/slot_state.py`` holds what the families over it share, and a
+family's file imports no other family's). Its model file under ``models/``
+takes the layers it shares with the others from ``models/paged_layers.py``
+and imports no sibling either.
 """
 
 import numpy as np

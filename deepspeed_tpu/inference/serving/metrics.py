@@ -261,7 +261,7 @@ class ServingMetrics:
 
     def record_attn_blocks(self, blocks, layers):
         """One decode step of a family whose paged attention walks a work
-        list (``models/nemotron_h.py::gqa_decode``): ``blocks`` key blocks
+        list (``models/paged_layers.py::gqa_decode``): ``blocks`` key blocks
         each active lane owns, in each of ``layers`` layers. Beside the
         pairs walked, the rectangle they are cut from: every lane to the
         longest one's end (``decode_attn_blocks_walked``,

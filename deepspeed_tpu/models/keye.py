@@ -26,7 +26,7 @@ sections then rotate as plain rotary positions do, which is what is here:
 the programs take one position a token.
 
 Two entry points, both functions of ``(params, cfg, state, ...)`` that the
-serving engine jits, laid out as ``models/laguna.py``'s:
+serving engine jits, with the arguments of every slot-state family's:
 
 - ``prefill_chunk``: ``R`` rows of one page of tokens, each the next tokens
   of some prompt; rows of one prompt are consecutive and in order. A layer
@@ -35,7 +35,7 @@ serving engine jits, laid out as ``models/laguna.py``'s:
   ``topk``-th largest score exactly (bisection over the scores' bits), and
   walks the key blocks with the selection as its mask: on a TPU in one
   kernel a layer that keeps a block's scores in VMEM
-  (``ops/paged_prefill.py``), elsewhere as ``nemotron_h.gqa_prefill``
+  (``ops/paged_prefill.py``), elsewhere as ``paged_layers.gqa_prefill``
   does.
 - ``decode_step``: one token for every active lane. A layer scores the
   lane's indexer keys, takes the exact top ``topk`` (``lax.top_k``: the
@@ -68,22 +68,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.kimi_linear import (
-    _dot,
-    _online_softmax_loop,
-    rms_norm,
-)
-from deepspeed_tpu.models.laguna import (
-    AttentionShape,
-    RopeSpec,
-    apply_rope,
-    rotary,
-)
-from deepspeed_tpu.models.nemotron_h import (
-    PREFILL_KEY_BLOCK,
-    _blocks_of_pages,
-    _gqa_project,
-)
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.ops import paged_prefill
 from deepspeed_tpu.ops.column_write import write_columns
 from deepspeed_tpu.parallel import expert as expert_mod
@@ -130,14 +115,8 @@ class KeyeConfig:
     def __post_init__(self):
         for name in ("mrope_section", "mlp_only_layers"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
-        if self.experts_held is None:
-            object.__setattr__(self, "experts_held", (0, self.num_experts))
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1
-                and first + count <= self.num_experts):
-            raise ValueError(
-                f"experts_held={self.experts_held} outside the "
-                f"{self.num_experts} experts the router scores")
+        object.__setattr__(self, "experts_held", expert_mod.held_share(
+            self.experts_held, self.num_experts))
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("query heads must divide into their key-value "
                              "heads")
@@ -196,15 +175,15 @@ class KeyeConfig:
     def attention(self):
         """The heads and head sizes, as the grouped-query functions read
         them."""
-        return AttentionShape(self.num_attention_heads,
-                              self.num_key_value_heads, self.head_dim,
-                              self.head_dim)
+        return pl.AttentionShape(self.num_attention_heads,
+                                 self.num_key_value_heads, self.head_dim,
+                                 self.head_dim)
 
     @property
     def rope(self):
         """Plain frequencies over the whole head: what the three sections
         of ``mrope_section`` are for a text token."""
-        return RopeSpec(rope_theta=self.rope_theta)
+        return pl.RopeSpec(rope_theta=self.rope_theta)
 
     @property
     def n_moe_layers(self):
@@ -228,14 +207,14 @@ def _rotate(p, cfg):
     """``rotate(q, k, positions)`` of the grouped-query projections: the
     RMSNorm over each head, then the rotation of the whole head."""
     shape = cfg.attention
-    turn = rotary(cfg.rope, shape, "rope")
+    turn = pl.rotary(cfg.rope, shape, "rope")
 
     def rotate(q, k, positions):
         with jax.named_scope("qk_norm"):
             heads = k.shape[:-1] + (shape.num_key_value_heads, shape.head_dim)
-            q = rms_norm(q, p["q_norm"]["scale"], cfg.rms_norm_eps)
-            k = rms_norm(k.reshape(heads), p["k_norm"]["scale"],
-                         cfg.rms_norm_eps).reshape(k.shape)
+            q = pl.rms_norm(q, p["q_norm"]["scale"], cfg.rms_norm_eps)
+            k = pl.rms_norm(k.reshape(heads), p["k_norm"]["scale"],
+                            cfg.rms_norm_eps).reshape(k.shape)
         return turn(q, k, positions)
     return rotate
 
@@ -254,12 +233,12 @@ def indexer_project(p, cfg, x, positions):
     holds the keys), rotated; ``w [..., 16]`` float32, scaled."""
     ni, hi = cfg.indexer_num_heads, cfg.indexer_head_dim
     with jax.named_scope("dsa_project"):
-        qI = _dot(x, p["wq"]["kernel"]).reshape(x.shape[:-1] + (ni, hi))
-        kI = _layer_norm(_dot(x, p["wk"]["kernel"]), p["k_norm"],
+        qI = pl.dot(x, p["wq"]["kernel"]).reshape(x.shape[:-1] + (ni, hi))
+        kI = _layer_norm(pl.dot(x, p["wk"]["kernel"]), p["k_norm"],
                          cfg.rms_norm_eps)
-        qI = apply_rope(cfg.rope, qI, positions).astype(x.dtype)
-        kI = apply_rope(cfg.rope, kI[..., None, :], positions)[..., 0, :]
-        w = _dot(x, p["weights_proj"]["kernel"]) * (ni * hi) ** -0.5
+        qI = pl.apply_rope(cfg.rope, qI, positions).astype(x.dtype)
+        kI = pl.apply_rope(cfg.rope, kI[..., None, :], positions)[..., 0, :]
+        w = pl.dot(x, p["weights_proj"]["kernel"]) * (ni * hi) ** -0.5
     return qI, kI.astype(x.dtype), w
 
 
@@ -338,7 +317,7 @@ def _tiles(cfg, k, v, dtype):
 
 def prefill_key_span(page_tokens):
     """Positions in a key block of the prefill walk (whole pages)."""
-    return max(1, PREFILL_KEY_BLOCK // page_tokens) * page_tokens
+    return max(1, pl.PREFILL_KEY_BLOCK // page_tokens) * page_tokens
 
 
 def _selected(j, uj, least, ties_left, ties_before, below):
@@ -365,7 +344,7 @@ def _attend_blocks(q, kv_pool, n, tables, bp, n_blocks, selection):
     for every backend but a TPU (and every shape but its own): ``n_blocks``
     key blocks of ``bp`` pages of row ``n`` of ``kv_pool`` under the
     selection (``_selected``'s operands, a row each), every row to the
-    call's longest, as ``nemotron_h.gqa_prefill`` walks its own. A block's
+    call's longest, as ``paged_layers.gqa_prefill`` walks its own. A block's
     float32 scores ``[R, KV, J, T, span]`` are an array in memory here.
     Returns the context ``[R, KV, J, T, hd]`` float32."""
     R, T, kvh, J, hd = q.shape
@@ -391,7 +370,7 @@ def _attend_blocks(q, kv_pool, n, tables, bp, n_blocks, selection):
                 kvb[..., kvh:, :], preferred_element_type=jnp.float32)
         return s, weigh
 
-    return _online_softmax_loop(n_blocks, block, (R, kvh, J, T), hd)
+    return pl.online_softmax_loop(n_blocks, block, (R, kvh, J, T), hd)
 
 
 def attend_selected(q, kv_pool, n, tables, bp, starts, lens, u, topk):
@@ -458,7 +437,7 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
     mp = page_tables.shape[1]
     assert T == pt, (T, pt)
     pos = starts[:, None] + jnp.arange(T)[None, :]                   # [R, T]
-    q, k, v = _gqa_project(p, shape, x)
+    q, k, v = pl.gqa_project(p, shape, x)
     q, k = _rotate(p, cfg)(q, k, pos)
     qI, kI, w = indexer_project(p["indexer"], cfg, x, pos)
     logical = starts // pt
@@ -476,7 +455,7 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
                     pools[1], ik_new[r][None, None], (n, dest[r], 0, 0)))
 
     kv_pool, ik_pool = jax.lax.fori_loop(0, R, put, (kv_pool, ik_pool))
-    tables, bp = _blocks_of_pages(page_tables, PREFILL_KEY_BLOCK, pt)
+    tables, bp = pl.blocks_of_pages(page_tables, pl.PREFILL_KEY_BLOCK, pt)
     span = bp * pt
     end = jnp.max(jnp.where(lens > 0, starts + lens, 0))
     n_blocks = (end + span - 1) // span
@@ -499,7 +478,7 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
     ctx = attend_selected(q, kv_pool, n, tables, bp, starts, lens, u,
                           cfg.topk)
     ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * hd)
-    return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
+    return (pl.dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
             kv_pool, ik_pool)
 
 
@@ -555,7 +534,7 @@ def dsa_decode(p, cfg, x, kv_pool, ik_pool, n, page_tables, positions, active,
     phys = jnp.where(active & (positions < mp * pt),
                      page_tables[jnp.arange(Bn), logical], 0)
     col = positions % pt
-    q, k, v = _gqa_project(p, shape, x)
+    q, k, v = pl.gqa_project(p, shape, x)
     q, k = _rotate(p, cfg)(q, k, positions)
     qI, kI, w = indexer_project(p["indexer"], cfg, x, positions)
     with jax.named_scope("page_write"):
@@ -577,7 +556,7 @@ def dsa_decode(p, cfg, x, kv_pool, ik_pool, n, page_tables, positions, active,
         tiles = kv_pool[n, page, at % pt].astype(x.dtype)    # [B, K, 2KV, hd]
         ctx = attend_chosen(q, tiles, chosen, positions, active)
     ctx = ctx.reshape(Bn, -1)
-    return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
+    return (pl.dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
             kv_pool, ik_pool)
 
 
@@ -593,16 +572,10 @@ def _experts(lp, cfg, x, live, tile, every_expert=False):
         every_expert=every_expert, scoring="softmax")
 
 
-def _head(params, cfg, h):
-    with jax.named_scope("lm_head"):
-        h = rms_norm(h, params["norm"]["scale"], cfg.rms_norm_eps)
-        return _dot(h, params["lm_head"]["kernel"])
-
-
 def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
                   *, page_tokens, moe_tile=128):
-    """``R`` rows of the prompts being read, as ``laguna.prefill_chunk``
-    takes them: ``ids [R, T]`` with ``T = page_tokens``, ``slots [R]``
+    """``R`` rows of the prompts being read, as every family of rows of one
+    page takes them: ``ids [R, T]`` with ``T = page_tokens``, ``slots [R]``
     (read by nothing: no state is a slot's), ``starts [R]`` (a multiple of
     ``T``), ``lens [R]`` (0: an empty row, which writes the spare page),
     ``page_tables [R, mp]``. Returns ``(state, first [R], logits [R,
@@ -616,17 +589,18 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
     kv_pool, ik_pool = state["kv"], state["ik"]
     for l in range(cfg.num_hidden_layers):
         lp = params["layers"][str(l)]
-        x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["input_layernorm"]["scale"], eps)
         y, kv_pool, ik_pool = dsa_prefill(
             lp["self_attn"], cfg, x, kv_pool, ik_pool, l, page_tables,
             starts, lens, page_tokens)
         h = h + y
-        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
         y, _ = _experts(lp, cfg, x.reshape(R * T, -1), live, moe_tile)
         h = h + y.reshape(h.shape)
     at = jnp.clip(lens - 1, 0, T - 1)
     h_last = jnp.take_along_axis(h, at[:, None, None], axis=1)[:, 0]
-    logits = _head(params, cfg, h_last)
+    logits = pl.lm_head(h_last, params["norm"]["scale"], eps,
+                        params["lm_head"]["kernel"])
     first = jnp.argmax(logits, -1).astype(jnp.int32)
     return {"kv": kv_pool, "ik": ik_pool}, first, logits
 
@@ -634,9 +608,10 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
 def decode_step(params, cfg, state, tokens, positions, active, page_tables,
                 *, page_tokens, moe_tile=16):
     """One token for every active lane (lane ``b`` is slot ``b``). Returns
-    ``(state, tokens, positions, logits [B, V], moe [3] int32)`` as
-    ``laguna.decode_step`` does. An expert layer reads every held expert,
-    picked or not, as ``mimo_v2.decode_step`` does and for its reason: an
+    ``(state, tokens, positions, logits [B, V], moe [3] int32)``; ``moe``
+    sums, over this step's expert layers, the picks that fell on held
+    experts, the held experts touched and the busiest one's tokens (active
+    lanes only). An expert layer reads every held expert, picked or not: an
     eighth of the experts under a full batch's picks leaves few idle in a
     step, which ones follows the weights, and a step that reads them all
     takes the same time whatever they are (``moe`` still counts the experts
@@ -647,16 +622,17 @@ def decode_step(params, cfg, state, tokens, positions, active, page_tables,
     moe = jnp.zeros(3, jnp.int32)
     for l in range(cfg.num_hidden_layers):
         lp = params["layers"][str(l)]
-        x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["input_layernorm"]["scale"], eps)
         y, kv_pool, ik_pool = dsa_decode(
             lp["self_attn"], cfg, x, kv_pool, ik_pool, l, page_tables,
             positions, active, page_tokens)
         h = h + y
-        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
         y, counts = _experts(lp, cfg, x, active, moe_tile, every_expert=True)
         moe = moe + counts
         h = h + y
-    logits = _head(params, cfg, h)
+    logits = pl.lm_head(h, params["norm"]["scale"], eps,
+                        params["lm_head"]["kernel"])
     nxt = jnp.argmax(logits, -1).astype(jnp.int32)
     tokens = jnp.where(active, nxt, tokens)
     positions = jnp.where(active, positions + 1, positions)

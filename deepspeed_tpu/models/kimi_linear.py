@@ -34,6 +34,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -78,14 +79,8 @@ class KimiLinearConfig:
     vocab_first: int = 0
 
     def __post_init__(self):
-        if self.experts_held is None:
-            object.__setattr__(self, "experts_held", (0, self.num_experts))
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1
-                and first + count <= self.num_experts):
-            raise ValueError(
-                f"experts_held={self.experts_held} outside the "
-                f"{self.num_experts} experts the router scores")
+        object.__setattr__(self, "experts_held", expert_mod.held_share(
+            self.experts_held, self.num_experts))
         for i in range(1, self.num_hidden_layers + 1):
             if (i in self.kda_layers) == (i in self.full_attn_layers):
                 raise ValueError(
@@ -154,27 +149,8 @@ class KimiLinearConfig:
 
 # -- small pieces ---------------------------------------------------------
 
-def rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _dot(x, w):
-    """``x @ w`` in the parameters' type with float32 accumulation."""
-    return jnp.matmul(x.astype(w.dtype), w,
-                      preferred_element_type=jnp.float32)
-
-
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-
-
-def swiglu(x, p):
-    """SwiGLU with ``{gate,up,down}_proj/kernel``."""
-    a = jax.nn.silu(_dot(x, p["gate_proj"]["kernel"])) * _dot(
-        x, p["up_proj"]["kernel"])
-    return _dot(a.astype(x.dtype), p["down_proj"]["kernel"]).astype(x.dtype)
 
 
 # -- KDA --------------------------------------------------------------------
@@ -265,29 +241,29 @@ def _kda_inputs(p, cfg, x, conv_ext):
                for i in range(3))
     q = _l2norm(q) * (D ** -0.5)
     k = _l2norm(k)
-    f = _dot(_dot(x, p["f_a_proj"]["kernel"]).astype(x.dtype),
-             p["f_b_proj"]["kernel"]) + p["dt_bias"].astype(jnp.float32)
+    f = pl.dot(pl.dot(x, p["f_a_proj"]["kernel"]).astype(x.dtype),
+               p["f_b_proj"]["kernel"]) + p["dt_bias"].astype(jnp.float32)
     g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
         f.reshape(lead + (H, D)))
-    beta = jax.nn.sigmoid(_dot(x, p["b_proj"]["kernel"]))
-    gate = _dot(_dot(x, p["g_a_proj"]["kernel"]).astype(x.dtype),
-                p["g_b_proj"]["kernel"]) + p["g_b_proj"]["bias"].astype(
-                    jnp.float32)
+    beta = jax.nn.sigmoid(pl.dot(x, p["b_proj"]["kernel"]))
+    gate = pl.dot(pl.dot(x, p["g_a_proj"]["kernel"]).astype(x.dtype),
+                  p["g_b_proj"]["kernel"]) + p["g_b_proj"]["bias"].astype(
+                      jnp.float32)
     return q, k, v, g, beta, jax.nn.sigmoid(gate).reshape(lead + (H, D))
 
 
 def _kda_project(p, x):
     """Pre-convolution projections ``[..., 3W]`` in ``x``'s type."""
     return jnp.concatenate(
-        [_dot(x, p[n]["kernel"]) for n in ("q_proj", "k_proj", "v_proj")],
+        [pl.dot(x, p[n]["kernel"]) for n in ("q_proj", "k_proj", "v_proj")],
         -1).astype(x.dtype)
 
 
 def _kda_output(p, cfg, o, gate, dtype):
-    o = rms_norm(o, p["o_norm"]["scale"], cfg.rms_norm_eps) * gate
+    o = pl.rms_norm(o, p["o_norm"]["scale"], cfg.rms_norm_eps) * gate
     lead = o.shape[:-2]
-    return _dot(o.reshape(lead + (cfg.kda_width,)).astype(dtype),
-                p["o_proj"]["kernel"]).astype(dtype)
+    return pl.dot(o.reshape(lead + (cfg.kda_width,)).astype(dtype),
+                  p["o_proj"]["kernel"]).astype(dtype)
 
 
 def kda_prefill(p, cfg, x, S0, tail, lens):
@@ -326,7 +302,7 @@ def kda_decode(p, cfg, x, S0, tail, active):
 
 def _mla_q(p, cfg, x):
     nh = cfg.num_attention_heads
-    q = _dot(x, p["q_proj"]["kernel"]).astype(x.dtype)
+    q = pl.dot(x, p["q_proj"]["kernel"]).astype(x.dtype)
     q = q.reshape(x.shape[:-1] + (nh, cfg.qk_nope_head_dim
                                   + cfg.qk_rope_head_dim))
     return q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
@@ -334,9 +310,9 @@ def _mla_q(p, cfg, x):
 
 def mla_latent(p, cfg, x):
     """The row cached per token: ``[RMSNorm(c), kr]``, rank + rope wide."""
-    ckr = _dot(x, p["kv_a_proj_with_mqa"]["kernel"]).astype(x.dtype)
-    c = rms_norm(ckr[..., :cfg.kv_lora_rank], p["kv_a_layernorm"]["scale"],
-                 cfg.rms_norm_eps)
+    ckr = pl.dot(x, p["kv_a_proj_with_mqa"]["kernel"]).astype(x.dtype)
+    c = pl.rms_norm(ckr[..., :cfg.kv_lora_rank], p["kv_a_layernorm"]["scale"],
+                    cfg.rms_norm_eps)
     return jnp.concatenate([c, ckr[..., cfg.kv_lora_rank:]], -1)
 
 
@@ -345,27 +321,6 @@ def _kv_b(p, cfg):
     nh = cfg.num_attention_heads
     return p["kv_b_proj"]["kernel"].reshape(
         cfg.kv_lora_rank, nh, cfg.qk_nope_head_dim + cfg.v_head_dim)
-
-
-def _online_softmax_loop(n_blocks, scores_and_values, q_shape, v_width):
-    """Shared skeleton of both attention forms: ``scores_and_values(j)``
-    gives masked float32 scores ``[..., n]`` and a function mapping
-    probabilities to their weighted values ``[..., v_width]``."""
-    m0 = jnp.full(q_shape, -1e30, jnp.float32)
-    l0 = jnp.zeros(q_shape, jnp.float32)
-    a0 = jnp.zeros(q_shape + (v_width,), jnp.float32)
-
-    def body(j, carry):
-        m, l, acc = carry
-        s, weigh = scores_and_values(j)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        scale = jnp.exp(m - m_new)
-        pr = jnp.exp(s - m_new[..., None])
-        return (m_new, l * scale + jnp.sum(pr, -1),
-                acc * scale[..., None] + weigh(pr))
-
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
-    return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
 def mla_prefill(p, cfg, x, latent_pool, n, page_tables, starts, lens,
@@ -433,9 +388,9 @@ def mla_prefill(p, cfg, x, latent_pool, n, page_tables, starts, lens,
                               preferred_element_type=jnp.float32)
         return s, weigh
 
-    ctx = _online_softmax_loop(n_blocks, block, (R, nh, Tc), dvh)
+    ctx = pl.online_softmax_loop(n_blocks, block, (R, nh, Tc), dvh)
     ctx = jnp.moveaxis(ctx, 1, 2).reshape(R, Tc, nh * dvh)
-    return _dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(
+    return pl.dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(
         x.dtype), latent_pool
 
 
@@ -497,11 +452,11 @@ def mla_decode(p, cfg, x, latent_pool, n, page_tables, positions, active,
                               preferred_element_type=jnp.float32)
         return s, weigh
 
-    ctx_lat = _online_softmax_loop(n_blocks, block, (B, nh), rank)
+    ctx_lat = pl.online_softmax_loop(n_blocks, block, (B, nh), rank)
     ctx = jnp.einsum("bhc,chd->bhd", ctx_lat.astype(x.dtype), wkv[..., dn:],
                      preferred_element_type=jnp.float32)
-    return _dot(ctx.reshape(B, nh * dvh).astype(x.dtype),
-                p["o_proj"]["kernel"]).astype(x.dtype), latent_pool
+    return pl.dot(ctx.reshape(B, nh * dvh).astype(x.dtype),
+                  p["o_proj"]["kernel"]).astype(x.dtype), latent_pool
 
 
 # -- the two programs -------------------------------------------------------
@@ -511,17 +466,11 @@ def _ffn(lp, cfg, i, x, live, tile):
     which tokens are real. Returns ``(y, counts [3] int32)``: picks that
     fell on held experts, held experts touched, the busiest one's tokens."""
     if not cfg.layer_is_moe(i):
-        return swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
+        return pl.swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
     return expert_mod.routed_moe_ffn(
         lp["mlp"], x, live, k=cfg.num_experts_per_token,
         scaling=cfg.routed_scaling_factor,
         renormalize=cfg.moe_renormalize, held=cfg.experts_held, tile=tile)
-
-
-def _head(params, cfg, h):
-    with jax.named_scope("lm_head"):
-        h = rms_norm(h, params["norm"]["scale"], cfg.rms_norm_eps)
-        return _dot(h, params["lm_head"]["kernel"])
 
 
 def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
@@ -539,7 +488,7 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
     kda, conv, latent = state["kda"], state["conv"], state["latent"]
     for i in range(1, cfg.num_hidden_layers + 1):
         lp = params["layers"][str(i)]
-        x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["input_layernorm"]["scale"], eps)
         if cfg.layer_kind(i) == "kda":
             n = cfg.kda_index[i]
             with jax.named_scope("kda_mix"):
@@ -554,12 +503,13 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
                                         page_tables, starts, lens,
                                         page_tokens)
         h = h + y
-        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
         y, _ = _ffn(lp, cfg, i, x.reshape(R * Tc, -1), live, moe_tile)
         h = h + y.reshape(h.shape)
     last = jnp.clip(lens - 1, 0, Tc - 1)
     h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-    logits = _head(params, cfg, h_last)
+    logits = pl.lm_head(h_last, params["norm"]["scale"], eps,
+                        params["lm_head"]["kernel"])
     first = jnp.argmax(logits, -1).astype(jnp.int32)
     return {"kda": kda, "conv": conv, "latent": latent}, first, logits
 
@@ -578,7 +528,7 @@ def decode_step(params, cfg, state, tokens, positions, active, page_tables,
     moe = jnp.zeros(3, jnp.int32)
     for i in range(1, cfg.num_hidden_layers + 1):
         lp = params["layers"][str(i)]
-        x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["input_layernorm"]["scale"], eps)
         if cfg.layer_kind(i) == "kda":
             n = cfg.kda_index[i]
             with jax.named_scope("kda_mix"):
@@ -593,11 +543,12 @@ def decode_step(params, cfg, state, tokens, positions, active, page_tables,
                                        page_tables, positions, active,
                                        page_tokens)
         h = h + y
-        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
         y, counts = _ffn(lp, cfg, i, x, active, moe_tile)
         moe = moe + counts
         h = h + y
-    logits = _head(params, cfg, h)
+    logits = pl.lm_head(h, params["norm"]["scale"], eps,
+                        params["lm_head"]["kernel"])
     nxt = jnp.argmax(logits, -1).astype(jnp.int32)
     tokens = jnp.where(active, nxt, tokens)
     positions = jnp.where(active, positions + 1, positions)

@@ -28,13 +28,11 @@ renormalised, no scaling factor, no shared expert; this program holds
 what those give.
 
 Two entry points, both functions of ``(params, cfg, state, ...)`` that the
-serving engine jits, laid out as ``models/laguna.py``'s and built from the
-same functions (``nemotron_h.gqa_prefill`` / ``gqa_decode`` for the full
-layers, ``laguna.window_prefill`` / ``window_decode`` for the window ones):
-
-- ``prefill_chunk``: ``R`` rows of one page of tokens, each the next tokens
-  of some prompt; rows of one prompt are consecutive and in order.
-- ``decode_step``: one token for every active lane.
+serving engine jits: ``prefill_chunk`` (``R`` rows of one page of tokens, each
+the next tokens of some prompt) and ``decode_step`` (one token for every
+active lane). Both are the walk of a decoder of full and window layers in
+``models/paged_layers.py`` over this model's hooks, ``_attention`` and
+``_ffn``.
 
 ``state`` is ``{"k": [Lf, pages, KV x 192, page_tokens], "v": [Lf, pages, KV
 x 128, page_tokens], "wk": [Lw, slots, W / page_tokens, KVw x 192,
@@ -50,15 +48,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.kimi_linear import _dot, rms_norm, swiglu
-from deepspeed_tpu.models.laguna import (
-    AttentionShape,
-    RopeSpec,
-    rotary,
-    window_decode,
-    window_prefill,
-)
-from deepspeed_tpu.models.nemotron_h import gqa_decode, gqa_prefill
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
 
 _PATTERN = (0, 1, 1, 1, 1) + (0, 1, 1, 1, 1, 1) * 7 + (0,)
@@ -119,15 +109,8 @@ class MiMoV2Config:
                                  f"num_hidden_layers={L}")
             if set(value[:L]) - {0, 1}:
                 raise ValueError(f"{name}: 0 or 1 a layer")
-        if self.experts_held is None:
-            object.__setattr__(self, "experts_held",
-                               (0, self.n_routed_experts))
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1
-                and first + count <= self.n_routed_experts):
-            raise ValueError(
-                f"experts_held={self.experts_held} outside the "
-                f"{self.n_routed_experts} experts the router scores")
+        object.__setattr__(self, "experts_held", expert_mod.held_share(
+            self.experts_held, self.n_routed_experts))
         for heads, kv in ((self.num_attention_heads,
                            self.num_key_value_heads),
                           (self.swa_num_attention_heads,
@@ -172,12 +155,12 @@ class MiMoV2Config:
 
     def _shape(self, window):
         if window:
-            return AttentionShape(
+            return pl.AttentionShape(
                 self.swa_num_attention_heads, self.swa_num_key_value_heads,
                 self.swa_head_dim, self.swa_v_head_dim)
-        return AttentionShape(self.num_attention_heads,
-                              self.num_key_value_heads, self.head_dim,
-                              self.v_head_dim)
+        return pl.AttentionShape(self.num_attention_heads,
+                                 self.num_key_value_heads, self.head_dim,
+                                 self.v_head_dim)
 
     def attention(self, l):
         """Layer ``l``'s heads and head sizes, as the grouped-query and the
@@ -185,7 +168,7 @@ class MiMoV2Config:
         return self._shape(self.is_window(l))
 
     def rope(self, l):
-        return RopeSpec(
+        return pl.RopeSpec(
             rope_theta=(self.swa_rope_theta if self.is_window(l)
                         else self.rope_theta),
             partial_rotary_factor=self.partial_rotary_factor)
@@ -233,12 +216,15 @@ def _value_scale(cfg):
     return scale
 
 
-def _attention(cfg, l, p):
-    """``(shape, keyword arguments)`` of layer ``l``'s attention call."""
+def _attention(cfg, l, p, x):
+    """``(shape, keyword arguments)`` of layer ``l``'s attention call, as
+    ``paged_layers``'s walk asks for them (``x``, the layer's normed input,
+    is read by nothing here)."""
+    del x
     shape = cfg.attention(l)
     window = cfg.is_window(l)
-    how = dict(rotate=rotary(cfg.rope(l), shape,
-                             "rope_window" if window else "rope_full"),
+    how = dict(rotate=pl.rotary(cfg.rope(l), shape,
+                                "rope_window" if window else "rope_full"),
                gate=_value_scale(cfg))
     if window:
         how["window"] = cfg.sliding_window
@@ -251,98 +237,37 @@ def _attention(cfg, l, p):
 
 # -- the two programs -------------------------------------------------------
 
-def _ffn(lp, cfg, l, x, live, tile, every_expert=False):
+def _ffn(lp, cfg, l, x, live, tile, decode):
     """Layer ``l``'s FFN over flat tokens ``x [N, d]``; ``live [N]`` says
     which tokens are real. Returns ``(y, counts [3] int32)`` as
-    ``expert.routed_moe_ffn`` gives them (zeros for a dense layer)."""
+    ``expert.routed_moe_ffn`` gives them (zeros for a dense layer). In a
+    ``decode`` step an expert layer reads every held expert, picked or not:
+    a sixteenth of the experts under a full batch's picks leaves one in
+    twenty idle in a step, which one it is follows the weights, and a step
+    that reads them all takes the same time whatever they are (the counts
+    are still of the experts that were picked)."""
     if not cfg.is_moe(l):
-        return swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
+        return pl.swiglu(x, lp["mlp"]), jnp.zeros(3, jnp.int32)
     return expert_mod.routed_moe_ffn(
         lp["mlp"], x, live, k=cfg.num_experts_per_tok,
         scaling=cfg.routed_scaling_factor or 1.0,
         renormalize=cfg.norm_topk_prob, held=cfg.experts_held, tile=tile,
-        every_expert=every_expert)
-
-
-def _head(params, cfg, h):
-    with jax.named_scope("lm_head"):
-        h = rms_norm(h, params["norm"]["scale"], cfg.layernorm_epsilon)
-        return _dot(h, params["lm_head"]["kernel"])
+        every_expert=decode)
 
 
 def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
                   *, page_tokens, moe_tile=128):
-    """``R`` rows of the prompts being read, as ``laguna.prefill_chunk``
-    takes them: ``ids [R, T]`` with ``T = page_tokens``, ``slots [R]``,
-    ``starts [R]`` (a multiple of ``T``), ``lens [R]`` (0: an empty row,
-    which writes nothing), ``page_tables [R, mp]``. Returns ``(state, first
-    [R], logits [R, V])``."""
-    R, T = ids.shape
-    assert T == page_tokens, (T, page_tokens)
-    eps = cfg.layernorm_epsilon
-    h = params["embed_tokens"]["embedding"][ids]
-    live = (jnp.arange(T)[None, :] < lens[:, None]).reshape(R * T)
-    k_pool, v_pool, wk, wv = (state[n] for n in ("k", "v", "wk", "wv"))
-    for l in range(cfg.num_hidden_layers):
-        lp = params["layers"][str(l)]
-        x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
-        p = lp["self_attn"]
-        shape, how = _attention(cfg, l, p)
-        if cfg.is_window(l):
-            y, wk, wv = window_prefill(p, shape, x, wk, wv,
-                                       cfg.window_index[l], slots, starts,
-                                       lens, **how)
-        else:
-            with jax.named_scope("attend_full"):
-                y, k_pool, v_pool = gqa_prefill(
-                    p, shape, x, k_pool, v_pool, cfg.full_index[l],
-                    page_tables, starts, lens, page_tokens, **how)
-        h = h + y
-        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
-        y, _ = _ffn(lp, cfg, l, x.reshape(R * T, -1), live, moe_tile)
-        h = h + y.reshape(h.shape)
-    at = jnp.clip(lens - 1, 0, T - 1)
-    h_last = jnp.take_along_axis(h, at[:, None, None], axis=1)[:, 0]
-    logits = _head(params, cfg, h_last)
-    first = jnp.argmax(logits, -1).astype(jnp.int32)
-    return {"k": k_pool, "v": v_pool, "wk": wk, "wv": wv}, first, logits
+    """``paged_layers.full_window_prefill_chunk`` over this model's layers."""
+    return pl.full_window_prefill_chunk(
+        params, cfg, state, ids, slots, starts, lens, page_tables,
+        page_tokens=page_tokens, moe_tile=moe_tile, attention=_attention,
+        ffn=_ffn, eps=cfg.layernorm_epsilon)
 
 
 def decode_step(params, cfg, state, tokens, positions, active, page_tables,
                 *, page_tokens, moe_tile=16):
-    """One token for every active lane (lane ``b`` is slot ``b``). Returns
-    ``(state, tokens, positions, logits [B, V], moe [3] int32)`` as
-    ``laguna.decode_step`` does. An expert layer reads every held expert,
-    picked or not: a sixteenth of the experts under a full batch's picks
-    leaves one in twenty idle in a step, which one it is follows the
-    weights, and a step that reads them all takes the same time whatever
-    they are (``moe`` still counts the experts that were picked)."""
-    eps = cfg.layernorm_epsilon
-    h = params["embed_tokens"]["embedding"][tokens]
-    k_pool, v_pool, wk, wv = (state[n] for n in ("k", "v", "wk", "wv"))
-    moe = jnp.zeros(3, jnp.int32)
-    for l in range(cfg.num_hidden_layers):
-        lp = params["layers"][str(l)]
-        x = rms_norm(h, lp["input_layernorm"]["scale"], eps)
-        p = lp["self_attn"]
-        shape, how = _attention(cfg, l, p)
-        if cfg.is_window(l):
-            y, wk, wv = window_decode(p, shape, x, wk, wv,
-                                      cfg.window_index[l], positions, active,
-                                      **how)
-        else:
-            with jax.named_scope("attend_full"):
-                y, k_pool, v_pool = gqa_decode(
-                    p, shape, x, k_pool, v_pool, cfg.full_index[l],
-                    page_tables, positions, active, page_tokens, **how)
-        h = h + y
-        x = rms_norm(h, lp["post_attention_layernorm"]["scale"], eps)
-        y, counts = _ffn(lp, cfg, l, x, active, moe_tile, every_expert=True)
-        moe = moe + counts
-        h = h + y
-    logits = _head(params, cfg, h)
-    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-    tokens = jnp.where(active, nxt, tokens)
-    positions = jnp.where(active, positions + 1, positions)
-    return ({"k": k_pool, "v": v_pool, "wk": wk, "wv": wv}, tokens,
-            positions, logits, moe)
+    """``paged_layers.full_window_decode_step`` over this model's layers."""
+    return pl.full_window_decode_step(
+        params, cfg, state, tokens, positions, active, page_tables,
+        page_tokens=page_tokens, moe_tile=moe_tile, attention=_attention,
+        ffn=_ffn, eps=cfg.layernorm_epsilon)

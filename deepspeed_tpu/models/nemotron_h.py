@@ -21,7 +21,7 @@ serving engine jits:
   pages. One program serves every prompt length.
 - ``decode_step``: one token for every active lane, Mamba-2 by the
   recurrence and attention over the lane's pages (a work list of the
-  lanes' blocks of keys: ``gqa_decode``).
+  lanes' blocks of keys: ``paged_layers.gqa_decode``).
 
 ``state`` is ``{"ssm": [Lm, slots, H, P, N] float32, "conv": [Lm, slots,
 K-1, conv_dim], "k", "v": [La, pages, kv_heads * head_dim, page_tokens]}``
@@ -37,20 +37,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-# what the two serving-only decoders have letter for letter in common
-from deepspeed_tpu.models.kimi_linear import (
-    _dot,
-    _online_softmax_loop,
-    rms_norm,
-)
-from deepspeed_tpu.ops.column_write import write_columns
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
-
-PREFILL_KEY_BLOCK = 512     # keys a row attends at a time in prefill
-DECODE_KEY_BLOCK = 512      # keys a lane attends at a time in decode
-_TILE_BYTES = 32 << 20      # keys and values a tile of decode pairs gathers
-_MM = dict(precision=jax.lax.Precision.HIGHEST,
-           preferred_element_type=jnp.float32)
 
 
 @dataclass(frozen=True)
@@ -89,15 +77,8 @@ class NemotronHConfig:
     vocab_first: int = 0
 
     def __post_init__(self):
-        if self.experts_held is None:
-            object.__setattr__(self, "experts_held",
-                               (0, self.n_routed_experts))
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1
-                and first + count <= self.n_routed_experts):
-            raise ValueError(
-                f"experts_held={self.experts_held} outside the "
-                f"{self.n_routed_experts} experts the router scores")
+        object.__setattr__(self, "experts_held", expert_mod.held_share(
+            self.experts_held, self.n_routed_experts))
         if len(self.hybrid_override_pattern) < self.num_hidden_layers:
             raise ValueError(
                 f"hybrid_override_pattern names "
@@ -168,7 +149,7 @@ class NemotronHConfig:
     @property
     def v_head_dim(self):
         """A value head is as wide as a key head here; the grouped-query
-        functions below read the two apart."""
+        functions (``models/paged_layers.py``) read the two apart."""
         return self.head_dim
 
     @property
@@ -212,19 +193,20 @@ def ssd_chunk(x, B, C, dt, A):
     cum = jnp.cumsum(dts * A[..., None], axis=-1)
     span = cum[..., :, None] - cum[..., None, :]             # [G, J, t, s]
     L = jnp.exp(jnp.where(jnp.tril(jnp.ones((T, T), bool)), span, -jnp.inf))
-    CB = jnp.einsum("tgn,sgn->gts", C, B, **_MM)
+    CB = jnp.einsum("tgn,sgn->gts", C, B, **pl.EXACT)
     M = CB[:, None] * L * dts[..., None, :]
-    y = jnp.einsum("gjts,sgjp->tgjp", M, x, **_MM)
+    y = jnp.einsum("gjts,sgjp->tgjp", M, x, **pl.EXACT)
     to_end = jnp.exp(cum[..., -1:] - cum) * dts              # [G, J, T]
     local = jnp.einsum("sgjp,sgn->gjpn",
-                       x * jnp.moveaxis(to_end, -1, 0)[..., None], B, **_MM)
+                       x * jnp.moveaxis(to_end, -1, 0)[..., None], B,
+                       **pl.EXACT)
     return y, jnp.moveaxis(jnp.exp(cum), -1, 0), local
 
 
 def _mamba_project(p, cfg, x):
     """``[z, xBC, dt] = x W_in`` in ``x``'s type (``dt`` float32)."""
     with jax.named_scope("mamba_in_proj"):
-        zxbcdt = _dot(x, p["in_proj"]["kernel"])
+        zxbcdt = pl.dot(x, p["in_proj"]["kernel"])
         di, cd = cfg.d_inner, cfg.conv_dim
         return (zxbcdt[..., :di].astype(x.dtype),
                 zxbcdt[..., di:di + cd].astype(x.dtype),
@@ -271,18 +253,7 @@ def _mamba_gate_out(p, cfg, y, z, dtype):
                               + cfg.layer_norm_epsilon)
         y = y.reshape(lead + (cfg.d_inner,)) * p["norm"]["scale"].astype(
             jnp.float32)
-        return _dot(y.astype(dtype), p["out_proj"]["kernel"]).astype(dtype)
-
-
-def row_links(slots, starts, lens, row_tokens):
-    """Which rows of a prefill call go on from the row before them (the same
-    prompt's next ``row_tokens`` tokens), and which are the last of their
-    prompt in the call and so write their state back to the slot."""
-    follows = jnp.concatenate([jnp.zeros(1, bool), (
-        (slots[1:] == slots[:-1]) & (starts[1:] == starts[:-1] + row_tokens)
-        & (lens[1:] > 0) & (lens[:-1] == row_tokens))])
-    last = (lens > 0) & ~jnp.concatenate([follows[1:], jnp.zeros(1, bool)])
-    return follows, last
+        return pl.dot(y.astype(dtype), p["out_proj"]["kernel"]).astype(dtype)
 
 
 def mamba_prefill(p, cfg, x, S_slot, tail_slot, lens, follows):
@@ -321,7 +292,7 @@ def mamba_prefill(p, cfg, x, S_slot, tail_slot, lens, follows):
             chain, jnp.zeros_like(S_slot[0]),
             (S_slot, follows, grow[:, -1], local))
         y = (y + grow[..., None] * jnp.einsum("rgjpn,rtgn->rtgjp", S0, C,
-                                              **_MM)
+                                              **pl.EXACT)
              + D[..., None] * xs)
     new_tail = jax.vmap(
         lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, K1, axis=0))(ext, lens)
@@ -348,233 +319,6 @@ def mamba_decode(p, cfg, x, S0, tail, active):
     return _mamba_gate_out(p, cfg, y, z, x.dtype), S.reshape(S0.shape), new_tail
 
 
-# -- grouped-query attention (no positions) ---------------------------------
-
-def _gqa_project(p, cfg, x):
-    """``q [..., KV, Q/KV, hd]`` (query head ``j`` reads key-value head ``j
-    // (Q/KV)``) and the rows cached a token: ``k [..., KV * hd]`` and ``v
-    [..., KV * vd]``, each as wide as its projection makes it (``vd =
-    cfg.v_head_dim`` may differ from ``hd = cfg.head_dim``)."""
-    kvh, hd = cfg.num_key_value_heads, cfg.head_dim
-    q = _dot(x, p["q_proj"]["kernel"]).astype(x.dtype).reshape(
-        x.shape[:-1] + (kvh, cfg.num_attention_heads // kvh, hd))
-    k = _dot(x, p["k_proj"]["kernel"]).astype(x.dtype)
-    v = _dot(x, p["v_proj"]["kernel"]).astype(x.dtype)
-    return q, k, v
-
-
-def _blocks_of_pages(page_tables, key_block, page_tokens):
-    """The page tables padded to whole blocks of ``bp`` pages."""
-    mp = page_tables.shape[1]
-    bp = max(1, key_block // page_tokens)
-    nblk = -(-mp // bp)
-    return jnp.pad(page_tables, ((0, 0), (0, nblk * bp - mp))), bp
-
-
-def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
-                page_tokens, rotate=None, gate=None):
-    """Attention over ``R`` rows: a row's keys and values are written to
-    its prompt's pages first (whole pages, each with one in-place update),
-    then every query attends the prompt's rows up to its own position, a
-    block of pages at a time. ``x [R, T, d]``; ``k_pool`` the whole ``[La,
-    pages, KV * hd, page_tokens]`` array, ``v_pool`` the whole ``[La, pages,
-    KV * vd, page_tokens]`` one and ``n`` this block's row of them. Returns
-    ``(y, k_pool, v_pool)``. What a page holds beyond the prompt's end is
-    overwritten by decode before it can be attended. ``cfg`` is read for
-    ``num_attention_heads``, ``num_key_value_heads``, ``head_dim`` (queries
-    and keys: the scores are over ``hd``) and ``v_head_dim`` (values: the
-    context and the partial sums are over ``vd``) only. A model with
-    positions gives ``rotate(q, k, positions) -> (q, k)`` (keys are cached
-    rotated), one that multiplies something onto the context ahead of
-    ``o_proj`` gives ``gate(ctx [..., Q * vd]) -> ctx`` (``models/
-    laguna.py``'s gate a head, ``models/mimo_v2.py``'s value scale);
-    without them nothing is traced for either."""
-    R, T, _ = x.shape
-    kvh, hd, vd = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
-    J = cfg.num_attention_heads // kvh
-    pt = page_tokens
-    mp = page_tables.shape[1]
-    assert T % pt == 0, (T, pt)
-    per_row = T // pt
-    pos = starts[:, None] + jnp.arange(T)[None, :]                   # [R, T]
-    q, k, v = _gqa_project(p, cfg, x)
-    if rotate is not None:
-        q, k = rotate(q, k, pos)
-    logical = starts[:, None] // pt + jnp.arange(per_row)[None, :]
-    dest = jnp.where((lens[:, None] > 0) & (logical < mp),
-                     jnp.take_along_axis(
-                         page_tables, jnp.clip(logical, 0, mp - 1), 1), 0)
-
-    def as_pages(rows, pool):
-        return jnp.swapaxes(rows.astype(pool.dtype).reshape(
-            R, per_row, pt, rows.shape[-1]), 2, 3)
-
-    k_new, v_new = as_pages(k, k_pool), as_pages(v, v_pool)
-
-    def put(i, pools):
-        r, j = i // per_row, i % per_row
-        at = (n, dest[r, j], 0, 0)
-        return (jax.lax.dynamic_update_slice(pools[0], k_new[r, j][None, None],
-                                             at),
-                jax.lax.dynamic_update_slice(pools[1], v_new[r, j][None, None],
-                                             at))
-
-    k_pool, v_pool = jax.lax.fori_loop(0, R * per_row, put, (k_pool, v_pool))
-    tables, bp = _blocks_of_pages(page_tables, PREFILL_KEY_BLOCK, pt)
-    end = jnp.max(jnp.where(lens > 0, starts + lens, 0))
-    n_blocks = (end + bp * pt - 1) // (bp * pt)
-    scale = hd ** -0.5
-
-    def block(j):
-        pages = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
-        kb = k_pool[n, pages].astype(x.dtype).reshape(R, bp, kvh, hd, pt)
-        vb = v_pool[n, pages].astype(x.dtype).reshape(R, bp, kvh, vd, pt)
-        kpos = j * bp * pt + jnp.arange(bp * pt)
-        s = jnp.einsum("rtgjd,rngdp->rgjtnp", q, kb,
-                       preferred_element_type=jnp.float32).reshape(
-                           R, kvh, J, T, bp * pt) * scale
-        ok = kpos[None, None, None, None, :] <= pos[:, None, None, :, None]
-        s = jnp.where(ok, s, -1e30)
-
-        def weigh(pr):
-            return jnp.einsum(
-                "rgjtnp,rngdp->rgjtd",
-                pr.astype(x.dtype).reshape(R, kvh, J, T, bp, pt), vb,
-                preferred_element_type=jnp.float32)
-        return s, weigh
-
-    ctx = _online_softmax_loop(n_blocks, block, (R, kvh, J, T), vd)
-    ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * vd)
-    if gate is not None:
-        ctx = gate(ctx)
-    return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
-            k_pool, v_pool)
-
-
-def decode_key_span(page_tokens):
-    """Keys in one block of a lane's decode attention: the pages that hold
-    ``DECODE_KEY_BLOCK`` tokens, at least one."""
-    return max(1, DECODE_KEY_BLOCK // page_tokens) * page_tokens
-
-
-def decode_work_list(positions, active, span, nblk, bound):
-    """The (lane, key block) pairs a decode step attends, lane by lane: an
-    active lane at position ``p`` owns blocks ``0 .. p // span`` (at most
-    the ``nblk`` its page table holds), an inactive lane none. A prefix sum
-    over the lanes' counts lays them out, as ``expert.held_experts_ffn``
-    lays out its tiles: ``bound`` pairs of static shape, of which the first
-    ``n_pairs`` exist. Returns ``(lane [bound], block [bound], live
-    [bound], n_pairs)``; a pair that does not exist reads lane and block
-    0."""
-    owned = jnp.where(active, jnp.clip(positions // span + 1, 0, nblk), 0)
-    lane_end = jnp.cumsum(owned)
-    i = jnp.arange(bound)
-    n_pairs = jnp.minimum(lane_end[-1], bound)
-    live = i < n_pairs
-    # the lane whose run of pairs holds i: those that ended at or before it
-    lane = jnp.where(live, jnp.searchsorted(lane_end, i, side="right",
-                                            method="compare_all"), 0)
-    block = jnp.where(live, i - (lane_end - owned)[lane], 0)
-    return lane, block, live, n_pairs
-
-
-def pairs_per_tile(bound, pair_bytes):
-    """Pairs one iteration of the decode attention's loop gathers
-    (``pair_bytes``: a pair's keys and its values, each at its own width):
-    the power of two whose keys and values come nearest ``_TILE_BYTES`` from
-    below (an iteration has to move tens of megabytes to stream), and no
-    more than the list can hold."""
-    g = max(1, _TILE_BYTES // pair_bytes)
-    return max(1, min(1 << (g.bit_length() - 1), bound))
-
-
-def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
-               page_tokens, rotate=None, gate=None):
-    """Attention for one token of every lane over the lane's pages. ``x [B,
-    d]``; the new key and value are written at ``positions`` before they are
-    attended: a column of each lane's page, all lanes' pages in one
-    operation an array (``write_columns``: a page is read once and written
-    once, in place; inactive lanes all name the spare page 0). ``rotate``,
-    ``gate`` and the two head sizes (keys of ``hd``, values of ``vd``) as in
-    ``gqa_prefill``.
-
-    What is walked is the work list of ``decode_work_list``: the (lane,
-    block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, a tile of them an
-    iteration, so a step reads the sum of the lanes' contexts and not every
-    lane up to the longest one's end. An iteration gathers its pairs' pages
-    from the pool and leaves each pair's masked partial softmax (running
-    max, sum and weighted values ``[..., vd]``, float32); the partials of a
-    lane's pairs, in one tile or in several, are combined after the loop."""
-    Bn = x.shape[0]
-    kvh, hd, vd = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
-    J = cfg.num_attention_heads // kvh
-    pt = page_tokens
-    mp = page_tables.shape[1]
-    logical = jnp.clip(positions // pt, 0, mp - 1)
-    phys = jnp.where(active & (positions < mp * pt),
-                     page_tables[jnp.arange(Bn), logical], 0)
-    q, k, v = _gqa_project(p, cfg, x)
-    if rotate is not None:
-        q, k = rotate(q, k, positions)
-    with jax.named_scope("page_write"):
-        k_pool = write_columns(k_pool, (n, phys), k, positions % pt)
-        v_pool = write_columns(v_pool, (n, phys), v, positions % pt)
-    tables, bp = _blocks_of_pages(page_tables, DECODE_KEY_BLOCK, pt)
-    span, nblk = decode_key_span(pt), tables.shape[1] // bp
-    # lanes hold pages of their own, so their blocks are at most the pool's
-    # and, a lane, a partial last one and the one a retired lane's step in
-    # flight runs past its span
-    bound = min(Bn * nblk, -(-k_pool.shape[1] // bp) + 2 * Bn)
-    G = pairs_per_tile(
-        bound, bp * kvh * (hd + vd) * pt * jnp.dtype(k_pool.dtype).itemsize)
-    bound = -(-bound // G) * G
-    lane, blk, live, n_pairs = decode_work_list(positions, active, span, nblk,
-                                                bound)
-    pair_pages = tables.reshape(Bn, nblk, bp)[lane, blk]            # [P, bp]
-    # the last key of its block a pair attends; none where there is no pair
-    pair_last = jnp.where(live, positions[lane] - blk * span, -1)
-    pair_q = q[lane]
-    scale = hd ** -0.5
-
-    def tile(i, parts):
-        at = i * G
-        pages = jax.lax.dynamic_slice_in_dim(pair_pages, at, G)
-        qt = jax.lax.dynamic_slice_in_dim(pair_q, at, G)
-        last = jax.lax.dynamic_slice_in_dim(pair_last, at, G)
-        kb = k_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, hd, pt)
-        vb = v_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, vd, pt)
-        s = jnp.einsum("bgjd,bngdp->bgjnp", qt, kb,
-                       preferred_element_type=jnp.float32).reshape(
-                           G, kvh, J, span) * scale
-        ok = jnp.arange(span)[None, None, None, :] <= last[:, None, None, None]
-        m = jnp.max(jnp.where(ok, s, -1e30), axis=-1)
-        pr = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
-        acc = jnp.einsum("bgjnp,bngdp->bgjd",
-                         pr.astype(x.dtype).reshape(G, kvh, J, bp, pt), vb,
-                         preferred_element_type=jnp.float32)
-        return tuple(jax.lax.dynamic_update_slice_in_dim(whole, part, at, 0)
-                     for whole, part in zip(parts, (m, jnp.sum(pr, -1), acc)))
-
-    m, l, acc = jax.lax.fori_loop(
-        0, (n_pairs + G - 1) // G, tile,
-        (jnp.full((bound, kvh, J), -1e30, jnp.float32),
-         jnp.zeros((bound, kvh, J), jnp.float32),
-         jnp.zeros((bound, kvh, J, vd), jnp.float32)))
-    # by lane: the running max, each pair rescaled to it, the sums
-    mine = (lane[None, :] == jnp.arange(Bn)[:, None]) & live[None, :]  # [B, P]
-    m_lane = jnp.max(jnp.where(mine[..., None, None], m[None], -1e30), axis=1)
-    w = jnp.exp(m - m_lane[lane])
-    l_lane = jnp.einsum("bp,pgj->bgj", mine.astype(jnp.float32), l * w, **_MM)
-    ctx = jnp.einsum("bp,pgjd->bgjd", mine.astype(jnp.float32),
-                     acc * w[..., None], **_MM)
-    ctx = (ctx / jnp.maximum(l_lane, 1e-30)[..., None]).reshape(
-        Bn, kvh * J * vd)
-    if gate is not None:
-        ctx = gate(ctx)
-    return (_dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),
-            k_pool, v_pool)
-
-
 # -- the two programs -------------------------------------------------------
 
 def _experts(lp, cfg, x, live, tile):
@@ -585,12 +329,6 @@ def _experts(lp, cfg, x, live, tile):
         lp["mixer"], x, live, k=cfg.num_experts_per_tok,
         scaling=cfg.routed_scaling_factor, renormalize=cfg.norm_topk_prob,
         held=cfg.experts_held, tile=tile)
-
-
-def _head(params, cfg, h):
-    with jax.named_scope("lm_head"):
-        h = rms_norm(h, params["norm_f"]["scale"], cfg.layer_norm_epsilon)
-        return _dot(h, params["lm_head"]["kernel"])
 
 
 def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
@@ -609,13 +347,13 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
     h = params["embed_tokens"]["embedding"][ids]
     live = (jnp.arange(T)[None, :] < lens[:, None]).reshape(R * T)
     ssm, conv, k_pool, v_pool = (state[n] for n in ("ssm", "conv", "k", "v"))
-    follows, last = row_links(slots, starts, lens, T)
+    follows, last = pl.row_links(slots, starts, lens, T)
     n_slots = ssm.shape[1]
     read = jnp.minimum(slots, n_slots - 1)
     write = jnp.where(last, slots, n_slots)          # out of range: no write
     for i in range(1, cfg.num_hidden_layers + 1):
         lp = params["layers"][str(i)]
-        x = rms_norm(h, lp["norm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["norm"]["scale"], eps)
         kind = cfg.layer_kind(i)
         if kind == "mamba":
             n = cfg.mamba_index[i]
@@ -627,7 +365,7 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
         elif kind == "attn":
             n = cfg.attn_index[i]
             with jax.named_scope("gqa_attend"):
-                y, k_pool, v_pool = gqa_prefill(
+                y, k_pool, v_pool = pl.gqa_prefill(
                     lp["mixer"], cfg, x, k_pool, v_pool, n, page_tables,
                     starts, lens, page_tokens)
         else:
@@ -636,7 +374,8 @@ def prefill_chunk(params, cfg, state, ids, slots, starts, lens, page_tables,
         h = h + y
     at = jnp.clip(lens - 1, 0, T - 1)
     h_last = jnp.take_along_axis(h, at[:, None, None], axis=1)[:, 0]
-    logits = _head(params, cfg, h_last)
+    logits = pl.lm_head(h_last, params["norm_f"]["scale"], eps,
+                        params["lm_head"]["kernel"])
     first = jnp.argmax(logits, -1).astype(jnp.int32)
     return ({"ssm": ssm, "conv": conv, "k": k_pool, "v": v_pool}, first,
             logits)
@@ -656,7 +395,7 @@ def decode_step(params, cfg, state, tokens, positions, active, page_tables,
     moe = jnp.zeros(3, jnp.int32)
     for i in range(1, cfg.num_hidden_layers + 1):
         lp = params["layers"][str(i)]
-        x = rms_norm(h, lp["norm"]["scale"], eps)
+        x = pl.rms_norm(h, lp["norm"]["scale"], eps)
         kind = cfg.layer_kind(i)
         if kind == "mamba":
             n = cfg.mamba_index[i]
@@ -667,14 +406,15 @@ def decode_step(params, cfg, state, tokens, positions, active, page_tables,
         elif kind == "attn":
             n = cfg.attn_index[i]
             with jax.named_scope("gqa_attend"):
-                y, k_pool, v_pool = gqa_decode(
+                y, k_pool, v_pool = pl.gqa_decode(
                     lp["mixer"], cfg, x, k_pool, v_pool, n, page_tables,
                     positions, active, page_tokens)
         else:
             y, counts = _experts(lp, cfg, x, active, moe_tile)
             moe = moe + counts
         h = h + y
-    logits = _head(params, cfg, h)
+    logits = pl.lm_head(h, params["norm_f"]["scale"], eps,
+                        params["lm_head"]["kernel"])
     nxt = jnp.argmax(logits, -1).astype(jnp.int32)
     tokens = jnp.where(active, nxt, tokens)
     positions = jnp.where(active, positions + 1, positions)

@@ -8,7 +8,7 @@ decode step: the gathered array is never laid out again).
 
 A chunked prefill call attends ``R`` rows of ``T`` queries, each row to its
 own prompt's cached positions, found through the row's page table. Walked in
-plain operations (``models/nemotron_h.py::gqa_prefill``, and until PR 43
+plain operations (``models/paged_layers.py::gqa_prefill``, and until PR 43
 ``models/keye.py::dsa_prefill``) a block of keys costs an array of float32
 scores with a query axis, a key axis and the heads, which goes through HBM
 three times: the walk then runs at the memory's rate and a twentieth of the

@@ -235,6 +235,17 @@ def held_experts_ffn(x, experts, idx, weights, held, tile, live=None,
     return y, stats
 
 
+def held_share(held, scored):
+    """``(first, count)`` of the ``scored`` experts a router scores that one
+    chip holds, as a configuration states it: None is all of them, and a
+    share that reaches outside them is refused."""
+    first, count = (0, scored) if held is None else held
+    if not (0 <= first and count >= 1 and first + count <= scored):
+        raise ValueError(f"experts_held={held} outside the {scored} experts "
+                         f"the router scores")
+    return first, count
+
+
 def routed_moe_ffn(params, x, live=None, *, k, scaling, renormalize, held,
                    tile, every_expert=False, scoring="sigmoid"):
     """One chip's share of a routed expert layer, with a shared expert

@@ -31,6 +31,7 @@ from deepspeed_tpu.inference.serving.families import keye as keye_family
 from deepspeed_tpu.inference.serving.family import UnsupportedOptionError
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import keye as ky
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
 from tests.unit import test_mimo_v2
 
@@ -465,7 +466,7 @@ def test_equal_position_triples_rotate_as_plain_rotary_positions():
             np.asarray(ref.mrope(x, triple, 1e7, (2, 2, 4))),
             np.asarray(plain), atol=1e-6)
         np.testing.assert_allclose(
-            np.asarray(ky.apply_rope(ky.RopeSpec(rope_theta=1e7), x, pos)),
+            np.asarray(pl.apply_rope(pl.RopeSpec(rope_theta=1e7), x, pos)),
             np.asarray(plain), atol=1e-5)
         patch = triple.at[1].add(3).at[2].add(7)
         assert np.abs(np.asarray(ref.mrope(x, patch, 1e7, (2, 2, 4)))

@@ -27,7 +27,7 @@ from deepspeed_tpu.inference.serving.families.laguna import LagunaFamily
 from deepspeed_tpu.inference.serving.family import UnsupportedOptionError
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import laguna as lg
-from deepspeed_tpu.models import nemotron_h as nh
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
 
 FULL, WINDOW = "full_attention", "sliding_attention"
@@ -248,11 +248,11 @@ def test_rotation_matches_complex_multiplication(kind):
     rng = np.random.default_rng(0)
     x = rng.normal(size=(6, 3, 128)).astype(np.float32)
     positions = np.array([0, 1, 511, 4095, 4097, 16000])
-    inv, r = lg.rope_inv_freq(spec, 128)
+    inv, r = pl.rope_inv_freq(spec, 128)
     assert r == (64 if kind == FULL else 128)
     want = _complex_rope(x.astype(np.float64), positions, inv, r,
                          spec.attention_factor)
-    got = lg.apply_rope(spec, jnp.asarray(x), jnp.asarray(positions))
+    got = pl.apply_rope(spec, jnp.asarray(x), jnp.asarray(positions))
     np.testing.assert_allclose(got, want, atol=2e-3)
     np.testing.assert_array_equal(np.asarray(got)[:, :, r:], x[:, :, r:])
     # and the reference's own
@@ -267,7 +267,7 @@ def test_yarn_frequencies_match_numbers_worked_by_hand():
     ``d(1) = 64 ln(4096 / (2 pi)) / (2 ln 500000) = 15.80``, so ``high =
     16``. Frequencies 0-5 are extrapolated (as published), 16-31
     interpolated (a 64th), and frequency 10 is 5/11 of the way."""
-    inv, r = lg.rope_inv_freq(lg.LagunaConfig().rope_full, 128)
+    inv, r = pl.rope_inv_freq(lg.LagunaConfig().rope_full, 128)
     assert r == 64 and inv.shape == (32,)
     base = 500000.0
     extrap = lambda i: base ** (-2 * i / 64)             # noqa: E731
@@ -287,7 +287,7 @@ def test_yarn_frequencies_match_numbers_worked_by_hand():
          "partial_rotary_factor": 0.5}, 128)
     np.testing.assert_allclose(inv, want, rtol=1e-12)
     assert factor == 1.4158883083359672
-    plain, r = lg.rope_inv_freq(lg.LagunaConfig().rope_window, 128)
+    plain, r = pl.rope_inv_freq(lg.LagunaConfig().rope_window, 128)
     np.testing.assert_allclose(plain, 10000.0 ** (-np.arange(64) / 64),
                                rtol=1e-12)
 
@@ -316,7 +316,7 @@ def test_a_zeroed_gate_halves_every_heads_output(layer):
             size=(3, 3, W // ROW, 32, ROW)), jnp.float32),) * 2
 
         def run(cfg):
-            return lg.window_decode(
+            return pl.window_decode(
                 p, cfg.attention(layer), x, *rings, 0, positions, active,
                 window=W, rotate=lg._rotate(cfg, layer),
                 gate=lg._gate(p, cfg, layer, x))[0]
@@ -326,7 +326,7 @@ def test_a_zeroed_gate_halves_every_heads_output(layer):
         tables = jnp.asarray(1 + np.arange(9).reshape(3, 3), jnp.int32)
 
         def run(cfg):
-            return nh.gqa_decode(
+            return pl.gqa_decode(
                 p, cfg.attention(layer), x, *pools, 0, tables, positions,
                 active, ROW, rotate=lg._rotate(cfg, layer),
                 gate=lg._gate(p, cfg, layer, x))[0]
@@ -360,12 +360,12 @@ def test_window_rows_in_one_call_match_the_reference_layer():
     how = dict(window=W, rotate=lg._rotate(mcfg, layer))
     shape = mcfg.attention(layer)
     # the earlier call of the prompt in slot 1: three full rows
-    _, wk, wv = lg.window_prefill(
+    _, wk, wv = pl.window_prefill(
         p, shape, earlier, *rings, n, jnp.asarray([1, 1, 1], jnp.int32),
         jnp.asarray([0, 16, 32], jnp.int32),
         jnp.asarray([16, 16, 16], jnp.int32), **how,
         gate=lg._gate(p, mcfg, layer, earlier))
-    y, wk2, wv2 = lg.window_prefill(p, shape, x, wk, wv, n, slots, starts,
+    y, wk2, wv2 = pl.window_prefill(p, shape, x, wk, wv, n, slots, starts,
                                     lens, **how,
                                     gate=lg._gate(p, mcfg, layer, x))
     layer_ref = jax.jit(lambda w, x: ref.attention(w, x, D, layer, "f32"))
@@ -381,7 +381,7 @@ def test_window_rows_in_one_call_match_the_reference_layer():
         np.testing.assert_allclose(got_of[slot][:took], want[total - took:],
                                    atol=2e-5, rtol=2e-4)
         # the ring: position q of the prompt's last 32 at q % 32
-        k_all = lg.apply_rope(
+        k_all = pl.apply_rope(
             mcfg.rope_window,
             (whole @ p["k_proj"]["kernel"]).reshape(-1, 2, 16),
             jnp.arange(total)).reshape(-1, 32)

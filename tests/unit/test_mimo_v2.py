@@ -48,7 +48,7 @@ from deepspeed_tpu.inference.serving.family import UnsupportedOptionError
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import laguna as lg
 from deepspeed_tpu.models import mimo_v2 as mm
-from deepspeed_tpu.models import nemotron_h as nh
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
 from tests.unit import test_laguna, test_nemotron_h, test_step_fusion
 
@@ -254,10 +254,10 @@ def _window_layer(sink, positions=(0, 3, 15, 40)):
     x = jnp.asarray(rng.normal(size=(4, 64)), jnp.float32)
     wk = jnp.asarray(rng.normal(size=(5, 4, 1, 4 * 24, ROW)), jnp.float32)
     wv = jnp.asarray(rng.normal(size=(5, 4, 1, 4 * 16, ROW)), jnp.float32)
-    how = dict(window=W, rotate=lg.rotary(mcfg.rope(1), shape, "rope_window"))
+    how = dict(window=W, rotate=pl.rotary(mcfg.rope(1), shape, "rope_window"))
     if sink is not None:
         how["sink"] = jnp.asarray(sink, jnp.float32).reshape(4, 2)
-    y, wk2, wv2 = lg.window_decode(
+    y, wk2, wv2 = pl.window_decode(
         p, shape, x, wk, wv, 2, jnp.asarray(positions, jnp.int32),
         jnp.ones(4, bool), **how)
     return np.asarray(y), p, x, np.asarray(wk2[2]), np.asarray(wv2[2])
@@ -269,8 +269,8 @@ def _by_hand(p, x, keys, values, positions, sink, sink_value=None):
     weighs ``sink_value`` (None: nothing, as published)."""
     mcfg = model_config()
     shape = mcfg.attention(1)
-    q, _, _ = nh._gqa_project(p, shape, x)
-    q, _ = lg.rotary(mcfg.rope(1), shape, "r")(
+    q, _, _ = pl.gqa_project(p, shape, x)
+    q, _ = pl.rotary(mcfg.rope(1), shape, "r")(
         q, jnp.zeros((4, 4 * 24)), jnp.asarray(positions))
     q = np.asarray(q)                                   # [B, 4, 2, 24]
     out = np.zeros((4, 8, 16))
@@ -369,9 +369,9 @@ def test_the_cache_holds_keys_and_values_of_their_own_widths():
     st = eng.pool.state
     h = params["embed_tokens"]["embedding"][prompt]
     lp = params["layers"]["0"]
-    x = nh.rms_norm(h, lp["input_layernorm"]["scale"], 1e-5)
+    x = pl.rms_norm(h, lp["input_layernorm"]["scale"], 1e-5)
     v = x @ lp["self_attn"]["v_proj"]["kernel"]                  # [20, 32]
-    k = lg.apply_rope(mcfg.rope(0), (
+    k = pl.apply_rope(mcfg.rope(0), (
         x @ lp["self_attn"]["k_proj"]["kernel"]).reshape(20, 2, 24),
         jnp.arange(20)).reshape(20, 48)
     table = eng.pool.page_tables[0]
@@ -385,13 +385,13 @@ def test_the_cache_holds_keys_and_values_of_their_own_widths():
     assert st["wk"].shape[2:] == (1, 96, ROW)
     assert st["wv"].shape[2:] == (1, 64, ROW)
     # 8 of a head's 24 dimensions are rotated, 16 passed through
-    inv, r = lg.rope_inv_freq(mcfg.rope(1), 24)
+    inv, r = pl.rope_inv_freq(mcfg.rope(1), 24)
     assert r == 8 and inv.shape == (4,)
     np.testing.assert_allclose(inv, 10000.0 ** (-np.arange(4) / 4),
                                rtol=1e-12)
-    np.testing.assert_allclose(lg.rope_inv_freq(mcfg.rope(0), 24)[0][1],
+    np.testing.assert_allclose(pl.rope_inv_freq(mcfg.rope(0), 24)[0][1],
                                1e7 ** -0.25, rtol=1e-12)
-    assert lg.rope_inv_freq(mm.MiMoV2Config().rope(0), 192)[1] == 64
+    assert pl.rope_inv_freq(mm.MiMoV2Config().rope(0), 192)[1] == 64
 
 
 # -- (c2) what one decode step writes ----------------------------------------
@@ -600,8 +600,8 @@ def test_config_reads_the_published_keys_up_to_the_depth():
     assert mcfg.full_index == {0: 0, 5: 1}
     assert mcfg.window_index == {1: 0, 2: 1, 3: 2, 4: 3, 6: 4}
     assert mcfg.n_moe_layers == 6 and mcfg.experts_held == (4, 4)
-    assert mcfg.attention(0) == lg.AttentionShape(8, 2, 24, 16)
-    assert mcfg.attention(1) == lg.AttentionShape(8, 4, 24, 16)
+    assert mcfg.attention(0) == pl.AttentionShape(8, 2, 24, 16)
+    assert mcfg.attention(1) == pl.AttentionShape(8, 4, 24, 16)
     assert mcfg.cache_widths == {"k": 48, "v": 32, "wk": 96, "wv": 64}
     hash(mcfg)                                # a static argument of the jit
     full = mm.MiMoV2Config()
@@ -703,7 +703,7 @@ def _lowered(which):
     from tests.unit import test_keye, test_kimi_linear
 
     family, program = which.split("_")
-    slots, pages, mp, row = 3, 9, 4, ROW
+    slots, pages, mp, rows, row = 3, 9, 4, ROWS, ROW
     if family == "laguna":
         cfg = lg.LagunaConfig.from_dict(test_laguna.CFG)
         shapes = laguna_ref.weight_shapes(test_laguna.CFG)
@@ -741,14 +741,13 @@ def _lowered(which):
     else:
         cfg = test_kimi_linear.model_config(test_kimi_linear.CFG)
         shapes = kimi_linear_ref.weight_shapes(test_kimi_linear.CFG)
-        row = test_kimi_linear.CHUNK         # one prompt's next chunk a call
+        rows, row = 1, test_kimi_linear.CHUNK   # a prompt's next chunk a call
         state = {"kda": _sds((4, slots, 2, 16, 16)),
                  "conv": _sds((4, slots, 3, 3 * cfg.kda_width)),
                  "latent": _sds((1, pages, cfg.latent_width, ROW))}
         fns = (kimi_family._kimi_decode_step_jit,
                kimi_family._kimi_prefill_chunk_jit)
     i32 = jnp.int32
-    rows = 1 if family == "kimi" else ROWS
     args = ((_sds((slots,), i32), _sds((slots,), i32),
              _sds((slots,), jnp.bool_), _sds((slots, mp), i32))
             if program == "decode" else

@@ -277,3 +277,24 @@ def test_held_experts_loop_runs_the_function_its_matrices_name(
                       for n in names})
     np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-4)
     assert int(stats[0]) == T * k
+
+
+@pytest.mark.parametrize("model", ["kimi_linear.KimiLinearConfig",
+                                   "nemotron_h.NemotronHConfig",
+                                   "mimo_v2.MiMoV2Config", "keye.KeyeConfig"])
+def test_a_share_of_experts_outside_those_the_router_scores_is_refused(model):
+    """``experts_held`` of the four configurations that hold a share goes
+    through ``expert.held_share``: all of them by default, a pair kept as a
+    tuple (a configuration is a static argument), a pair that reaches
+    outside the published count refused by the key's name."""
+    import importlib
+
+    module, name = model.split(".")
+    config = getattr(importlib.import_module("deepspeed_tpu.models." + module),
+                     name)
+    scored = config().experts_held[1]
+    assert config().experts_held == (0, scored)
+    assert config(experts_held=[scored - 4, 4]).experts_held == (scored - 4, 4)
+    for bad in ((scored - 3, 4), (-1, 4), (0, 0)):
+        with pytest.raises(ValueError, match="experts_held"):
+            config(experts_held=bad)

@@ -28,6 +28,7 @@ from deepspeed_tpu.inference.serving.families.slot_state import (
 )
 from deepspeed_tpu.inference.serving.family import UnsupportedOptionError
 from deepspeed_tpu.models import nemotron_h as nh
+from deepspeed_tpu.models import paged_layers as pl
 from deepspeed_tpu.parallel import expert as expert_mod
 
 CFG = {
@@ -325,7 +326,7 @@ def test_rows_of_one_prompt_chain_and_other_rows_read_their_slot():
     slots = jnp.asarray([2, 0, 0, 0, 3, 1], jnp.int32)
     starts = jnp.asarray([32, 0, 16, 32, 0, 16], jnp.int32)
     lens = jnp.asarray([16, 16, 16, 7, 0, 9], jnp.int32)
-    follows, last = nh.row_links(slots, starts, lens, ROW)
+    follows, last = pl.row_links(slots, starts, lens, ROW)
     assert follows.tolist() == [False, False, True, True, False, False]
     assert last.tolist() == [True, False, False, True, False, True]
     H, P, N = 4, 16, 16
@@ -366,12 +367,12 @@ def test_grouped_query_decode_over_pages_matches_the_full_form():
     x = jnp.asarray(rng.normal(size=(2, ROW, 64)), jnp.float32)
     tables = jnp.asarray(1 + np.arange(4)[None], jnp.int32)
     pools = (jnp.zeros((1, 6, mcfg.kv_width, pt), jnp.float32),) * 2
-    y_pre, k_pre, v_pre = nh.gqa_prefill(
+    y_pre, k_pre, v_pre = pl.gqa_prefill(
         p, mcfg, x, *pools, 0, jnp.repeat(tables, 2, axis=0),
         jnp.asarray([0, ROW], jnp.int32), jnp.asarray([ROW, T - ROW],
                                                       jnp.int32), pt)
     flat_x = x.reshape(2 * ROW, 64)
-    decode = jax.jit(lambda x_t, k, v, t: nh.gqa_decode(
+    decode = jax.jit(lambda x_t, k, v, t: pl.gqa_decode(
         p, mcfg, x_t, k, v, 0, tables, t, jnp.asarray([True]), pt))
     k_dec, v_dec = pools
     for t in range(T):
@@ -394,7 +395,7 @@ def test_grouped_query_decode_over_pages_matches_the_full_form():
 
 # -- (c2) decode over ragged lanes: the work list of (lane, block) pairs -----
 
-SPAN = 512                        # keys a pair holds: nh.DECODE_KEY_BLOCK
+SPAN = 512                        # keys a pair holds: pl.DECODE_KEY_BLOCK
 RAGGED = {
     # name: (positions, active, pairs a tile (None: the rule's), extras)
     "a lane at position 0": ([0, 5, 700], [1, 1, 1], None, False),
@@ -426,7 +427,7 @@ def test_decode_over_ragged_lanes_matches_a_dense_softmax_a_lane(
     pt, kvw, hd = 16, mcfg.kv_width, mcfg.head_dim
     if per_tile is not None:
         pair_bytes = 2 * SPAN * kvw * 4
-        monkeypatch.setattr(nh, "_TILE_BYTES", per_tile * pair_bytes)
+        monkeypatch.setattr(pl, "_TILE_BYTES", per_tile * pair_bytes)
     B = len(positions)
     rng = np.random.default_rng(5)
     owned = [-(-(n + 1) // pt) for n in positions]
@@ -445,11 +446,11 @@ def test_decode_over_ragged_lanes_matches_a_dense_softmax_a_lane(
             k * (1 + jnp.sin(0.01 * at))[:, None])
         gate = lambda ctx: ctx * jnp.linspace(0.5, 1.5, ctx.shape[-1])
     y, k_out, v_out = jax.jit(
-        lambda x, k, v: nh.gqa_decode(
+        lambda x, k, v: pl.gqa_decode(
             p, mcfg, x, k, v, 0, jnp.asarray(tables), pos,
             jnp.asarray(active, bool), pt, rotate=rotate, gate=gate))(
         x, jnp.asarray(pool[0]), jnp.asarray(pool[1]))
-    q, k_new, v_new = nh._gqa_project(p, mcfg, x)
+    q, k_new, v_new = pl.gqa_project(p, mcfg, x)
     if rotate is not None:
         q, k_new = rotate(q, k_new, pos)
     for b in range(B):
@@ -489,7 +490,7 @@ def test_the_work_list_holds_each_active_lanes_blocks_and_no_others():
 
     def pairs(positions, active):
         lane, block, live, n = jax.jit(
-            nh.decode_work_list, static_argnums=(2, 3, 4))(
+            pl.decode_work_list, static_argnums=(2, 3, 4))(
                 jnp.asarray(positions, jnp.int32), jnp.asarray(active, bool),
                 span, nblk, bound)
         n = int(n)
@@ -515,9 +516,9 @@ def test_the_work_list_holds_each_active_lanes_blocks_and_no_others():
     assert len(pairs([40000, 10], [1, 1])) == nblk + 1
     assert len(pairs([16383] * 4, [1] * 4)) == bound
     # the tiles the loop runs follow the pairs, not the longest lane
-    G = nh.pairs_per_tile(bound, 2 << 20)
-    assert G == 16 and nh.pairs_per_tile(8, 1 << 10) == 8
-    assert nh.pairs_per_tile(bound, 3 << 20) == 8       # 10 fit: 8 is taken
+    G = pl.pairs_per_tile(bound, 2 << 20)
+    assert G == 16 and pl.pairs_per_tile(8, 1 << 10) == 8
+    assert pl.pairs_per_tile(bound, 3 << 20) == 8       # 10 fit: 8 is taken
     assert -(-len(got) // G) == 3 == -(-len(after) // G)
 
 

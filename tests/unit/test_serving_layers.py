@@ -39,6 +39,22 @@ def _imported_modules(tree):
     return out
 
 
+def _imports_of(path, package, names):
+    """Which modules among ``names`` of ``package`` (dotted) the file
+    imports, absolutely (``import a.b.x``, ``from a.b import x``, ``from
+    a.b.x import y``) or relative to its own package."""
+    found = set()
+    for module in _imported_modules(_tree(path)):
+        if module.startswith("."):
+            parts = module.lstrip(".").split(".")
+        elif module.startswith(package + "."):
+            parts = module[len(package) + 1:].split(".")
+        else:
+            continue
+        found.update(parts[:1])
+    return found & set(names)
+
+
 def _dotted(node):
     """``a.b.c`` for a Name/Attribute chain, else None."""
     parts = []
@@ -58,6 +74,38 @@ def test_the_loop_imports_no_model_code():
     bad = sorted(m for m in _imported_modules(tree)
                  if set(m.strip(".").split(".")) & set(banned))
     assert bad == []
+
+
+MODELS = os.path.join(REPO_ROOT, "deepspeed_tpu", "models")
+SLOT_STATE_MODELS = ("kimi_linear", "nemotron_h", "laguna", "mimo_v2", "keye")
+
+
+@pytest.mark.parametrize("module", SLOT_STATE_MODELS + ("paged_layers",))
+def test_a_slot_state_model_imports_no_sibling(module):
+    """The layers the five decoders share are ``models/paged_layers.py``'s:
+    a model's file imports it and no other model's, and it imports none of
+    them, so a new family adds files under ``models/`` and edits none."""
+    path = os.path.join(MODELS, module + ".py")
+    others = set(SLOT_STATE_MODELS) - {module}
+    assert _imports_of(path, "deepspeed_tpu.models", others) == set()
+    if module != "paged_layers":
+        assert _imports_of(path, "deepspeed_tpu.models",
+                           ["paged_layers"]) == {"paged_layers"}
+
+
+def test_a_familys_file_imports_no_other_familys():
+    """What families share is ``families/slot_state.py``'s (and the
+    contract's, ``family.py``): no family subclasses a sibling."""
+    names = {os.path.basename(p)[:-3] for p in FAMILY_FILES}
+    bad = {}
+    for path in FAMILY_FILES:
+        own = os.path.basename(path)[:-3]
+        others = names - {own, "slot_state", "__init__"}
+        got = _imports_of(
+            path, "deepspeed_tpu.inference.serving.families", others)
+        if got:
+            bad[own] = got
+    assert bad == {}
 
 
 def test_there_are_families_to_hold_to_the_contract():

@@ -17,13 +17,11 @@ digest}}``; ``--out`` also keeps the texts.
 """
 
 import argparse
-import base64
 import glob
 import hashlib
 import importlib
 import json
 import os
-import re
 import sys
 import types
 
@@ -33,21 +31,10 @@ sys.path.insert(0, REPO_ROOT)
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-
-def _text_without_locations(lowered):
-    """As ``tests/unit/test_kernels_tpu_lowering.py`` strips it: a Mosaic
-    kernel's serialized body embeds source lines."""
-    from jax._src.interpreters import mlir
-    from jax._src.lib.mlir import ir
-
-    def body(match):
-        ctx = mlir.make_ir_context()
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            module = ir.Module.parse(base64.b64decode(match.group(1)))
-            return module.operation.get_asm(enable_debug_info=False)
-
-    return re.sub(r'\\22body\\22: \\22([^\\]*)\\22', body, lowered.as_text())
+# a Mosaic kernel's serialized body embeds source lines: one way to strip them
+from tests.unit.test_kernels_tpu_lowering import (  # noqa: E402
+    _text_without_locations,
+)
 
 
 def _sds(shape, dtype):
@@ -61,6 +48,8 @@ def cell_programs(cfg):
     weights' shapes of the configuration's reference."""
     from benchmarks.refs import weights as weights_mod
     from deepspeed_tpu.inference.serving import ServingConfig
+    from deepspeed_tpu.inference.serving.families.slot_state import (
+        RowPrefillFamily)
     from deepspeed_tpu.inference.serving.family import family_for
 
     adapter = importlib.import_module("benchmarks.models." + cfg["adapter"])
@@ -85,21 +74,22 @@ def cell_programs(cfg):
 
     state = jax.eval_shape(state)
     pool = pools[0]
-    B, mp, pt = pool.max_slots, pool.pages_per_lane, pool.page_tokens
-    R, T = ((1, family.chunk) if hasattr(family, "chunk")
-            else (family.rows, family.row_tokens))
+    B, mp = pool.max_slots, pool.pages_per_lane
+    R, T = ((family.rows, family.row_tokens)
+            if isinstance(family, RowPrefillFamily) else (1, family.chunk))
     i32 = jnp.int32
-    how = dict(cfg=model_cfg, page_tokens=pt, keep_logits=False)
-    traced = {
-        family.decode_program.__name__: family.decode_program.trace(
-            params, state, _sds((B,), i32), _sds((B,), i32),
-            _sds((B,), jnp.bool_), _sds((B, mp), i32), **how),
-        family.prefill_program.__name__: family.prefill_program.trace(
-            params, state, _sds((R, T), i32), _sds((R,), i32),
-            _sds((R,), i32), _sds((R,), i32), _sds((R, mp), i32), **how),
+    calls = {
+        family.decode_program: (_sds((B,), i32), _sds((B,), i32),
+                                _sds((B,), jnp.bool_), _sds((B, mp), i32)),
+        family.prefill_program: (_sds((R, T), i32), _sds((R,), i32),
+                                 _sds((R,), i32), _sds((R,), i32),
+                                 _sds((R, mp), i32)),
     }
-    return {name: t.lower(lowering_platforms=("tpu",))
-            for name, t in traced.items()}
+    return {program.__name__: program.trace(
+                params, state, *args, cfg=model_cfg,
+                page_tokens=pool.page_tokens, keep_logits=False,
+            ).lower(lowering_platforms=("tpu",))
+            for program, args in calls.items()}
 
 
 def main():
