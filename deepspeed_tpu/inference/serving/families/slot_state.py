@@ -1,12 +1,12 @@
 """What the families whose lanes hold a slot's state have letter for letter
 in common (``families/kimi_linear.py``, ``families/nemotron_h.py``,
-``families/laguna.py``, ``families/mimo_v2.py``, ``families/keye.py``): a
-request holds a lane while its prompt is read,
+``families/laguna.py``, ``families/mimo_v2.py``, ``families/keye.py``,
+``families/ouro.py``): a request holds a lane while its prompt is read,
 admission claims the lane's slot and pages of a ``HybridStatePool`` (and
 zeroes what the pool says a new occupant must not inherit), lane churn
 patches the device's lane vectors, one decode step is kept in flight, and
 the options none of them can honour. ``RowPrefillFamily`` adds the prefill
-call that four of them lay out alike: several prompts a call, in rows.
+call that five of them lay out alike: several prompts a call, in rows.
 ``PagesAndRingsFamily`` adds the pool of two of them: pages for the full
 layers, a ring a lane for the window layers. What differs stays with the
 family: the state's description and the jitted programs. A family's file

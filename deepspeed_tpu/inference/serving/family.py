@@ -7,7 +7,8 @@ guard, the injector hooks and the threads. What a lane's state is and which
 jitted programs fill and advance it is a ``ServingFamily``
 (``families/gpt2.py``, ``families/kimi_linear.py``,
 ``families/nemotron_h.py``, ``families/laguna.py``,
-``families/mimo_v2.py``, ``families/keye.py``). The arrows point one
+``families/mimo_v2.py``, ``families/keye.py``, ``families/ouro.py``). The
+arrows point one
 way: the loop calls the family through the methods below, and a family calls
 back only this short public list of the loop it was built for:
 
@@ -41,6 +42,7 @@ from deepspeed_tpu.models.kimi_linear import KimiLinearConfig
 from deepspeed_tpu.models.laguna import LagunaConfig
 from deepspeed_tpu.models.mimo_v2 import MiMoV2Config
 from deepspeed_tpu.models.nemotron_h import NemotronHConfig
+from deepspeed_tpu.models.ouro import OuroConfig
 from deepspeed_tpu.profiling.sentinels import CompileSentinel
 
 
@@ -167,5 +169,8 @@ def family_for(model_config):
     if isinstance(model_config, KeyeConfig):
         from deepspeed_tpu.inference.serving.families.keye import KeyeFamily
         return KeyeFamily(model_config)
+    if isinstance(model_config, OuroConfig):
+        from deepspeed_tpu.inference.serving.families.ouro import OuroFamily
+        return OuroFamily(model_config)
     from deepspeed_tpu.inference.serving.families.gpt2 import GPT2Family
     return GPT2Family(model_config)

@@ -163,6 +163,14 @@ class ServingMetrics:
         self.dsa_decode_blocks_dense = 0
         self.decode_attn_blocks_walked = 0
         self.decode_attn_blocks_dense = 0
+        # a family whose decoder runs its stack several times a token: the
+        # passes its decode steps ran, the layer applications (passes x
+        # layers) of its decode steps and prefill calls, and what a token
+        # caches (a row a (pass, layer))
+        self.loop_passes = 0
+        self.loop_layer_calls = 0
+        self.loop_cache_rows = 0
+        self.loop_cache_bytes_per_token = 0
         self.page_waits = 0
         self.state_slots_in_use = 0
         self.latent_pages_in_use = 0
@@ -270,6 +278,25 @@ class ServingMetrics:
             self.decode_attn_blocks_walked += layers * int(blocks.sum())
             self.decode_attn_blocks_dense += (layers * len(blocks)
                                               * int(blocks.max()))
+
+    def record_loop(self, passes, layer_calls):
+        """One program call of a family whose decoder is a loop over its
+        stack (``families/ouro.py``): the ``passes`` a decode step ran (0
+        for a prefill call, which is not a step) and the call's layer
+        applications, passes x layers (``loop_passes``,
+        ``loop_layer_calls``)."""
+        self.loop_passes += int(passes)
+        self.loop_layer_calls += int(layer_calls)
+
+    def record_loop_cache(self, rows, bytes_per_token):
+        """Gauges of such a family's cache, constants of its construction:
+        the rows a token caches (one a (pass, layer)) and their bytes, keys
+        and values."""
+        self.loop_cache_rows = int(rows)
+        self.loop_cache_bytes_per_token = int(bytes_per_token)
+        self._record("Loop/cache_rows", self.loop_cache_rows, 1)
+        self._record("Loop/cache_bytes_per_token",
+                     self.loop_cache_bytes_per_token, 1)
 
     def record_page_wait(self):
         """An admission pass ended with a slot free and the head of the
@@ -555,6 +582,10 @@ class ServingMetrics:
             "dsa_decode_blocks_dense": self.dsa_decode_blocks_dense,
             "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
             "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
+            "loop_passes": self.loop_passes,
+            "loop_layer_calls": self.loop_layer_calls,
+            "loop_cache_rows": self.loop_cache_rows,
+            "loop_cache_bytes_per_token": self.loop_cache_bytes_per_token,
             "page_waits": self.page_waits,
             "state_slots_in_use": self.state_slots_in_use,
             "latent_pages_in_use": self.latent_pages_in_use,

@@ -1,5 +1,5 @@
 """What the serving-only decoders share (``kimi_linear``, ``nemotron_h``,
-``laguna``, ``mimo_v2``, ``keye``): the small pieces, rotary positions,
+``laguna``, ``mimo_v2``, ``keye``, ``ouro``): the small pieces, rotary positions,
 grouped-query attention over a lane's pages, a window layer's ring, and the
 walk of a decoder whose layers are full and window ones. Pure functions over
 the arrays of a ``HybridStatePool``, no model's own: a model's file imports
@@ -222,8 +222,10 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
     then every query attends the prompt's rows up to its own position, a
     block of pages at a time. ``x [R, T, d]``; ``k_pool`` the whole ``[La,
     pages, KV * hd, page_tokens]`` array, ``v_pool`` the whole ``[La, pages,
-    KV * vd, page_tokens]`` one and ``n`` this block's row of them. Returns
-    ``(y, k_pool, v_pool)``. What a page holds beyond the prompt's end is
+    KV * vd, page_tokens]`` one and ``n`` this block's row of them: a Python
+    int in a decoder whose layers are unrolled, or a traced int32 scalar in
+    one walked by a loop inside the program (``models/ouro.py``), where the
+    pools are the loop's carries. Returns ``(y, k_pool, v_pool)``. What a page holds beyond the prompt's end is
     overwritten by decode before it can be attended. ``cfg`` is read for
     ``num_attention_heads``, ``num_key_value_heads``, ``head_dim`` (queries
     and keys: the scores are over ``hd``) and ``v_head_dim`` (values: the
@@ -339,8 +341,11 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     attended: a column of each lane's page, all lanes' pages in one
     operation an array (``write_columns``: a page is read once and written
     once, in place; inactive lanes all name the spare page 0). ``rotate``,
-    ``gate`` and the two head sizes (keys of ``hd``, values of ``vd``) as in
-    ``gqa_prefill``.
+    ``gate``, the two head sizes (keys of ``hd``, values of ``vd``) and the
+    row ``n`` (a Python int or a traced scalar) as in ``gqa_prefill``. The
+    work list depends on the lanes alone, not on ``n``: a caller that loops
+    over rows inside the program leaves it to the compiler to keep what no
+    iteration changes out of the loop.
 
     What is walked is the work list of ``decode_work_list``: the (lane,
     block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, a tile of them an

@@ -23,6 +23,7 @@ of their columns it then holds is not said; it never fails.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -33,10 +34,14 @@ def _on_tpu():
 
 def write_columns(pool, at, new, col):
     """``pool [L, ..., width, T]`` with lane ``b``'s block at ``pool[at[0],
-    at[1][b], ...]``: ``at`` is a row ``n`` of the leading axis (a Python
-    int) and then one index array ``[B]`` for every axis up to the block.
-    ``new [B, width]`` becomes column ``col [B]`` of the lane's block; a
-    lane whose ``col`` is -1 leaves its block as it was. Returns the pool."""
+    at[1][b], ...]``: ``at`` is a row ``n`` of the leading axis and then one
+    index array ``[B]`` for every axis up to the block. ``n`` is a Python
+    int (a decoder whose layers are unrolled: the row is part of the
+    program's text) or a traced int32 scalar (a decoder walked by a loop
+    inside the program, ``models/ouro.py``: the kernel reads the row as one
+    more scalar ahead of the grid). ``new [B, width]`` becomes column ``col
+    [B]`` of the lane's block; a lane whose ``col`` is -1 leaves its block
+    as it was. Returns the pool."""
     new, col = new.astype(pool.dtype), col.astype(jnp.int32)
     if _on_tpu():
         return _write_columns_pallas(pool, at, new, col)
@@ -51,9 +56,14 @@ def _write_columns_pallas(pool, at, new, col, interpret=False):
     k = len(index)
     assert pool.ndim == k + 3 and new.shape[1:] == (width,), (
         pool.shape, k, new.shape)
+    # a traced row is the first scalar ahead of the grid; a Python int stays
+    # in the index map, and the call is what it was without the former
+    row = () if isinstance(n, (int, np.integer)) else (
+        jnp.asarray(n, jnp.int32).reshape(1),)
+    r = len(row)
 
     def kernel(*refs):
-        col_ref, new_ref, old_ref, out_ref = refs[k:]
+        col_ref, new_ref, old_ref, out_ref = refs[r + k:]
         here = jax.lax.broadcasted_iota(jnp.int32, (width, T), 1)
         # float32 in VMEM: a select of 16-bit values is no vector
         # operation on a v5e, and the round trip is exact
@@ -63,15 +73,16 @@ def _write_columns_pallas(pool, at, new, col, interpret=False):
             old_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
     def block_of(b, *scalars):
-        return (n, *(s[b] for s in scalars[:k]), 0, 0)
+        return (scalars[0][0] if r else n,
+                *(s[b] for s in scalars[r:r + k]), 0, 0)
 
     block = pl.BlockSpec((None,) * (k + 1) + (width, T), block_of)
     return pl.pallas_call(
         kernel, out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=k + 1, grid=(new.shape[0],),
+            num_scalar_prefetch=r + k + 1, grid=(new.shape[0],),
             in_specs=[pl.BlockSpec((None, width, 1), lambda b, *_: (b, 0, 0)),
                       block],
             out_specs=block),
-        input_output_aliases={k + 2: 0}, interpret=interpret,
-    )(*(i.astype(jnp.int32) for i in index), col, new[:, :, None], pool)
+        input_output_aliases={r + k + 2: 0}, interpret=interpret,
+    )(*row, *(i.astype(jnp.int32) for i in index), col, new[:, :, None], pool)

@@ -475,3 +475,84 @@ def test_nemotron_h_programs_compile_for_v5e_at_the_cells_size():
         assert mem.temp_size_in_bytes < 1.0e9, name
         assert mem.alias_size_in_bytes > 1.4e9, name     # the state, donated
         assert not pool_copy.search(compiled.as_text()), name
+
+
+def test_column_write_lowers_with_a_traced_row():
+    """The row of the leading axis as a traced scalar (a decoder walked by
+    a loop inside the program): one Mosaic call still, with the row one
+    more scalar ahead of the grid."""
+    shape, lanes = _COLUMN_POOLS[-1]
+
+    def fn(pool, n, *rest):
+        *index, new, col = rest
+        return column_write._write_columns_pallas(pool, (n, *index), new, col)
+
+    pool, *rest = _column_write_args(shape, lanes)
+    _assert_mosaic(_lower_tpu(fn, pool, SDS((), jnp.int32), *rest), 1)
+
+
+@pytest.mark.slow
+def test_ouro_programs_compile_for_v5e_at_the_cells_size(monkeypatch):
+    """The two programs of the Ouro serving family at the benchmark cell's
+    own size (8 lanes of 640 positions, 4 rows of 128 a prefill call, 48
+    layers x 4 passes, bfloat16), for one described v5e chip: arguments of
+    13.6 GB fit beside temporaries of a few MB, the two pools are carried
+    through both loops in place (aliased to the results, no copy of pool
+    shape), and the text holds one layer, not 192. About 10 s; nothing
+    runs."""
+    import json
+    import os
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmarks.models import ouro_serve
+    from benchmarks.refs import ouro_ref as ref
+    from benchmarks.refs import weights as weights_mod
+    from deepspeed_tpu.inference.serving.families import ouro as fam
+    from deepspeed_tpu.models import ouro as ou
+
+    monkeypatch.setattr(column_write, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ouro_2p6b_serve.json")) as f:
+        cfg = json.load(f)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    dev = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: SDS(shape, dtype, sharding=dev)
+    m = ouro_serve.model_config(cfg)
+    stacked = jax.eval_shape(ou.stack_layers, weights_mod.nest(
+        {k: SDS(v, jnp.bfloat16) for k, v in ref.weight_shapes(cfg).items()}))
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), stacked)
+    serving = cfg["serving"]
+    B, pt = serving["max_slots"], serving["kv_page_tokens"]
+    mp = serving["max_seq_len"] // pt
+    R = serving["prefill_chunk_tokens"] // pt
+    pages = serving["kv_pool_tokens"] // pt + 1
+    pool = (m.cache_rows, pages, m.cache_widths["k"], pt)
+    assert pool == (192, 41, 2048, 128)
+    state = {n: sds(pool, jnp.bfloat16) for n in ("k", "v")}
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    static = dict(cfg=m, page_tokens=pt, keep_logits=False)
+    programs = {
+        "decode": fam._ouro_decode_step_jit.lower(
+            params, state, i32(B), i32(B), sds((B,), jnp.bool_), i32(B, mp),
+            **static),
+        "prefill": fam._ouro_prefill_chunk_jit.lower(
+            params, state, i32(R, pt), i32(R), i32(R), i32(R), i32(R, mp),
+            **static)}
+    pool_copy = re.compile(r"= bf16\[192,41,2048,128\]\S* copy(-start)?\(")
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        assert 13.5e9 < mem.argument_size_in_bytes < 13.7e9, name
+        assert mem.temp_size_in_bytes < 64e6, name
+        assert mem.alias_size_in_bytes == 2 * 192 * 41 * 2048 * 128 * 2, name
+        text = compiled.as_text()
+        assert not pool_copy.search(text), name
+        assert len(text.splitlines()) < 4000, name
+    assert programs["decode"].compile().as_text().count(
+        "tpu_custom_call") == 2                      # page_write, k and v

@@ -1,7 +1,9 @@
-"""The ten slot-state serving programs as lowered for a TPU at their cells'
-own shapes: one sha256 a program, of the StableHLO text with the Mosaic
-kernels' bodies stripped of source locations. A refactor that traces the
-same operations in the same order keeps all ten; the control a move of
+"""The slot-state serving programs (two a family: ten of the five families
+whose layers are unrolled, two of the looped one) as lowered for a TPU at
+their cells' own shapes: one sha256 a program, of the StableHLO text with
+the Mosaic kernels' bodies stripped of source locations. A refactor that
+traces the same operations in the same order keeps them all; the control a
+move of
 shared model code is held to where the CPU's pins
 (``tests/unit/test_mimo_v2.py::PARENT_TEXT``) cannot see, because on a TPU
 ``write_columns``, ``attend_pages`` and ``attend_tiles`` take their Pallas
@@ -51,6 +53,7 @@ def cell_programs(cfg):
     from deepspeed_tpu.inference.serving.families.slot_state import (
         RowPrefillFamily)
     from deepspeed_tpu.inference.serving.family import family_for
+    from deepspeed_tpu.inference.serving.metrics import ServingMetrics
 
     adapter = importlib.import_module("benchmarks.models." + cfg["adapter"])
     ref = importlib.import_module("benchmarks.refs." + cfg["reference"])
@@ -64,15 +67,16 @@ def cell_programs(cfg):
         config=ServingConfig(**{k: serving[k] for k in (
             "max_slots", "max_seq_len", "kv_cache_dtype", "kv_page_tokens",
             "kv_pool_tokens", "prefill_chunk_tokens") if k in serving}),
-        max_seq_len=serving["max_seq_len"],
-        metrics=types.SimpleNamespace(record_state_pool=lambda *a: None))
+        max_seq_len=serving["max_seq_len"], metrics=ServingMetrics())
     pools = []
 
-    def state():
-        pools.append(family.build(loop, params)[1])
-        return pools[0].state
+    def build(params):
+        built, pool = family.build(loop, params)
+        pools.append(pool)
+        return built, pool.state
 
-    state = jax.eval_shape(state)
+    # the parameters as the programs take them (a family may restack them)
+    params, state = jax.eval_shape(build, params)
     pool = pools[0]
     B, mp = pool.max_slots, pool.pages_per_lane
     R, T = ((family.rows, family.row_tokens)
