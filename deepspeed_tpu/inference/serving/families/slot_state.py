@@ -297,10 +297,13 @@ class RowPrefillFamily(SlotStateFamily):
 
     def count_attended(self, held):
         """``held [lanes]`` positions of the active lanes: how much of the
-        lanes x blocks rectangle the step's paged attention walks."""
+        lanes x blocks rectangle the step's paged attention walks, and how
+        many of the walked blocks' pages hold a key it attends."""
+        page = self.loop.pool.page_tokens
+        span = decode_key_span(page)
         self.loop.metrics.record_attn_blocks(
-            held // decode_key_span(self.loop.pool.page_tokens) + 1,
-            self.paged_attn_layers)
+            held // span + 1, self.paged_attn_layers, held // page + 1,
+            span // page)
 
     def count_prefill(self, starts, lens):
         """``starts [R]``, ``lens [R]`` of the prefill call about to run

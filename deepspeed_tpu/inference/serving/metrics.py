@@ -163,6 +163,8 @@ class ServingMetrics:
         self.dsa_decode_blocks_dense = 0
         self.decode_attn_blocks_walked = 0
         self.decode_attn_blocks_dense = 0
+        self.decode_attn_pages_fetched = 0
+        self.decode_attn_pages_in_blocks = 0
         # a family whose decoder runs its stack several times a token: the
         # passes its decode steps ran, the layer applications (passes x
         # layers) of its decode steps and prefill calls, and what a token
@@ -267,17 +269,25 @@ class ServingMetrics:
         self.dsa_decode_blocks_walked += int(walked)
         self.dsa_decode_blocks_dense += int(dense)
 
-    def record_attn_blocks(self, blocks, layers):
+    def record_attn_blocks(self, blocks, layers, pages, block_pages):
         """One decode step of a family whose paged attention walks a work
         list (``models/paged_layers.py::gqa_decode``): ``blocks`` key blocks
         each active lane owns, in each of ``layers`` layers. Beside the
         pairs walked, the rectangle they are cut from: every lane to the
         longest one's end (``decode_attn_blocks_walked``,
-        ``decode_attn_blocks_dense``)."""
+        ``decode_attn_blocks_dense``). And the walked blocks by page:
+        ``pages`` each active lane holds a key in, which is what
+        ``ops/paged_decode.py``'s kernel fetches, beside every page of every
+        walked block, ``block_pages`` a block, which is what the walk in
+        plain operations gathers (``decode_attn_pages_fetched``,
+        ``decode_attn_pages_in_blocks``)."""
         if len(blocks):
             self.decode_attn_blocks_walked += layers * int(blocks.sum())
             self.decode_attn_blocks_dense += (layers * len(blocks)
                                               * int(blocks.max()))
+            self.decode_attn_pages_fetched += layers * int(pages.sum())
+            self.decode_attn_pages_in_blocks += (layers * block_pages
+                                                 * int(blocks.sum()))
 
     def record_loop(self, passes, layer_calls):
         """One program call of a family whose decoder is a loop over its
@@ -582,6 +592,8 @@ class ServingMetrics:
             "dsa_decode_blocks_dense": self.dsa_decode_blocks_dense,
             "decode_attn_blocks_walked": self.decode_attn_blocks_walked,
             "decode_attn_blocks_dense": self.decode_attn_blocks_dense,
+            "decode_attn_pages_fetched": self.decode_attn_pages_fetched,
+            "decode_attn_pages_in_blocks": self.decode_attn_pages_in_blocks,
             "loop_passes": self.loop_passes,
             "loop_layer_calls": self.loop_layer_calls,
             "loop_cache_rows": self.loop_cache_rows,

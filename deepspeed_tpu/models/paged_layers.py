@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.ops import paged_decode
 from deepspeed_tpu.ops.column_write import write_columns
 
 PREFILL_KEY_BLOCK = 512     # keys a row attends at a time in prefill
@@ -334,6 +335,60 @@ def pairs_per_tile(bound, pair_bytes):
     return max(1, min(1 << (g.bit_length() - 1), bound))
 
 
+def walk_pairs(q, k_pool, v_pool, n, pair_pages, pair_lane, pair_last,
+               n_pairs, live, G):
+    """The work list of ``decode_work_list`` walked in plain operations, a
+    tile of ``G`` pairs an iteration (the list's length a multiple of
+    ``G``; ``live [P]`` which pairs exist): the plain twin of
+    ``ops/paged_decode.py::attend_pairs``, the same list, the same context
+    ``[B, KV, J, vd]`` float32. An iteration gathers its pairs' ``bp``
+    pages whole from row ``n`` of the pools and leaves each pair's masked
+    partial softmax (running max, sum and weighted values ``[..., vd]``,
+    float32); the partials of a lane's pairs, in one tile or in several,
+    are combined after the loop."""
+    Bn, kvh, J, hd = q.shape
+    bound, bp = pair_pages.shape
+    pt = k_pool.shape[3]
+    vd = v_pool.shape[2] // kvh
+    span = bp * pt
+    pair_q = q[pair_lane]
+    scale = hd ** -0.5
+
+    def tile(i, parts):
+        at = i * G
+        pages = jax.lax.dynamic_slice_in_dim(pair_pages, at, G)
+        qt = jax.lax.dynamic_slice_in_dim(pair_q, at, G)
+        last = jax.lax.dynamic_slice_in_dim(pair_last, at, G)
+        kb = k_pool[n, pages].astype(q.dtype).reshape(G, bp, kvh, hd, pt)
+        vb = v_pool[n, pages].astype(q.dtype).reshape(G, bp, kvh, vd, pt)
+        s = jnp.einsum("bgjd,bngdp->bgjnp", qt, kb,
+                       preferred_element_type=jnp.float32).reshape(
+                           G, kvh, J, span) * scale
+        ok = jnp.arange(span)[None, None, None, :] <= last[:, None, None, None]
+        m = jnp.max(jnp.where(ok, s, -1e30), axis=-1)
+        pr = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
+        acc = jnp.einsum("bgjnp,bngdp->bgjd",
+                         pr.astype(q.dtype).reshape(G, kvh, J, bp, pt), vb,
+                         preferred_element_type=jnp.float32)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(whole, part, at, 0)
+                     for whole, part in zip(parts, (m, jnp.sum(pr, -1), acc)))
+
+    m, l, acc = jax.lax.fori_loop(
+        0, (n_pairs + G - 1) // G, tile,
+        (jnp.full((bound, kvh, J), -1e30, jnp.float32),
+         jnp.zeros((bound, kvh, J), jnp.float32),
+         jnp.zeros((bound, kvh, J, vd), jnp.float32)))
+    # by lane: the running max, each pair rescaled to it, the sums
+    mine = (pair_lane[None, :] == jnp.arange(Bn)[:, None]) & live[None, :]
+    m_lane = jnp.max(jnp.where(mine[..., None, None], m[None], -1e30), axis=1)
+    w = jnp.exp(m - m_lane[pair_lane])
+    l_lane = jnp.einsum("bp,pgj->bgj", mine.astype(jnp.float32), l * w,
+                        **EXACT)
+    ctx = jnp.einsum("bp,pgjd->bgjd", mine.astype(jnp.float32),
+                     acc * w[..., None], **EXACT)
+    return ctx / jnp.maximum(l_lane, 1e-30)[..., None]
+
+
 def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
                page_tokens, rotate=None, gate=None):
     """Attention for one token of every lane over the lane's pages. ``x [B,
@@ -348,12 +403,19 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     iteration changes out of the loop.
 
     What is walked is the work list of ``decode_work_list``: the (lane,
-    block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, a tile of them an
-    iteration, so a step reads the sum of the lanes' contexts and not every
-    lane up to the longest one's end. An iteration gathers its pairs' pages
-    from the pool and leaves each pair's masked partial softmax (running
-    max, sum and weighted values ``[..., vd]``, float32); the partials of a
-    lane's pairs, in one tile or in several, are combined after the loop."""
+    block of ``DECODE_KEY_BLOCK`` keys) pairs that exist, so a step reads
+    the sum of the lanes' contexts and not every lane up to the longest
+    one's end. Where ``ops/paged_decode.py::usable`` takes the shapes (a
+    TPU, bfloat16 pools whose 128-token pages hold 128 KB or more) the
+    list is walked by one Pallas kernel a call, ``attend_pairs``: it fetches
+    a pair's pages itself, those that hold an attended key and no other,
+    multiplies under the fetch and carries the softmax across a lane's
+    pairs. Everywhere else (the CPU suite, float32 pools, smaller pages:
+    Nemotron-H's, which measured slower through the kernel) by
+    ``walk_pairs``, its twin in plain operations: a tile of pairs an
+    iteration, each pair's pages gathered whole, the pairs' partial
+    softmaxes combined by lane after the loop. The pools are only read by
+    either."""
     Bn = x.shape[0]
     kvh, hd, vd = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
     J = cfg.num_attention_heads // kvh
@@ -382,43 +444,13 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     pair_pages = tables.reshape(Bn, nblk, bp)[lane, blk]            # [P, bp]
     # the last key of its block a pair attends; none where there is no pair
     pair_last = jnp.where(live, positions[lane] - blk * span, -1)
-    pair_q = q[lane]
-    scale = hd ** -0.5
-
-    def tile(i, parts):
-        at = i * G
-        pages = jax.lax.dynamic_slice_in_dim(pair_pages, at, G)
-        qt = jax.lax.dynamic_slice_in_dim(pair_q, at, G)
-        last = jax.lax.dynamic_slice_in_dim(pair_last, at, G)
-        kb = k_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, hd, pt)
-        vb = v_pool[n, pages].astype(x.dtype).reshape(G, bp, kvh, vd, pt)
-        s = jnp.einsum("bgjd,bngdp->bgjnp", qt, kb,
-                       preferred_element_type=jnp.float32).reshape(
-                           G, kvh, J, span) * scale
-        ok = jnp.arange(span)[None, None, None, :] <= last[:, None, None, None]
-        m = jnp.max(jnp.where(ok, s, -1e30), axis=-1)
-        pr = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
-        acc = jnp.einsum("bgjnp,bngdp->bgjd",
-                         pr.astype(x.dtype).reshape(G, kvh, J, bp, pt), vb,
-                         preferred_element_type=jnp.float32)
-        return tuple(jax.lax.dynamic_update_slice_in_dim(whole, part, at, 0)
-                     for whole, part in zip(parts, (m, jnp.sum(pr, -1), acc)))
-
-    m, l, acc = jax.lax.fori_loop(
-        0, (n_pairs + G - 1) // G, tile,
-        (jnp.full((bound, kvh, J), -1e30, jnp.float32),
-         jnp.zeros((bound, kvh, J), jnp.float32),
-         jnp.zeros((bound, kvh, J, vd), jnp.float32)))
-    # by lane: the running max, each pair rescaled to it, the sums
-    mine = (lane[None, :] == jnp.arange(Bn)[:, None]) & live[None, :]  # [B, P]
-    m_lane = jnp.max(jnp.where(mine[..., None, None], m[None], -1e30), axis=1)
-    w = jnp.exp(m - m_lane[lane])
-    l_lane = jnp.einsum("bp,pgj->bgj", mine.astype(jnp.float32), l * w,
-                        **EXACT)
-    ctx = jnp.einsum("bp,pgjd->bgjd", mine.astype(jnp.float32),
-                     acc * w[..., None], **EXACT)
-    ctx = (ctx / jnp.maximum(l_lane, 1e-30)[..., None]).reshape(
-        Bn, kvh * J * vd)
+    if paged_decode.usable(q, k_pool, v_pool):
+        ctx = paged_decode.attend_pairs(q, k_pool, v_pool, n, pair_pages,
+                                        lane, pair_last, n_pairs)
+    else:
+        ctx = walk_pairs(q, k_pool, v_pool, n, pair_pages, lane, pair_last,
+                         n_pairs, live, G)
+    ctx = ctx.reshape(Bn, kvh * J * vd)
     if gate is not None:
         ctx = gate(ctx)
     return (dot(ctx.astype(x.dtype), p["o_proj"]["kernel"]).astype(x.dtype),

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import kernels
 from deepspeed_tpu.models import keye as keye_mod
-from deepspeed_tpu.ops import column_write, paged_prefill
+from deepspeed_tpu.ops import column_write, paged_decode, paged_prefill
 from deepspeed_tpu.ops.transformer import attention as attn_mod
 from deepspeed_tpu.ops.transformer.attention import flash_attention
 
@@ -314,6 +314,42 @@ def test_selected_tiles_attention_lowers_as_the_tiles_lie(monkeypatch):
     assert plain.count("dot_general") == 2 and "64x2048x4x128xbf16" in plain
 
 
+# the cells whose decode attention is ``gqa_decode``: (lanes, KV heads, query
+# heads a KV head, hd, vd, the pools' rows and pages, pairs in the work list)
+_PAGED_DECODE_CELLS = {
+    "ouro": (8, 16, 1, 128, 128, (192, 41), 16),
+    "mimo_v2": (128, 4, 16, 192, 128, (2, 4097), 1296),
+    "laguna_48_heads": (64, 8, 6, 128, 128, (2, 3585), 1040),
+    "laguna_64_heads": (64, 8, 8, 128, 128, (2, 3585), 1040),
+    "nemotron_h": (128, 2, 16, 128, 128, (1, 3073), 768),
+}
+
+
+def _paged_decode_args(cell):
+    B, kvh, J, hd, vd, rows_pages, pairs = _PAGED_DECODE_CELLS[cell]
+    i32, bf = jnp.int32, jnp.bfloat16
+    return [SDS((B, kvh, J, hd), bf), SDS(rows_pages + (kvh * hd, 128), bf),
+            SDS(rows_pages + (kvh * vd, 128), bf), SDS((), i32),
+            SDS((pairs, 4), i32), SDS((pairs,), i32), SDS((pairs,), i32),
+            SDS((), i32)]
+
+
+@pytest.mark.parametrize("cell", sorted(_PAGED_DECODE_CELLS))
+def test_paged_decode_attention_lowers_at_the_cells_shapes(cell, monkeypatch):
+    """``ops/paged_decode.py::attend_pairs`` at each cell's own shapes, the
+    pools' row a traced scalar (Ouro's loop; a Python int is the same call
+    with a constant): one Mosaic call that is handed both pools whole, and
+    ``usable`` takes the shape on a TPU (but Nemotron-H's pages of 64 KB,
+    which lower and measured slower than the plain walk)."""
+    q, k_pool, v_pool, *_ = args = _paged_decode_args(cell)
+    assert not paged_decode.usable(q, k_pool, v_pool)
+    monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
+    assert paged_decode.usable(q, k_pool, v_pool) == (cell != "nemotron_h")
+    lowered = _lower_tpu(paged_decode.attend_pairs, *args)
+    _assert_mosaic(lowered, 1)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+
+
 @pytest.mark.slow
 def test_serving_kernels_compile_for_v5e(monkeypatch):
     """The real compiler: Mosaic + XLA:TPU from the installed libtpu,
@@ -514,6 +550,7 @@ def test_ouro_programs_compile_for_v5e_at_the_cells_size(monkeypatch):
     from deepspeed_tpu.models import ouro as ou
 
     monkeypatch.setattr(column_write, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     with open(os.path.join(root, "benchmarks", "configs",
@@ -554,5 +591,6 @@ def test_ouro_programs_compile_for_v5e_at_the_cells_size(monkeypatch):
         text = compiled.as_text()
         assert not pool_copy.search(text), name
         assert len(text.splitlines()) < 4000, name
+    # page_write of k and of v, and the attention over the pages
     assert programs["decode"].compile().as_text().count(
-        "tpu_custom_call") == 2                      # page_write, k and v
+        "tpu_custom_call") == 3

@@ -608,6 +608,15 @@ def test_the_attention_block_counters_count_what_a_hand_made_schedule_owes(
     snap = metrics.snapshot()
     assert snap["decode_attn_blocks_walked"] == layers * (5 + 6)
     assert snap["decode_attn_blocks_dense"] == layers * (3 * 3 + 3 * 3)
+    # by page (a block is 512 / ROW pages): those a lane holds a key in,
+    # which the kernel fetches, within those of the blocks walked
+    per_block = 512 // ROW
+    held = [np.array([5, 511, 1100]), np.array([6, 512, 1101])]
+    assert snap["decode_attn_pages_fetched"] == layers * sum(
+        int((p // ROW + 1).sum()) for p in held)
+    assert snap["decode_attn_pages_in_blocks"] == layers * (5 + 6) * per_block
+    assert (snap["decode_attn_pages_fetched"]
+            < snap["decode_attn_pages_in_blocks"])
     # what the rings hold behind their masks: min(position + 1, 32) a lane
     # in each of Laguna's three window layers, and nothing for Nemotron-H
     assert snap["decode_ring_positions"] == (
@@ -615,6 +624,36 @@ def test_the_attention_block_counters_count_what_a_hand_made_schedule_owes(
     fam.loop.lanes.requests = {}          # a step with no lane owes nothing
     fam.decode_step(None)
     assert metrics.snapshot()["decode_attn_blocks_dense"] == layers * 18
+
+
+@pytest.mark.parametrize("positions, equal", [
+    ([5, 511, 1100], False),        # one page of four, a full block, 9 of 12
+    ([511, 1023, 2047], True),      # every lane ends on a block's edge
+    ([0], False), ([127], False), ([128], False),
+])
+def test_the_attention_page_counters_are_the_live_pages_of_the_walked_blocks(
+        positions, equal):
+    """``decode_attn_pages_fetched`` (what ``ops/paged_decode.py`` fetches)
+    is at most ``decode_attn_pages_in_blocks`` (what the plain walk
+    gathers), and equal only where every lane's last page is its block's
+    last."""
+    from deepspeed_tpu.inference.serving.metrics import ServingMetrics
+    from deepspeed_tpu.models.paged_layers import decode_key_span
+
+    page, layers = 128, 3
+    span = decode_key_span(page)
+    held = np.array(positions)
+    metrics = ServingMetrics()
+    metrics.record_attn_blocks(held // span + 1, layers, held // page + 1,
+                               span // page)
+    snap = metrics.snapshot()
+    fetched, gathered = (snap["decode_attn_pages_fetched"],
+                         snap["decode_attn_pages_in_blocks"])
+    assert fetched == layers * sum(p // page + 1 for p in positions)
+    assert gathered == snap["decode_attn_blocks_walked"] * (span // page)
+    assert (fetched == gathered) if equal else (fetched < gathered)
+    metrics.record_attn_blocks(held[:0], layers, held[:0], span // page)
+    assert metrics.snapshot()["decode_attn_pages_fetched"] == fetched
 
 
 # -- (g) each unsupported option raises, by name -----------------------------

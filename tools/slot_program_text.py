@@ -6,8 +6,8 @@ traces the same operations in the same order keeps them all; the control a
 move of
 shared model code is held to where the CPU's pins
 (``tests/unit/test_mimo_v2.py::PARENT_TEXT``) cannot see, because on a TPU
-``write_columns``, ``attend_pages`` and ``attend_tiles`` take their Pallas
-branches.
+``write_columns``, ``attend_pages``, ``attend_tiles`` and ``attend_pairs``
+take their Pallas branches.
 
     python tools/slot_program_text.py [--out DIR]
 
@@ -102,8 +102,10 @@ def main():
     args = ap.parse_args()
     forced = jax.default_backend() != "tpu"
     if forced:
-        from deepspeed_tpu.ops import column_write, paged_prefill
-        column_write._on_tpu = paged_prefill._on_tpu = lambda: True
+        from deepspeed_tpu.ops import (column_write, paged_decode,
+                                       paged_prefill)
+        for kernels in (column_write, paged_decode, paged_prefill):
+            kernels._on_tpu = lambda: True
     digests = {}
     for path in sorted(glob.glob(os.path.join(
             REPO_ROOT, "benchmarks", "configs", "*.json"))):
