@@ -395,7 +395,10 @@ def gqa_decode(p, cfg, x, k_pool, v_pool, n, page_tables, positions, active,
     d]``; the new key and value are written at ``positions`` before they are
     attended: a column of each lane's page, all lanes' pages in one
     operation an array (``write_columns``: a page is read once and written
-    once, in place; inactive lanes all name the spare page 0). ``rotate``,
+    once, in place; inactive lanes all name the spare page 0; the kernel
+    takes ``k`` and ``v [B, width]`` as the projections leave them and turns
+    a lane's row into its column itself, so no ``[B, width, 1]`` copy of
+    them is made around it). ``rotate``,
     ``gate``, the two head sizes (keys of ``hd``, values of ``vd``) and the
     row ``n`` (a Python int or a traced scalar) as in ``gqa_prefill``. The
     work list depends on the lanes alone, not on ``n``: a caller that loops

@@ -13,7 +13,11 @@ therefore owes, a lane, one read of its block and one write of it.
   replaced in VMEM and the block goes back where it came from
   (``input_output_aliases``: the pool is updated in place and every block no
   lane names is never touched), fetches and write-backs overlapping from
-  lane to lane;
+  lane to lane. The new values enter as they lie, ``[B, width]``, whole and
+  once a call, and a lane's row becomes its column inside the kernel, on
+  the transpose unit (laid out ``[B, width, 1]`` for the kernel they are
+  one value a 128-lane row, and that relayout took longer than the write:
+  ``PERF.md``, PR 48);
 - elsewhere (the CPU suite) the same in plain operations: the lanes' blocks
   gathered, the column chosen by ``where``, one scatter of whole blocks.
 
@@ -64,12 +68,17 @@ def _write_columns_pallas(pool, at, new, col, interpret=False):
 
     def kernel(*refs):
         col_ref, new_ref, old_ref, out_ref = refs[r + k:]
+        b = pl.program_id(0)
+        # the lane's row, alike on T sublanes, turned: every column of the
+        # result is the row, and no other lane's value (a NaN among them)
+        # is ever in a register with it
+        mine = jnp.broadcast_to(new_ref[pl.ds(b, 1), :], (T, width)).T
         here = jax.lax.broadcasted_iota(jnp.int32, (width, T), 1)
-        # float32 in VMEM: a select of 16-bit values is no vector
-        # operation on a v5e, and the round trip is exact
+        # float32 in VMEM: a select of 16-bit values is no vector operation
+        # on a v5e and a row of a packed 16-bit array cannot be read at a
+        # traced index; the round trip is exact
         out_ref[...] = jnp.where(
-            here == col_ref[pl.program_id(0)],
-            new_ref[...].astype(jnp.float32),
+            here == col_ref[b], mine,
             old_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
     def block_of(b, *scalars):
@@ -81,8 +90,8 @@ def _write_columns_pallas(pool, at, new, col, interpret=False):
         kernel, out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=r + k + 1, grid=(new.shape[0],),
-            in_specs=[pl.BlockSpec((None, width, 1), lambda b, *_: (b, 0, 0)),
-                      block],
+            in_specs=[pl.BlockSpec(new.shape, lambda b, *_: (0, 0)), block],
             out_specs=block),
         input_output_aliases={r + k + 2: 0}, interpret=interpret,
-    )(*row, *(i.astype(jnp.int32) for i in index), col, new[:, :, None], pool)
+    )(*row, *(i.astype(jnp.int32) for i in index), col,
+      new.astype(jnp.float32), pool)

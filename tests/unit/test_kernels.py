@@ -645,29 +645,67 @@ def test_snapshot_exposes_kernel_registry(model):
 # column_write: one new column in each lane's block, in place
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape, at", [
-    ((2, 9, 24, 16), (1, [3, 0, 5, 0, 8])),                   # pages
-    ((3, 5, 2, 24, 16), (2, [0, 1, 2, 3, 4], [1, 0, 1, 1, 0])),    # rings
-    ((3, 5, 1, 40, 8), (0, [0, 1, 2, 3, 4], [0, 0, 0, 0, 0])),
-])
+def _pages_of(lanes, pages, rng):
+    """Distinct pages for the lanes, but lanes 1 and 3 on the spare page 0."""
+    index = 1 + rng.permutation(pages - 1)[:lanes]
+    index[[1, 3]] = 0
+    return index.tolist()
+
+
+# pool shape, (row, an index array an axis up to the block), a traced row,
+# values no arithmetic may touch in lanes 5 and 6 (pages of their own)
+_COLUMN_CASES = {
+    "pages": ((2, 9, 24, 16), (1, [3, 0, 5, 0, 8]), False, False),
+    "rings": ((3, 5, 2, 24, 16), (2, [0, 1, 2, 3, 4], [1, 0, 1, 1, 0]),
+              False, False),
+    "rings_of_one_block": ((3, 5, 1, 40, 8),
+                           (0, [0, 1, 2, 3, 4], [0, 0, 0, 0, 0]),
+                           False, False),
+    # Ouro's block: 8 lanes of [2048, 128], the cache row traced
+    "ouro_block_traced_row": (
+        (3, 9, 2048, 128), (2, [3, 0, 5, 0, 8, 1, 7, 2]), True, False),
+    # lanes no multiple of a tile's 8 or 16 rows
+    "lanes_127": ((2, 130, 24, 16),
+                  (1, _pages_of(127, 130, np.random.default_rng(7))),
+                  False, False),
+    # a lane beside others whose rows hold inf, NaN and -0.0
+    "neighbours_inf_nan": ((2, 12, 24, 16), (1, [3, 0, 5, 0, 8, 1, 7, 2, 9]),
+                           True, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_COLUMN_CASES))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_column_write_kernel_is_its_plain_twin(shape, at, dtype):
+def test_column_write_kernel_is_its_plain_twin(case, dtype):
     """The Pallas kernel (interpreted here) and the gather, ``where`` and
     scatter the CPU runs write the same pool, bit for bit: column ``col``
     of each lane's block and nothing else, a lane with ``col`` -1 nothing
-    at all. Lanes 1 and 3 of the paged case both name the spare page 0,
+    at all. Lanes 1 and 3 of the paged cases both name the spare page 0,
     which may hold either's column: left out of the comparison, and neither
-    path fails on it."""
+    path fails on it. A lane's ``inf``, ``NaN`` and ``-0.0`` reach that
+    lane's column as they are and no other lane's (the kernel turns a row
+    into a column itself: a sum or a product over the lanes of a tile would
+    spread the first two and lose the sign of the third)."""
+    shape, at, traced, poisoned = _COLUMN_CASES[case]
     rng = np.random.default_rng(3)
-    pool = jnp.asarray(rng.normal(size=shape), dtype)
-    new = jnp.asarray(rng.normal(size=(5, shape[-2])), dtype)
-    col = jnp.asarray([2, 7, -1, shape[-1] - 1, 0], jnp.int32)
     n, *index = at
+    lanes, T = len(index[0]), shape[-1]
+    pool = jnp.asarray(rng.normal(size=shape), dtype)
+    new = rng.normal(size=(lanes, shape[-2])).astype(np.float32)
+    if poisoned:
+        new[5, ::3], new[5, 1::3], new[5, 2::3] = np.inf, np.nan, -0.0
+        new[6, ::2], new[6, 1::2] = -np.inf, np.nan
+        new[4, 5], new[lanes - 1, 0] = -0.0, np.nan
+    new = jnp.asarray(new, dtype)
+    col = rng.integers(0, T, lanes)
+    col[:5] = [2, 7, -1, T - 1, 0]
+    col = jnp.asarray(col, jnp.int32)
     index = [jnp.asarray(i, jnp.int32) for i in index]
     plain, fused = (
-        np.asarray(jax.jit(lambda pool, index, new, col: write(
-            pool, (n, *index), new, col))(pool, index, new, col).astype(
-                jnp.float32))
+        np.asarray(jax.jit(lambda pool, n, index, new, col: write(
+            pool, (n, *index), new, col), static_argnums=() if traced else 1)(
+                pool, jnp.int32(n) if traced else n, index, new, col).astype(
+                    jnp.float32))
         for write in (column_write.write_columns, functools.partial(
             column_write._write_columns_pallas, interpret=True)))
     want = np.array(pool.astype(jnp.float32))
@@ -677,4 +715,5 @@ def test_column_write_kernel_is_its_plain_twin(shape, at, dtype):
                 new.astype(jnp.float32))[b]
     rows = slice(1, None) if len(index) == 1 else slice(None)  # spare page
     for got in (plain, fused):
-        np.testing.assert_array_equal(got[:, rows], want[:, rows])
+        np.testing.assert_array_equal(got[:, rows].view(np.uint32),
+                                      want[:, rows].view(np.uint32))

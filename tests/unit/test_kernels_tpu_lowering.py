@@ -236,10 +236,12 @@ def test_sparse_attention_lowers():
                               *_band_args(jnp.float32)), 1)
 
 
-# the four arrays a decode step writes a column into: MiMo-V2.5's pages and
-# ring of one block, Laguna's ring of four blocks, Nemotron-H's pages
+# the five arrays a decode step writes a column into: MiMo-V2.5's pages and
+# ring of one block, Laguna's ring of four blocks, Nemotron-H's pages, Ouro's
+# pages (a cache row a (pass, layer), the row traced in its programs)
 _COLUMN_POOLS = [((2, 4097, 768, 128), 128), ((5, 128, 1, 1536, 128), 128),
-                 ((3, 64, 4, 1024, 128), 64), ((1, 4097, 256, 128), 128)]
+                 ((3, 64, 4, 1024, 128), 64), ((1, 4097, 256, 128), 128),
+                 ((192, 41, 2048, 128), 8)]
 
 
 def _column_write_args(shape, lanes, dtype=jnp.bfloat16):
@@ -257,6 +259,21 @@ def _column_write_fn(pool, *rest):
 def test_column_write_lowers(shape, lanes):
     _assert_mosaic(_lower_tpu(_column_write_fn,
                               *_column_write_args(shape, lanes)), 1)
+
+
+@pytest.mark.parametrize("shape, lanes", _COLUMN_POOLS)
+def test_column_write_takes_the_new_values_as_they_lie(shape, lanes):
+    """No array ``[lanes, width, 1]`` in the text around the kernel's call:
+    the kernel's operand is ``new [lanes, width]`` itself. (Laid out
+    ``[8, 2048, 1]`` for a ``(width, 1)`` block, a token's 32 KB of keys
+    became 4 MB, one value a 128-lane row, and the relayout took Ouro's
+    layer call longer than the page write: ``PERF.md``, PR 48.)"""
+    text = _lower_tpu(_column_write_fn,
+                      *_column_write_args(shape, lanes)).as_text()
+    width = shape[-2]
+    assert f"tensor<{lanes}x{width}x1x" not in text
+    call, = (l for l in text.splitlines() if "tpu_custom_call" in l)
+    assert f"tensor<{lanes}x{width}xf32>, tensor<" in call.rsplit(" : ", 1)[1]
 
 
 def _prefill_walk_args(rows=16, pages=5121, table=128):
