@@ -1,12 +1,13 @@
 """What the families whose lanes hold a slot's state have letter for letter
 in common (``families/kimi_linear.py``, ``families/nemotron_h.py``,
 ``families/laguna.py``, ``families/mimo_v2.py``, ``families/keye.py``,
-``families/ouro.py``): a request holds a lane while its prompt is read,
-admission claims the lane's slot and pages of a ``HybridStatePool`` (and
-zeroes what the pool says a new occupant must not inherit), lane churn
-patches the device's lane vectors, one decode step is kept in flight, and
-the options none of them can honour. ``RowPrefillFamily`` adds the prefill
-call that five of them lay out alike: several prompts a call, in rows.
+``families/ouro.py``, ``families/glm_dsa.py``): a request holds a lane while
+its prompt is read, admission claims the lane's slot and pages of a
+``HybridStatePool`` (and zeroes what the pool says a new occupant must not
+inherit), lane churn patches the device's lane vectors, one decode step is kept
+in flight, and the options none of them can honour. ``RowPrefillFamily`` adds
+the prefill call that six of them lay out alike: several prompts a call, in
+rows.
 ``PagesAndRingsFamily`` adds the pool of two of them: pages for the full
 layers, a ring a lane for the window layers. What differs stays with the
 family: the state's description and the jitted programs. A family's file
@@ -252,14 +253,14 @@ class RowPrefillFamily(SlotStateFamily):
     """A ``SlotStateFamily`` whose attention layers over pages are
     ``models/paged_layers.py``'s (``paged_attn_layers`` of them, which
     ``build`` sets; a family whose paged attention walks no work list,
-    ``families/keye.py``, counts what it reads in a ``count_attended`` of
-    its own) and whose prefill call runs ``prefill_chunk_tokens``
-    positions as ``rows`` rows of ``row_tokens`` tokens (``build`` sets
-    both; a row is ``row_length`` tokens: one page, unless the family's
-    mixer has a chunk of its own). The prompts being read take rows in the
-    order they were admitted, each as many as its remaining tokens need
-    while rows are left, so a call holds several prompts, a long prompt
-    advances by several rows in one call and no prompt is padded by more
+    ``families/keye.py`` or ``families/glm_dsa.py``, counts what it reads in a
+    ``count_attended`` of its own) and whose prefill call runs
+    ``prefill_chunk_tokens`` positions as ``rows`` rows of ``row_tokens``
+    tokens (``build`` sets both; a row is ``row_length`` tokens: one page,
+    unless the family's mixer has a chunk of its own). The prompts being read
+    take rows in the order they were admitted, each as many as its remaining
+    tokens need while rows are left, so a call holds several prompts, a long
+    prompt advances by several rows in one call and no prompt is padded by more
     than a row. A call is held back, for at most ``PREFILL_HOLD_STEPS``
     steps and only while lanes decode, until the prompts waiting fill its
     rows. The program takes ``(params, state, ids [R, T], slots [R], starts

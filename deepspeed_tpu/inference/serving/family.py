@@ -37,6 +37,7 @@ and imports no sibling either.
 
 import numpy as np
 
+from deepspeed_tpu.models.glm_dsa import GlmDsaConfig
 from deepspeed_tpu.models.keye import KeyeConfig
 from deepspeed_tpu.models.kimi_linear import KimiLinearConfig
 from deepspeed_tpu.models.laguna import LagunaConfig
@@ -172,5 +173,9 @@ def family_for(model_config):
     if isinstance(model_config, OuroConfig):
         from deepspeed_tpu.inference.serving.families.ouro import OuroFamily
         return OuroFamily(model_config)
+    if isinstance(model_config, GlmDsaConfig):
+        from deepspeed_tpu.inference.serving.families.glm_dsa import (
+            GlmDsaFamily)
+        return GlmDsaFamily(model_config)
     from deepspeed_tpu.inference.serving.families.gpt2 import GPT2Family
     return GPT2Family(model_config)

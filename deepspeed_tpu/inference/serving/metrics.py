@@ -157,6 +157,7 @@ class ServingMetrics:
         self.decode_ring_positions = 0
         self.dsa_keys_scored = 0
         self.dsa_keys_attended = 0
+        self.dsa_layers_shared_attended = 0
         self.dsa_prefill_blocks_walked = 0
         self.dsa_prefill_blocks_dense = 0
         self.dsa_decode_blocks_walked = 0
@@ -242,14 +243,18 @@ class ServingMetrics:
         window layers (``decode_ring_positions``)."""
         self.decode_ring_positions += int(positions)
 
-    def record_selected(self, keys_scored, keys_attended):
+    def record_selected(self, keys_scored, keys_attended, shared_attended=0):
         """One decode step of a family whose attention reads what a learned
-        indexer selects: the keys its indexers scored, summed over layers
-        and active lanes (every position a lane holds), and the keys its
-        attention then read (``min(context, topk)`` a lane a layer):
-        ``dsa_keys_scored``, ``dsa_keys_attended``."""
+        indexer selects: the keys its indexers scored, summed over the
+        layers that score and active lanes (every position a lane holds),
+        and the keys its attention then read, summed over the layers that
+        attend (``min(context, topk)`` a lane a layer): ``dsa_keys_scored``,
+        ``dsa_keys_attended``. Where some layers attend under a selection
+        that another layer computed, ``shared_attended`` is their part of
+        the second sum: ``dsa_layers_shared_attended``."""
         self.dsa_keys_scored += int(keys_scored)
         self.dsa_keys_attended += int(keys_attended)
+        self.dsa_layers_shared_attended += int(shared_attended)
 
     def record_prefill_blocks(self, walked, dense):
         """One prefill call of such a family: the key blocks its rows' own
@@ -586,6 +591,7 @@ class ServingMetrics:
             "decode_ring_positions": self.decode_ring_positions,
             "dsa_keys_scored": self.dsa_keys_scored,
             "dsa_keys_attended": self.dsa_keys_attended,
+            "dsa_layers_shared_attended": self.dsa_layers_shared_attended,
             "dsa_prefill_blocks_walked": self.dsa_prefill_blocks_walked,
             "dsa_prefill_blocks_dense": self.dsa_prefill_blocks_dense,
             "dsa_decode_blocks_walked": self.dsa_decode_blocks_walked,

@@ -219,14 +219,6 @@ def _rotate(p, cfg):
     return rotate
 
 
-def _layer_norm(x, p, eps):
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x32 - mu), -1, keepdims=True)
-    return ((x32 - mu) * jax.lax.rsqrt(var + eps)
-            * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32))
-
-
 def indexer_project(p, cfg, x, positions):
     """The indexer's inputs for ``x [..., d]`` at ``positions [...]``: ``qI
     [..., 16, 64]`` and ``kI [..., 64]`` in ``x``'s type (as the cache
@@ -234,74 +226,20 @@ def indexer_project(p, cfg, x, positions):
     ni, hi = cfg.indexer_num_heads, cfg.indexer_head_dim
     with jax.named_scope("dsa_project"):
         qI = pl.dot(x, p["wq"]["kernel"]).reshape(x.shape[:-1] + (ni, hi))
-        kI = _layer_norm(pl.dot(x, p["wk"]["kernel"]), p["k_norm"],
-                         cfg.rms_norm_eps)
+        kI = pl.layer_norm(pl.dot(x, p["wk"]["kernel"]), p["k_norm"],
+                           cfg.rms_norm_eps)
         qI = pl.apply_rope(cfg.rope, qI, positions).astype(x.dtype)
         kI = pl.apply_rope(cfg.rope, kI[..., None, :], positions)[..., 0, :]
         w = pl.dot(x, p["weights_proj"]["kernel"]) * (ni * hi) ** -0.5
     return qI, kI.astype(x.dtype), w
 
 
-def _sortable(s):
-    """float32 -> int32 whose order, and whose equality, are the floats'
-    (the two zeros are one number)."""
-    i = jax.lax.bitcast_convert_type(jnp.where(s == 0, 0.0, s), jnp.int32)
-    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
-
-
-_LOWEST = -(2 ** 31)        # below every score's image, -inf's too
-
-
-_BITS = 3                   # bits of the threshold a pass over the scores fixes
-
-
-def kth_largest(u, k):
-    """The ``k``-th largest of each row of ``u [..., S]`` int32 (``k
-    [...]``, at least 1 and at most ``S``), exactly: the value ``t`` with
-    ``count(u >= t) >= k > count(u > t)``, found from its highest bit down,
-    ``_BITS`` bits a pass. A pass reads the row once and counts it against
-    the ``2 ** _BITS - 1`` thresholds that split what is left of the range
-    (the passes are bound by reading the scores, not by comparing them, so
-    eleven passes of seven counts cost a third of thirty-two of one). Ties
-    do not matter to it."""
-    lo = jnp.full(k.shape, _LOWEST, jnp.int32)   # count(u >= lo) >= k, always
-    for left in range(32, 0, -_BITS):            # bits not yet fixed
-        take = min(_BITS, left)
-        step = 1 << (left - take)
-        # the thresholds of one pass, upwards in steps that fit 32 bits: the
-        # counts fall as they rise, so the last one that passes stands
-        best = t = lo
-        for _ in range((1 << take) - 1):
-            t = t + step
-            enough = jnp.sum((u >= t[..., None]).astype(jnp.int32),
-                             axis=-1) >= k
-            best = jnp.where(enough, t, best)
-        lo = best
-    return lo
-
-
-def index_scores(qI, w, keys):
-    """``I [..., S]`` float32 of queries ``qI [..., 16, 64]`` with head
-    weights ``w [..., 16]`` against ``keys [..., n, 64, p]`` (``n`` pages of
-    ``p`` tokens, ``S = n p``): the products in the operands' type with
-    float32 accumulation, relu, weights and the sum over heads in float32."""
-    dots = jnp.einsum("...jd,...ndp->...jnp", qI, keys,
-                      preferred_element_type=jnp.float32)
-    dots = dots.reshape(dots.shape[:-2] + (-1,))
-    return jnp.sum(w[..., None] * jax.nn.relu(dots), axis=-2)
-
-
-def select_topk(s, positions, topk):
-    """The decode step's selection: of ``s [B, S]`` (a score a cached
-    position) the ``min(topk, position + 1)`` largest among positions ``0 ..
-    position``, exactly, the lower position first among equal scores
-    (``lax.top_k``). Returns ``(at [B, K] positions, chosen [B, K] bool)``
-    with ``K = min(topk, S)``; where a lane holds fewer than ``K``
-    positions the rest of its row is not ``chosen``."""
-    held = jnp.arange(s.shape[1])[None, :] <= positions[:, None]
-    best, at = jax.lax.top_k(jnp.where(held, s, -jnp.inf),
-                             min(topk, s.shape[1]))
-    return at, best > -jnp.inf
+# The indexer's arithmetic and the exact top-k two ways are
+# ``paged_layers``'s (shared with ``models/glm_dsa.py``), under the names
+# they had here.
+_LOWEST, _sortable, _selected = pl.LOWEST, pl.sortable, pl.selected
+kth_largest, index_scores = pl.kth_largest, pl.index_scores
+select_topk, prefill_key_span = pl.select_topk, pl.prefill_key_span
 
 
 # -- attention under a selection ---------------------------------------------
@@ -313,30 +251,6 @@ def _tiles(cfg, k, v, dtype):
     lead = k.shape[:-1]
     return jnp.concatenate([k.reshape(lead + (kvh, hd)),
                             v.reshape(lead + (kvh, hd))], axis=-2).astype(dtype)
-
-
-def prefill_key_span(page_tokens):
-    """Positions in a key block of the prefill walk (whole pages)."""
-    return max(1, pl.PREFILL_KEY_BLOCK // page_tokens) * page_tokens
-
-
-def _selected(j, uj, least, ties_left, ties_before, below):
-    """The selection within key block ``j``, ``bool [span, T]`` (keys first,
-    the row's queries last: as ``ops/paged_prefill.py`` takes a mask): a key
-    whose score ``uj [span, T]`` is above its query's threshold ``least [1,
-    T]``, and of the keys exactly at it the first ``ties_left [1, T]`` of the
-    whole row, ``ties_before [blocks, T]`` of which lie in earlier blocks
-    (``below [span, span]`` is ``i >= j``: a product with it counts a block's
-    ties up to each key, exactly). A key after the query scored ``-inf``,
-    which is below every threshold. Traced inside ``paged_prefill``'s
-    kernel, and by the plain walk."""
-    tie = uj == least
-    nth = jnp.dot(below, tie.astype(below.dtype),
-                  preferred_element_type=jnp.float32).astype(jnp.int32)
-    here = jax.lax.broadcasted_iota(jnp.int32, ties_before.shape, 0) == j
-    nth = nth + jnp.sum(jnp.where(here, ties_before, 0), axis=0,
-                        keepdims=True)
-    return (uj > least) | (tie & (nth <= ties_left))
 
 
 def _attend_blocks(q, kv_pool, n, tables, bp, n_blocks, selection):
@@ -384,23 +298,11 @@ def attend_selected(q, kv_pool, n, tables, bp, starts, lens, u, topk):
     that keeps a block's scores in VMEM and walks each row's own blocks
     (``ops/paged_prefill.py``); anywhere else ``_attend_blocks``. Returns
     the context ``[R, KV, J, T, hd]``."""
-    R, T = q.shape[:2]
+    T = q.shape[1]
     span = bp * T
     pos = starts[:, None] + jnp.arange(T)[None, :]                   # [R, T]
     with jax.named_scope("dsa_select"):
-        take = jnp.minimum(topk, pos + 1)                            # [R, T]
-        least = kth_largest(u, take)[..., None]
-        # of the keys that score exactly ``least`` only the first few are
-        # taken, lower positions first: as many as the keys above it leave
-        # of ``take``; counted a block here and a key inside its block
-        ties_left = take - jnp.sum((u > least).astype(jnp.int32), axis=-1)
-        ties = jnp.sum((u == least).reshape(R, T, -1, span)
-                       .astype(jnp.int32), axis=-1)
-        ties_before = jnp.cumsum(ties, axis=-1) - ties       # [R, T, blocks]
-        # as ``_selected`` takes them: keys first, the row's queries last
-        selection = (jnp.swapaxes(u, 1, 2), jnp.swapaxes(least, 1, 2),
-                     ties_left[:, None, :], jnp.swapaxes(ties_before, 1, 2),
-                     jnp.tril(jnp.ones((span, span), q.dtype)))  # [i >= j]
+        selection = pl.row_selection(u, pos, topk, span, q.dtype)
     with jax.named_scope("dsa_attend"):
         if paged_prefill.usable(q, kv_pool):
             # a row walks its own prompt's blocks; none, where it is empty
@@ -460,21 +362,8 @@ def dsa_prefill(p, cfg, x, kv_pool, ik_pool, n, page_tables, starts, lens,
     end = jnp.max(jnp.where(lens > 0, starts + lens, 0))
     n_blocks = (end + span - 1) // span
 
-    def pages_of(j):
-        return jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
-
-    def score(j, u):
-        keys = ik_pool[n, pages_of(j)].astype(x.dtype)       # [R, bp, 64, pt]
-        s = index_scores(qI, w, keys[:, None])               # [R, T, span]
-        kpos = j * span + jnp.arange(span)
-        s = jnp.where(kpos[None, None, :] <= pos[:, :, None], s, -jnp.inf)
-        return jax.lax.dynamic_update_slice_in_dim(u, _sortable(s), j * span,
-                                                   axis=2)
-
     with jax.named_scope("dsa_index"):
-        u = jax.lax.fori_loop(
-            0, n_blocks, score,
-            jnp.full((R, T, tables.shape[1] * pt), _LOWEST, jnp.int32))
+        u = pl.index_rows(qI, w, ik_pool, n, tables, bp, pos, n_blocks)
     ctx = attend_selected(q, kv_pool, n, tables, bp, starts, lens, u,
                           cfg.topk)
     ctx = jnp.moveaxis(ctx, 3, 1).reshape(R, T, kvh * J * hd)
@@ -547,12 +436,7 @@ def dsa_decode(p, cfg, x, kv_pool, ik_pool, n, page_tables, positions, active,
     with jax.named_scope("dsa_select"):
         at, chosen = select_topk(s, positions, cfg.topk)             # [B, K]
     with jax.named_scope("dsa_attend"):
-        # the page of each chosen position, by comparison with every entry
-        # of the lane's table (a gather of 2,048 single integers a lane
-        # takes the chip a millisecond a layer, this a fiftieth of it)
-        page = jnp.sum(jnp.where(
-            (at // pt)[:, :, None] == jnp.arange(mp)[None, None, :],
-            page_tables[:, None, :], 0), axis=-1)
+        page = pl.pages_of(at, page_tables, pt)
         tiles = kv_pool[n, page, at % pt].astype(x.dtype)    # [B, K, 2KV, hd]
         ctx = attend_chosen(q, tiles, chosen, positions, active)
     ctx = ctx.reshape(Bn, -1)

@@ -298,6 +298,172 @@ def gqa_prefill(p, cfg, x, k_pool, v_pool, n, page_tables, starts, lens,
             k_pool, v_pool)
 
 
+# -- attention under a learned selection --------------------------------------
+# What the decoders whose attention reads only the positions an indexer
+# selects have in common (``keye``, ``glm_dsa``): the index scores, the exact
+# top-k two ways (a threshold by bisection for a prefill row's queries,
+# ``lax.top_k`` for a decode step's one query a lane) and the page of a chosen
+# position. The indexer's keys lie ``[rows, pages, head, page_tokens]``, a
+# page's tokens last, because every one of them is read.
+
+def layer_norm(x, p, eps):
+    """LayerNorm with ``p["scale"]`` and ``p["bias"]``, float32 out."""
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mu), -1, keepdims=True)
+    return ((x32 - mu) * jax.lax.rsqrt(var + eps)
+            * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32))
+
+
+def sortable(s):
+    """float32 -> int32 whose order, and whose equality, are the floats'
+    (the two zeros are one number)."""
+    i = jax.lax.bitcast_convert_type(jnp.where(s == 0, 0.0, s), jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+LOWEST = -(2 ** 31)         # below every score's image, -inf's too
+
+
+_BITS = 3                   # bits of the threshold a pass over the scores fixes
+
+
+def kth_largest(u, k):
+    """The ``k``-th largest of each row of ``u [..., S]`` int32 (``k
+    [...]``, at least 1 and at most ``S``), exactly: the value ``t`` with
+    ``count(u >= t) >= k > count(u > t)``, found from its highest bit down,
+    ``_BITS`` bits a pass. A pass reads the row once and counts it against
+    the ``2 ** _BITS - 1`` thresholds that split what is left of the range
+    (the passes are bound by reading the scores, not by comparing them, so
+    eleven passes of seven counts cost a third of thirty-two of one). Ties
+    do not matter to it."""
+    lo = jnp.full(k.shape, LOWEST, jnp.int32)    # count(u >= lo) >= k, always
+    for left in range(32, 0, -_BITS):            # bits not yet fixed
+        take = min(_BITS, left)
+        step = 1 << (left - take)
+        # the thresholds of one pass, upwards in steps that fit 32 bits: the
+        # counts fall as they rise, so the last one that passes stands
+        best = t = lo
+        for _ in range((1 << take) - 1):
+            t = t + step
+            enough = jnp.sum((u >= t[..., None]).astype(jnp.int32),
+                             axis=-1) >= k
+            best = jnp.where(enough, t, best)
+        lo = best
+    return lo
+
+
+def index_scores(qI, w, keys):
+    """``I [..., S]`` float32 of indexer queries ``qI [..., heads, hi]``
+    with head weights ``w [..., heads]`` against ``keys [..., n, hi, p]``
+    (``n`` pages of ``p`` tokens, ``S = n p``): the products in the
+    operands' type with float32 accumulation, relu, weights and the sum over
+    heads in float32."""
+    dots = jnp.einsum("...jd,...ndp->...jnp", qI, keys,
+                      preferred_element_type=jnp.float32)
+    dots = dots.reshape(dots.shape[:-2] + (-1,))
+    return jnp.sum(w[..., None] * jax.nn.relu(dots), axis=-2)
+
+
+def select_topk(s, positions, topk):
+    """The decode step's selection: of ``s [B, S]`` (a score a cached
+    position) the ``min(topk, position + 1)`` largest among positions ``0 ..
+    position``, exactly, the lower position first among equal scores
+    (``lax.top_k``). Returns ``(at [B, K] positions, chosen [B, K] bool)``
+    with ``K = min(topk, S)``; where a lane holds fewer than ``K``
+    positions the rest of its row is not ``chosen``."""
+    held = jnp.arange(s.shape[1])[None, :] <= positions[:, None]
+    best, at = jax.lax.top_k(jnp.where(held, s, -jnp.inf),
+                             min(topk, s.shape[1]))
+    return at, best > -jnp.inf
+
+
+def pages_of(at, page_tables, page_tokens):
+    """The physical page of each position ``at [B, K]`` under ``page_tables
+    [B, mp]``, by comparison with every entry of the lane's table (a gather
+    of 2,048 single integers a lane takes the chip a millisecond a layer,
+    this a fiftieth of it)."""
+    mp = page_tables.shape[1]
+    return jnp.sum(jnp.where(
+        (at // page_tokens)[:, :, None] == jnp.arange(mp)[None, None, :],
+        page_tables[:, None, :], 0), axis=-1)
+
+
+def prefill_key_span(page_tokens):
+    """Positions in a key block of the prefill walk (whole pages)."""
+    return max(1, PREFILL_KEY_BLOCK // page_tokens) * page_tokens
+
+
+def index_rows(qI, w, ik_pool, n, tables, bp, pos, n_blocks):
+    """A prefill call's index scores: queries ``qI [R, T, heads, hi]`` with
+    head weights ``w [R, T, heads]`` at positions ``pos [R, T]`` against row
+    ``n`` of ``ik_pool [rows, pages, hi, pt]`` under ``tables [R, blocks *
+    bp]``, a block of ``bp`` pages at a time for ``n_blocks`` blocks.
+    Returns ``u [R, T, S]`` int32, the scores' sortable images (``S`` the
+    tables' positions); a key after its query, and every key beyond the
+    blocks scored, holds ``-inf``'s image or less."""
+    R, T = pos.shape
+    pt = ik_pool.shape[-1]
+    span = bp * pt
+
+    def score(j, u):
+        pages = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+        keys = ik_pool[n, pages].astype(qI.dtype)            # [R, bp, hi, pt]
+        s = index_scores(qI, w, keys[:, None])               # [R, T, span]
+        kpos = j * span + jnp.arange(span)
+        s = jnp.where(kpos[None, None, :] <= pos[:, :, None], s, -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(u, sortable(s), j * span,
+                                                   axis=2)
+
+    return jax.lax.fori_loop(
+        0, n_blocks, score,
+        jnp.full((R, T, tables.shape[1] * pt), LOWEST, jnp.int32))
+
+
+def selected(j, uj, least, ties_left, ties_before, below):
+    """The selection within key block ``j``, ``bool [span, T]`` (keys first,
+    the row's queries last: as ``ops/paged_prefill.py`` takes a mask): a key
+    whose score ``uj [span, T]`` is above its query's threshold ``least [1,
+    T]``, and of the keys exactly at it the first ``ties_left [1, T]`` of the
+    whole row, ``ties_before [blocks, T]`` of which lie in earlier blocks
+    (``below [span, span]`` is ``i >= j``: a product with it counts a block's
+    ties up to each key, exactly). A key after the query scored ``-inf``,
+    which is below every threshold. Traced inside ``paged_prefill``'s
+    kernel, and by the plain walks."""
+    tie = uj == least
+    nth = jnp.dot(below, tie.astype(below.dtype),
+                  preferred_element_type=jnp.float32).astype(jnp.int32)
+    here = jax.lax.broadcasted_iota(jnp.int32, ties_before.shape, 0) == j
+    nth = nth + jnp.sum(jnp.where(here, ties_before, 0), axis=0,
+                        keepdims=True)
+    return (uj > least) | (tie & (nth <= ties_left))
+
+
+def row_selection(u, pos, topk, span, dtype):
+    """What ``selected`` needs of a prefill call's rows, from their index
+    scores ``u [R, T, S]`` (sortable) at positions ``pos [R, T]``: each
+    query's ``min(topk, position + 1)``-th largest score (``kth_largest``)
+    and how the keys that score exactly that are shared out, lower positions
+    first. Returns ``selected``'s operands after ``j``, a row each, keys
+    first and the row's queries last: ``(u [R, S, T], least [R, 1, T],
+    ties_left [R, 1, T], ties_before [R, blocks, T], below [span, span])``
+    with blocks of ``span`` keys."""
+    R, T = pos.shape
+    take = jnp.minimum(topk, pos + 1)                                # [R, T]
+    least = kth_largest(u, take)[..., None]
+    # of the keys that score exactly ``least`` only the first few are
+    # taken, lower positions first: as many as the keys above it leave
+    # of ``take``; counted a block here and a key inside its block
+    ties_left = take - jnp.sum((u > least).astype(jnp.int32), axis=-1)
+    ties = jnp.sum((u == least).reshape(R, T, -1, span)
+                   .astype(jnp.int32), axis=-1)
+    ties_before = jnp.cumsum(ties, axis=-1) - ties           # [R, T, blocks]
+    # as ``selected`` takes them: keys first, the row's queries last
+    return (jnp.swapaxes(u, 1, 2), jnp.swapaxes(least, 1, 2),
+            ties_left[:, None, :], jnp.swapaxes(ties_before, 1, 2),
+            jnp.tril(jnp.ones((span, span), dtype)))             # [i >= j]
+
+
 def decode_key_span(page_tokens):
     """Keys in one block of a lane's decode attention: the pages that hold
     ``DECODE_KEY_BLOCK`` tokens, at least one."""

@@ -78,12 +78,12 @@ def test_the_loop_imports_no_model_code():
 
 MODELS = os.path.join(REPO_ROOT, "deepspeed_tpu", "models")
 SLOT_STATE_MODELS = ("kimi_linear", "nemotron_h", "laguna", "mimo_v2", "keye",
-                     "ouro")
+                     "ouro", "glm_dsa")
 
 
 @pytest.mark.parametrize("module", SLOT_STATE_MODELS + ("paged_layers",))
 def test_a_slot_state_model_imports_no_sibling(module):
-    """The layers the six decoders share are ``models/paged_layers.py``'s:
+    """The layers the seven decoders share are ``models/paged_layers.py``'s:
     a model's file imports it and no other model's, and it imports none of
     them, so a new family adds files under ``models/`` and edits none."""
     path = os.path.join(MODELS, module + ".py")
@@ -112,7 +112,8 @@ def test_a_familys_file_imports_no_other_familys():
 def test_there_are_families_to_hold_to_the_contract():
     names = {os.path.basename(p) for p in FAMILY_FILES}
     assert {"gpt2.py", "kimi_linear.py", "nemotron_h.py", "laguna.py",
-            "mimo_v2.py", "keye.py", "ouro.py", "slot_state.py"} <= names, names
+            "mimo_v2.py", "keye.py", "ouro.py", "glm_dsa.py",
+            "slot_state.py"} <= names, names
 
 
 @pytest.mark.parametrize(
@@ -219,6 +220,8 @@ HOMES = {
     "_keye_prefill_chunk_jit": "families/keye.py",
     "_ouro_decode_step_jit": "families/ouro.py",
     "_ouro_prefill_chunk_jit": "families/ouro.py",
+    "_glm_decode_step_jit": "families/glm_dsa.py",
+    "_glm_prefill_chunk_jit": "families/glm_dsa.py",
     "_install_pages": "kv_pool.py",
     "_zero_slot": "kv_pool.py",
 }

@@ -1,4 +1,4 @@
-"""The slot-state serving programs (two a family: ten of the five families
+"""The slot-state serving programs (two a family: twelve of the six families
 whose layers are unrolled, two of the looped one) as lowered for a TPU at
 their cells' own shapes: one sha256 a program, of the StableHLO text with
 the Mosaic kernels' bodies stripped of source locations. A refactor that
@@ -98,7 +98,7 @@ def cell_programs(cfg):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--out", help="directory to keep the ten texts in")
+    ap.add_argument("--out", help="directory to keep the texts in")
     args = ap.parse_args()
     forced = jax.default_backend() != "tpu"
     if forced:
