@@ -17,6 +17,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.serving.families.slot_state import (
     RowPrefillFamily,
+    count_prefill_blocks,
 )
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import glm_dsa as gd
@@ -79,6 +80,14 @@ class GlmDsaFamily(RowPrefillFamily):
         loop.metrics.record_state_pool(0, 0, pool.slot_bytes(),
                                        pool.paged_bytes())
         return params, pool
+
+    def count_prefill(self, starts, lens):
+        """The key blocks the call's walks of latent pages reach, every
+        layer's: the layers that select and those that attend under another
+        layer's selection walk alike."""
+        count_prefill_blocks(self.loop.metrics, starts, lens,
+                             page_tokens=self.row_tokens,
+                             layers=self.cfg.num_hidden_layers)
 
     def count_attended(self, held):
         """What the step's indexers score and what its attention then

@@ -16,6 +16,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.serving.families.slot_state import (
     RowPrefillFamily,
+    count_prefill_blocks,
 )
 from deepspeed_tpu.inference.serving.kv_pool import HybridStatePool
 from deepspeed_tpu.models import keye as ky
@@ -39,18 +40,6 @@ def _keye_decode_step_jit(params, state, tokens, positions, active,
         params, cfg, state, tokens, positions, active, page_tables,
         page_tokens=page_tokens)
     return state, tokens, positions, logits if keep_logits else None, moe
-
-
-def count_prefill_blocks(metrics, starts, lens, *, page_tokens, layers):
-    """What a prefill call's attention walks, from the rows the host laid
-    out (``starts [R]``, ``lens [R]``, 0 an empty row): the key blocks each
-    row's own prompt reaches, and every row to the longest one's end, each
-    summed over ``layers`` (``ServingMetrics.record_prefill_blocks``)."""
-    span = ky.prefill_key_span(page_tokens)
-    blocks = np.where(lens > 0, -(-(starts.astype(np.int64) + lens) // span),
-                      0)
-    metrics.record_prefill_blocks(layers * blocks.sum(),
-                                  layers * len(blocks) * blocks.max())
 
 
 def count_decode_blocks(metrics, context, *, topk, lanes, table_positions,

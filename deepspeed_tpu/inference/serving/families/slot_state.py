@@ -32,7 +32,10 @@ from deepspeed_tpu.inference.serving.kv_pool import (
     HybridStatePool,
     PoolExhaustedError,
 )
-from deepspeed_tpu.models.paged_layers import decode_key_span
+from deepspeed_tpu.models.paged_layers import (
+    decode_key_span,
+    prefill_key_span,
+)
 
 
 @jax.jit  # jaxlint: hot
@@ -42,6 +45,19 @@ def _patch_lanes_jit(tokens, positions, joined, new_tokens, new_positions):
     ahead of the host while a decode step is in flight."""
     return (jnp.where(joined, new_tokens, tokens),
             jnp.where(joined, new_positions, positions))
+
+
+def count_prefill_blocks(metrics, starts, lens, *, page_tokens, layers):
+    """What a prefill call's attention walks under a selection, from the
+    rows the host laid out (``starts [R]``, ``lens [R]``, 0 an empty row):
+    the key blocks each row's own prompt reaches, and every row to the
+    longest one's end, each summed over the ``layers`` that attend
+    (``ServingMetrics.record_prefill_blocks``)."""
+    span = prefill_key_span(page_tokens)
+    blocks = np.where(lens > 0, -(-(starts.astype(np.int64) + lens) // span),
+                      0)
+    metrics.record_prefill_blocks(layers * blocks.sum(),
+                                  layers * len(blocks) * blocks.max())
 
 
 class Prefilling:

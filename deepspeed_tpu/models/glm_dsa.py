@@ -56,7 +56,9 @@ serving engine jits, with the arguments of every slot-state family's:
   ``full`` layer also writes the row's indexer keys, scores the rows' queries
   against their prompts' indexer keys (``dsa_index``) and finds each query's
   threshold (``dsa_select``); every layer walks the latent pages a block at
-  a time under the selection as its mask (``mla_attend``).
+  a time under the selection as its mask (``mla_attend``: on a TPU one
+  kernel a layer, ``ops/paged_prefill.py::attend_latent``, in which a row
+  walks its own blocks; elsewhere ``_attend_blocks``).
 - ``decode_step``: one token for every active lane. A ``full`` layer scores
   the lane's indexer keys and takes the exact top ``index_topk``; every layer
   **fetches those positions' latent rows and no others** (``dsa_fetch``: a
@@ -82,6 +84,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models import paged_layers as pl
+from deepspeed_tpu.ops import paged_prefill
 from deepspeed_tpu.ops.column_write import write_columns
 from deepspeed_tpu.parallel import expert as expert_mod
 
@@ -407,8 +410,11 @@ def _attend_blocks(cfg, q, latent, n, tables, bp, n_blocks, selection):
     blocks of ``bp`` pages of row ``n`` of ``latent [L, pages, pt,
     latent_row]`` under the selection (``paged_layers.selected``'s operands,
     a row each), every row to the call's longest: a block's latent rows are the
-    keys as they lie and, their first ``rank`` values, the values. Returns
-    the weighted latent rows ``[R, heads, T, rank]`` float32."""
+    keys as they lie and, their first ``rank`` values, the values. The walk
+    in plain operations, the twin of ``paged_prefill.attend_latent`` for
+    every backend but a TPU (and every shape but its own): a block's float32
+    scores and the accumulator ``[R, heads, T, span]`` are arrays in memory
+    here. Returns the weighted latent rows ``[R, heads, T, rank]`` float32."""
     R, T, nh, _ = q.shape
     rank = cfg.kv_lora_rank
     u, least, ties_left, ties_before, below = selection
@@ -432,6 +438,27 @@ def _attend_blocks(cfg, q, latent, n, tables, bp, n_blocks, selection):
         return s, weigh
 
     return pl.online_softmax_loop(n_blocks, block, (R, nh, T), rank)
+
+
+def attend_selected(cfg, q, latent, n, tables, bp, n_blocks, starts, lens,
+                    selection):
+    """The walk of ``mla_prefill``: ``q [R, T, heads, latent_row]``
+    (absorbed) attends, of row ``n`` of ``latent`` under ``tables [R, blocks
+    * bp]``, the keys ``selection`` names (``paged_layers.row_selection``'s
+    tuple for these queries). On a TPU at the shapes it takes, one kernel a
+    layer that keeps a block's scores and the accumulator in VMEM and walks
+    each row's own blocks (``ops/paged_prefill.py::attend_latent``); anywhere
+    else ``_attend_blocks``, every row to the ``n_blocks`` of the call's
+    longest. Returns the weighted latent rows ``[R, heads, T, rank]``."""
+    if paged_prefill.latent_usable(q, latent, cfg.kv_lora_rank):
+        span = bp * q.shape[1]
+        # a row walks its own prompt's blocks; none, where it is empty
+        counts = jnp.where(lens > 0, (starts + lens + span - 1) // span, 0)
+        return paged_prefill.attend_latent(
+            q, latent, n, tables, counts, pl.selected, keyed=selection[:1],
+            rowed=selection[1:4], shared=selection[4:], block_pages=bp,
+            rank=cfg.kv_lora_rank, scale=_softmax_scale(cfg))
+    return _attend_blocks(cfg, q, latent, n, tables, bp, n_blocks, selection)
 
 
 def mla_prefill(p, cfg, x, latent, ik, rows, page_tables, starts, lens,
@@ -483,8 +510,8 @@ def mla_prefill(p, cfg, x, latent, ik, rows, page_tables, starts, lens,
             selection = pl.row_selection(u, pos, cfg.index_topk, span,
                                          x.dtype)
     with _carried(m is None), jax.named_scope("mla_attend"):
-        ctx_lat = _attend_blocks(cfg, q, latent, n, tables, bp, n_blocks,
-                                 selection)
+        ctx_lat = attend_selected(cfg, q, latent, n, tables, bp, n_blocks,
+                                  starts, lens, selection)
         y = mla_output(p, cfg, jnp.swapaxes(ctx_lat, 1, 2), x.dtype)
     return y, latent, ik, selection
 

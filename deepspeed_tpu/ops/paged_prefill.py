@@ -1,10 +1,15 @@
-"""Attention over a cache whose tokens are tiles, a token's key heads and
-value heads together as one ``(rows, hd)`` tile, as two kernels that read the
-tiles where they lie and take a head out of them in VMEM (``_head``):
-``attend_pages``, rows of queries over their prompts' pages under a mask the
-caller forms (a prefill call: a block's scores never leave the chip's VMEM),
-and ``attend_tiles``, one query a lane over the tiles a selection gathered (a
-decode step: the gathered array is never laid out again).
+"""Attention over a paged cache as kernels that read the cache where it
+lies. Two are for a cache whose tokens are tiles, a token's key heads and
+value heads together as one ``(rows, hd)`` tile, and take a head out of
+them in VMEM (``_head``): ``attend_pages``, rows of queries over their
+prompts' pages under a mask the caller forms (a prefill call: a block's
+scores never leave the chip's VMEM), and ``attend_tiles``, one query a lane
+over the tiles a selection gathered (a decode step: the gathered array is
+never laid out again). The third, ``attend_latent``, is ``attend_pages``'s
+walk for a cache whose tokens are latent rows (absorbed MLA: one row is
+every head's key and, its first columns, every head's value), with the
+same grid plumbing (``_block_of``, ``_mask_specs``, ``_MASKED``) and a body
+of its own: there is no head to unpack.
 
 A chunked prefill call attends ``R`` rows of ``T`` queries, each row to its
 own prompt's cached positions, found through the row's page table. Walked in
@@ -56,6 +61,36 @@ The kernel is for a TPU and for the shapes it was compiled and measured at
 plain twin is the caller's own walk (``models/keye.py::dsa_prefill`` keeps
 it for every other backend and shape), and ``tests/unit/
 test_keye_prefill_kernel.py`` holds the two together with ``interpret=True``.
+
+``attend_latent`` is that walk where the cache holds ONE row a token for
+all heads (``models/glm_dsa.py``: ``pool [L, pages, pt, width]``, 512 latent
+values, a rotated key of 64 and zeros up to 640; the queries absorbed, 64
+heads as wide as the row). In plain operations (``glm_dsa.py::
+_attend_blocks``) a block of 512 keys costs a layer the float32 scores and
+the float32 accumulator ``[16, 64, 128, 512]`` through HBM, 2.4 ms for 155
+GFLOP, a third of the matrix unit (``PERF.md``, PR 50). The kernel's grid is
+``(rows, groups of LATENT_HEADS heads, key blocks)``: a step fetches the
+block's pages as they lie, ``[span, width]``, which is the key of every
+head and whose first ``rank`` columns are the value, so the block is the
+matrix unit's stationary operand of both products and neither is
+transposed: ``[heads x T, width] x [span, width]^T`` and ``[heads x T, span]
+x [span, rank]``, with the scores, the probabilities and the accumulator
+``[heads x T, rank]`` in VMEM. **Here the queries run down the sublanes**,
+the group's heads one under another, and the maxima and sums over keys run
+across the lanes: with 1,152 products a score where ``attend_pages`` has
+256 the reductions weigh little, and this form needs no relayout of the
+queries (heads ahead of a row's tokens is a move of whole rows) and none of
+the context, which leaves the kernel as the caller wants it; on the chip
+(``PERF.md``, PR 51) it took 17.7 ms a layer at the cell's shapes where the
+form with the queries on the lanes took 19.5, 2.8 of them the two relayouts
+around the kernel. The mask, the per-row bound and the mathematics are
+``attend_pages``'s word for word, the scale the caller's (it is the
+model's ``qk_head_dim``, which the row's width does not show). The pool's
+row is a scalar ahead of the grid, so a program's layers share one kernel
+body. Which calls take it is ``latent_usable``'s to say, from the call
+alone; the plain twin is ``_attend_blocks``, and ``tests/unit/
+test_glm_prefill_kernel.py`` holds the two together through
+``mla_prefill``.
 
 A decode step attends, a lane, the ``K`` positions its indexer selected,
 whose tiles one gather has laid side by side: ``tiles [B, K, rows, hd]``, in
@@ -116,6 +151,42 @@ def _block_of(i, j, counts):
     return jnp.maximum(jnp.minimum(j, counts[i] - 1), 0)
 
 
+def _start(j, m_ref, l_ref, acc_ref):
+    """At a row's (or lane's) first block: nothing seen yet."""
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+
+def _emit(j, nb, out_ref, l_ref, acc_ref):
+    """At the grid's last block: the one division, and the context out."""
+    @pl.when(j == nb - 1)
+    def _():
+        out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                        ).astype(out_ref.dtype)
+
+
+def _mask_specs(axes, span, T, keyed, rowed, shared):
+    """The block specs of a mask's operands, for a grid of ``axes`` indices
+    whose first is the row and whose last the key block, behind the two
+    scalars every walk here has ahead of its grid (the tables, then the
+    counts): ``keyed`` arrays ``[R, S, T]`` a block of ``span`` keys at a
+    time (the block ``_block_of`` names), ``rowed`` arrays ``[R, w, T]`` a
+    row at a time, ``shared`` arrays whole."""
+    def key_block(*at):
+        r, j, cnt = at[0], at[axes - 1], at[axes + 1]
+        return r, _block_of(r, j, cnt), 0
+
+    specs = [pl.BlockSpec((None, span, T), key_block) for _ in keyed]
+    specs += [pl.BlockSpec((None,) + a.shape[1:], lambda *at: (at[0], 0, 0))
+              for a in rowed]
+    specs += [pl.BlockSpec(a.shape, lambda *at, nd=a.ndim: (0,) * nd)
+              for a in shared]
+    return specs
+
+
 def _head(pages, h):
     """Row ``h`` (an index, traced or not) of every token's tile of the
     block's pages: ``[span, hd]`` float32, exactly. A page's words hold rows
@@ -159,11 +230,7 @@ def attend_pages(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
         out_ref, m_ref, l_ref, acc_ref = refs
         r, j = pl.program_id(0), pl.program_id(1)
 
-        @pl.when(j == 0)
-        def _start():
-            m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
-            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        _start(j, m_ref, l_ref, acc_ref)
 
         @pl.when(j < cnt_ref[r])
         def _walk():
@@ -191,10 +258,7 @@ def attend_pages(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
             # a loop and not four copies: a quarter of the program's text
             jax.lax.fori_loop(0, kvh, group, None)
 
-        @pl.when(j == nb - 1)
-        def _emit():
-            out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                            ).astype(out_ref.dtype)
+        _emit(j, nb, out_ref, l_ref, acc_ref)
 
     def page(i):
         return pl.BlockSpec(
@@ -205,13 +269,7 @@ def attend_pages(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
     heads = pl.BlockSpec((None, kvh, hd, rows),
                          lambda r, j, tab, cnt: (r, 0, 0, 0))
     in_specs = [heads] + [page(i) for i in range(bp)]
-    in_specs += [pl.BlockSpec((None, span, T),
-                              lambda r, j, tab, cnt: (r, _block_of(r, j, cnt), 0))
-                 for _ in keyed]
-    in_specs += [pl.BlockSpec((None,) + a.shape[1:],
-                              lambda r, j, tab, cnt: (r, 0, 0)) for a in rowed]
-    in_specs += [pl.BlockSpec(a.shape, lambda r, j, tab, cnt, nd=a.ndim:
-                              (0,) * nd) for a in shared]
+    in_specs += _mask_specs(2, span, T, keyed, rowed, shared)
     ctx = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((R, kvh, hd, rows), q.dtype),
@@ -265,11 +323,7 @@ def attend_tiles(q, tiles, chosen, counts, *, interpret=False):
                acc_ref):
         b, j = pl.program_id(0), pl.program_id(1)
 
-        @pl.when(j == 0)
-        def _start():
-            m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
-            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        _start(j, m_ref, l_ref, acc_ref)
 
         @pl.when(j < cnt_ref[b])
         def _walk():
@@ -294,10 +348,7 @@ def attend_tiles(q, tiles, chosen, counts, *, interpret=False):
 
             jax.lax.fori_loop(0, kvh, group, None)
 
-        @pl.when(j == nb - 1)
-        def _emit():
-            out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                            ).astype(out_ref.dtype)
+        _emit(j, nb, out_ref, l_ref, acc_ref)
 
     heads = pl.BlockSpec((None, kvh, J, hd), lambda b, j, cnt: (b, 0, 0, 0))
     return pl.pallas_call(
@@ -320,3 +371,110 @@ def attend_tiles(q, tiles, chosen, counts, *, interpret=False):
         interpret=interpret, name="selected_tiles_attention",
     )(counts.astype(jnp.int32), q,
       jnp.where(chosen, 0.0, _MASKED).astype(jnp.float32)[:, None, :], tiles)
+
+
+LATENT_HEADS = 16           # absorbed query heads a grid step of ``attend_latent``
+
+
+def latent_usable(q, pool, rank):
+    """Whether ``attend_latent`` takes ``q [R, T, heads, width]`` over
+    ``pool [L, pages, pt, width]`` with values of ``rank``: on a TPU, 16-bit
+    values, a page of 128 tokens that is also a row of queries, a latent
+    row and a value width that are whole 128-lane tiles, and heads in whole
+    groups of ``LATENT_HEADS``."""
+    return (_on_tpu() and q.dtype == pool.dtype == jnp.bfloat16
+            and q.shape[1] == pool.shape[2] == 128
+            and q.shape[-1] == pool.shape[-1]
+            and q.shape[-1] % 128 == 0 and rank % 128 == 0
+            and 0 < rank <= q.shape[-1]
+            and q.shape[2] % LATENT_HEADS == 0)
+
+
+def attend_latent(q, pool, n, tables, counts, allowed, keyed=(), rowed=(),
+                  shared=(), *, block_pages, rank, scale, interpret=False):
+    """``q [R, T, heads, width]`` (absorbed queries: every head reads the
+    one key row) over row ``n`` of ``pool [L, pages, pt, width]``, whose
+    rows are the keys as they lie and, their first ``rank`` values, the
+    values; ``tables``, ``counts``, ``allowed`` and its operands as
+    ``attend_pages`` takes them; scores are scaled by ``scale`` in float32.
+    A query no key is allowed in its walked blocks reads an average of their
+    values, as the plain walk gives it. Returns the weighted latent rows
+    ``[R, heads, T, rank]`` in ``q``'s type."""
+    R, T, nh, width = q.shape
+    pt = pool.shape[2]
+    bp = block_pages
+    span = bp * pt
+    nb = tables.shape[1] // bp
+    hg = LATENT_HEADS
+    groups = nh // hg
+    assert tables.shape[1] == nb * bp and nh == groups * hg, (
+        tables.shape, bp, nh, hg)
+    rows = hg * T
+
+    def kernel(tab_ref, cnt_ref, row_ref, q_ref, *refs):
+        del tab_ref, row_ref
+        pages, refs = refs[:bp], refs[bp:]
+        given, refs = refs[:-4], refs[-4:]
+        out_ref, m_ref, l_ref, acc_ref = refs
+        r, j = pl.program_id(0), pl.program_id(2)
+        _start(j, m_ref, l_ref, acc_ref)
+
+        @pl.when(j < cnt_ref[r])
+        def _walk():
+            ok = allowed(j, *(ref[...] for ref in given))
+            # one mask for the group's heads, whose queries lie one under
+            # another
+            bias = jnp.tile(jnp.where(ok, 0.0, _MASKED).astype(jnp.float32).T,
+                            (hg, 1))                         # [rows, span]
+            # the block is every head's key, and its first columns the
+            # value: the stationary operand of both products, as it lies
+            lat = jnp.concatenate([ref[0, 0] for ref in pages], axis=0)
+            # a masked key's score is below float32's sight of -1e30, so the
+            # sum is -1e30
+            s = jax.lax.dot_general(
+                q_ref[...], lat, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias
+            m = m_ref[...]                                   # [rows, 1]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            keep = jnp.exp(m - m_new)
+            pr = jnp.exp(s - m_new)
+            l_ref[...] = l_ref[...] * keep + jnp.sum(pr, 1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * keep + jnp.dot(
+                pr.astype(lat.dtype), lat[:, :rank],
+                preferred_element_type=jnp.float32)          # [rows, rank]
+            m_ref[...] = m_new
+
+        _emit(j, nb, out_ref, l_ref, acc_ref)
+
+    def page(i):
+        # the pool's row is a scalar ahead of the grid: one kernel body for
+        # every layer of a program
+        return pl.BlockSpec(
+            (1, 1) + pool.shape[2:],
+            lambda r, g, j, tab, cnt, row: (
+                row[0], tab[r, _block_of(r, j, cnt) * bp + i], 0, 0))
+
+    def heads(w):
+        return pl.BlockSpec((None, None, rows, w),
+                            lambda r, g, j, tab, cnt, row: (r, g, 0, 0))
+
+    in_specs = [heads(width)] + [page(i) for i in range(bp)]
+    in_specs += _mask_specs(3, span, T, keyed, rowed, shared)
+    ctx = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((R, groups, rows, rank), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(R, groups, nb), in_specs=in_specs,
+            out_specs=heads(rank),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, rank), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=96 * 2 ** 20),
+        interpret=interpret, name="latent_prefill_attention",
+    )(tables.astype(jnp.int32), counts.astype(jnp.int32),
+      jnp.full((1,), n, jnp.int32),
+      jnp.swapaxes(q, 1, 2).reshape(R, groups, rows, width),
+      *([pool] * bp), *keyed, *rowed, *shared)
+    return ctx.reshape(R, nh, T, rank)

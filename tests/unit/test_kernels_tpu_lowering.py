@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu import kernels
+from deepspeed_tpu.models import glm_dsa as glm_mod
 from deepspeed_tpu.models import keye as keye_mod
+from deepspeed_tpu.models import paged_layers
 from deepspeed_tpu.ops import column_write, paged_decode, paged_prefill
 from deepspeed_tpu.ops.transformer import attention as attn_mod
 from deepspeed_tpu.ops.transformer.attention import flash_attention
@@ -305,6 +307,40 @@ def test_paged_prefill_lowers_under_the_selection(monkeypatch):
     assert "tpu_custom_call" not in plain and "x4x8x128x512xf32" in plain
 
 
+def _latent_walk_args(rows=16, pages=5121, table=128):
+    """GLM-5.2's cell: 16 rows of 128 queries, 64 absorbed heads on a latent
+    row of 640, a pool of 6 layers, tables of 128 pages."""
+    i32, bf = jnp.int32, jnp.bfloat16
+    return [SDS((rows, 128, 64, 640), bf), SDS((6, pages, 128, 640), bf),
+            SDS((rows, table), i32), SDS((rows,), i32), SDS((rows,), i32),
+            SDS((rows, 128, table * 128), i32)]
+
+
+def _latent_walk_fn(q, latent, tables, starts, lens, u):
+    pos = starts[:, None] + jnp.arange(128)[None, :]
+    selection = paged_layers.row_selection(u, pos, 2048, 512, q.dtype)
+    n_blocks = (jnp.max(starts + lens) + 511) // 512
+    return glm_mod.attend_selected(glm_mod.GlmDsaConfig(), q, latent, 3,
+                                   tables, 4, n_blocks, starts, lens,
+                                   selection)
+
+
+def test_latent_prefill_lowers_under_the_selection(monkeypatch):
+    """``paged_prefill.attend_latent`` with GLM-5.2's mask traced inside it,
+    at the cell's shapes: one Mosaic call, and the lowered walk holds no
+    float32 array with the rows, the heads, the queries and a block of keys
+    (the scores) or the rank (the accumulator)."""
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
+    lowered = _lower_tpu(_latent_walk_fn, *_latent_walk_args())
+    _assert_mosaic(lowered, 1)
+    assert "16x64x128x512xf32" not in lowered.as_text()
+    monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: False)
+    # (a function of its own: the first one's trace is cached)
+    plain = _lower_tpu(lambda *args: _latent_walk_fn(*args),
+                       *_latent_walk_args()).as_text()
+    assert "tpu_custom_call" not in plain and "16x64x128x512xf32" in plain
+
+
 def _chosen_tiles_args(lanes=64, K=2048):
     """Keye-VL's cell: a decode step's 64 lanes, 2,048 selected tiles of 4
     key heads and 4 value heads each, 32 query heads."""
@@ -392,6 +428,9 @@ def test_serving_kernels_compile_for_v5e(monkeypatch):
     monkeypatch.setattr(paged_prefill, "_on_tpu", lambda: True)
     _lower_tpu(lambda *args: _prefill_walk_fn(*args),
                *place(_prefill_walk_args())).compile()
+    assert "latent_prefill_attention" in _lower_tpu(
+        lambda *args: _latent_walk_fn(*args),
+        *place(_latent_walk_args())).compile().as_text()
     text = _lower_tpu(lambda *args: keye_mod.attend_chosen(*args),
                       *place(_chosen_tiles_args())).compile().as_text()
     # the compiled program holds the kernel and no copy of a half of the
